@@ -2,7 +2,7 @@
 # cmd/agent reference — the agent entrypoint here is cmd.uav_agent).
 
 PY ?= python
-TEST_ENV = env PYTHONPATH= JAX_PLATFORMS=cpu
+TEST_ENV = env JAX_PLATFORMS=cpu
 SHELL := /bin/bash    # tier1 uses pipefail/PIPESTATUS
 
 .PHONY: run run-agent run-scheduler demo test test-fast tier1 tier1-mesh \
@@ -216,7 +216,7 @@ deploy-agent:       # build agent image, k3d import, roll out DaemonSet
 	bash scripts/build-and-deploy-uav-agent.sh
 
 dryrun:
-	env PYTHONPATH= $(PY) __graft_entry__.py 8
+	$(PY) __graft_entry__.py 8
 
 docker:
 	docker build -t k8s-llm-monitor-tpu-server:dev -f Dockerfile .
@@ -227,7 +227,7 @@ docker-agent:
 docker-scheduler:
 	docker build -t k8s-llm-monitor-tpu-scheduler:dev -f Dockerfile.scheduler .
 
-LINT_PATHS = k8s_llm_monitor_tpu tests bench.py __graft_entry__.py
+LINT_PATHS = k8s_llm_monitor_tpu tests bench.py chip_smoke.py __graft_entry__.py
 
 lint:               # compileall + graftcheck always; ruff/mypy when installed
 	$(PY) -m compileall -q k8s_llm_monitor_tpu

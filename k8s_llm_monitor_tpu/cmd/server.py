@@ -173,14 +173,14 @@ def main(argv: list[str] | None = None) -> int:
         # Persistent XLA compilation cache BEFORE any jit runs: a warm
         # restart reuses compiled prefill/decode programs (~seconds)
         # instead of recompiling the full ladder (~minutes on 8B).
-        import jax
+        from k8s_llm_monitor_tpu.utils.compile_cache import (
+            configure_compile_cache,
+        )
 
-        jax.config.update("jax_compilation_cache_dir",
-                          config.llm.tpu.compile_cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        log.info("XLA compilation cache at %s",
-                 config.llm.tpu.compile_cache_dir)
+        cache_dir, warm = configure_compile_cache(
+            config.llm.tpu.compile_cache_dir)
+        log.info("XLA compilation cache at %s (%s)", cache_dir,
+                 "warm" if warm else "cold")
 
     backend = None
     if args.cluster == "fake":

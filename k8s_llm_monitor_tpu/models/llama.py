@@ -94,10 +94,9 @@ class KVPages(NamedTuple):
 def kv_quant_spec(kv_quant: str) -> tuple[Any, float]:
     """(storage dtype, qmax) for a KV quantization mode.
 
-    ``int8`` is always available; ``fp8`` selects float8_e4m3fn when this
-    jax build ships it and otherwise falls back to int8 (the engine warns).
+    ``fp8`` is float8_e4m3fn, anything else int8.
     """
-    if kv_quant == "fp8" and hasattr(jnp, "float8_e4m3fn"):
+    if kv_quant == "fp8":
         return jnp.dtype(jnp.float8_e4m3fn), 448.0
     return jnp.dtype(jnp.int8), 127.0
 

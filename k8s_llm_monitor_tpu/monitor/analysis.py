@@ -421,6 +421,13 @@ class LocalEngineBackend(LLMBackend):
         from k8s_llm_monitor_tpu.serving.engine import EngineConfig, InferenceEngine
         from k8s_llm_monitor_tpu.utils.tokenizer import load_tokenizer
 
+        mesh = None
+        if tpu_cfg.mesh_shape:
+            from k8s_llm_monitor_tpu.parallel.mesh import MeshConfig, create_mesh
+
+            data, seq, model = (int(x) for x in tpu_cfg.mesh_shape.split(","))
+            mesh = create_mesh(MeshConfig(data=data, seq=seq, model=model))
+
         dev_weights = not tpu_cfg.checkpoint
         if tpu_cfg.checkpoint:
             from k8s_llm_monitor_tpu.utils.checkpoint import load_hf_checkpoint
@@ -434,12 +441,28 @@ class LocalEngineBackend(LLMBackend):
             cfg = PRESETS[tpu_cfg.model]
             if quantize:
                 from k8s_llm_monitor_tpu.utils.quantize import (
-                    init_params_quantized,
+                    init_params_quantized as init,
+                )
+            else:
+                init = llama.init_params
+            key = jax.random.PRNGKey(0)
+            if mesh is None:
+                params = init(key, cfg)
+            else:
+                # Born sharded: the init runs as one program whose outputs
+                # carry the engine's own weight shardings, so no device
+                # ever holds the whole model (an eager init would park it
+                # on the default device, where the supervisor's rebuild
+                # closure below would keep it alive).  Same key, same
+                # values as the one-chip init.
+                from k8s_llm_monitor_tpu.parallel.sharding import (
+                    param_named_shardings,
                 )
 
-                params = init_params_quantized(jax.random.PRNGKey(0), cfg)
-            else:
-                params = llama.init_params(jax.random.PRNGKey(0), cfg)
+                shardings = param_named_shardings(
+                    jax.eval_shape(lambda k: init(k, cfg), key), mesh)
+                params = jax.jit(lambda k: init(k, cfg),
+                                 out_shardings=shardings)(key)
             tokenizer = load_tokenizer(None)
 
         if qmode == "w8a8":
@@ -449,13 +472,6 @@ class LocalEngineBackend(LLMBackend):
             import dataclasses as _dc
 
             cfg = _dc.replace(cfg, act_quant=True)
-
-        mesh = None
-        if tpu_cfg.mesh_shape:
-            from k8s_llm_monitor_tpu.parallel.mesh import MeshConfig, create_mesh
-
-            data, seq, model = (int(x) for x in tpu_cfg.mesh_shape.split(","))
-            mesh = create_mesh(MeshConfig(data=data, seq=seq, model=model))
 
         # Factory, not a single engine: the supervisor rebuilds through
         # this closure after a step-loop death, reusing the (expensive)
@@ -495,9 +511,33 @@ class LocalEngineBackend(LLMBackend):
                 enforce=tenancy.enforce,
                 max_tenants=tenancy.max_tenants)
 
-        return cls(tokenizer=tokenizer, dev_weights=dev_weights,
-                   engine_factory=engine_factory, lifecycle=lifecycle,
-                   governor=governor)
+        backend = cls(tokenizer=tokenizer, dev_weights=dev_weights,
+                      engine_factory=engine_factory, lifecycle=lifecycle,
+                      governor=governor)
+        backend._first_compile()
+        return backend
+
+    def _first_compile(self) -> None:
+        """Start-up gate: one short greedy generation through the normal
+        submit path, so the smallest prefill program and the decode program
+        compile BEFORE the server takes traffic.  A kernel the chip's
+        compiler refuses then stops the boot with the compiler's message,
+        instead of a server that starts and fails every request."""
+        from k8s_llm_monitor_tpu.serving.engine import SamplingParams
+
+        # Four tokens: a refused decode program requeues its lane through
+        # prefill (one more token per requeue, engine max_requeues=2), so
+        # a shorter request could finish without ever decoding.
+        handle = self._submit(
+            self.tokenizer.encode("ok"),
+            SamplingParams(max_tokens=4, temperature=0.0),
+            slo_class="interactive")
+        res = handle.result(timeout=self.GENERATION_TIMEOUT_S)
+        if res.finish_reason == "error":
+            if self.supervisor is not None:
+                self.supervisor.close()
+            raise RuntimeError(
+                f"TPU backend failed its first compile: {res.error}")
 
     def generate(
         self, prompt: str, max_tokens: int = 512, temperature: float = 0.1,
@@ -748,14 +788,12 @@ def build_backend(cfg: LLMConfig,
                   lifecycle: LifecycleConfig | None = None,
                   tenancy=None) -> LLMBackend:
     if cfg.provider == "tpu":
-        try:
-            return LocalEngineBackend.from_config(cfg.tpu, lifecycle=lifecycle,
-                                                  tenancy=tenancy)
-        except Exception as exc:  # noqa: BLE001 — degrade, never fail boot
-            logger.warning(
-                "TPU backend unavailable (%s); falling back to template", exc
-            )
-            return TemplateBackend()
+        # No downgrade: a TPU backend that cannot be built is a start-up
+        # error.  ``--llm template`` is the explicit way to run without a
+        # model; answering from a template while the operator asked for
+        # the chip hides exactly the failure they need to see.
+        return LocalEngineBackend.from_config(cfg.tpu, lifecycle=lifecycle,
+                                              tenancy=tenancy)
     if cfg.provider == "openai":
         try:
             return OpenAICompatBackend(cfg)

@@ -87,7 +87,9 @@ class TPULLMConfig:
     max_batch: int = 32
     kv_blocks: int = 512
     # Persistent XLA compilation cache: warm server restarts skip the
-    # multi-minute prefill/decode compile ladder.  '' disables.
+    # multi-minute prefill/decode compile ladder.  '' disables.  A relative
+    # path anchors at the checkout, not the working directory, and
+    # JAX_COMPILATION_CACHE_DIR wins when set (utils/compile_cache.py).
     compile_cache_dir: str = ".jax_cache"
     # Prompt-lookup speculative decoding draft length (serving/spec.py);
     # 0 disables.  Every sampling mode speculates (greedy bit-identically;

@@ -278,24 +278,27 @@ def _engine_metrics(w: _Writer, engine) -> None:
                  "Serving mesh axis sizes (data/seq/model)",
                  [(f'{{axis="{a}"}}', int(n))
                   for a, n in sorted(axes.items())])
-        w.metric("engine_decode_collective_share", "gauge",
-                 "Estimated ICI (collective) share of a TP decode step, "
-                 "from the decode profile's byte model; 0 until "
-                 "profile_decode_phases() has run",
-                 [("", round(getattr(engine, "decode_collective_share",
-                                     0.0), 4))])
+        # The two ICI estimates are absent (not 0) on a device kind the
+        # bandwidth tables in parallel/mesh.py do not list.
+        coll = getattr(engine, "decode_collective_share", None)
+        if coll is not None:
+            w.metric("engine_decode_collective_share", "gauge",
+                     "Estimated ICI (collective) share of a TP decode "
+                     "step, from the decode profile's byte model; 0 until "
+                     "profile_decode_phases() has run",
+                     [("", round(coll, 4))])
         w.metric("engine_tp_overlap", "gauge",
                  "1 when the hand-staged reduce-scatter/all-gather decode "
                  "schedule is active (parallel/overlap.py); 0 = GSPMD "
                  "reference program",
                  [("", 1 if getattr(engine, "tp_overlap", False) else 0)])
-        w.metric("engine_decode_collective_hidden_share", "gauge",
-                 "Fraction of the per-step ring wire time the overlap "
-                 "schedule hides under compute (measured on TPU, "
-                 "analytic in dryrun); 0 until estimate_hidden_share() "
-                 "has run",
-                 [("", round(getattr(
-                     engine, "decode_collective_hidden_share", 0.0), 4))])
+        hidden = getattr(engine, "decode_collective_hidden_share", None)
+        if hidden is not None:
+            w.metric("engine_decode_collective_hidden_share", "gauge",
+                     "Fraction of the per-step ring wire time the overlap "
+                     "schedule hides under compute; 0 until "
+                     "estimate_hidden_share() has run",
+                     [("", round(hidden, 4))])
 
     # Decode-step phase attribution (fused fast-path observability).
     # attn/sample are populated by engine.profile_decode_phases() — a

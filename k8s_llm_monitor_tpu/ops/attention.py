@@ -19,8 +19,22 @@ selected by ``select_attn_impl`` (used by serving/engine.py).
 
 from __future__ import annotations
 
+import functools
+import logging
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from k8s_llm_monitor_tpu.ops.pallas_attention import (
+    flash_prefill_attention,
+    paged_decode_attention_fused,
+    paged_decode_attention_fused_quant,
+    paged_decode_attention_pallas,
+    paged_verify_attention_pallas,
+)
+
+logger = logging.getLogger("k8s_llm_monitor_tpu.ops")
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -272,9 +286,6 @@ def select_verify_impl(platform: str | None = None, cfg=None, mesh=None,
     gather's O(table width) reads.
     Returns a callable (q, k_pages, v_pages, table, start, lengths).
     """
-    import logging
-
-    logger = logging.getLogger("k8s_llm_monitor_tpu.ops")
     if platform is None:
         platform = jax.default_backend()
     if cfg is not None and getattr(cfg, "has_attn_extras", False):
@@ -292,17 +303,7 @@ def select_verify_impl(platform: str | None = None, cfg=None, mesh=None,
             "speculative verify uses the XLA gather fallback",
             getattr(cfg, "name", "model"))
         return paged_verify_attention
-    try:
-        from k8s_llm_monitor_tpu.ops.pallas_attention import (
-            paged_verify_attention_pallas,
-        )
-
-        return paged_verify_attention_pallas
-    except Exception as exc:  # pragma: no cover - import/lowering unavailable
-        logger.warning(
-            "Pallas verify kernel failed to import (%s); speculative "
-            "verify uses the XLA gather fallback", exc)
-        return paged_verify_attention
+    return paged_verify_attention_pallas
 
 
 def make_tp_paged_attention(mesh, cfg, interpret: bool = False):
@@ -320,22 +321,13 @@ def make_tp_paged_attention(mesh, cfg, interpret: bool = False):
     ``interpret`` runs the kernel in the Pallas interpreter per shard — the
     CPU-mesh path used by tests and the driver's virtual-device dryrun.
     """
-    import functools
-
-    from jax.sharding import PartitionSpec as P
-
-    from k8s_llm_monitor_tpu.ops.pallas_attention import (
-        paged_decode_attention_pallas,
-    )
-    from k8s_llm_monitor_tpu.parallel.mesh import shard_map_compat
-
     qspec = P(None, None, "model", None)       # query heads over TP
     pspec = P(None, None, "model")             # fused kv lanes over TP
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(qspec, pspec, pspec, P(None, None), P(None)),
-        out_specs=qspec, check_replication=False)
+        out_specs=qspec, check_vma=False)
     def attn(q, k_pages, v_pages, block_table, lengths):
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, block_table, lengths, interpret=interpret)
@@ -364,9 +356,6 @@ def select_attn_impl(platform: str | None = None, cfg=None, mesh=None):
     gate (tiny test configs) get the XLA path with a logged warning — never
     a silent compile-time crash or a quiet performance cliff.
     """
-    import logging
-
-    logger = logging.getLogger("k8s_llm_monitor_tpu.ops")
     if platform is None:
         platform = jax.default_backend()
 
@@ -395,13 +384,7 @@ def select_attn_impl(platform: str | None = None, cfg=None, mesh=None):
                 "(per-shard fused lanes not 128-aligned); using the XLA "
                 "gather fallback", getattr(cfg, "name", "model"), tp)
             return paged_decode_attention
-        try:
-            return make_tp_paged_attention(mesh, cfg, interpret=interpret)
-        except Exception as exc:  # pragma: no cover
-            logger.warning(
-                "TP Pallas paged attention unavailable (%s); using the XLA "
-                "gather fallback", exc)
-            return paged_decode_attention
+        return make_tp_paged_attention(mesh, cfg, interpret=interpret)
 
     if platform != "tpu":
         return paged_decode_attention
@@ -413,18 +396,7 @@ def select_attn_impl(platform: str | None = None, cfg=None, mesh=None):
             "per decode step", getattr(cfg, "name", "model"),
             cfg.num_kv_heads * cfg.head_dim_)
         return paged_decode_attention
-    try:
-        from k8s_llm_monitor_tpu.ops.pallas_attention import (
-            paged_decode_attention_pallas,
-        )
-
-        return paged_decode_attention_pallas
-    except Exception as exc:  # pragma: no cover - import/lowering unavailable
-        logger.warning(
-            "Pallas paged-attention kernel failed to import (%s); using the "
-            "XLA gather fallback — O(B*max_ctx) HBM traffic per decode "
-            "step", exc)
-        return paged_decode_attention
+    return paged_decode_attention_pallas
 
 
 def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
@@ -457,10 +429,6 @@ def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
     impls are marked (``is_fused_decode_impl``) and use the extended
     calling convention (raw q/k/v + angles in, pages out).
     """
-    import functools
-    import logging
-
-    logger = logging.getLogger("k8s_llm_monitor_tpu.ops")
     if platform is None:
         platform = jax.default_backend()
 
@@ -471,15 +439,12 @@ def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
                 and cfg.head_dim_ % 2 == 0
                 and _pallas_geometry_ok(cfg, 1))
 
-    def _fused_quant():
-        from k8s_llm_monitor_tpu.ops.pallas_attention import (
-            paged_decode_attention_fused_quant,
-        )
-
-        if platform != "tpu":
-            return functools.partial(paged_decode_attention_fused_quant,
-                                     interpret=True)
-        return paged_decode_attention_fused_quant
+    def _fused():
+        impl = (paged_decode_attention_fused_quant if kv_quant
+                else paged_decode_attention_fused)
+        if platform != "tpu":      # CPU tests: the Pallas interpreter
+            return functools.partial(impl, interpret=True)
+        return impl
 
     if mode == "gather":
         return paged_decode_attention
@@ -496,33 +461,13 @@ def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
                 "decode_path='fused' but the model/mesh can't take the "
                 "fused kernel (mesh, attn extras, odd head_dim, or lane "
                 "alignment); use decode_path='auto' for gated selection")
-        if kv_quant:
-            return _fused_quant()
-        from k8s_llm_monitor_tpu.ops.pallas_attention import (
-            paged_decode_attention_fused,
-        )
-
-        if platform != "tpu":
-            return functools.partial(paged_decode_attention_fused,
-                                     interpret=True)
-        return paged_decode_attention_fused
+        return _fused()
     if mode != "auto":
         raise ValueError(f"unknown decode_path {mode!r}; expected "
                          "'auto', 'fused', 'gather', or 'pallas'")
 
     if platform == "tpu" and _fused_ok():
-        try:
-            if kv_quant:
-                return _fused_quant()
-            from k8s_llm_monitor_tpu.ops.pallas_attention import (
-                paged_decode_attention_fused,
-            )
-
-            return paged_decode_attention_fused
-        except Exception as exc:  # pragma: no cover - import unavailable
-            logger.warning(
-                "fused decode kernel failed to import (%s); using the "
-                "split path", exc)
+        return _fused()
     if kv_quant:
         # Mesh or gather regime: decode_step's quant branch gathers pages
         # AND scales (paged_decode_attention_quant) — GSPMD partitions it
@@ -547,25 +492,16 @@ def make_tp_flash_prefill(mesh, cfg, interpret: bool = False,
     (SpecLayout.kv_scales: the kv-heads axis splits when the fused lane
     dim does).
     """
-    import functools
-
-    from jax.sharding import PartitionSpec as P
-
-    from k8s_llm_monitor_tpu.ops.pallas_attention import (
-        flash_prefill_attention,
-    )
-    from k8s_llm_monitor_tpu.parallel.mesh import shard_map_compat
-
     qspec = P(None, None, "model", None)       # query heads over TP
     pspec = P(None, None, "model")             # fused kv lanes / scale heads
     tspec = P(None, None)                      # block tables: global ids
 
     if kv_quant:
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(qspec, pspec, pspec, pspec, pspec, tspec, P(None),
                       P(None)),
-            out_specs=qspec, check_replication=False)
+            out_specs=qspec, check_vma=False)
         def _attn_sharded(q, k_pages, v_pages, k_scale, v_scale, table,
                           start, lengths):
             return flash_prefill_attention(
@@ -578,9 +514,9 @@ def make_tp_flash_prefill(mesh, cfg, interpret: bool = False,
                                  table, start, lengths)
     else:
         @functools.partial(
-            shard_map_compat, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(qspec, pspec, pspec, tspec, P(None), P(None)),
-            out_specs=qspec, check_replication=False)
+            out_specs=qspec, check_vma=False)
         def _attn_sharded(q, k_pages, v_pages, table, start, lengths):
             return flash_prefill_attention(
                 q, k_pages, v_pages, table, start, lengths,
@@ -619,10 +555,6 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
     tested against) or an impl marked ``is_flash_prefill_impl`` with the
     ``flash_prefill_attention`` calling convention.
     """
-    import functools
-    import logging
-
-    logger = logging.getLogger("k8s_llm_monitor_tpu.ops")
     if platform is None:
         platform = jax.default_backend()
 
@@ -649,10 +581,6 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
         return _pallas_geometry_ok(cfg, tp) and cfg.head_dim_ == 128
 
     def _build():
-        from k8s_llm_monitor_tpu.ops.pallas_attention import (
-            flash_prefill_attention,
-        )
-
         if mesh is not None:
             return make_tp_flash_prefill(
                 mesh, cfg, interpret=platform != "tpu", kv_quant=kv_quant)
@@ -674,10 +602,4 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
     # remains the oracle the flash path is diffed against in tests.
     if platform != "tpu" or not _flash_ok():
         return None
-    try:
-        return _build()
-    except Exception as exc:  # pragma: no cover - import unavailable
-        logger.warning(
-            "flash prefill kernel unavailable (%s); prefill stays on the "
-            "dense XLA path", exc)
-        return None
+    return _build()

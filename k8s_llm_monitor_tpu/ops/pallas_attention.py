@@ -44,10 +44,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 # Pages DMA'd per burst: W pages' copies are issued together and waited
 # once, so per-copy HBM latency overlaps within the burst instead of
@@ -235,7 +231,7 @@ def _run_paged_attn(q, k_pages, v_pages, block_table, starts, qlens,
         functools.partial(_paged_attn_kernel, QS, H),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, QS * H, F), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Programs touch disjoint q/o tiles and only read pages: the
             # tile grid is safely parallel (megacore splits it).
             dimension_semantics=("parallel",),
@@ -305,6 +301,53 @@ def _rotate_half_fused(x, D):
     return jnp.where(first_half, -bwd, fwd)
 
 
+def _append_tile_rows(bs: int, dtype) -> int:
+    """Rows of the aligned page tile the fused kernels read-modify-write to
+    append one token.  Mosaic refuses a one-row DMA into a tiled HBM page
+    ("slice shape ... must be aligned to tiling"); the smallest slice it
+    takes along the token axis is one sublane tile — 8 rows of 32-bit,
+    16 of 16-bit, 32 of 8-bit — or the whole page when that is smaller."""
+    rows = 8 * 4 // jnp.dtype(dtype).itemsize
+    return bs if bs <= rows or bs % rows else rows
+
+
+def _append_rows(pages_out, tiles, new_rows, blk, off, sems):
+    """Append one token row to each of ``pages_out`` (HBM page arrays) at
+    ``[blk, off]`` by an aligned read-modify-write of the tile that holds
+    the row: DMA the tile into its VMEM buffer in ``tiles``, overwrite row
+    ``off`` with ``new_rows[i]`` [1, F], and start the write-back.  Returns
+    the started write-back copies; the caller waits on them before its
+    program ends.
+
+    The tile's other rows are written back unchanged, so a concurrent page
+    stream may read them; the merge is a full-tile select (no dynamic
+    single-row store into packed sublanes), and its float32 round trip is
+    exact for every pool dtype.
+    """
+    bs = pages_out[0].shape[1]
+    rows = tiles[0].shape[0]
+    if rows == bs:
+        dsts, row = [p.at[blk] for p in pages_out], off
+    else:
+        r0 = pl.multiple_of((off // rows) * rows, rows)
+        dsts, row = [p.at[blk, pl.ds(r0, rows)] for p in pages_out], off - r0
+    reads = [pltpu.make_async_copy(d, t, sems.at[i])
+             for i, (d, t) in enumerate(zip(dsts, tiles))]
+    for c in reads:
+        c.start()
+    for c in reads:
+        c.wait()
+    own = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == row
+    for t, new in zip(tiles, new_rows):
+        t[...] = jnp.where(own, new.astype(jnp.float32),
+                           t[...].astype(jnp.float32)).astype(t.dtype)
+    writes = [pltpu.make_async_copy(t, d, sems.at[i])
+              for i, (d, t) in enumerate(zip(dsts, tiles))]
+    for c in writes:
+        c.start()
+    return writes
+
+
 def _fused_decode_kernel(
     H,                     # static: query heads per token
     D,                     # static: head dim
@@ -325,8 +368,9 @@ def _fused_decode_kernel(
     v_out,
 ):
     """Decode step for TB sequences: RoPE the query and the new token's k
-    in-kernel, DMA the roped k / raw v row into its page (overlapped with
-    the attention math), stream the CACHED pages (positions < pos) with
+    in-kernel, merge the roped k / raw v row into the aligned tile of its
+    page (write-back overlapped with the attention math), stream the
+    CACHED pages (positions < pos) with
     online softmax, and fold the current token in as one extra softmax
     update from VMEM — so the appended row is never read back from HBM
     and the append DMA can land any time before the program ends.
@@ -342,7 +386,9 @@ def _fused_decode_kernel(
     NB = tables_ref.shape[1]
     W = min(_WINDOW, NB)
 
-    def scoped(k_buf, v_buf, k_row, v_row, sem, append_sem):
+    R = _append_tile_rows(bs, k_hbm.dtype)
+
+    def scoped(k_buf, v_buf, k_tile, v_tile, sem, append_sem):
         def start_window(slot, b, w):
             for i in range(W):
                 j = jnp.minimum(w * W + i, NB - 1)
@@ -387,14 +433,10 @@ def _fused_decode_kernel(
             blk = jnp.where(active & in_table,
                             tables_ref[b, jnp.minimum(raw_blk, NB - 1)], 0)
             off = jax.lax.rem(pos, bs)
-            k_row[...] = kf.astype(k_row.dtype)
-            v_row[...] = vn_ref[t].astype(v_row.dtype)
-            k_copy = pltpu.make_async_copy(
-                k_row, k_out.at[blk, pl.ds(off, 1)], append_sem.at[0])
-            v_copy = pltpu.make_async_copy(
-                v_row, v_out.at[blk, pl.ds(off, 1)], append_sem.at[1])
-            k_copy.start()
-            v_copy.start()
+            # The tail block is owned by this lane alone; the write-back
+            # overlaps the attention math below.
+            appends = _append_rows((k_out, v_out), (k_tile, v_tile),
+                                   (kf, vn_ref[t]), blk, off, append_sem)
 
             # --- stream the cached pages (positions < pos) ----------------
             n_blocks = (pos + bs - 1) // bs              # 0 for inactive
@@ -453,16 +495,16 @@ def _fused_decode_kernel(
             vf = vn_ref[t].astype(jnp.float32)            # [1, F]
             acc = alpha * acc + p_cur * vf
 
-            k_copy.wait()
-            v_copy.wait()
+            for c in appends:
+                c.wait()
             o_ref[t] = (acc / l).astype(o_ref.dtype)
 
     pl.run_scoped(
         scoped,
         k_buf=pltpu.VMEM((2, W * bs, F), k_hbm.dtype),
         v_buf=pltpu.VMEM((2, W * bs, F), v_hbm.dtype),
-        k_row=pltpu.VMEM((1, F), k_hbm.dtype),
-        v_row=pltpu.VMEM((1, F), v_hbm.dtype),
+        k_tile=pltpu.VMEM((R, F), k_hbm.dtype),
+        v_tile=pltpu.VMEM((R, F), v_hbm.dtype),
         sem=pltpu.SemaphoreType.DMA((2, W, 2)),
         append_sem=pltpu.SemaphoreType.DMA((2,)),
     )
@@ -560,7 +602,7 @@ def paged_decode_attention_fused(
         # Page arrays update in place: inputs 7/8 (after the 2 scalar-
         # prefetch operands) alias outputs 1/2.
         input_output_aliases={7: 1, 8: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Lanes append to blocks they own (the allocator hands out
             # distinct tail blocks; only never-read null-block rows race),
             # so the tile grid stays megacore-parallel like the decode
@@ -587,6 +629,24 @@ paged_decode_attention_fused.fused_decode = True
 # ---------------------------------------------------------------------------
 
 
+def _window_scales(scale, block_table, W):
+    """Gather a scale plane through the block table into per-window slabs.
+
+    scale [num_blocks, bs, KVH] f32 -> [B, NWIN, KVH, W*bs]: window ``w`` of
+    lane ``b`` holds the scales of table slots ``w*W .. w*W+W-1`` with the
+    token axis on lanes.  The kernels take these as ordinary VMEM blocks:
+    a ``[bs, KVH]`` scale page (KVH lanes of 128) is not a shape the chip's
+    DMA slices, and the scales are 1/(2*D) of the page bytes, so the XLA
+    gather costs noise next to the page stream it sits beside.
+    """
+    B, NB = block_table.shape
+    _, bs, KVH = scale.shape
+    nwin = -(-NB // W)
+    table = jnp.pad(block_table, ((0, 0), (0, nwin * W - NB)))
+    g = scale[table]                                  # [B, nwin*W, bs, KVH]
+    return g.reshape(B, nwin, W * bs, KVH).transpose(0, 1, 3, 2)
+
+
 def _fused_decode_quant_kernel(
     H,                     # static: query heads per token
     D,                     # static: head dim
@@ -602,31 +662,34 @@ def _fused_decode_quant_kernel(
     vn_ref,                # [TB, 1, F]
     cos_ref,               # [TB, 1, F]
     sin_ref,               # [TB, 1, F]
+    ks_ref,                # [TB, NWIN, KVH, W*bs] f32 cached-token k scales
+    vs_ref,                # same (see _window_scales)
     k_hbm,                 # [num_blocks, bs, F] quantized (aliased to k_out)
     v_hbm,
-    ks_hbm,                # [num_blocks, bs, KVH] f32 scales (aliased)
-    vs_hbm,
     # outputs
     o_ref,                 # [TB, H, F]
+    ksn_ref,               # [TB, KVH, 1] f32 new-token k scales
+    vsn_ref,
     k_out,
     v_out,
-    ks_out,
-    vs_out,
 ):
     """Quantized twin of ``_fused_decode_kernel``.
 
     Dequantization never expands scales to the F lane dim for the cached
     pages: per-(token, head) K scales factor out of ``q @ k^T`` (the
     block-diagonal q restricts head h to its own kv group's lanes), so the
-    score matrix is rescaled by ``scale_bd[h, j] = ks[j, group(h)]`` — one
-    small MXU dot (``onehot_h @ ks_win^T``) per window.  V scales fold into
-    the probabilities the same way: ``acc += (p * vs_bd) @ v_q`` is exact
-    for each head's own group slice (other slices carry garbage the caller
-    slices away) while the softmax denominator uses the unscaled ``p``.
+    score matrix is rescaled by ``scale_bd[h, j] = ks[group(h), j]``.  V
+    scales fold into the probabilities the same way: ``acc += (p * vs_bd)
+    @ v_q`` is exact for each head's own group slice (other slices carry
+    garbage the caller slices away) while the softmax denominator uses the
+    unscaled ``p``.
 
     The appended token is quantized in-kernel (per-head amax over its
     D-slice) and folded into the softmax as dequantize(quantize(k)) — bit
     parity with the gather path, which reads the row back dequantized.
+    Its codes merge into the page tile like the unquantized kernel's row;
+    its scales leave as a small output the wrapper scatters into the
+    scale planes.
     """
     TB = q_ref.shape[0]
     b0 = pl.program_id(0) * TB
@@ -634,34 +697,42 @@ def _fused_decode_quant_kernel(
     F = q_ref.shape[2]
     NB = tables_ref.shape[1]
     W = min(_WINDOW, NB)
+    R = _append_tile_rows(bs, k_hbm.dtype)
     # Constant index maps: lane j belongs to kv group j // D; head h reads
     # group h // (H // KVH).
     lane_group = jax.lax.broadcasted_iota(jnp.int32, (KVH, F), 1) // D
     grp_row = jax.lax.broadcasted_iota(jnp.int32, (KVH, F), 0)
-    onehot_lane = (lane_group == grp_row).astype(jnp.float32)   # [KVH, F]
-    head_grp = (jax.lax.broadcasted_iota(jnp.int32, (H, KVH), 0)
-                // max(H // KVH, 1))
-    kvh_col = jax.lax.broadcasted_iota(jnp.int32, (H, KVH), 1)
-    onehot_h = (kvh_col == head_grp).astype(jnp.float32)        # [H, KVH]
+    own_lane = lane_group == grp_row                            # [KVH, F]
+    head_grp = (jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0)
+                // max(H // KVH, 1))                            # [H, 1]
+
+    def _per_head(win):
+        """[KVH, W*bs] group scales -> [H, W*bs], head h taking its
+        group's row (a select chain: exact, and no KVH-deep MXU dot)."""
+        out = jnp.zeros((H, win.shape[1]), jnp.float32)
+        for g in range(KVH):
+            out = jnp.where(head_grp == g, win[g:g + 1, :], out)
+        return out
 
     def _quantize_row(xf):
-        """xf [1, F] float -> (store [1, F] float pre-cast, scale [1, KVH],
+        """xf [1, F] float -> (codes [1, F] float pre-cast, scale [KVH, 1],
         dequantized [1, F] f32)."""
-        masked = jnp.where(onehot_lane > 0, jnp.abs(xf), 0.0)   # [KVH, F]
-        amax = jnp.max(masked, axis=1, keepdims=True)           # [KVH, 1]
+        amax = jnp.max(jnp.where(own_lane, jnp.abs(xf), 0.0), axis=1,
+                       keepdims=True)                           # [KVH, 1]
         scale = jnp.maximum(amax / qmax, 1e-8)
-        # Lane-expand via one small dot: scale_lane[0, j] = scale[g(j)].
-        scale_lane = jax.lax.dot_general(
-            scale.reshape(1, KVH), onehot_lane, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                 # [1, F]
+        # Lane-expand: scale_lane[0, j] = scale[group(j)].
+        scale_lane = jnp.sum(jnp.where(own_lane, scale, 0.0), axis=0,
+                             keepdims=True)                     # [1, F]
         xq = xf / scale_lane
         if is_int8:
             xq = jnp.clip(jnp.round(xq), -qmax, qmax)
-        deq = xq * scale_lane
-        return xq, scale.reshape(1, KVH), deq
+        else:
+            # The stored code is the fp8-rounded value: dequantize that,
+            # not the unrounded quotient.
+            xq = xq.astype(k_hbm.dtype).astype(jnp.float32)
+        return xq, scale, xq * scale_lane
 
-    def scoped(k_buf, v_buf, ks_buf, vs_buf, k_row, v_row, ks_row, vs_row,
-               sem, ssem, append_sem):
+    def scoped(k_buf, v_buf, k_tile, v_tile, sem, append_sem):
         def start_window(slot, b, w):
             for i in range(W):
                 j = jnp.minimum(w * W + i, NB - 1)
@@ -672,12 +743,6 @@ def _fused_decode_quant_kernel(
                 pltpu.make_async_copy(
                     v_hbm.at[blk], v_buf.at[slot, pl.ds(i * bs, bs)],
                     sem.at[slot, i, 1]).start()
-                pltpu.make_async_copy(
-                    ks_hbm.at[blk], ks_buf.at[slot, pl.ds(i * bs, bs)],
-                    ssem.at[slot, i, 0]).start()
-                pltpu.make_async_copy(
-                    vs_hbm.at[blk], vs_buf.at[slot, pl.ds(i * bs, bs)],
-                    ssem.at[slot, i, 1]).start()
 
         def wait_window(slot, b, w):
             for i in range(W):
@@ -689,12 +754,6 @@ def _fused_decode_quant_kernel(
                 pltpu.make_async_copy(
                     v_hbm.at[blk], v_buf.at[slot, pl.ds(i * bs, bs)],
                     sem.at[slot, i, 1]).wait()
-                pltpu.make_async_copy(
-                    ks_hbm.at[blk], ks_buf.at[slot, pl.ds(i * bs, bs)],
-                    ssem.at[slot, i, 0]).wait()
-                pltpu.make_async_copy(
-                    vs_hbm.at[blk], vs_buf.at[slot, pl.ds(i * bs, bs)],
-                    ssem.at[slot, i, 1]).wait()
 
         for t in range(TB):
             b = b0 + t
@@ -712,28 +771,16 @@ def _fused_decode_quant_kernel(
             # --- quantize-on-append (per-head amax over the D-slice) ------
             kq, k_scale, kdeq = _quantize_row(kf)
             vq, v_scale, vdeq = _quantize_row(vf)
+            ksn_ref[t] = k_scale
+            vsn_ref[t] = v_scale
 
             raw_blk = pos // bs
             in_table = raw_blk < NB
             blk = jnp.where(active & in_table,
                             tables_ref[b, jnp.minimum(raw_blk, NB - 1)], 0)
             off = jax.lax.rem(pos, bs)
-            k_row[...] = kq.astype(k_row.dtype)
-            v_row[...] = vq.astype(v_row.dtype)
-            ks_row[...] = k_scale
-            vs_row[...] = v_scale
-            copies = [
-                pltpu.make_async_copy(
-                    k_row, k_out.at[blk, pl.ds(off, 1)], append_sem.at[0]),
-                pltpu.make_async_copy(
-                    v_row, v_out.at[blk, pl.ds(off, 1)], append_sem.at[1]),
-                pltpu.make_async_copy(
-                    ks_row, ks_out.at[blk, pl.ds(off, 1)], append_sem.at[2]),
-                pltpu.make_async_copy(
-                    vs_row, vs_out.at[blk, pl.ds(off, 1)], append_sem.at[3]),
-            ]
-            for c in copies:
-                c.start()
+            appends = _append_rows((k_out, v_out), (k_tile, v_tile),
+                                   (kq, vq), blk, off, append_sem)
 
             n_blocks = (pos + bs - 1) // bs
             n_windows = (n_blocks + W - 1) // W
@@ -742,7 +789,7 @@ def _fused_decode_quant_kernel(
             def _first():
                 start_window(0, b, 0)
 
-            def body(w, carry, b=b, pos=pos, n_windows=n_windows):
+            def body(w, carry, b=b, t=t, pos=pos, n_windows=n_windows):
                 m, l, acc = carry
                 slot = jax.lax.rem(w, 2)
 
@@ -754,16 +801,12 @@ def _fused_decode_quant_kernel(
                 p_idx = (w * (W * bs)
                          + jax.lax.broadcasted_iota(jnp.int32, (1, W * bs), 1))
                 valid = p_idx < pos
-                kblk = k_buf[slot].astype(jnp.float32)      # quantized ints
+                kblk = k_buf[slot].astype(jnp.float32)      # quantized codes
                 vblk = v_buf[slot].astype(jnp.float32)
                 # K scales factor out of the contraction: scale_bd[h, j] =
-                # ks[j, group(h)], built as one [H, KVH] x [KVH, W*bs] dot.
-                ks_bd = jax.lax.dot_general(
-                    onehot_h, ks_buf[slot], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)     # [H, W*bs]
-                vs_bd = jax.lax.dot_general(
-                    onehot_h, vs_buf[slot], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
+                # ks[group(h), j].
+                ks_bd = _per_head(ks_ref[t, w])             # [H, W*bs]
+                vs_bd = _per_head(vs_ref[t, w])
                 s = jax.lax.dot_general(
                     qf, kblk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * ks_bd
@@ -796,7 +839,7 @@ def _fused_decode_quant_kernel(
             l = alpha * l + p_cur
             acc = alpha * acc + p_cur * vdeq
 
-            for c in copies:
+            for c in appends:
                 c.wait()
             o_ref[t] = (acc / l).astype(o_ref.dtype)
 
@@ -804,18 +847,10 @@ def _fused_decode_quant_kernel(
         scoped,
         k_buf=pltpu.VMEM((2, W * bs, F), k_hbm.dtype),
         v_buf=pltpu.VMEM((2, W * bs, F), v_hbm.dtype),
-        # Scale slabs keep the KVH lane dim (sub-128 lanes: Mosaic pads;
-        # the bytes are 1/(2*D) of the page slabs so the padding waste is
-        # bounded and the VMEM cost is noise).
-        ks_buf=pltpu.VMEM((2, W * bs, KVH), jnp.float32),
-        vs_buf=pltpu.VMEM((2, W * bs, KVH), jnp.float32),
-        k_row=pltpu.VMEM((1, F), k_hbm.dtype),
-        v_row=pltpu.VMEM((1, F), v_hbm.dtype),
-        ks_row=pltpu.VMEM((1, KVH), jnp.float32),
-        vs_row=pltpu.VMEM((1, KVH), jnp.float32),
+        k_tile=pltpu.VMEM((R, F), k_hbm.dtype),
+        v_tile=pltpu.VMEM((R, F), v_hbm.dtype),
         sem=pltpu.SemaphoreType.DMA((2, W, 2)),
-        ssem=pltpu.SemaphoreType.DMA((2, W, 2)),
-        append_sem=pltpu.SemaphoreType.DMA((4,)),
+        append_sem=pltpu.SemaphoreType.DMA((2,)),
     )
 
 
@@ -837,10 +872,12 @@ def paged_decode_attention_fused_quant(
     """Quantized-KV fused decode step (see ``paged_decode_attention_fused``).
 
     Identical calling convention plus the per-(token, head) float32 scale
-    arrays ``k_scale``/``v_scale`` [num_blocks, bs, KVH], which — like the
-    pages — alias their outputs and update in place.  The engine's donated
-    quantized pool (pages + scales) is therefore never copied; traceguard
-    asserts the rebinding exactly as for the fp16 pool.
+    arrays ``k_scale``/``v_scale`` [num_blocks, bs, KVH].  The code pages
+    alias their outputs and update in place inside the kernel; the scale
+    planes are read through an XLA gather (``_window_scales``) and take the
+    new token's scales by an XLA scatter, which is equally in place on the
+    engine's donated pool — traceguard asserts the rebinding exactly as for
+    the fp16 pool.
 
     Returns:
       (attn [B, 1, H, D], k_pages, v_pages, k_scale, v_scale) — the four
@@ -852,8 +889,11 @@ def paged_decode_attention_fused_quant(
     assert F % D == 0 and D % 2 == 0 and D <= 128, (F, D)
     KVH = F // D
     q_per_kv = H // KVH
+    NB = block_table.shape[1]
+    W = min(_WINDOW, NB)
     qmax = 127.0 if jnp.dtype(k_pages.dtype) == jnp.int8 else 448.0
     is_int8 = jnp.dtype(k_pages.dtype) == jnp.int8
+    positions = positions.astype(jnp.int32)
 
     group = jnp.arange(H, dtype=jnp.int32) // q_per_kv
     onehot = jax.nn.one_hot(group, KVH, dtype=q.dtype)
@@ -863,12 +903,16 @@ def paged_decode_attention_fused_quant(
     vn = v_new.reshape(B, 1, F)
     cos_f = jnp.tile(cos.astype(jnp.float32), (1, 1, KVH))
     sin_f = jnp.tile(sin.astype(jnp.float32), (1, 1, KVH))
+    ks_win = _window_scales(k_scale, block_table, W)
+    vs_win = _window_scales(v_scale, block_table, W)
 
     budget = 4 * 2**20 // max(H * F * q.dtype.itemsize, 1)
     TB = next(tb for tb in (8, 4, 2, 1)
               if B % tb == 0 and (B // tb >= 2 or B == 1)
               and (tb <= budget or tb == 1))
     lane_spec = lambda p, tbl, pos: (p, 0, 0)  # noqa: E731
+    win_spec = pl.BlockSpec((TB,) + ks_win.shape[1:],
+                            lambda p, tbl, pos: (p, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B // TB,),
@@ -878,40 +922,51 @@ def paged_decode_attention_fused_quant(
             pl.BlockSpec((TB, 1, F), lane_spec),
             pl.BlockSpec((TB, 1, F), lane_spec),
             pl.BlockSpec((TB, 1, F), lane_spec),
+            win_spec,                            # gathered K scales (VMEM)
+            win_spec,                            # gathered V scales (VMEM)
             pl.BlockSpec(memory_space=pl.ANY),   # K pages stay in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V pages stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # K scales stay in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # V scales stay in HBM
         ],
         out_specs=[
             pl.BlockSpec((TB, H, F), lane_spec),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((TB, KVH, 1), lane_spec),
+            pl.BlockSpec((TB, KVH, 1), lane_spec),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
     )
 
-    out_full, k_out, v_out, ks_out, vs_out = pl.pallas_call(
+    out_full, ks_new, vs_new, k_out, v_out = pl.pallas_call(
         functools.partial(_fused_decode_quant_kernel, H, D, KVH, qmax,
                           is_int8),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, F), q.dtype),
+            jax.ShapeDtypeStruct((B, KVH, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, KVH, 1), jnp.float32),
             jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
             jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-            jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
         ],
-        # Pool arrays update in place: inputs 7..10 (after the 2 scalar-
-        # prefetch operands) alias outputs 1..4.
-        input_output_aliases={7: 1, 8: 2, 9: 3, 10: 4},
-        compiler_params=_CompilerParams(
+        # Code pages update in place: inputs 9/10 (after the 2 scalar-
+        # prefetch operands) alias outputs 3/4.
+        input_output_aliases={9: 3, 10: 4},
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(block_table, positions.astype(jnp.int32), q_bd, kn, vn, cos_f, sin_f,
-      k_pages, v_pages, k_scale, v_scale)
+    )(block_table, positions, q_bd, kn, vn, cos_f, sin_f, ks_win, vs_win,
+      k_pages, v_pages)
+
+    # New-token scales land where the kernel put the codes: the lane's
+    # tail block at ``pos % bs``, the null block for inactive lanes and
+    # positions past the table (models/llama.py:_scatter_pages).
+    raw_blk = positions // bs
+    blk = jnp.take_along_axis(
+        block_table, jnp.clip(raw_blk, 0, NB - 1)[:, None], axis=1)[:, 0]
+    blk = jnp.where((positions > 0) & (raw_blk < NB), blk, 0)
+    off = positions % bs
+    ks_out = k_scale.at[blk, off].set(ks_new[:, :, 0])
+    vs_out = v_scale.at[blk, off].set(vs_new[:, :, 0])
 
     out = jnp.take_along_axis(
         out_full.reshape(B, 1, H, KVH, D),
@@ -975,10 +1030,10 @@ def _flash_prefill_kernel(
     starts_ref,            # [B] int32 cached tokens before this chunk
     qlens_ref,             # [B] int32 valid query tokens (0 = inactive lane)
     # inputs
-    q_ref,                 # [1, TQ, 1, qpk*D] this (seq, tile, group) q slab
+    q_ref,                 # [1, 1, TQ, qpk*D] this (seq, group, tile) q slab
     k_hbm,                 # [num_blocks, bs, KVH*D] (ANY/HBM, whole array)
     v_hbm,                 # same
-    *rest,                 # (ks_hbm, vs_hbm,) o_ref
+    *rest,                 # (ks_ref, vs_ref,) o_ref
 ):
     """One program: one query tile of one sequence for one kv group.
 
@@ -995,15 +1050,15 @@ def _flash_prefill_kernel(
     never materialized, which is what lets 8k/32k buckets fit where the
     dense path's [B, H, S, T] float32 logits cannot.
 
-    ``quant``: pages hold int8/fp8 codes; the per-(token, head) scale rows
-    are DMA'd whole ([bs, KVH]) and this group's column is extracted with a
-    one-hot dot (a [1, KVH] x [KVH, W*bs] contraction — never a sub-lane
-    sliced DMA).  K scales factor out of ``q @ k^T`` onto the score tile; V
-    scales fold into the probabilities, exactly the
+    ``quant``: pages hold int8/fp8 codes; the per-(token, head) scales
+    arrive gathered through the block table as VMEM window slabs
+    ([1, NWIN, KVH, W*bs], see ``_window_scales``) and this group's row is
+    picked with a select.  K scales factor out of ``q @ k^T`` onto the
+    score tile; V scales fold into the probabilities, exactly the
     ``_fused_decode_quant_kernel`` convention.
     """
     if quant:
-        ks_hbm, vs_hbm, o_ref = rest
+        ks_ref, vs_ref, o_ref = rest
     else:
         (o_ref,) = rest
     b = pl.program_id(0)
@@ -1032,14 +1087,17 @@ def _flash_prefill_kernel(
     n_windows = (n_blocks + W - 1) // W
 
     if quant:
-        onehot_g = (jax.lax.broadcasted_iota(jnp.int32, (1, KVH), 1)
-                    == g).astype(jnp.float32)    # picks this group's scales
+        own_row = jax.lax.broadcasted_iota(jnp.int32, (KVH, 1), 0) == g
 
-    qt = q_ref[0, :, 0, :].astype(jnp.float32)   # [TQ, qpk*D]
+        def _group_scales(win):                  # [KVH, W*bs] -> [1, W*bs]
+            return jnp.sum(jnp.where(own_row, win, 0.0), axis=0,
+                           keepdims=True)
+
+    qt = q_ref[0, 0].astype(jnp.float32)         # [TQ, qpk*D]
     q2 = jnp.concatenate(
         [qt[:, j * D:(j + 1) * D] for j in range(qpk)], axis=0)  # [R, D]
 
-    def scoped(k_buf, v_buf, sem, ks_buf=None, vs_buf=None, ssem=None):
+    def scoped(k_buf, v_buf, sem):
         # k_buf/v_buf: [2, W*bs, D] double-buffered page-slice slabs —
         # only this group's D lanes ever leave HBM.
         def start_window(slot, w):
@@ -1054,13 +1112,6 @@ def _flash_prefill_kernel(
                     v_hbm.at[blk, :, pl.ds(g * D, D)],
                     v_buf.at[slot, pl.ds(i * bs, bs)],
                     sem.at[slot, i, 1]).start()
-                if quant:
-                    pltpu.make_async_copy(
-                        ks_hbm.at[blk], ks_buf.at[slot, pl.ds(i * bs, bs)],
-                        ssem.at[slot, i, 0]).start()
-                    pltpu.make_async_copy(
-                        vs_hbm.at[blk], vs_buf.at[slot, pl.ds(i * bs, bs)],
-                        ssem.at[slot, i, 1]).start()
 
         def wait_window(slot, w):
             for i in range(W):
@@ -1074,13 +1125,6 @@ def _flash_prefill_kernel(
                     v_hbm.at[blk, :, pl.ds(g * D, D)],
                     v_buf.at[slot, pl.ds(i * bs, bs)],
                     sem.at[slot, i, 1]).wait()
-                if quant:
-                    pltpu.make_async_copy(
-                        ks_hbm.at[blk], ks_buf.at[slot, pl.ds(i * bs, bs)],
-                        ssem.at[slot, i, 0]).wait()
-                    pltpu.make_async_copy(
-                        vs_hbm.at[blk], vs_buf.at[slot, pl.ds(i * bs, bs)],
-                        ssem.at[slot, i, 1]).wait()
 
         start_window(0, 0)                       # n_windows >= 1 always
 
@@ -1102,13 +1146,7 @@ def _flash_prefill_kernel(
                 q2, kblk, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [R, W*bs]
             if quant:
-                ks_g = jax.lax.dot_general(
-                    onehot_g, ks_buf[slot], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)     # [1, W*bs]
-                vs_g = jax.lax.dot_general(
-                    onehot_g, vs_buf[slot], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                s = s * ks_g
+                s = s * _group_scales(ks_ref[0, w])
             s = jnp.where(valid, s, NEG_INF)
             m_cur = jnp.max(s, axis=-1, keepdims=True)
             m_new = jnp.maximum(m, m_cur)
@@ -1116,7 +1154,7 @@ def _flash_prefill_kernel(
             p = jnp.exp(s - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
             if quant:
-                p = p * vs_g
+                p = p * _group_scales(vs_ref[0, w])
             pv = jax.lax.dot_general(
                 p, vblk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [R, D]
@@ -1130,21 +1168,15 @@ def _flash_prefill_kernel(
         # the guard only hardens against a fully-degenerate table.
         out = acc / jnp.where(l > 0.0, l, 1.0)
         for j in range(qpk):
-            o_ref[0, :, 0, j * D:(j + 1) * D] = out[
+            o_ref[0, 0, :, j * D:(j + 1) * D] = out[
                 j * TQ:(j + 1) * TQ].astype(o_ref.dtype)
 
-    scope = dict(
+    pl.run_scoped(
+        scoped,
         k_buf=pltpu.VMEM((2, W * bs, D), k_hbm.dtype),
         v_buf=pltpu.VMEM((2, W * bs, D), v_hbm.dtype),
         sem=pltpu.SemaphoreType.DMA((2, W, 2)),
     )
-    if quant:
-        scope.update(
-            ks_buf=pltpu.VMEM((2, W * bs, KVH), jnp.float32),
-            vs_buf=pltpu.VMEM((2, W * bs, KVH), jnp.float32),
-            ssem=pltpu.SemaphoreType.DMA((2, W, 2)),
-        )
-    pl.run_scoped(scoped, **scope)
 
 
 def flash_prefill_attention(
@@ -1165,15 +1197,16 @@ def flash_prefill_attention(
     ``start[b] + i`` and attends causally through itself — the same
     geometry contract as ``paged_verify_attention_pallas``, but tiled for
     bucket-sized S: queries split into TQ-token tiles (largest power of two
-    <= 128 dividing S), scores reduce through online-softmax carries, and
-    the ``[S, T]`` score matrix is never materialized.  The chunk's own K/V
+    in 8..128 dividing S, after padding S to a multiple of 8), scores
+    reduce through online-softmax carries, and the ``[S, T]`` score matrix
+    is never materialized.  The chunk's own K/V
     must already be scattered into the pages (models/llama.py scatters
     before attention), which is what collapses fresh prefill
     (``start = 0``), continuation chunks, and spec verify into one kernel.
 
     ``k_scale``/``v_scale`` ([num_blocks, bs, KVH] float32) switch on
     in-kernel dequantization of int8/fp8 pages — the quantized pool never
-    widens in HBM.
+    widens in HBM (only the scales are gathered ahead of the kernel).
 
     Args:
       q: [B, S, H, D] (S = prefill bucket).
@@ -1191,44 +1224,59 @@ def flash_prefill_attention(
     assert H % KVH == 0, (H, KVH)
     qpk = H // KVH
     quant = k_scale is not None
-    TQ = next(tt for tt in (128, 64, 32, 16, 8, 4, 2, 1) if S % tt == 0)
-    NQ = S // TQ
+    # Mosaic tiles the last two block dims (8, 128): the query tile must
+    # span a multiple of 8 rows, so a bucket no power of two >= 8 divides
+    # (spec verify's S = k+1) pads up to the next multiple of 8.  Pad rows
+    # sit past ``lengths`` — dead rows the slice below drops.
+    Sp = -(-S // 8) * 8
+    TQ = next(tt for tt in (128, 64, 32, 16, 8) if Sp % tt == 0)
+    NQ = Sp // TQ
 
     # Head order is group-major (head h serves kv group h // qpk), so a
     # plain reshape lands each group's qpk heads on contiguous D-lane
-    # slices of its [B, S, KVH, qpk*D] slab.
-    qg = (q * (D ** -0.5)).reshape(B, S, KVH, qpk * D)
+    # slices; the kv-group axis then moves ahead of the token axis so the
+    # block's last two dims are (TQ, qpk*D) — a size-1 KVH block in the
+    # second-to-last place is not a shape the TPU lowering accepts.
+    qg = (q * (D ** -0.5)).reshape(B, S, KVH, qpk * D).transpose(0, 2, 1, 3)
+    if Sp != S:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
 
     def qmap(b, g, t, *_):
-        return (b, t, g, 0)
+        return (b, g, t, 0)
+
+    scale_ops, scale_specs = [], []
+    if quant:
+        W = min(_WINDOW, block_table.shape[1])
+        scale_ops = [_window_scales(k_scale, block_table, W),
+                     _window_scales(v_scale, block_table, W)]
+        scale_specs = [pl.BlockSpec((1,) + scale_ops[0].shape[1:],
+                                    lambda b, g, t, *_: (b, 0, 0, 0))] * 2
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, KVH, NQ),
         in_specs=[
-            pl.BlockSpec((1, TQ, 1, qpk * D), qmap),
+            pl.BlockSpec((1, 1, TQ, qpk * D), qmap),
             pl.BlockSpec(memory_space=pl.ANY),   # K pages stay in HBM
             pl.BlockSpec(memory_space=pl.ANY),   # V pages stay in HBM
-        ] + ([pl.BlockSpec(memory_space=pl.ANY)] * 2 if quant else []),
-        out_specs=pl.BlockSpec((1, TQ, 1, qpk * D), qmap),
+        ] + scale_specs,
+        out_specs=pl.BlockSpec((1, 1, TQ, qpk * D), qmap),
     )
 
     operands = [block_table, start.astype(jnp.int32),
-                lengths.astype(jnp.int32), qg, k_pages, v_pages]
-    if quant:
-        operands += [k_scale, v_scale]
+                lengths.astype(jnp.int32), qg, k_pages, v_pages] + scale_ops
     out = pl.pallas_call(
         functools.partial(_flash_prefill_kernel, TQ, D, KVH, qpk, quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, KVH, qpk * D), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((B, KVH, Sp, qpk * D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             # Programs are fully independent (read-only pages, disjoint
             # output tiles): megacore may split any grid axis.
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
     )(*operands)
-    return out.reshape(B, S, H, D)
+    return out[:, :, :S].transpose(0, 2, 1, 3).reshape(B, S, H, D)
 
 
 # Marker consumed by models/llama.py:is_flash_prefill_impl — the prefill

@@ -22,9 +22,9 @@ AXES = ("data", "seq", "model")
 
 # Approximate aggregate ICI bandwidth per chip (GB/s, all links, one
 # direction), keyed by substrings of jax Device.device_kind — the byte-model
-# input for the engine's per-step collective-time share estimate.  CPU and
-# unknown chips fall back to the v5e figure: the estimate is explicitly a
-# model, and on the forced-host dev mesh it is annotated as a dryrun.
+# input for the engine's per-step collective-time share estimate.  A device
+# outside the table (the CPU test meshes included) has no figure: the
+# lookups return None and the engine publishes no estimate for it.
 ICI_GBS = {
     "v5 lite": 200.0,   # v5e: 4 links x 400 Gbps
     "v5e": 200.0,
@@ -32,16 +32,20 @@ ICI_GBS = {
     "v4": 300.0,
     "v6": 448.0,        # v6e (Trillium)
 }
-_ICI_GBS_DEFAULT = 200.0
 
 
-def ici_bandwidth_gbs(device_kind: str) -> float:
-    """Per-chip aggregate ICI bandwidth for ``device_kind`` (GB/s)."""
+def _lookup_gbs(table: dict[str, float], device_kind: str) -> float | None:
     kind = device_kind.lower()
-    for key, gbs in ICI_GBS.items():
+    for key, gbs in table.items():
         if key in kind:
             return gbs
-    return _ICI_GBS_DEFAULT
+    return None
+
+
+def ici_bandwidth_gbs(device_kind: str) -> float | None:
+    """Per-chip aggregate ICI bandwidth for ``device_kind`` (GB/s); None
+    for a device that is not in the table."""
+    return _lookup_gbs(ICI_GBS, device_kind)
 
 
 # Per-chip HBM bandwidth (GB/s), same keying as ICI_GBS.  Paired with it
@@ -56,16 +60,12 @@ HBM_GBS = {
     "v4": 1228.0,
     "v6": 1640.0,       # v6e (Trillium)
 }
-_HBM_GBS_DEFAULT = 819.0
 
 
-def hbm_bandwidth_gbs(device_kind: str) -> float:
-    """Per-chip HBM bandwidth for ``device_kind`` (GB/s)."""
-    kind = device_kind.lower()
-    for key, gbs in HBM_GBS.items():
-        if key in kind:
-            return gbs
-    return _HBM_GBS_DEFAULT
+def hbm_bandwidth_gbs(device_kind: str) -> float | None:
+    """Per-chip HBM bandwidth for ``device_kind`` (GB/s); None for a
+    device that is not in the table."""
+    return _lookup_gbs(HBM_GBS, device_kind)
 
 
 def init_multihost(coordinator: str | None = None,
@@ -91,18 +91,8 @@ def init_multihost(coordinator: str | None = None,
 
     # Must not touch any API that initializes the XLA backend before
     # initialize() — jax.process_count() does, after which initialize()
-    # raises unconditionally.  Only read distributed-client state here
-    # (jax.distributed.is_initialized() where available, else the global
-    # state object older jax exposes).
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is None:
-        from jax._src import distributed as _dist
-
-        def is_init():
-            state = getattr(_dist, "global_state", None)
-            return state is not None and state.client is not None
-
-    if is_init():
+    # raises unconditionally.  Only read distributed-client state here.
+    if jax.distributed.is_initialized():
         return jax.process_index()
     coordinator = coordinator or os.environ.get("JAX_COORDINATOR")
     num_processes = num_processes or int(os.environ.get("NUM_PROCESSES", 0))
@@ -122,22 +112,6 @@ def init_multihost(coordinator: str | None = None,
                 "jax.distributed.initialize() not applicable (%s); "
                 "continuing single-host", exc)
     return jax.process_index()
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs,
-                     check_replication=True):
-    """``jax.shard_map`` across the 0.8 API rename (check_rep -> check_vma)
-    — the single compat point for every shard_map call site in the tree."""
-    try:  # jax >= 0.8
-        from jax import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_replication)
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_replication)
 
 
 @dataclasses.dataclass(frozen=True)

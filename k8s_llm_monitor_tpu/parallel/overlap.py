@@ -87,9 +87,11 @@ from k8s_llm_monitor_tpu.ops.attention import (
     paged_decode_attention,
     paged_decode_attention_quant,
 )
+from k8s_llm_monitor_tpu.ops.pallas_attention import (
+    paged_decode_attention_pallas,
+)
 from k8s_llm_monitor_tpu.ops.norms import rms_norm
 from k8s_llm_monitor_tpu.ops.rope import apply_rope, rope_angles
-from k8s_llm_monitor_tpu.parallel.mesh import shard_map_compat
 from k8s_llm_monitor_tpu.parallel.sharding import (
     kv_pages_partition_specs,
     param_partition_specs,
@@ -150,15 +152,8 @@ def _per_shard_attn(cfg: ModelConfig, tp: int, attn_path: str):
     if attn_path != "gather" and not cfg.has_attn_extras:
         interpret = jax.default_backend() != "tpu"
         if interpret or _pallas_geometry_ok(cfg, tp):
-            try:
-                from k8s_llm_monitor_tpu.ops.pallas_attention import (
-                    paged_decode_attention_pallas,
-                )
-
-                return functools.partial(paged_decode_attention_pallas,
-                                         interpret=interpret)
-            except Exception:  # pragma: no cover - lowering unavailable
-                pass
+            return functools.partial(paged_decode_attention_pallas,
+                                     interpret=interpret)
     return paged_decode_attention
 
 
@@ -247,14 +242,14 @@ def make_overlap_decode_step(mesh, cfg: ModelConfig, params, pages: KVPages,
         x_full = jax.lax.all_gather(x_scat, MODEL_AXIS, axis=2, tiled=True)
         return x_full, new_k, new_v, new_ks, new_vs
 
-    sharded_layers = shard_map_compat(
+    sharded_layers = jax.shard_map(
         _layers, mesh=mesh,
         in_specs=(layer_specs, rep3, rep3, rep3, rep2, rep2, P(None),
                   kv_specs.k, kv_specs.v, list(kv_specs.k_scale),
                   list(kv_specs.v_scale), rep2),
         out_specs=(rep3, kv_specs.k, kv_specs.v, list(kv_specs.k_scale),
                    list(kv_specs.v_scale)),
-        check_replication=False)
+        check_vma=False)
 
     def step(params, tokens, context_lens, pages, tables):
         positions = context_lens[:, None]

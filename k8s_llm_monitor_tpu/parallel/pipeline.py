@@ -52,14 +52,13 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from k8s_llm_monitor_tpu.parallel.mesh import shard_map_compat
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
     # Replication checking stays off: the psum-broadcast output pattern
     # (only the last stage holds real values pre-psum) trips it.
-    return shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_replication=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from k8s_llm_monitor_tpu.models import llama

@@ -29,7 +29,6 @@ from k8s_llm_monitor_tpu.ops.attention import NEG_INF, _repeat_kv
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from k8s_llm_monitor_tpu.parallel.mesh import shard_map_compat as _shard_map
 
 
 def _block_update(q, k, v, q_pos, kv_pos, kv_len, m, l, acc):
@@ -107,7 +106,7 @@ def make_ring_attention(mesh: Mesh, axis: str = "seq"):
         if kv_len is None:
             kv_len = jnp.full((B,), T, jnp.int32)
         qkv_spec = P("data", axis, "model", None)
-        fn = _shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec,
                       P("data", axis), P("data")),

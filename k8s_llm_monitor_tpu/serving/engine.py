@@ -22,9 +22,8 @@ diagnosis queries, BASELINE.md):
     calls join an in-flight queue (depth ``max_inflight``); their results are
     fetched via ``copy_to_host_async`` and reconciled (emission, EOS/budget
     retirement, TTFT stamping) behind the dispatch front.  This hides the
-    device->host latency that would otherwise serialize every step — on a
-    remote-tunneled chip that latency is the dominant cost, and on a local
-    chip it still buys dispatch/compute overlap.
+    device->host latency that would otherwise serialize every step, and
+    buys dispatch/compute overlap.
   * Prompts longer than the largest bucket admit into *prefilling* slots:
     their chunks stream one batched round per scheduler step (depth-first —
     lanes closest to completion go first), so decode dispatches and
@@ -216,8 +215,7 @@ class EngineConfig:
     # the model-dtype pool (the flag-selectable fp16/bf16 oracle, same
     # pattern as decode_path); "int8"/"fp8" store pages in the narrow dtype
     # with per-(token, head) f32 dequant scales — roughly doubling resident
-    # lanes on the same pool bytes (page_slice_bytes accounting).  fp8
-    # falls back to int8 when this jax build lacks float8_e4m3fn.
+    # lanes on the same pool bytes (page_slice_bytes accounting).
     # K8SLLM_KV_DTYPE overrides.
     kv_dtype: str = "auto"
     # Host-RAM spill tier capacity in bytes (rung 2): pressured prefix-cache
@@ -461,10 +459,6 @@ class InferenceEngine:
             self.kv_quant = ""
         elif kvd in ("int8", "fp8"):
             self.kv_quant = kvd
-            if kvd == "fp8" and not hasattr(jnp, "float8_e4m3fn"):
-                logger.warning(
-                    "kv_dtype=fp8 requested but this jax build has no "
-                    "float8_e4m3fn; falling back to int8 KV")
         else:
             raise ValueError(
                 f"unknown kv_dtype {kvd!r} (auto | int8 | fp8)")
@@ -634,8 +628,10 @@ class InferenceEngine:
                                "schedule (%s)", why_not)
         # Measured share of the per-step ring time the overlap schedule
         # hides; estimate_hidden_share() fills it from profile/bench runs
-        # and the exporter publishes it.
-        self.decode_collective_hidden_share = 0.0
+        # and the exporter publishes it.  None (never published) when the
+        # mesh's device kind has no bandwidth figure (parallel/mesh.py).
+        self.decode_collective_hidden_share: Optional[float] = (
+            None if self._ring_ici_ms() is None else 0.0)
         # Multi-query attention for the speculative verify pass (Pallas
         # kernel on compatible single-chip TPU; XLA gather otherwise).
         # Quantized pools drop the dedicated verify kernel: llama's
@@ -817,6 +813,14 @@ class InferenceEngine:
         self.brownout = None
         self.dispatch_failures = 0
         self.consecutive_dispatch_failures = 0
+        # True while the step thread is inside a jitted program call, and
+        # when it last left one.  The call is asynchronous once compiled,
+        # so a long stay means the program's first compile (about a minute
+        # for a 7B program on the chip's host, and one step() can make
+        # several).  The supervisor's stale-heartbeat detector reads both,
+        # so compiling is not mistaken for a wedged loop.
+        self.in_program_call = False
+        self.last_program_call = 0.0
         self.watchdog_trips = 0
         self.deadline_expired = 0
         self.requeues = 0
@@ -855,8 +859,10 @@ class InferenceEngine:
         self.prefill_bucket_rounds: dict[int, int] = {}
         # Per-step collective (ICI) share of the TP decode step, estimated
         # by profile_decode_phases() from the measured step time and the
-        # ring-all-reduce byte model; 0.0 off-mesh or before profiling.
-        self.decode_collective_share = 0.0
+        # ring-all-reduce byte model; 0.0 off-mesh or before profiling,
+        # None for a device kind without an ICI figure.
+        self.decode_collective_share: Optional[float] = (
+            None if self._ring_ici_ms() is None else 0.0)
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
         # class, with exemplar trace ids, observed on the step thread only.
         # The exporter renders these as real Prometheus histograms.
@@ -2119,6 +2125,7 @@ class InferenceEngine:
         fnext = None
         try:
             self._faults.maybe_raise("prefill_dispatch")
+            self.in_program_call = True
             if not any_shared:
                 if constrained:
                     self._rng, sub = jax.random.split(self._rng)
@@ -2182,6 +2189,9 @@ class InferenceEngine:
                     requeue.append(req)
             self._pending.extendleft(reversed(requeue))
             return admitted_long > 0
+        finally:
+            self.in_program_call = False
+            self.last_program_call = time.monotonic()
         self._record_dispatch_ok()
         self.prefill_bucket_rounds[bucket] = (
             self.prefill_bucket_rounds.get(bucket, 0) + 1)
@@ -2286,6 +2296,7 @@ class InferenceEngine:
         fnext = None
         try:
             self._faults.maybe_raise("prefill_dispatch")
+            self.in_program_call = True
             if final_constrained:
                 # Only final lanes sample, so only they consult the FSM;
                 # non-final lanes stay at state 0 and drop their token (and
@@ -2319,7 +2330,14 @@ class InferenceEngine:
                 if became_final:
                     s.prefilling = True
             self._record_dispatch_failure(exc)
+            if self.consecutive_dispatch_failures > ec.max_requeues:
+                # Same bound as the decode dispatch: a chunk program that
+                # fails identically every step must end its requests.
+                self._reset_pipeline(f"chunk dispatch failed: {exc!r}")
             return False
+        finally:
+            self.in_program_call = False
+            self.last_program_call = time.monotonic()
         self._record_dispatch_ok()
         self.prefill_bucket_rounds[bucket] = (
             self.prefill_bucket_rounds.get(bucket, 0) + 1)
@@ -2599,7 +2617,7 @@ class InferenceEngine:
         ``mesh_axes`` topology gauge."""
         return dict(self.mesh.shape) if self.mesh is not None else {}
 
-    def _estimate_collective_share(self, step_ms: float) -> float:
+    def _estimate_collective_share(self, step_ms: float) -> Optional[float]:
         """Per-step ICI time share of the TP decode step (byte model).
 
         Row-parallel o/down projections each psum a [B, hidden] activation
@@ -2607,21 +2625,24 @@ class InferenceEngine:
         over each chip's links.  Dividing that wire time (at the chip's
         aggregate ICI bandwidth) by the *measured* step time gives the
         share the dashboard shows next to ``decode_attn_ms``.  It is an
-        estimate — collectives overlap compute on real meshes — and on the
-        forced-host CPU mesh the step time itself is a dryrun stand-in.
+        estimate — collectives overlap compute on real meshes.  None for a
+        device kind without an ICI figure (the CPU test meshes).
         """
         ici_ms = self._ring_ici_ms()
+        if ici_ms is None:
+            return None
         if ici_ms <= 0.0 or step_ms <= 0.0:
             return 0.0
         return min(1.0, ici_ms / step_ms)
 
-    def _ring_ici_ms(self) -> float:
+    def _ring_ici_ms(self) -> Optional[float]:
         """Per-step wire time of the TP decode collectives (byte model,
         ms): row-parallel o/down each move ``2*(tp-1)/tp`` of a
         [max_slots, hidden] activation over each chip's ICI links per
         layer — the same bytes whether staged as one ring all-reduce
         (GSPMD) or as a reduce-scatter + all-gather pair (overlap path).
-        0.0 off-mesh / TP=1."""
+        0.0 off-mesh / TP=1; None when the mesh's device kind has no ICI
+        figure in parallel/mesh.py (no device gets another's)."""
         if self.mesh is None:
             return 0.0
         tp = self.mesh.shape.get("model", 1)
@@ -2634,35 +2655,37 @@ class InferenceEngine:
         payload = self.ecfg.max_slots * cfg.hidden_size * act_bytes
         per_chip_bytes = (2 * cfg.num_layers          # o-proj + down-proj
                           * 2.0 * (tp - 1) / tp * payload)
-        kind = self.mesh.devices.flat[0].device_kind
-        return per_chip_bytes / (ici_bandwidth_gbs(kind) * 1e9) * 1e3
+        gbs = ici_bandwidth_gbs(self.mesh.devices.flat[0].device_kind)
+        if gbs is None:
+            return None
+        return per_chip_bytes / (gbs * 1e9) * 1e3
 
     def estimate_hidden_share(self, step_ms_on: float | None = None,
-                              step_ms_off: float | None = None) -> float:
+                              step_ms_off: float | None = None
+                              ) -> Optional[float]:
         """``decode_collective_hidden_share``: fraction of the per-step
         ring wire time the overlap schedule hides under compute.
 
-        On TPU, with measured overlap-on and overlap-off step times, the
-        hidden share is the observed saving against the byte model:
-        ``(off - on) / ring_ici_ms``, clamped to [0, 1].
-
-        Off-TPU (the forced-host dev mesh), interpreter step times are
-        meaningless, so the dryrun falls back to the analytic window
-        model: a reduce-scatter/all-gather half is hidden up to the time
-        the next column-parallel matmuls spend streaming their weight
-        shard HBM->VMEM (decode is weight-streaming bound).  Per layer
-        that window is the per-chip column weight bytes over HBM
-        bandwidth; the wire is the per-layer share of ``_ring_ici_ms``.
-        Both the measured and analytic figures land in
-        ``self.decode_collective_hidden_share`` for /metrics.
+        With measured overlap-on and overlap-off step times, the hidden
+        share is the observed saving against the byte model:
+        ``(off - on) / ring_ici_ms``, clamped to [0, 1].  Without them it
+        is the analytic window model: a reduce-scatter/all-gather half is
+        hidden up to the time the next column-parallel matmuls spend
+        streaming their weight shard HBM->VMEM (decode is weight-streaming
+        bound).  Per layer that window is the per-chip column weight bytes
+        over HBM bandwidth; the wire is the per-layer share of
+        ``_ring_ici_ms``.  Either figure lands in
+        ``self.decode_collective_hidden_share`` for /metrics.  A device
+        kind without ICI/HBM figures (the CPU test meshes) gets None.
         """
-        share = 0.0
         ici_ms = self._ring_ici_ms()
+        if ici_ms is None:
+            self.decode_collective_hidden_share = None
+            return None
         if ici_ms <= 0.0 or not self.tp_overlap:
             self.decode_collective_hidden_share = 0.0
             return 0.0
-        on_tpu = jax.default_backend() == "tpu"
-        if (on_tpu and step_ms_on is not None and step_ms_off is not None
+        if (step_ms_on is not None and step_ms_off is not None
                 and step_ms_off > 0.0):
             share = max(0.0, min(1.0, (step_ms_off - step_ms_on) / ici_ms))
         else:
@@ -2670,6 +2693,8 @@ class InferenceEngine:
 
             cfg = self.cfg
             tp = self.mesh.shape.get("model", 1)
+            hbm_gbs = hbm_bandwidth_gbs(
+                self.mesh.devices.flat[0].device_kind)
             # int8 weights stream 1 byte/element; float params their dtype.
             layer0 = self.params["layers"][0]
             wbytes = (1 if "kernel_q" in layer0["q"]
@@ -2678,10 +2703,7 @@ class InferenceEngine:
             col_weights = (cfg.hidden_size * cfg.num_heads * D       # q
                            + 2 * cfg.hidden_size * cfg.num_kv_heads * D
                            + 2 * cfg.hidden_size * cfg.intermediate_size)
-            stream_ms = (col_weights * wbytes / tp
-                         / (hbm_bandwidth_gbs(
-                             self.mesh.devices.flat[0].device_kind) * 1e9)
-                         * 1e3)
+            stream_ms = col_weights * wbytes / tp / (hbm_gbs * 1e9) * 1e3
             wire_ms = ici_ms / (2 * cfg.num_layers)   # one RS/AG pair
             share = min(1.0, stream_ms / wire_ms) if wire_ms > 0 else 0.0
         self.decode_collective_hidden_share = share
@@ -2953,6 +2975,7 @@ class InferenceEngine:
             s.req.sampling.constrained for _, s in lanes))
         try:
             self._faults.maybe_raise("decode_dispatch")
+            self.in_program_call = True
             payload, kind = self._dispatch_decode_call(
                 spec and not constrained, all_greedy, lanes, K, ctx,
                 steps_arr, table, temp, topk, topp, eos,
@@ -2964,7 +2987,17 @@ class InferenceEngine:
             for _, s, steps_i in meta:
                 s.inflight_decode -= steps_i
             self._record_dispatch_failure(exc)
+            if self.consecutive_dispatch_failures > ec.max_requeues:
+                # The same call keeps failing before it reaches the device
+                # — a program the compiler refuses fails identically every
+                # step.  Bound it like a failed prefill dispatch: requeue
+                # the lanes (max_requeues), then fail them with the cause,
+                # instead of spinning on it with the callers waiting.
+                self._reset_pipeline(f"decode dispatch failed: {exc!r}")
             return False
+        finally:
+            self.in_program_call = False
+            self.last_program_call = time.monotonic()
         self._record_dispatch_ok()
         if self._faults.should_fire("decode_stuck"):
             payload = _StuckPayload(payload)
@@ -3187,7 +3220,7 @@ class InferenceEngine:
                         max(0.0, now - call.t0) / steps_i,
                         s.req.slo_class, self._trace_id(s.req))
                 attrs = {"steps": steps_i, "emitted": len(new)}
-                if coll > 0.0:
+                if coll:
                     attrs["collective_share"] = coll
                 if call.kind == "spec":
                     attrs["rounds"] = self.ecfg.spec_rounds_per_iter

@@ -72,6 +72,13 @@ from k8s_llm_monitor_tpu.serving.service import EngineService, RequestHandle
 
 logger = logging.getLogger("serving.supervisor")
 
+# Silence the stale-heartbeat detector tolerates while the step thread is
+# inside a jitted program call (InferenceEngine.in_program_call): a first
+# call compiles — about a minute per program for a 7B model on a v5e host,
+# several in a row on a cold start — and rebuilding the engine under a
+# compile only starts the same compile again.
+COMPILE_GRACE_S = 1800.0
+
 SERVING = "serving"
 REBUILDING = "rebuilding"
 TERMINATING = "terminating"
@@ -330,8 +337,19 @@ class EngineSupervisor:
                 dead = svc._dead
             reason = dead
             if reason is None and svc.engine.has_work:
-                stale_s = self._clock() - svc.last_heartbeat
-                if stale_s > self.heartbeat_timeout_s:
+                # A finished program call is a sign of life too: one
+                # step() can compile several programs back to back, and
+                # the loop's own heartbeat only ticks between steps.
+                stale_s = self._clock() - max(
+                    svc.last_heartbeat,
+                    getattr(svc.engine, "last_program_call", 0.0))
+                # Inside a program call the thread is compiling, not
+                # wedged: only COMPILE_GRACE_S of silence reads as a wedge
+                # there (a hung device shows at reconcile, outside it).
+                limit = (max(self.heartbeat_timeout_s, COMPILE_GRACE_S)
+                         if getattr(svc.engine, "in_program_call", False)
+                         else self.heartbeat_timeout_s)
+                if stale_s > limit:
                     reason = (f"step loop wedged: no heartbeat for "
                               f"{stale_s:.1f}s with work pending")
             if reason is not None:

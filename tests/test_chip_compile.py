@@ -1,0 +1,205 @@
+"""Ask the chip's compiler, with no chip attached.
+
+Interpret-mode tests cannot see what Mosaic refuses (a slice not aligned to
+the tiling, a block shape the lowering does not take); PR 21 found both the
+fused decode kernel and flash prefill refused that way after passing every
+CPU test.  These tests compile each default-path Pallas kernel at Qwen2-7B
+widths and the engine's real pool shapes for a *described* TPU v5e — about
+two seconds each, no chip time — and the ``shard_map``-wrapped kernels of the
+tensor-parallel path on a mesh of the four described devices.
+
+A compile that passes is not a chip run: it says the kernel is accepted, not
+that its results or its speed are right (``chip_smoke.py`` checks results on
+the chip).
+
+The topology is described inside a fixture, never at import or collection:
+only one process may load the TPU library, and every xdist worker imports
+every test file.  All of these tests stay in this one file for the same
+reason, and compile in the test's own process.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from k8s_llm_monitor_tpu.models.config import PRESETS
+from k8s_llm_monitor_tpu.ops import attention as ops
+from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+
+CFG = PRESETS["qwen2-7b"]
+H, KVH, D = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_
+F = KVH * D
+# TPULLMConfig / EngineConfig defaults: 512 blocks x 16 tokens, 32 decode
+# lanes, 64 blocks per sequence, 8 prefill lanes.
+NBLK, BS, NB, LANES, PLANES = 512, 16, 64, 32, 8
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+FP8 = jnp.float8_e4m3fn
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (the next one warns and compiles
+    again): keep the cache out of these tests."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo, no_persistent_cache):
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=sharding)
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo, no_persistent_cache):
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
+                ("data", "seq", "model"))
+
+    def arg(shape, dtype, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    return mesh, arg
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described device(s); raises what the chip's
+    compiler would raise.  Returns the compiled text."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def _decode_args(S, pool_dtype, bs=BS):
+    return (S((LANES, 1, H, D), BF16), S((LANES, 1, KVH, D), BF16),
+            S((LANES, 1, KVH, D), BF16), S((LANES, 1, D), F32),
+            S((LANES, 1, D), F32), S((NBLK, bs, F), pool_dtype),
+            S((NBLK, bs, F), pool_dtype))
+
+
+def test_fused_decode_compiles(one_chip):
+    S = one_chip
+    _compile(pa.paged_decode_attention_fused, *_decode_args(S, BF16),
+             S((LANES, NB), I32), S((LANES,), I32))
+
+
+@pytest.mark.parametrize("bs,dtype", [(32, BF16), (16, F32)],
+                         ids=["bf16-bs32", "f32-bs16"])
+def test_fused_decode_partial_tile_append_compiles(one_chip, bs, dtype):
+    """Pages taller than one sublane tile append through a dynamic-offset
+    aligned tile (``_append_rows``'s ``pl.ds`` branch), not the whole page."""
+    S = one_chip
+    assert pa._append_tile_rows(bs, dtype) < bs
+    _compile(pa.paged_decode_attention_fused, *_decode_args(S, dtype, bs),
+             S((LANES, NB), I32), S((LANES,), I32))
+
+
+@pytest.mark.parametrize("pool", [jnp.int8, FP8], ids=["int8", "fp8"])
+def test_fused_quant_decode_compiles(one_chip, pool):
+    S = one_chip
+    _compile(pa.paged_decode_attention_fused_quant, *_decode_args(S, pool),
+             S((NBLK, BS, KVH), F32), S((NBLK, BS, KVH), F32),
+             S((LANES, NB), I32), S((LANES,), I32))
+
+
+def test_split_decode_compiles(one_chip):
+    S = one_chip
+    _compile(pa.paged_decode_attention_pallas, S((LANES, 1, H, D), BF16),
+             S((NBLK, BS, F), BF16), S((NBLK, BS, F), BF16),
+             S((LANES, NB), I32), S((LANES,), I32))
+
+
+def test_verify_kernel_compiles(one_chip):
+    S = one_chip
+    _compile(pa.paged_verify_attention_pallas, S((LANES, 5, H, D), BF16),
+             S((NBLK, BS, F), BF16), S((NBLK, BS, F), BF16),
+             S((LANES, NB), I32), S((LANES,), I32), S((LANES,), I32))
+
+
+# Smallest, a middle and the largest default bucket, and spec verify's
+# S = spec_k + 1 = 5 (not a multiple of 8: the wrapper pads it).
+@pytest.mark.parametrize("lanes,seq", [(PLANES, 32), (PLANES, 256),
+                                       (PLANES, 2048), (LANES, 5)])
+@pytest.mark.parametrize("pool", [None, jnp.int8, FP8],
+                         ids=["bf16", "int8", "fp8"])
+def test_flash_prefill_compiles(one_chip, lanes, seq, pool):
+    S = one_chip
+    args = [S((lanes, seq, H, D), BF16), S((NBLK, BS, F), pool or BF16),
+            S((NBLK, BS, F), pool or BF16), S((lanes, NB), I32),
+            S((lanes,), I32), S((lanes,), I32)]
+    if pool is None:
+        _compile(pa.flash_prefill_attention, *args)
+    else:
+        _compile(lambda q, k, v, t, s, n, ks, vs: pa.flash_prefill_attention(
+            q, k, v, t, s, n, k_scale=ks, v_scale=vs),
+            *args, S((NBLK, BS, KVH), F32), S((NBLK, BS, KVH), F32))
+
+
+def test_select_impls_resolve_to_the_kernels_on_tpu():
+    """What ``auto`` picks for this geometry on a TPU, decided before any
+    tracing: the fused decode kernel and flash prefill, compiled (never the
+    interpreter), one chip or a TP-4 mesh of the kv heads."""
+    dec = ops.select_decode_impl("tpu", cfg=CFG, mode="auto")
+    assert dec is pa.paged_decode_attention_fused
+    decq = ops.select_decode_impl("tpu", cfg=CFG, mode="auto",
+                                  kv_quant="int8")
+    assert decq is pa.paged_decode_attention_fused_quant
+    pre = ops.select_prefill_impl("tpu", cfg=CFG, mode="auto")
+    assert pre is pa.flash_prefill_attention
+    assert not isinstance(dec, functools.partial)
+
+
+def test_tp_decode_kernel_compiles_on_four_chips(four_chips):
+    mesh, A = four_chips
+    attn = ops.make_tp_paged_attention(mesh, CFG)
+    heads, lanes = P(None, None, "model", None), P(None, None, "model")
+    text = _compile(attn, A((LANES, 1, H, D), BF16, heads),
+                    A((NBLK, BS, F), BF16, lanes),
+                    A((NBLK, BS, F), BF16, lanes),
+                    A((LANES, NB), I32), A((LANES,), I32))
+    # Head-sharded paged attention needs no collective.
+    assert "all-reduce" not in text and "all-gather" not in text
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"], ids=["bf16", "int8"])
+def test_tp_flash_prefill_compiles_on_four_chips(four_chips, kv_quant):
+    mesh, A = four_chips
+    attn = ops.make_tp_flash_prefill(mesh, CFG, kv_quant=kv_quant)
+    heads, lanes = P(None, None, "model", None), P(None, None, "model")
+    pool = jnp.int8 if kv_quant else BF16
+    args = [A((PLANES, 256, H, D), BF16, heads),
+            A((NBLK, BS, F), pool, lanes), A((NBLK, BS, F), pool, lanes),
+            A((PLANES, NB), I32), A((PLANES,), I32), A((PLANES,), I32)]
+    if kv_quant:
+        _compile(lambda q, k, v, t, s, n, ks, vs: attn(
+            q, k, v, t, s, n, k_scale=ks, v_scale=vs), *args,
+            A((NBLK, BS, KVH), F32, lanes), A((NBLK, BS, KVH), F32, lanes))
+    else:
+        _compile(attn, *args)

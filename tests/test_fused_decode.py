@@ -198,6 +198,55 @@ def test_fused_bf16():
         rtol=2e-2, atol=2e-2)
 
 
+# The append is an aligned read-modify-write of the sublane tile holding the
+# new row (PR 21: Mosaic refuses a one-row DMA into a tiled page).  Rows:
+# 8 of 32-bit, 16 of 16-bit, capped at the page — so (f32, bs=16) and
+# (bf16, bs=32) take the partial-tile branch with a dynamic aligned offset,
+# and (bf16, bs=16) writes the whole page back.
+@pytest.mark.parametrize("dtype,bs,rows", [
+    (jnp.float32, 16, 8),
+    (jnp.bfloat16, 32, 16),
+    (jnp.bfloat16, 16, 16),
+], ids=["f32-bs16-tile8", "bf16-bs32-tile16", "bf16-bs16-page"])
+def test_fused_append_tile_read_modify_write_is_byte_exact(dtype, bs, rows):
+    """``off`` at the first and last row of a tile, on both sides of a tile
+    boundary and of a block boundary: the appended row lands byte-for-byte
+    where the gather oracle's scatter puts it, and every other row of every
+    page comes back untouched."""
+    from k8s_llm_monitor_tpu.ops.pallas_attention import _append_tile_rows
+
+    assert _append_tile_rows(bs, dtype) == rows
+    B, H, KVH, D, max_blocks = 8, 4, 2, 64, 3
+    rng = np.random.default_rng(rows * 100 + bs)
+    #          tile first | tile last | next tile | block last | next block
+    positions = np.array([0, 1, rows - 1, rows, bs - 1, bs, bs + rows - 1,
+                          2 * bs])
+    positions[0] = bs + 1          # and one interior row (no inactive lane)
+    case = _fused_case(rng, B, H, KVH, D, bs, max_blocks, positions,
+                       dtype=dtype)
+    want, wk, wv = _gather_reference(*case)
+    got, gk, gv = _run_fused(*case)
+
+    # V is appended unroped: byte-exact everywhere.  K is roped in-kernel
+    # in float32 like ops/rope.py, so its appended rows agree to the last
+    # bit in float32 and to one rounding in bfloat16; all other rows are
+    # the read-modify-write's untouched bytes.
+    assert np.array_equal(np.asarray(gv, np.float32),
+                          np.asarray(wv, np.float32))
+    k_pages, table = np.asarray(case[3], np.float32), np.asarray(case[5])
+    gk32, wk32 = np.asarray(gk, np.float32), np.asarray(wk, np.float32)
+    touched = np.zeros(gk32.shape[:2], bool)
+    for b, pos in enumerate(positions):
+        touched[table[b, pos // bs], pos % bs] = True
+    assert np.array_equal(gk32[~touched], k_pages[~touched])
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(gk32[touched], wk32[touched],
+                               rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
 # ---------------------------------------------------------------------------
 # Path selection + decode_step stream identity
 # ---------------------------------------------------------------------------

@@ -268,6 +268,56 @@ def test_double_kill_under_load_replays_without_duplicates(params, tmp_path):
     assert scan_journal(tmp_path / "wal")[1], "shutdown must seal the journal"
 
 
+def test_compiling_step_is_not_a_wedge(params):
+    """A step() that compiles several programs back to back is silent far
+    longer than the heartbeat timeout — about a minute per program for a 7B
+    model on the chip's host (PR 21: the supervisor rebuilt the engine four
+    times under its own compiles and gave up).  While the engine is inside
+    a program call, and each time it leaves one, the loop counts as alive."""
+
+    class _SlowCompiles:
+        """Engine proxy whose first step() spends 4 x 0.25 s inside
+        'program calls' (10x the heartbeat timeout in total)."""
+
+        def __init__(self, inner):
+            object.__setattr__(self, "_inner", inner)
+            object.__setattr__(self, "_compiled", False)
+
+        def __getattr__(self, name):
+            return getattr(self._inner, name)
+
+        def __setattr__(self, name, value):
+            setattr(self._inner, name, value)
+
+        def step(self):
+            if not self._compiled:
+                object.__setattr__(self, "_compiled", True)
+                for _ in range(4):
+                    self._inner.in_program_call = True
+                    time.sleep(0.25)
+                    self._inner.in_program_call = False
+                    self._inner.last_program_call = time.monotonic()
+                    time.sleep(0.05)     # host work between two programs
+            return self._inner.step()
+
+    sup = EngineSupervisor(
+        lambda: _SlowCompiles(_mk_engine(params)), max_restarts=3,
+        backoff=Backoff(base_s=0.01, cap_s=0.05, jitter=0.0),
+        heartbeat_timeout_s=0.1, poll_interval_s=0.01)
+    try:
+        h = sup.submit([1, 2, 3], SamplingParams(max_tokens=4))
+        # Loosen once the slow step is over: only the compile window is
+        # under test, not scheduler hiccups on a loaded CI box.
+        assert _wait(lambda: sup.engine._compiled, timeout=10.0)
+        time.sleep(1.3)
+        sup.heartbeat_timeout_s = 60.0
+        res = h.result(timeout=60.0)
+        assert res.finish_reason != "error", res.error
+        assert sup.restarts == 0, "a compiling step was read as a wedge"
+    finally:
+        sup.shutdown(grace_s=1.0)
+
+
 @pytest.mark.slow  # rebuild recompiles: seconds on CPU; covered by make chaos-lifecycle
 def test_wedged_loop_detected_by_stale_heartbeat(params):
     """A step() that never returns (no exception) must still trigger a

@@ -150,3 +150,90 @@ def test_tpu_backend_boot_preflight_tolerates_bogus_quantize(caplog):
         assert "kernel_q" not in q0 and q0["kernel"].dtype == jnp.bfloat16
     finally:
         backend.service.stop()
+
+
+def test_tpu_backend_build_failure_is_a_startup_error(monkeypatch):
+    """``provider="tpu"`` with a backend that cannot be built raises — it
+    never downgrades to ``TemplateBackend`` behind the operator's back
+    (``--llm template`` is the explicit way to run without a model)."""
+    from k8s_llm_monitor_tpu.monitor import analysis
+    from k8s_llm_monitor_tpu.monitor.config import LLMConfig
+
+    def boom(*_a, **_kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(analysis.LocalEngineBackend, "from_config",
+                        classmethod(boom))
+    with pytest.raises(RuntimeError, match="Mosaic failed"):
+        analysis.build_backend(LLMConfig(provider="tpu"))
+    # The explicit template provider still builds without a model.
+    assert isinstance(analysis.build_backend(LLMConfig(provider="template")),
+                      analysis.TemplateBackend)
+
+
+def test_tpu_backend_first_compile_failure_stops_the_boot(monkeypatch):
+    """A kernel the compiler refuses fails at the first compile, inside the
+    engine's jitted step — after the engine was built.  The backend's
+    start-up gate turns that into a boot error carrying the compiler's
+    message, not a server that starts and fails every request; and a
+    refused DECODE program ends its requests (bounded requeues) instead of
+    re-dispatching forever."""
+    from k8s_llm_monitor_tpu.monitor.analysis import LocalEngineBackend
+    from k8s_llm_monitor_tpu.monitor.config import TPULLMConfig
+    from k8s_llm_monitor_tpu.ops import attention
+
+    def refused(*_a, **_kw):
+        raise ValueError("Slice shape along dimension 1 must be aligned "
+                         "to tiling (8), but is 1")
+
+    monkeypatch.setattr(attention, "select_decode_impl",
+                        lambda **_kw: refused)
+    with pytest.raises(RuntimeError, match="first compile.*aligned to tiling"):
+        LocalEngineBackend.from_config(
+            TPULLMConfig(model="tiny", quantize="", spec_k=0))
+
+
+def test_compile_cache_helper_leaves_an_env_placed_cache_alone(monkeypatch,
+                                                               tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper never touches
+    ``jax_compilation_cache_dir``: whoever starts the process places it."""
+    import jax
+
+    from k8s_llm_monitor_tpu.utils import compile_cache
+
+    calls = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: calls.append(k) or real_update(k, v))
+    before_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    before_size = jax.config.jax_persistent_cache_min_entry_size_bytes
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        path, warm = compile_cache.configure_compile_cache()
+        assert path == str(tmp_path) and warm is False
+        assert "jax_compilation_cache_dir" not in calls
+        assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        real_update("jax_persistent_cache_min_compile_time_secs", before_min)
+        real_update("jax_persistent_cache_min_entry_size_bytes", before_size)
+
+
+def test_compile_cache_dir_is_the_same_from_any_working_directory(
+        monkeypatch, tmp_path):
+    """Unset, the cache is ``<checkout>/.jax_cache`` resolved from the
+    package, not from the working directory: two launch directories, one
+    absolute path."""
+    import pathlib
+
+    from k8s_llm_monitor_tpu.utils import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    seen = []
+    for where in (tmp_path, pathlib.Path(__file__).parent):
+        monkeypatch.chdir(where)
+        seen.append(compile_cache.compile_cache_dir())
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert seen == [str(repo / ".jax_cache")] * 2
+    assert pathlib.Path(seen[0]).is_absolute()

@@ -137,7 +137,10 @@ def test_mesh_topology_gauges_on_tp_engine():
     text = w.render()
     assert f'k8s_llm_monitor_mesh_axes{{axis="model"}} {n_dev}' in text
     assert 'k8s_llm_monitor_mesh_axes{axis="data"} 1' in text
-    assert "k8s_llm_monitor_engine_decode_collective_share 0.0" in text
+    # The CPU mesh's device kind has no ICI figure: the estimate is absent,
+    # not a number derived from another chip's bandwidth.
+    assert "k8s_llm_monitor_engine_decode_collective_share" not in text
+    assert "k8s_llm_monitor_engine_tp_overlap" in text
     assert eng.mesh_axes()["model"] == n_dev
 
 

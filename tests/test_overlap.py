@@ -185,10 +185,29 @@ def test_traceguard_overlap_path_zero_recompiles():
 # -- hidden-share model -------------------------------------------------------
 
 
-def test_hidden_share_dryrun_floor(cpu_mesh_devices):
-    """Off-TPU the share is the analytic weight-streaming window (column
-    weight bytes / shard over HBM bandwidth vs the per-layer ring wire
-    time).  The ISSUE's floor: >= 0.5 of the analytic ring time."""
+def test_hidden_share_absent_for_unlisted_device(cpu_mesh_devices):
+    """A device kind outside the bandwidth tables (the CPU test mesh) gets
+    no defaulted v5e figure: both estimates are None, never a number."""
+    params = llama.init_params(jax.random.PRNGKey(0), CFG)
+    mesh = create_mesh(MeshConfig(model=8))
+    eng = _engine(params, "on", mesh)
+    assert eng.decode_collective_share is None
+    assert eng.estimate_hidden_share() is None
+    assert eng.decode_collective_hidden_share is None
+    assert eng._estimate_collective_share(10.0) is None
+
+
+def test_hidden_share_analytic_floor(cpu_mesh_devices, monkeypatch):
+    """For a LISTED device the unmeasured share is the analytic
+    weight-streaming window (column weight bytes / shard over HBM
+    bandwidth vs the per-layer ring wire time).  The ISSUE's floor:
+    >= 0.5 of the analytic ring time.  The test lists this mesh's kind
+    with v5e's figures; the program itself never defaults them."""
+    from k8s_llm_monitor_tpu.parallel import mesh as mesh_mod
+
+    kind = jax.devices()[0].device_kind.lower()
+    monkeypatch.setitem(mesh_mod.ICI_GBS, kind, 200.0)
+    monkeypatch.setitem(mesh_mod.HBM_GBS, kind, 819.0)
     params = llama.init_params(jax.random.PRNGKey(0), CFG)
     mesh = create_mesh(MeshConfig(model=8))
     eng = _engine(params, "on", mesh)
