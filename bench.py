@@ -2193,7 +2193,6 @@ def main() -> None:
         log(f"decode phase attribution skipped: {exc}")
     # Captured now: the headline engine is deleted before extras assembly.
     decode_path = eng.decode_path
-    decode_host_gap_ms = eng.decode_host_gap_ms
 
     # --- E2E 128-lane decode saturation: short prompts, generations that
     # fill each lane's KV capacity, all max_slots lanes live — the engine
@@ -3132,7 +3131,6 @@ def main() -> None:
         extras["decode_attn_ms"] = round(decode_phases["decode_attn_ms"], 2)
         extras["decode_sample_ms"] = round(
             decode_phases["decode_sample_ms"], 2)
-        extras["decode_host_gap_ms"] = round(decode_host_gap_ms, 2)
     if dec_e2e_tok_s is not None:
         extras["decode_e2e_128lane_tok_s"] = round(dec_e2e_tok_s, 1)
     if w8a8_decode_tok_s is not None:

@@ -345,13 +345,14 @@ def _embed_lookup(params: Params, cfg: ModelConfig,
     """Token embedding lookup, handling int8-quantized tables."""
     emb = params["embed"]
     dtype = jnp.dtype(cfg.dtype)
-    if "weight_q" in emb:
-        rows = emb["weight_q"][tokens].astype(dtype)
-        rows = rows * emb["scale"][tokens][..., None].astype(dtype)
-    else:
-        rows = emb["weight"][tokens]
-    if cfg.embed_scale:   # Gemma: sqrt(H) normalizer, rounded to dtype
-        rows = rows * jnp.asarray(cfg.hidden_size ** 0.5, rows.dtype)
+    with jax.named_scope("embed"):
+        if "weight_q" in emb:
+            rows = emb["weight_q"][tokens].astype(dtype)
+            rows = rows * emb["scale"][tokens][..., None].astype(dtype)
+        else:
+            rows = emb["weight"][tokens]
+        if cfg.embed_scale:   # Gemma: sqrt(H) normalizer, rounded to dtype
+            rows = rows * jnp.asarray(cfg.hidden_size ** 0.5, rows.dtype)
     return rows
 
 
@@ -362,17 +363,19 @@ def _qkv_proj(layer: Params, cfg: ModelConfig, x: jnp.ndarray):
     B, S, _ = x.shape
     D = cfg.head_dim_
     aq = cfg.act_quant
-    q = _linear(layer["q"], x, aq).reshape(B, S, cfg.num_heads, D)
-    k = _linear(layer["k"], x, aq).reshape(B, S, cfg.num_kv_heads, D)
-    v = _linear(layer["v"], x, aq).reshape(B, S, cfg.num_kv_heads, D)
+    with jax.named_scope("qkv"):
+        q = _linear(layer["q"], x, aq).reshape(B, S, cfg.num_heads, D)
+        k = _linear(layer["k"], x, aq).reshape(B, S, cfg.num_kv_heads, D)
+        v = _linear(layer["v"], x, aq).reshape(B, S, cfg.num_kv_heads, D)
     return q, k, v
 
 
 def _qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
     """Project + rope.  x: [B, S, H] -> q [B,S,nH,D], k/v [B,S,nKV,D]."""
     q, k, v = _qkv_proj(layer, cfg, x)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("qkv"):
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
 
 
@@ -619,10 +622,11 @@ def _residual_tail(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
         o = rms_norm(o, layer["post_attn_norm"], cfg.rms_norm_eps, uo)
     x = x + o
     h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps, uo)
-    if cfg.num_experts > 0 and collect_aux:
-        y, aux = _moe_mlp(layer, cfg, h)
-    else:
-        y, aux = _mlp(layer, cfg, h), jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp"):
+        if cfg.num_experts > 0 and collect_aux:
+            y, aux = _moe_mlp(layer, cfg, h)
+        else:
+            y, aux = _mlp(layer, cfg, h), jnp.zeros((), jnp.float32)
     if cfg.sandwich_norms:
         y = rms_norm(y, layer["post_mlp_norm"], cfg.rms_norm_eps, uo)
     return x + y, aux
@@ -638,6 +642,14 @@ def _attn_extras(cfg: ModelConfig, layer_idx: int) -> dict:
             "window": cfg.layer_window(layer_idx)}
 
 
+def _attn_out(layer: Params, cfg: ModelConfig,
+              attn: jnp.ndarray) -> jnp.ndarray:
+    """The attention output projection.  attn: [B, S, nH*D] -> [B, S, H]."""
+    with jax.named_scope("attn_out"):
+        return _linear(layer["o"], attn, cfg.act_quant)
+
+
+@jax.named_scope("lm_head")
 def _unembed(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
                  cfg.rmsnorm_unit_offset)
@@ -694,9 +706,10 @@ def layer_block(
     h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps,
                  cfg.rmsnorm_unit_offset)
     q, k, v = _qkv(layer, cfg, h, cos, sin)
-    attn = attn_fn(q, k, v, q_positions=positions,
-                   **_attn_extras(cfg, layer_idx))
-    o = _linear(layer["o"], attn.reshape(B, S, -1), cfg.act_quant)
+    with jax.named_scope("attention"):
+        attn = attn_fn(q, k, v, q_positions=positions,
+                       **_attn_extras(cfg, layer_idx))
+    o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
     return _residual_tail(layer, cfg, x, o, collect_aux)
 
 
@@ -850,71 +863,77 @@ def _prefill_impl(
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
         q, k, v = _qkv(layer, cfg, h, cos, sin)
-        if quant:
-            pk, psk = _scatter_pages_quant(pages.k[li], pages.k_scale[li],
-                                           k, block_tables, positions, valid)
-            pv, psv = _scatter_pages_quant(pages.v[li], pages.v_scale[li],
-                                           v, block_tables, positions, valid)
-            new_ks.append(psk)
-            new_vs.append(psv)
-        else:
-            pk = _scatter_pages(pages.k[li], k, block_tables, positions,
-                                valid)
-            pv = _scatter_pages(pages.v[li], v, block_tables, positions,
-                                valid)
-        new_k.append(pk)
-        new_v.append(pv)
-        if paged_attn_fn is not None and is_flash_prefill_impl(paged_attn_fn):
-            # Flash paged prefill: the scatter above already wrote this
-            # chunk's K/V into the pages, so fresh prefill (positions
-            # start at 0) and continuation chunks are the same kernel
-            # call — no gather_pages round-trip, no [S, T] score matrix.
-            # Quantized pools hand the kernel their scale planes and
-            # dequantize in-kernel; the pool never widens in HBM.
+        # KV append and attention together: the fused decode kernel does
+        # both in one call, so the scope means the same on every path.
+        with jax.named_scope("attention"):
             if quant:
-                attn = paged_attn_fn(q, pk, pv, block_tables,
-                                     positions[:, 0], lengths,
-                                     k_scale=psk, v_scale=psv)
+                pk, psk = _scatter_pages_quant(
+                    pages.k[li], pages.k_scale[li], k, block_tables,
+                    positions, valid)
+                pv, psv = _scatter_pages_quant(
+                    pages.v[li], pages.v_scale[li], v, block_tables,
+                    positions, valid)
+                new_ks.append(psk)
+                new_vs.append(psv)
             else:
+                pk = _scatter_pages(pages.k[li], k, block_tables, positions,
+                                    valid)
+                pv = _scatter_pages(pages.v[li], v, block_tables, positions,
+                                    valid)
+            new_k.append(pk)
+            new_v.append(pv)
+            if (paged_attn_fn is not None
+                    and is_flash_prefill_impl(paged_attn_fn)):
+                # Flash paged prefill: the scatter above already wrote this
+                # chunk's K/V into the pages, so fresh prefill (positions
+                # start at 0) and continuation chunks are the same kernel
+                # call — no gather_pages round-trip, no [S, T] score matrix.
+                # Quantized pools hand the kernel their scale planes and
+                # dequantize in-kernel; the pool never widens in HBM.
+                if quant:
+                    attn = paged_attn_fn(q, pk, pv, block_tables,
+                                         positions[:, 0], lengths,
+                                         k_scale=psk, v_scale=psv)
+                else:
+                    attn = paged_attn_fn(q, pk, pv, block_tables,
+                                         positions[:, 0], lengths)
+            elif attend_to_pages and paged_attn_fn is not None and not quant:
+                # Page-streaming path (Pallas verify kernel): queries are
+                # contiguous at positions[:, 0] + i, which both verify_step
+                # and prefill_chunk guarantee.  (select_verify_impl returns
+                # None for attn-extras models, so no kwargs needed here.
+                # Quantized pools take the gather branch below instead — the
+                # verify kernel has no scale inputs; the engine mirrors this
+                # by dropping its verify impl under kv quant.)
                 attn = paged_attn_fn(q, pk, pv, block_tables,
                                      positions[:, 0], lengths)
-        elif attend_to_pages and paged_attn_fn is not None and not quant:
-            # Page-streaming path (Pallas verify kernel): queries are
-            # contiguous at positions[:, 0] + i, which both verify_step
-            # and prefill_chunk guarantee.  (select_verify_impl returns
-            # None for attn-extras models, so no kwargs needed here.
-            # Quantized pools take the gather branch below instead — the
-            # verify kernel has no scale inputs; the engine mirrors this
-            # by dropping its verify impl under kv quant.)
-            attn = paged_attn_fn(q, pk, pv, block_tables,
-                                 positions[:, 0], lengths)
-        else:
-            if attend_to_pages and quant:
-                # Dequantize-on-read: gather pages AND scales, apply the
-                # per-(token, head) scale on the small gathered activation
-                # (never the resident pool).
-                ks = gather_pages(psk, block_tables)       # [B, T, KVH]
-                vs = gather_pages(psv, block_tables)
-                kk = (gather_pages(pk, block_tables).astype(jnp.float32)
-                      .reshape(B, -1, cfg.num_kv_heads, cfg.head_dim_)
-                      * ks[..., None]).astype(k.dtype)
-                vv = (gather_pages(pv, block_tables).astype(jnp.float32)
-                      .reshape(B, -1, cfg.num_kv_heads, cfg.head_dim_)
-                      * vs[..., None]).astype(v.dtype)
-            elif attend_to_pages:
-                # Gathered view is [B, T, KVH*D]; unfuse for attention (the
-                # reshape touches the small gathered activation, never the
-                # resident page arrays).
-                kk = gather_pages(pk, block_tables).reshape(
-                    B, -1, cfg.num_kv_heads, cfg.head_dim_)
-                vv = gather_pages(pv, block_tables).reshape(
-                    B, -1, cfg.num_kv_heads, cfg.head_dim_)
             else:
-                kk, vv = k, v
-            attn = causal_attention(q, kk, vv, q_positions=positions,
-                                    kv_len=kv_len,
-                                    **_attn_extras(cfg, li))
-        o = _linear(layer["o"], attn.reshape(B, S, -1), cfg.act_quant)
+                if attend_to_pages and quant:
+                    # Dequantize-on-read: gather pages AND scales, apply the
+                    # per-(token, head) scale on the small gathered activation
+                    # (never the resident pool).
+                    ks = gather_pages(psk, block_tables)       # [B, T, KVH]
+                    vs = gather_pages(psv, block_tables)
+                    kk = (gather_pages(pk, block_tables).astype(jnp.float32)
+                          .reshape(B, -1, cfg.num_kv_heads, cfg.head_dim_)
+                          * ks[..., None]).astype(k.dtype)
+                    vv = (gather_pages(pv, block_tables).astype(jnp.float32)
+                          .reshape(B, -1, cfg.num_kv_heads, cfg.head_dim_)
+                          * vs[..., None]).astype(v.dtype)
+                elif attend_to_pages:
+                    # Gathered view is [B, T, KVH*D]; unfuse for attention (the
+                    # reshape touches the small gathered activation, never the
+                    # resident page arrays).
+                    kk = gather_pages(pk, block_tables).reshape(
+                        B, -1, cfg.num_kv_heads, cfg.head_dim_)
+                    vv = gather_pages(pv, block_tables).reshape(
+                        B, -1, cfg.num_kv_heads, cfg.head_dim_)
+                else:
+                    kk, vv = k, v
+                attn = causal_attention(q, kk, vv, q_positions=positions,
+                                        kv_len=kv_len,
+                                        **_attn_extras(cfg, li))
+        o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
         x, _ = _residual_tail(layer, cfg, x, o)
 
     out_pages = KVPages(k=new_k, v=new_v,
@@ -1087,60 +1106,56 @@ def decode_step(
     new_ks, new_vs = [], []
     for li, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
-        if fused_q:
-            # Quantized fused fast-path: rope + quantize-on-append +
-            # dequantize-in-kernel attention in one Pallas call; pages AND
-            # scales are updated in place (aliased outputs).
+        # The fused kernels rope in-kernel and take the raw projections.
+        if fused_q or fused:
             q, k, v = _qkv_proj(layer, cfg, h)
-            attn, pk, pv, psk, psv = attn_impl(
-                q, k, v, cos, sin, pages.k[li], pages.v[li],
-                pages.k_scale[li], pages.v_scale[li],
-                block_tables, context_lens)
-            new_k.append(pk)
-            new_v.append(pv)
-            new_ks.append(psk)
-            new_vs.append(psv)
-        elif fused:
-            # Fused fast-path: rope + KV append + attention in one Pallas
-            # call; the kernel owns the scatter (in-place page update) and
-            # the query/new-k rotary math.  Extras models never select
-            # this path (ops/attention.py gates on has_attn_extras).
-            q, k, v = _qkv_proj(layer, cfg, h)
-            attn, pk, pv = attn_impl(q, k, v, cos, sin,
-                                     pages.k[li], pages.v[li],
-                                     block_tables, context_lens)
-            new_k.append(pk)
-            new_v.append(pv)
-        elif quant:
-            q, k, v = _qkv(layer, cfg, h, cos, sin)
-            pk, psk = _scatter_pages_quant(pages.k[li], pages.k_scale[li],
-                                           k, block_tables, positions,
-                                           active)
-            pv, psv = _scatter_pages_quant(pages.v[li], pages.v_scale[li],
-                                           v, block_tables, positions,
-                                           active)
-            new_k.append(pk)
-            new_v.append(pv)
-            new_ks.append(psk)
-            new_vs.append(psv)
-            attn = paged_decode_attention_quant(q, pk, pv, psk, psv,
-                                                block_tables, new_lens,
-                                                **_attn_extras(cfg, li))
         else:
             q, k, v = _qkv(layer, cfg, h, cos, sin)
-            pk = _scatter_pages(pages.k[li], k, block_tables, positions,
-                                active)
-            pv = _scatter_pages(pages.v[li], v, block_tables, positions,
-                                active)
-            new_k.append(pk)
-            new_v.append(pv)
-            # Extras models are guaranteed the gather impl
-            # (select_attn_impl), which accepts the per-layer kwargs;
-            # default models pass none so custom/Pallas impls keep their
-            # fixed signature.
-            attn = attn_impl(q, pk, pv, block_tables, new_lens,
-                             **_attn_extras(cfg, li))
-        o = _linear(layer["o"], attn.reshape(B, 1, -1), cfg.act_quant)
+        with jax.named_scope("attention"):
+            if fused_q:
+                # Quantized fused fast-path: rope + quantize-on-append +
+                # dequantize-in-kernel attention in one Pallas call; pages
+                # AND scales are updated in place (aliased outputs).
+                attn, pk, pv, psk, psv = attn_impl(
+                    q, k, v, cos, sin, pages.k[li], pages.v[li],
+                    pages.k_scale[li], pages.v_scale[li],
+                    block_tables, context_lens)
+            elif fused:
+                # Fused fast-path: rope + KV append + attention in one
+                # Pallas call; the kernel owns the scatter (in-place page
+                # update) and the query/new-k rotary math.  Extras models
+                # never select this path (ops/attention.py gates on
+                # has_attn_extras).
+                attn, pk, pv = attn_impl(q, k, v, cos, sin,
+                                         pages.k[li], pages.v[li],
+                                         block_tables, context_lens)
+            elif quant:
+                pk, psk = _scatter_pages_quant(
+                    pages.k[li], pages.k_scale[li], k, block_tables,
+                    positions, active)
+                pv, psv = _scatter_pages_quant(
+                    pages.v[li], pages.v_scale[li], v, block_tables,
+                    positions, active)
+                attn = paged_decode_attention_quant(
+                    q, pk, pv, psk, psv, block_tables, new_lens,
+                    **_attn_extras(cfg, li))
+            else:
+                pk = _scatter_pages(pages.k[li], k, block_tables, positions,
+                                    active)
+                pv = _scatter_pages(pages.v[li], v, block_tables, positions,
+                                    active)
+                # Extras models are guaranteed the gather impl
+                # (select_attn_impl), which accepts the per-layer kwargs;
+                # default models pass none so custom/Pallas impls keep
+                # their fixed signature.
+                attn = attn_impl(q, pk, pv, block_tables, new_lens,
+                                 **_attn_extras(cfg, li))
+        new_k.append(pk)
+        new_v.append(pv)
+        if quant:
+            new_ks.append(psk)
+            new_vs.append(psv)
+        o = _attn_out(layer, cfg, attn.reshape(B, 1, -1))
         x, _ = _residual_tail(layer, cfg, x, o)
 
     logits = _unembed(params, cfg, x)[:, 0, :]

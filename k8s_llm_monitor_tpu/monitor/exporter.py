@@ -303,9 +303,7 @@ def _engine_metrics(w: _Writer, engine) -> None:
     # Decode-step phase attribution (fused fast-path observability).
     # attn/sample are populated by engine.profile_decode_phases() — a
     # bench/admin probe, never run on scrape — so they read 0.0 until a
-    # profile has run.  host_gap is a live EMA updated at every decode
-    # reconcile and is the one to alert on: it should sit near 0 when
-    # dispatch-ahead hides device latency.
+    # profile has run.
     path = getattr(engine, "decode_path", "unknown")
     w.metric("engine_decode_path_info", "gauge",
              "Selected decode attention path (1 = active)",
@@ -316,23 +314,16 @@ def _engine_metrics(w: _Writer, engine) -> None:
     w.metric("engine_decode_sample_ms", "gauge",
              "Profiled per-step on-device sampling cost",
              [("", round(getattr(engine, "decode_sample_ms", 0.0), 4))])
-    w.metric("engine_decode_host_gap_ms", "gauge",
-             "EMA of host time blocked per decode/spec reconcile "
-             "(~0 when dispatch-ahead hides device latency)",
-             [("", round(getattr(engine, "decode_host_gap_ms", 0.0), 4))])
 
-    # Prefill fast-path attribution, mirroring the decode trio: which
-    # path the engine selected (flash paged-prefill kernel vs dense XLA),
-    # how long prefill calls take, and which bucket sizes production
-    # actually dispatches (the 4096/8192 rungs exist only on flash).
+    # Prefill fast-path attribution: which path the engine selected
+    # (flash paged-prefill kernel vs dense XLA) and which bucket sizes
+    # production actually dispatches (the 4096/8192 rungs exist only on
+    # flash).
     ppath = getattr(engine, "prefill_path", "dense")
     w.metric("engine_prefill_path_info", "gauge",
              "Selected prefill attention path (1 = active)",
              [(f'{{path="{ppath}"}}', 1)])
-    w.metric("engine_prefill_attn_ms", "gauge",
-             "EMA of per-prefill-call wall time (dispatch to reconcile), "
-             "admission and chunk rounds alike",
-             [("", round(getattr(engine, "prefill_attn_ms", 0.0), 4))])
+    _loop_metrics(w, engine)
     bucket_rounds = getattr(engine, "prefill_bucket_rounds", {})
     if bucket_rounds:
         w.metric("engine_prefill_bucket_rounds_total", "counter",
@@ -353,6 +344,41 @@ def _engine_metrics(w: _Writer, engine) -> None:
              "Time to first token per request", samples)
     w.lines.append(f"{_PREFIX}_engine_ttft_seconds_sum {engine.ttft_sum}")
     w.lines.append(f"{_PREFIX}_engine_ttft_seconds_count {engine.ttft_count}")
+
+
+def _loop_metrics(w: _Writer, engine) -> None:
+    """Where the step thread's time goes and what its device calls carried
+    (engine._phase / engine._call_attrs): counters, so a rate over any
+    window gives the loop's shares — host time by phase, lanes and prompt
+    tokens of use against those computed, calls that found the device
+    empty.  The same numbers ride on the ``engine.step*`` and
+    ``engine.call`` spans when the loop is traced."""
+    w.metric("engine_loop_seconds_total", "counter",
+             "Step-thread seconds by loop phase (phases never overlap; "
+             "step = step() outside its phases)",
+             [(f'{{phase="{name.rsplit(".", 1)[-1]}"}}', round(sec, 6))
+              for name, sec in sorted(engine.loop_seconds.items())])
+    w.metric("engine_calls_total", "counter",
+             "Device calls dispatched, by kind (admit, chunk, decode, spec)",
+             [(f'{{kind="{k}"}}', n)
+              for k, n in sorted(engine.calls_by_kind.items())])
+    w.metric("engine_decode_slot_steps_total", "counter",
+             "Decode lane-steps computed: max_slots x steps of every "
+             "decode or spec call, live lane or not",
+             [("", engine.decode_slot_steps)])
+    w.metric("engine_decode_tokens_total", "counter",
+             "Tokens decode and spec calls delivered to requests "
+             "(over decode_slot_steps: the share of lane-steps of use)",
+             [("", engine.decode_tokens)])
+    w.metric("engine_prefill_tokens_total", "counter",
+             "Prompt tokens by kind: real = computed, padded = bucket x "
+             "rows computed, cached = served from the prefix cache",
+             [(f'{{kind="{k}"}}', n)
+              for k, n in sorted(engine.prefill_tokens.items())])
+    w.metric("engine_dispatch_on_empty_device_total", "counter",
+             "Calls enqueued when every earlier call had already "
+             "finished: the device idled while the host prepared them",
+             [("", engine.dispatch_on_empty_device)])
 
 
 def _latency_histograms(w: _Writer, engine) -> None:

@@ -131,6 +131,11 @@ class _SpanScope:
         self._ctx = ctx
         self.span = span
 
+    @property
+    def context(self) -> TraceContext:
+        """The span's own context: the parent to give its children."""
+        return self._ctx
+
     def __enter__(self) -> Span:
         self._prev = self._tracer._swap_local(self._ctx)
         return self.span
@@ -178,6 +183,14 @@ class Tracer:
         self._rid_cap = 1024
         self.recorded = 0   # spans pushed to the ring
         self.unsampled = 0  # record attempts on unsampled traces
+        self._last: Optional[Span] = None  # newest span (record_merged)
+
+    @property
+    def overwritten(self) -> int:
+        """Spans the ring has lost to newer ones since construction.
+        Above 0, a reader of ``snapshot()`` must check that the span it
+        needs is not older than the oldest one still resident."""
+        return max(0, self.recorded - self._size)
 
     # -- identity --------------------------------------------------------
 
@@ -280,8 +293,28 @@ class Tracer:
         self._push(sp)
         return sid
 
+    def record_merged(self, name: str, t0: float, t1: float,
+                      ctx: Optional[TraceContext]) -> None:
+        """Like :meth:`record`, but when the span recorded last (by any
+        thread) is a ``name`` span under the same parent, that span is
+        stretched to ``t1`` instead and its ``merged`` attribute counts
+        the pieces.  A loop that waits in short slices so leaves one span
+        for a stretch of waiting, not one a slice — an idle process does
+        not wash its own ring out."""
+        if ctx is None or not ctx.sampled:
+            return
+        last = self._last
+        if (last is not None and last.name == name
+                and last.trace_id == ctx.trace_id
+                and last.parent_id == ctx.span_id):
+            last.end = t1
+            last.attrs["merged"] += 1
+        else:
+            self.record(name, t0, t1, ctx, attrs={"merged": 1})
+
     def _push(self, span: Span) -> None:
         self._ring[next(self._ring_idx) % self._size] = span
+        self._last = span
         self.recorded += 1
 
     # -- request-id index ------------------------------------------------

@@ -237,6 +237,8 @@ def _run_paged_attn(q, k_pages, v_pages, block_table, starts, qlens,
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        # The kernel's name in a device trace (one query a lane = decode).
+        name="paged_decode_attention" if QS == 1 else "paged_verify_attention",
     )(block_table, starts, qlens, q_bd, k_pages, v_pages)
 
     # Extract each head's own kv-group slice.
@@ -610,6 +612,7 @@ def paged_decode_attention_fused(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="paged_decode_attention_fused",
     )(block_table, positions.astype(jnp.int32), q_bd, kn, vn, cos_f, sin_f,
       k_pages, v_pages)
 
@@ -954,6 +957,7 @@ def paged_decode_attention_fused_quant(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="paged_decode_attention_fused_quant",
     )(block_table, positions, q_bd, kn, vn, cos_f, sin_f, ks_win, vs_win,
       k_pages, v_pages)
 
@@ -1275,6 +1279,8 @@ def flash_prefill_attention(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
+        name="flash_prefill_attention_quant" if quant
+        else "flash_prefill_attention",
     )(*operands)
     return out[:, :, :S].transpose(0, 2, 1, 3).reshape(B, S, H, D)
 

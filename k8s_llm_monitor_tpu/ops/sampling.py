@@ -20,11 +20,22 @@ import jax
 import jax.numpy as jnp
 
 
-def greedy_tokens(logits: jnp.ndarray) -> jnp.ndarray:
-    """Argmax sampling, [B, V] -> [B] int32. No sorting, no rng."""
+# Every public entry runs under the scope ``sampler`` (trace-time metadata
+# only), so a device trace can add up what sampling costs whichever entry a
+# program calls; ``filtered_scaled_logits`` is ``sampler/filter`` inside it.
+
+
+def _argmax_tokens(logits: jnp.ndarray) -> jnp.ndarray:
     return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sampler")
+def greedy_tokens(logits: jnp.ndarray) -> jnp.ndarray:
+    """Argmax sampling, [B, V] -> [B] int32. No sorting, no rng."""
+    return _argmax_tokens(logits)
+
+
+@jax.named_scope("sampler")
 def filtered_scaled_logits(
     logits: jnp.ndarray,
     *,
@@ -40,6 +51,19 @@ def filtered_scaled_logits(
     Args: logits [B, V]; temperature/top_k/top_p [B] (semantics as in
     ``sample_tokens``).  Returns [B, V] f32, filtered entries -inf.
     """
+    return _filter_logits(logits, temperature=temperature, top_k=top_k,
+                          top_p=top_p)
+
+
+@jax.named_scope("filter")
+def _filter_logits(
+    logits: jnp.ndarray,
+    *,
+    temperature: jnp.ndarray,
+    top_k: jnp.ndarray,
+    top_p: jnp.ndarray,
+) -> jnp.ndarray:
+    """``filtered_scaled_logits`` for callers already inside ``sampler``."""
     B, V = logits.shape
     logits = logits.astype(jnp.float32)
     temp = jnp.maximum(temperature, 1e-6)[:, None]
@@ -71,6 +95,7 @@ def filtered_scaled_logits(
     return jnp.where(keep, scaled, -jnp.inf)
 
 
+@jax.named_scope("sampler")
 def sample_tokens_bounded(
     rng: jax.Array,
     logits: jnp.ndarray,
@@ -99,7 +124,7 @@ def sample_tokens_bounded(
     """
     B, V = logits.shape
     logits = logits.astype(jnp.float32)
-    greedy = greedy_tokens(logits)
+    greedy = _argmax_tokens(logits)
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = logits / temp
 
@@ -151,6 +176,7 @@ def fsm_allowed_mask(fsm_state: jnp.ndarray, fsm_trans: jnp.ndarray,
     return allowed | (fsm_state <= 0)[:, None]
 
 
+@jax.named_scope("sampler")
 def fsm_mask_logits(logits: jnp.ndarray, fsm_state: jnp.ndarray,
                     fsm_trans: jnp.ndarray) -> jnp.ndarray:
     """Mask grammar-disallowed tokens to a large negative BEFORE sampling.
@@ -164,6 +190,7 @@ def fsm_mask_logits(logits: jnp.ndarray, fsm_state: jnp.ndarray,
     return jnp.where(allowed, logits.astype(jnp.float32), _FSM_NEG)
 
 
+@jax.named_scope("sampler")
 def fsm_advance(fsm_state: jnp.ndarray, fsm_trans: jnp.ndarray,
                 tokens: jnp.ndarray) -> jnp.ndarray:
     """Next per-lane FSM state after ``tokens`` ([B] int32).
@@ -178,6 +205,7 @@ def fsm_advance(fsm_state: jnp.ndarray, fsm_trans: jnp.ndarray,
     return fsm_trans[state, tok].astype(jnp.int32)
 
 
+@jax.named_scope("sampler")
 def sample_tokens(
     rng: jax.Array,
     logits: jnp.ndarray,
@@ -198,8 +226,8 @@ def sample_tokens(
     Returns:
       [B] int32 token ids.
     """
-    greedy = greedy_tokens(logits)
-    filtered = filtered_scaled_logits(
+    greedy = _argmax_tokens(logits)
+    filtered = _filter_logits(
         logits, temperature=temperature, top_k=top_k, top_p=top_p)
     sampled = jax.random.categorical(rng, filtered, axis=-1).astype(jnp.int32)
     return jnp.where(temperature <= 0.0, greedy, sampled)

@@ -202,43 +202,52 @@ def make_overlap_decode_step(mesh, cfg: ModelConfig, params, pages: KVPages,
             # Column-parallel projections: params arrive as their local
             # shard, so _linear computes exactly the per-device matmul
             # GSPMD partitions to (out-dim int8 scales shard along).
-            q = _linear(layer["q"], h, aq).reshape(B, 1, n_head_local, D)
-            k = _linear(layer["k"], h, aq).reshape(B, 1, n_kv_local, D)
-            v = _linear(layer["v"], h, aq).reshape(B, 1, n_kv_local, D)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            if quant:
-                pk, psk = _scatter_pages_quant(
-                    k_pages[li], k_scales[li], k, tables, positions, active)
-                pv, psv = _scatter_pages_quant(
-                    v_pages[li], v_scales[li], v, tables, positions, active)
-                new_ks.append(psk)
-                new_vs.append(psv)
-                attn = paged_decode_attention_quant(
-                    q, pk, pv, psk, psv, tables, new_lens,
-                    **_attn_extras(cfg, li))
-            else:
-                pk = _scatter_pages(k_pages[li], k, tables, positions,
-                                    active)
-                pv = _scatter_pages(v_pages[li], v, tables, positions,
-                                    active)
-                attn = attn_fn(q, pk, pv, tables, new_lens,
-                               **_attn_extras(cfg, li))
+            # Scope names as in models/llama.py:decode_step, so a device
+            # trace reads the same on either schedule.
+            with jax.named_scope("qkv"):
+                q = _linear(layer["q"], h, aq).reshape(
+                    B, 1, n_head_local, D)
+                k = _linear(layer["k"], h, aq).reshape(B, 1, n_kv_local, D)
+                v = _linear(layer["v"], h, aq).reshape(B, 1, n_kv_local, D)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            with jax.named_scope("attention"):
+                if quant:
+                    pk, psk = _scatter_pages_quant(
+                        k_pages[li], k_scales[li], k, tables, positions,
+                        active)
+                    pv, psv = _scatter_pages_quant(
+                        v_pages[li], v_scales[li], v, tables, positions,
+                        active)
+                    new_ks.append(psk)
+                    new_vs.append(psv)
+                    attn = paged_decode_attention_quant(
+                        q, pk, pv, psk, psv, tables, new_lens,
+                        **_attn_extras(cfg, li))
+                else:
+                    pk = _scatter_pages(k_pages[li], k, tables, positions,
+                                        active)
+                    pv = _scatter_pages(v_pages[li], v, tables, positions,
+                                        active)
+                    attn = attn_fn(q, pk, pv, tables, new_lens,
+                                   **_attn_extras(cfg, li))
             new_k.append(pk)
             new_v.append(pv)
-            part, fin = row_parallel_partial(
-                layer["o"], attn.reshape(B, 1, -1), aq, MODEL_AXIS)
-            x_scat = x_scat + fin(jax.lax.psum_scatter(
-                part, MODEL_AXIS, scatter_dimension=2, tiled=True))
+            with jax.named_scope("attn_out"):
+                part, fin = row_parallel_partial(
+                    layer["o"], attn.reshape(B, 1, -1), aq, MODEL_AXIS)
+                x_scat = x_scat + fin(jax.lax.psum_scatter(
+                    part, MODEL_AXIS, scatter_dimension=2, tiled=True))
             h = rms_norm(
                 jax.lax.all_gather(x_scat, MODEL_AXIS, axis=2, tiled=True),
                 layer["post_norm"], eps, uo)
-            gate = _linear(layer["gate"], h, aq)
-            up = _linear(layer["up"], h, aq)
-            part, fin = row_parallel_partial(
-                layer["down"], _mlp_act(cfg, gate) * up, aq, MODEL_AXIS)
-            x_scat = x_scat + fin(jax.lax.psum_scatter(
-                part, MODEL_AXIS, scatter_dimension=2, tiled=True))
+            with jax.named_scope("mlp"):
+                gate = _linear(layer["gate"], h, aq)
+                up = _linear(layer["up"], h, aq)
+                part, fin = row_parallel_partial(
+                    layer["down"], _mlp_act(cfg, gate) * up, aq, MODEL_AXIS)
+                x_scat = x_scat + fin(jax.lax.psum_scatter(
+                    part, MODEL_AXIS, scatter_dimension=2, tiled=True))
         x_full = jax.lax.all_gather(x_scat, MODEL_AXIS, axis=2, tiled=True)
         return x_full, new_k, new_v, new_ks, new_vs
 

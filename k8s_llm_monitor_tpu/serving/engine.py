@@ -53,6 +53,7 @@ import collections
 import dataclasses
 import logging
 import os
+import threading
 import time
 from typing import Any, Callable, Optional
 
@@ -338,7 +339,7 @@ class _Slot:
     __slots__ = ("req", "blocks", "ctx_len", "generated", "pending_admit",
                  "inflight_decode", "first_token_time", "retired",
                  "cancel_requested", "prefill_pos", "prefilling",
-                 "inflight_chunks", "abort_cause")
+                 "inflight_chunks", "abort_cause", "cached_uncounted")
 
     def __init__(self, req: GenerationRequest, blocks: list[int]):
         self.req = req
@@ -358,6 +359,9 @@ class _Slot:
         self.prefill_pos = 0
         self.prefilling = False
         self.inflight_chunks = 0         # chunk calls dispatched, unreconciled
+        # Prefix-cache tokens a streaming admission starts from; its first
+        # chunk call reports them as ``cached_tokens`` and zeroes this.
+        self.cached_uncounted = 0
 
     # -- predicted (dispatch-side) state --------------------------------
 
@@ -385,11 +389,222 @@ class _Inflight:
     lanes: list[tuple]
     # chunk: every slot touched by the call (inflight_chunks decrement).
     touched: list = dataclasses.field(default_factory=list)
-    # Dispatch timestamp (monotonic) — phase spans cover dispatch ->
-    # reconcile; host-side bookkeeping only.
+    # Enqueue timestamp (monotonic) — the per-lane phase spans and
+    # ``engine.call`` cover enqueue -> result on the host, which includes
+    # the time queued behind earlier calls; host-side bookkeeping only.
     t0: float = 0.0
-    # Per-call span attributes (chunk bucket, spec round count, ...).
+    # Attributes of the call's ``engine.call`` span, counted where the call
+    # is built (InferenceEngine._call_attrs); ``emitted`` joins at reconcile.
     span_attrs: dict = dataclasses.field(default_factory=dict)
+
+
+# Every span the engine and its service record, with the attributes a reader
+# may rely on (docs/observability.md, "Span catalog").  The benchmark's metric
+# files name spans and attributes from here; tests/test_benchmark.py holds
+# them to it, so a rename fails tier-1 and not a chip run.
+SPAN_CATALOG: dict[str, tuple[str, ...]] = {
+    "engine.maintenance": (),
+    "engine.request": ("request_id", "class", "finish_reason", "tokens",
+                       "ttft_s"),
+    "engine.queue_wait": ("request_id", "class"),
+    "engine.prefill": ("request_id", "class", "bucket", "lanes", "shared",
+                       "constrained"),
+    "engine.prefill_chunk": ("request_id", "class", "bucket", "lanes",
+                             "constrained"),
+    "engine.decode": ("request_id", "class", "steps", "emitted",
+                      "collective_share"),
+    "engine.spec_decode": ("request_id", "class", "steps", "emitted",
+                           "rounds", "collective_share"),
+    "engine.preempt": ("request_id", "class", "tokens_folded"),
+    "engine.requeue": ("request_id", "class", "cause", "requeues"),
+    "engine.kv_spill": ("blocks",),
+    "engine.kv_restore": ("request_id", "class", "tokens"),
+    # The step thread's loop (InferenceEngine._phase).
+    "engine.step": ("dispatched", "inflight"),
+    "engine.step.schedule": (),
+    "engine.step.admit": (),
+    "engine.step.chunk": (),
+    "engine.step.decode": (),
+    "engine.step.wait_device": (),
+    "engine.step.apply": (),
+    "service.intake": (),
+    "service.idle": ("merged",),
+    "service.shed": ("request_id", "class", "reason", "tenant"),
+    # One per device call (InferenceEngine._call_attrs).
+    "engine.call": ("kind", "program", "call_id", "device_empty",
+                    "kv_blocks", "kv_live_blocks", "kv_cached_blocks",
+                    "steps", "lanes", "slots", "emitted",
+                    "bucket", "rows", "prompts", "real_tokens",
+                    "padded_tokens", "cached_tokens", "shared"),
+    "xla.compile": ("seconds", "program"),
+}
+
+
+# The phases of the step thread's loop, in the order a turn takes them.
+LOOP_PHASES = (
+    "service.intake", "service.idle", "engine.step", "engine.step.schedule",
+    "engine.step.admit", "engine.step.chunk", "engine.step.decode",
+    "engine.step.wait_device", "engine.step.apply",
+)
+
+
+class _PhaseClock:
+    """Where the step thread's time goes, phase by phase.
+
+    ``InferenceEngine._phase(name)`` opens a phase for a with-block.  Phases
+    never overlap: one opened inside another (a reconcile inside
+    ``_dispatch_decode``) pauses the outer one until it closes, so
+    ``seconds`` adds up to the thread's time in phases and no phase is
+    counted twice.  This class keeps only that clock — two
+    ``time.monotonic()`` calls a phase, no allocation — and is the one
+    shared object every phase of an engine whose loop is not traced
+    returns; :class:`_PhaseTrace` adds the spans.
+    """
+
+    __slots__ = ("seconds", "_names", "_t0")
+
+    def __init__(self) -> None:
+        # Every phase from the start: a scrape never meets a new key.
+        self.seconds: dict[str, float] = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._names: list[str] = []    # open phases, innermost last
+        self._t0: list[float] = []     # when each last (re)started
+
+    def begin(self, name: str, container: bool = False,
+              merge: bool = False) -> "_PhaseClock":
+        now = time.monotonic()
+        if self._names:
+            self._bank(now)
+        self._names.append(name)
+        self._t0.append(now)
+        return self
+
+    def _bank(self, now: float) -> None:
+        self.seconds[self._names[-1]] += now - self._t0[-1]
+
+    def set_attrs(self, **attrs) -> None:
+        """Attributes for the innermost open phase's span (none here)."""
+
+    def __enter__(self) -> "_PhaseClock":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        now = time.monotonic()
+        self._bank(now)
+        self._names.pop()
+        self._t0.pop()
+        if self._names:
+            self._t0[-1] = now
+        return False
+
+
+class _PhaseTrace(_PhaseClock):
+    """The clock of an engine whose loop context is sampled: every phase is
+    also a :class:`Tracer` span and a ``jax.profiler.TraceAnnotation`` of
+    the same name, so a profiler capture shows the host phases on the
+    device trace's own clock.
+
+    A paused phase's span ends where the inner one begins and a new span of
+    its name opens when it resumes, so spans never overlap either — except
+    a ``container`` (``engine.step``), which stays open around its children
+    and is their parent.  ``merge`` phases (``service.idle``, waited in
+    50 ms slices) extend the span before them instead of adding one.
+    Spans set the thread's current context, which parents ``xla.compile``.
+    """
+
+    __slots__ = ("_tracer", "_root", "_marks")
+
+    class _Mark:
+        """One open phase: what it is, and its span scope and annotation
+        while it is not paused."""
+
+        __slots__ = ("name", "container", "merge", "scope", "ann")
+
+        def __init__(self, name: str, container: bool, merge: bool) -> None:
+            self.name, self.container, self.merge = name, container, merge
+            self.scope = self.ann = None
+
+    def __init__(self, tracer, root) -> None:
+        super().__init__()
+        self._tracer = tracer
+        self._root = root
+        self._marks: list[_PhaseTrace._Mark] = []  # beside _names
+
+    def begin(self, name: str, container: bool = False,
+              merge: bool = False) -> "_PhaseTrace":
+        if self._marks:
+            self._close(self._marks[-1], (None, None, None), final=False)
+        super().begin(name)
+        self._marks.append(self._Mark(name, container, merge))
+        self._open(self._marks[-1])
+        return self
+
+    def _open(self, mark: "_PhaseTrace._Mark") -> None:
+        if mark.ann is not None:
+            return  # a container resuming: it never closed
+        if not mark.merge:
+            parent = next((m.scope.context for m in reversed(self._marks[:-1])
+                           if m.container), self._root)
+            mark.scope = self._tracer.span(mark.name, parent=parent)
+            mark.scope.__enter__()
+        mark.ann = jax.profiler.TraceAnnotation(mark.name)
+        mark.ann.__enter__()
+
+    def _close(self, mark: "_PhaseTrace._Mark", exc: tuple,
+               final: bool) -> None:
+        if mark.container and not final:
+            return
+        mark.ann.__exit__(*exc)
+        if mark.merge:
+            self._tracer.record_merged(mark.name, self._t0[-1],
+                                       time.monotonic(), self._root)
+        else:
+            mark.scope.__exit__(*exc)
+        mark.scope = mark.ann = None
+
+    def set_attrs(self, **attrs) -> None:
+        scope = self._marks[-1].scope
+        if scope is not None:
+            scope.span.attrs.update(attrs)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._close(self._marks.pop(), (exc_type, exc, tb), final=True)
+        super().__exit__(exc_type, exc, tb)
+        if self._marks:
+            self._open(self._marks[-1])
+        return False
+
+
+# What the calling thread is doing for the compile listener: the program it
+# is about to run (set around every program call of the engine).
+_calling = threading.local()
+_compile_listener_on = False
+
+
+def _on_compile(event: str, duration: float, **_kw) -> None:
+    """``jax.monitoring`` listener: a backend compile becomes an
+    ``xla.compile`` span under the compiling thread's current context — on
+    the step thread that is the phase that triggered it."""
+    if "backend_compile" not in event:
+        return
+    tracer = get_tracer()
+    ctx = tracer.current()
+    if ctx is None or not ctx.sampled:
+        return
+    now = time.monotonic()
+    attrs = {"seconds": duration}
+    program = getattr(_calling, "program", "")
+    if program:
+        attrs["program"] = program
+    tracer.record("xla.compile", now - duration, now, ctx, attrs=attrs)
+
+
+def _listen_for_compiles() -> None:
+    """Register :func:`_on_compile` once in this process (JAX keeps
+    listeners for its lifetime)."""
+    global _compile_listener_on
+    if not _compile_listener_on:
+        _compile_listener_on = True
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
 
 
 class _StuckPayload:
@@ -840,23 +1055,26 @@ class InferenceEngine:
         self.ttft_counts = [0] * (len(self.ttft_buckets) + 1)  # +Inf last
         self.ttft_sum = 0.0
         self.ttft_count = 0
-        # Decode phase attribution (monitor/exporter.py gauges).
-        # decode_host_gap_ms: EMA of host time blocked per decode/spec
-        # reconcile — ~0 when dispatch-ahead fully hides device latency.
-        # decode_attn_ms / decode_sample_ms: per-step attention / sampling
-        # cost, populated by profile_decode_phases() (bench or an admin
-        # probe); never computed on a /metrics scrape.
-        self.decode_host_gap_ms = 0.0
+        # Decode phase attribution (monitor/exporter.py gauges):
+        # decode_attn_ms / decode_sample_ms are the per-step attention /
+        # sampling cost, populated by profile_decode_phases() (bench or an
+        # admin probe); never computed on a /metrics scrape.
         self.decode_attn_ms = 0.0
         self.decode_sample_ms = 0.0
-        # Prefill fast-path attribution (exporter parity with the decode
-        # trio): prefill_attn_ms is an EMA of per-prefill-call wall time
-        # (dispatch -> reconcile, admission and chunk rounds alike);
         # prefill_bucket_rounds counts dispatched rounds per bucket size,
         # so the signals plane can see which buckets production actually
         # runs (the 4096/8192 rungs exist only on the flash path).
-        self.prefill_attn_ms = 0.0
         self.prefill_bucket_rounds: dict[int, int] = {}
+        # Counts taken where a device call is built (_call_attrs) and where
+        # its result is applied (exporter counters; the same numbers ride
+        # on each ``engine.call`` span).  slot-steps are max_slots x steps
+        # of every decode call: all lanes compute every step, so
+        # decode_tokens / decode_slot_steps is the share that was of use.
+        self.calls_by_kind: dict[str, int] = {}
+        self.decode_slot_steps = 0
+        self.decode_tokens = 0
+        self.prefill_tokens = {"real": 0, "padded": 0, "cached": 0}
+        self.dispatch_on_empty_device = 0
         # Per-step collective (ICI) share of the TP decode step, estimated
         # by profile_decode_phases() from the measured step time and the
         # ring-all-reduce byte model; 0.0 off-mesh or before profiling,
@@ -881,11 +1099,23 @@ class InferenceEngine:
         self._tracer = get_tracer()
         self._flight = get_flight_recorder()
         self._maint_ctx = self._tracer.new_trace()
-        if self._maint_ctx is not None and self._maint_ctx.sampled:
+        # The same root carries the step thread's loop: ``engine.step`` and
+        # its phases, ``engine.call``, the service's intake and idle.  They
+        # are recorded only when this root is sampled; the seconds per
+        # phase (``loop_seconds``) are kept either way.
+        self._loop_sampled = bool(self._maint_ctx is not None
+                                  and self._maint_ctx.sampled)
+        if self._loop_sampled:
             t_now = time.monotonic()
             self._tracer.record(
                 "engine.maintenance", t_now, t_now, self._maint_ctx,
                 span_id=self._maint_ctx.span_id, parent_id="")
+            self._phases: _PhaseClock = _PhaseTrace(self._tracer,
+                                                    self._maint_ctx)
+            _listen_for_compiles()
+        else:
+            self._phases = _PhaseClock()
+        self.loop_seconds = self._phases.seconds
 
     # ------------------------------------------------------------------
     # public API
@@ -1132,35 +1362,100 @@ class InferenceEngine:
         batched prefills and one fused decode, then reconcile in-flight
         results down to the dispatch-ahead window (or fully, when there is
         nothing left to dispatch)."""
-        self._enforce_deadlines()
-        self._schedule_classes()
-        dispatched = 0
-        rounds = 0
-        while rounds < self.ecfg.max_admission_rounds and self._admit_round():
-            rounds += 1
-            dispatched += 1
-        chunked = self._dispatch_prefill_chunks()
-        if chunked:
-            dispatched += 1
-            self._chunks_since_decode += 1
-        if (not chunked or self._chunks_since_decode
-                >= self.ecfg.decode_every_n_chunk_rounds):
-            if self._dispatch_decode():
+        with self._phase("engine.step", container=True) as step:
+            with self._phase("engine.step.schedule"):
+                self._enforce_deadlines()
+                self._schedule_classes()
+            dispatched = 0
+            rounds = 0
+            with self._phase("engine.step.admit"):
+                while (rounds < self.ecfg.max_admission_rounds
+                       and self._admit_round()):
+                    rounds += 1
+                    dispatched += 1
+            with self._phase("engine.step.chunk"):
+                chunked = self._dispatch_prefill_chunks()
+            if chunked:
                 dispatched += 1
-                self._chunks_since_decode = 0
-        # Opportunistic drain: results the device already finished cost no
-        # host wait, and every reconcile here frees slots/pages one step
-        # earlier — admission and chunk prep in the NEXT step() overlap
-        # with whatever is still running on device.
-        while self._inflight and self._call_ready(self._inflight[0]):
-            self._reconcile_one()
-        if dispatched:
-            while len(self._inflight) > self.ecfg.max_inflight:
+                self._chunks_since_decode += 1
+            if (not chunked or self._chunks_since_decode
+                    >= self.ecfg.decode_every_n_chunk_rounds):
+                with self._phase("engine.step.decode"):
+                    decoded = self._dispatch_decode()
+                if decoded:
+                    dispatched += 1
+                    self._chunks_since_decode = 0
+            # Opportunistic drain: results the device already finished cost
+            # no host wait, and every reconcile here frees slots/pages one
+            # step earlier — admission and chunk prep in the NEXT step()
+            # overlap with whatever is still running on device.
+            while self._inflight and self._call_ready(self._inflight[0]):
                 self._reconcile_one()
+            if dispatched:
+                while len(self._inflight) > self.ecfg.max_inflight:
+                    self._reconcile_one()
+            else:
+                # Nothing dispatchable: drain so retirements/admissions
+                # unblock.
+                if self._inflight:
+                    self._reconcile_one()
+            step.set_attrs(dispatched=dispatched,
+                           inflight=len(self._inflight))
+
+    def _phase(self, name: str, container: bool = False,
+               merge: bool = False) -> _PhaseClock:
+        """Open one phase of the step thread's loop for a with-block (the
+        ``engine.step*`` / ``service.*`` rows of the span catalog).  Its
+        seconds always go to ``loop_seconds``; when the loop's root context
+        is sampled it is a span and a profiler annotation too
+        (:class:`_PhaseTrace`), otherwise the shared :class:`_PhaseClock`
+        comes back and nothing is recorded or allocated."""
+        return self._phases.begin(name, container, merge)
+
+    def _device_empty(self) -> bool:
+        """True when every call enqueued so far has finished: the call
+        about to be built finds the device with nothing queued — a bubble
+        the size of this call's host preparation."""
+        return all(self._call_ready(c) for c in self._inflight)
+
+    def _kv_census(self) -> dict[str, int]:
+        """Who holds the pool: distinct blocks in live slots' tables, and
+        blocks that only the prefix cache still references (allocated, in
+        no live table and not awaiting a deferred free).  O(resident
+        blocks), so only a sampled loop takes it."""
+        live: set[int] = set()
+        for s in self._slots:
+            if s is not None:
+                live.update(s.blocks)
+        held = set(live)
+        for _, blocks in self._deferred_frees:
+            held.update(blocks)
+        alloc = self.allocator
+        used = alloc.num_blocks - 1 - alloc.free_blocks  # block 0 is null
+        return {"kv_blocks": alloc.num_blocks, "kv_live_blocks": len(live),
+                "kv_cached_blocks": max(0, used - len(held))}
+
+    def _call_attrs(self, kind: str, program: str, device_empty: bool,
+                    **counts: int) -> dict:
+        """Count one device call where it is built — the exporter's
+        counters, always — and return the attributes of its ``engine.call``
+        span (SPAN_CATALOG), which gain the pool census when the loop is
+        sampled.  Call it after the program call succeeded and the slots
+        are in place, with the call's id still ``_next_call_id``."""
+        self.calls_by_kind[kind] = self.calls_by_kind.get(kind, 0) + 1
+        if device_empty:
+            self.dispatch_on_empty_device += 1
+        if kind in ("admit", "chunk"):
+            for key in self.prefill_tokens:
+                self.prefill_tokens[key] += counts[f"{key}_tokens"]
         else:
-            # Nothing dispatchable: drain so retirements/admissions unblock.
-            if self._inflight:
-                self._reconcile_one()
+            self.decode_slot_steps += counts["slots"] * counts["steps"]
+        attrs = {"kind": kind, "program": program,
+                 "call_id": self._next_call_id,
+                 "device_empty": int(device_empty), **counts}
+        if self._loop_sampled:
+            attrs.update(self._kv_census())
+        return attrs
 
     @staticmethod
     def _call_ready(call: _Inflight) -> bool:
@@ -2068,7 +2363,7 @@ class InferenceEngine:
                 # serial chunk loop.
                 slot = _Slot(req, blocks)
                 slot.ctx_len = L
-                slot.prefill_pos = shared_toks
+                slot.prefill_pos = slot.cached_uncounted = shared_toks
                 slot.prefilling = True
                 slot_idx = free.pop(0)
                 self._slots[slot_idx] = slot
@@ -2123,6 +2418,12 @@ class InferenceEngine:
         # stay exact via argmax-of-masked inside the shared sampler.
         constrained = any(r.sampling.constrained for _, r, _, _ in batch)
         fnext = None
+        program = _calling.program = (
+            f"prefill{'_chunk' if any_shared else ''}_b{bucket}_r{P}"
+            + (f"_w{W}" if any_shared else "")
+            + ("_sample_fsm" if constrained
+               else "_greedy" if all_greedy else "_sample"))
+        device_empty = self._device_empty()
         try:
             self._faults.maybe_raise("prefill_dispatch")
             self.in_program_call = True
@@ -2192,6 +2493,7 @@ class InferenceEngine:
         finally:
             self.in_program_call = False
             self.last_program_call = time.monotonic()
+            _calling.program = ""
         self._record_dispatch_ok()
         self.prefill_bucket_rounds[bucket] = (
             self.prefill_bucket_rounds.get(bucket, 0) + 1)
@@ -2199,10 +2501,15 @@ class InferenceEngine:
             for slot_idx, req, blocks, st in batch:
                 self.prefix_cache.register(req.prompt_ids, blocks,
                                            tenant=req.tenant)
+        cached = sum(st for _, _, _, st in batch)
         self._finish_admit_dispatch(
             first, [(s, r, b) for s, r, b, _ in batch], idx, fsm_next=fnext,
-            span_attrs={"bucket": bucket, "lanes": len(batch),
-                        "shared": any_shared})
+            counts={"bucket": bucket, "rows": P, "prompts": len(batch),
+                    "real_tokens": sum(len(r.prompt_ids)
+                                       for _, r, _, _ in batch) - cached,
+                    "padded_tokens": bucket * P, "cached_tokens": cached,
+                    "shared": int(any_shared)},
+            program=program, device_empty=device_empty)
         return True
 
     def _dispatch_prefill_chunks(self) -> bool:
@@ -2294,6 +2601,11 @@ class InferenceEngine:
             muts.append((s, n, became_final))
 
         fnext = None
+        program = _calling.program = (
+            f"prefill_chunk_b{bucket}_r{P}_w{W}"
+            + ("_sample_fsm" if final_constrained
+               else "_greedy" if final_greedy else "_sample"))
+        device_empty = self._device_empty()
         try:
             self._faults.maybe_raise("prefill_dispatch")
             self.in_program_call = True
@@ -2338,6 +2650,7 @@ class InferenceEngine:
         finally:
             self.in_program_call = False
             self.last_program_call = time.monotonic()
+            _calling.program = ""
         self._record_dispatch_ok()
         self.prefill_bucket_rounds[bucket] = (
             self.prefill_bucket_rounds.get(bucket, 0) + 1)
@@ -2345,10 +2658,15 @@ class InferenceEngine:
             self.prefix_cache.register(s.req.prompt_ids, s.blocks,
                                        tenant=s.req.tenant)
         self.prefills += len(lanes)
-        self._queue_inflight("chunk", first, idx, lanes, touched,
-                             fsm_next=fnext,
-                             span_attrs={"bucket": bucket,
-                                         "lanes": len(cands)})
+        cached = sum(s.cached_uncounted for s in touched)
+        for s in touched:
+            s.cached_uncounted = 0
+        self._queue_inflight(
+            "chunk", first, idx, lanes, touched, fsm_next=fnext,
+            span_attrs=self._call_attrs(
+                "chunk", program, device_empty, bucket=bucket, rows=P,
+                prompts=len(cands), real_tokens=sum(n for _, n, _ in muts),
+                padded_tokens=bucket * P, cached_tokens=cached))
         return True
 
     def _queue_inflight(self, kind: str, first, idx, lanes,
@@ -2378,8 +2696,9 @@ class InferenceEngine:
             t0=time.monotonic(), span_attrs=span_attrs or {}))
         self._next_call_id += 1
 
-    def _finish_admit_dispatch(self, first, batch, idx,
-                               fsm_next=None, span_attrs=None) -> None:
+    def _finish_admit_dispatch(self, first, batch, idx, fsm_next=None, *,
+                               counts: dict, program: str,
+                               device_empty: bool) -> None:
         """Admission tail: occupy slots, then queue via the shared path."""
         lanes = []
         for slot_idx, req, blocks in batch:
@@ -2389,8 +2708,10 @@ class InferenceEngine:
             lanes.append((slot_idx, req))
         self.prefills += len(batch)
         self._write_hist(lanes)
-        self._queue_inflight("admit", first, idx, lanes, fsm_next=fsm_next,
-                             span_attrs=span_attrs)
+        self._queue_inflight(
+            "admit", first, idx, lanes, fsm_next=fsm_next,
+            span_attrs=self._call_attrs("admit", program, device_empty,
+                                        **counts))
 
     # -- decode ---------------------------------------------------------
 
@@ -2762,8 +3083,10 @@ class InferenceEngine:
         cfg = self.cfg
         H = self._hist.shape[1]
 
-        def fn(params, tok_state, ctx, quota, pages, tables, hist, temp,
-               topk, topp, rng, eos):
+        # Named apart from the fused-decode family (``fn`` -> XLA module
+        # ``jit_fn``), so a device trace tells the two kinds of call apart.
+        def spec_decode_fn(params, tok_state, ctx, quota, pages, tables, hist,
+                           temp, topk, topp, rng, eos):
             active0 = ctx > 0
             B = tok_state.shape[0]
             lane = jnp.arange(B, dtype=jnp.int32)
@@ -2822,7 +3145,7 @@ class InferenceEngine:
             toks = jnp.transpose(outs, (0, 2, 1)).reshape(rounds * (k + 1), B)
             return toks, tok_state, pages, hist, jnp.sum(stats, axis=0)
 
-        prog = jax.jit(fn, donate_argnums=(1, 4, 6))
+        prog = jax.jit(spec_decode_fn, donate_argnums=(1, 4, 6))
         self._decode_cache[key] = prog
         return prog
 
@@ -2973,10 +3296,11 @@ class InferenceEngine:
         # FSM program; its free co-lanes run masked-by-nothing at state 0.
         constrained = (self._fsm_trans is not None and any(
             s.req.sampling.constrained for _, s in lanes))
+        device_empty = self._device_empty()
         try:
             self._faults.maybe_raise("decode_dispatch")
             self.in_program_call = True
-            payload, kind = self._dispatch_decode_call(
+            payload, kind, program = self._dispatch_decode_call(
                 spec and not constrained, all_greedy, lanes, K, ctx,
                 steps_arr, table, temp, topk, topp, eos,
                 constrained=constrained)
@@ -2998,14 +3322,15 @@ class InferenceEngine:
         finally:
             self.in_program_call = False
             self.last_program_call = time.monotonic()
+            _calling.program = ""
         self._record_dispatch_ok()
         if self._faults.should_fire("decode_stuck"):
             payload = _StuckPayload(payload)
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=payload, lanes=meta,
             t0=time.monotonic(),
-            span_attrs={"steps": K, "lanes": len(lanes),
-                        "constrained": constrained}))
+            span_attrs=self._call_attrs(kind, program, device_empty, steps=K,
+                                        lanes=len(lanes), slots=B)))
         self._next_call_id += 1
         return True
 
@@ -3014,7 +3339,7 @@ class InferenceEngine:
                               topp, eos, constrained: bool = False):
         """The device-call half of :meth:`_dispatch_decode`, split out so
         the dispatch fault/rollback boundary wraps exactly the program
-        call.  Returns ``(payload, kind)``."""
+        call.  Returns ``(payload, kind, program name)``."""
         ec = self.ecfg
         if constrained:
             # Grammar-masked fused decode: always the sampled program family
@@ -3027,6 +3352,8 @@ class InferenceEngine:
                 for _, s in lanes if s.req.sampling.temperature > 0.0)
             prog = self._decode_program(K, sampled=True, bounded=bounded,
                                         constrained=True)
+            program = _calling.program = (
+                f"decode_k{K}_sampled{'_bounded' if bounded else ''}_fsm")
             self._rng, sub = jax.random.split(self._rng)
             toks, self._tok_state, self._fsm_state, self.pages = prog(
                 self.params, self._tok_state, self._fsm_state,
@@ -3041,7 +3368,7 @@ class InferenceEngine:
                 toks.copy_to_host_async()
             except AttributeError:
                 pass
-            return payload, kind
+            return payload, kind, program
         if spec:
             # Filters only matter on lanes that actually sample: a greedy
             # lane carrying top_p (a common client default) must not force
@@ -3051,9 +3378,14 @@ class InferenceEngine:
                 s.req.sampling.temperature > 0.0
                 and (s.req.sampling.top_k > 0 or s.req.sampling.top_p < 1.0)
                 for _, s in lanes)
+            filtered = any_filtered and not all_greedy
             prog = self._spec_program(ec.spec_k, ec.spec_rounds_per_iter,
                                       sampled=not all_greedy,
-                                      filtered=any_filtered and not all_greedy)
+                                      filtered=filtered)
+            program = _calling.program = (
+                f"spec_k{ec.spec_k}_r{ec.spec_rounds_per_iter}_"
+                + ("greedy" if all_greedy
+                   else "filtered" if filtered else "sampled"))
             self._rng, sub = jax.random.split(self._rng)
             toks, self._tok_state, self.pages, self._hist, nver = prog(
                 self.params, self._tok_state, jnp.asarray(ctx),
@@ -3065,6 +3397,7 @@ class InferenceEngine:
             kind = "spec"
         elif all_greedy:
             prog = self._decode_program(K, sampled=False)
+            program = _calling.program = f"decode_k{K}_greedy"
             toks, self._tok_state, self.pages = prog(
                 self.params, self._tok_state, jnp.asarray(ctx),
                 jnp.asarray(steps_arr), self.pages, jnp.asarray(table), eos,
@@ -3080,6 +3413,8 @@ class InferenceEngine:
                 0 < s.req.sampling.top_k <= cap
                 for _, s in lanes if s.req.sampling.temperature > 0.0)
             prog = self._decode_program(K, sampled=True, bounded=bounded)
+            program = _calling.program = (
+                f"decode_k{K}_sampled{'_bounded' if bounded else ''}")
             self._rng, sub = jax.random.split(self._rng)
             toks, self._tok_state, self.pages = prog(
                 self.params, self._tok_state, jnp.asarray(ctx),
@@ -3094,31 +3429,24 @@ class InferenceEngine:
             toks.copy_to_host_async()
         except AttributeError:
             pass
-        return payload, kind
+        return payload, kind, program
 
     # -- reconciliation -------------------------------------------------
 
     def _reconcile_one(self) -> None:
         call = self._inflight.popleft()
-        budget = self.ecfg.dispatch_timeout_s
-        if budget > 0 and not self._call_ready(call):
-            # Watchdog: poll readiness instead of blocking in np.asarray —
-            # a wedged device call must trip recovery, not hang the loop.
-            t0 = time.monotonic()
-            while not self._call_ready(call):
-                if time.monotonic() - t0 >= budget:
-                    self.watchdog_trips += 1
-                    if self.health is not None:
-                        self.health.record_watchdog_trip()
-                    self._reset_pipeline(
-                        f"dispatch watchdog: {call.kind} call not ready "
-                        f"after {budget:.2f}s", extra_calls=(call,))
-                    return
-                time.sleep(0.002)
-        if self._faults.should_fire("slow_host_callback"):
-            time.sleep(self._faults.delay_s("slow_host_callback"))
         try:
-            self._apply_call(call)
+            with self._phase("engine.step.wait_device"):
+                # Everything the host waits for the device here, whichever
+                # way it waits: the watchdog's poll loop, or the blocking
+                # conversion when the watchdog is off.
+                if not self._await_call(call):
+                    return
+                if self._faults.should_fire("slow_host_callback"):
+                    time.sleep(self._faults.delay_s("slow_host_callback"))
+                arr = self._fetch_call(call)
+            with self._phase("engine.step.apply"):
+                self._apply_call(call, arr)
         except Exception as exc:
             # A failed host conversion (device error surfacing, injected
             # stuck payload with the watchdog off) poisons the donated
@@ -3138,44 +3466,53 @@ class InferenceEngine:
                     still.append((after_id, blocks))
             self._deferred_frees = still
 
-    def _apply_call(self, call: _Inflight) -> None:
-        """Convert one dispatched call's payload and apply it to slots
-        (token emission, retirement, chunk/decode accounting)."""
-        gap_t0 = time.monotonic()
-        if call.kind == "spec":
-            toks, stats = call.arr
-            arr = np.asarray(toks)
-            ran, lane_rounds = (int(x) for x in np.asarray(stats))
-            self.spec_verify_steps += ran
-            self.spec_lane_rounds += lane_rounds
-            self.steps += ran
-            if lane_rounds:
-                # Per-class acceptance EMA drives the adaptive spec/fused
-                # choice; the class is derived from the slots this call
-                # actually ran (meta holds the slot objects, so reuse of
-                # the lane index after dispatch cannot misattribute).
-                self._spec_accept.update(
-                    self._spec_class((i, s) for i, s, _ in call.lanes),
-                    int(np.sum(arr >= 0)), lane_rounds)
-        else:
-            arr = np.asarray(call.arr)
-        if call.kind in ("decode", "spec"):
-            # Host time spent blocked on this device call: ~0 whenever
-            # dispatch-ahead (or the ready-drain in step()) hid the device
-            # latency.  EMA so /metrics shows the steady-state gap.
-            gap_ms = (time.monotonic() - gap_t0) * 1e3
-            self.decode_host_gap_ms = (
-                gap_ms if self.decode_host_gap_ms == 0.0
-                else 0.9 * self.decode_host_gap_ms + 0.1 * gap_ms)
+    def _await_call(self, call: _Inflight) -> bool:
+        """Watchdog: poll readiness instead of blocking in np.asarray — a
+        wedged device call must trip recovery, not hang the loop.  False
+        when the budget ran out and the pipeline was reset."""
+        budget = self.ecfg.dispatch_timeout_s
+        if budget <= 0:
+            return True
+        t0 = time.monotonic()
+        while not self._call_ready(call):
+            if time.monotonic() - t0 >= budget:
+                self.watchdog_trips += 1
+                if self.health is not None:
+                    self.health.record_watchdog_trip()
+                self._reset_pipeline(
+                    f"dispatch watchdog: {call.kind} call not ready "
+                    f"after {budget:.2f}s", extra_calls=(call,))
+                return False
+            time.sleep(0.002)
+        return True
+
+    def _fetch_call(self, call: _Inflight) -> np.ndarray:
+        """The call's token matrix on the host (blocks until the device
+        has it); a spec call's verify statistics are booked on the way."""
+        if call.kind != "spec":
+            return np.asarray(call.arr)
+        toks, stats = call.arr
+        arr = np.asarray(toks)
+        ran, lane_rounds = (int(x) for x in np.asarray(stats))
+        self.spec_verify_steps += ran
+        self.spec_lane_rounds += lane_rounds
+        self.steps += ran
+        if lane_rounds:
+            # Per-class acceptance EMA drives the adaptive spec/fused
+            # choice; the class is derived from the slots this call
+            # actually ran (meta holds the slot objects, so reuse of
+            # the lane index after dispatch cannot misattribute).
+            self._spec_accept.update(
+                self._spec_class((i, s) for i, s, _ in call.lanes),
+                int(np.sum(arr >= 0)), lane_rounds)
+        return arr
+
+    def _apply_call(self, call: _Inflight, arr: np.ndarray) -> None:
+        """Apply one call's result, now on the host, to its slots (token
+        emission, retirement, chunk/decode accounting)."""
+        now = time.monotonic()
+        attrs = call.span_attrs
         if call.kind in ("admit", "chunk"):
-            now = time.monotonic()
-            # Per-prefill-call wall time (dispatch -> reconcile), the
-            # prefill twin of decode_host_gap_ms: an EMA across admission
-            # and chunk rounds, surfaced as engine_prefill_attn_ms.
-            pf_ms = max(0.0, now - call.t0) * 1e3
-            self.prefill_attn_ms = (
-                pf_ms if self.prefill_attn_ms == 0.0
-                else 0.9 * self.prefill_attn_ms + 0.1 * pf_ms)
             for s in call.touched:           # chunk calls: drain refcounts
                 s.inflight_chunks -= 1
             rows = (enumerate(call.lanes) if call.kind == "admit"
@@ -3183,6 +3520,10 @@ class InferenceEngine:
                           for row, slot_idx, req in call.lanes))
             span_name = ("engine.prefill" if call.kind == "admit"
                          else "engine.prefill_chunk")
+            lane_attrs = {"bucket": attrs["bucket"],
+                          "lanes": attrs["prompts"]}
+            if call.kind == "admit":
+                lane_attrs["shared"] = bool(attrs["shared"])
             for j, (slot_idx, req) in rows:
                 s = self._slots[slot_idx]
                 if s is None or s.req is not req:
@@ -3197,21 +3538,22 @@ class InferenceEngine:
                 s.first_token_time = req.first_token_time
                 self._span(span_name, call.t0, now, req,
                            constrained=req.sampling.constrained,
-                           **call.span_attrs)
+                           **lane_attrs)
                 self._emit(req, [tok])
                 if self._is_finished(s) or s.cancel_requested:
                     self._retire(slot_idx)
         else:
-            now = time.monotonic()
             span_name = ("engine.spec_decode" if call.kind == "spec"
                          else "engine.decode")
             # Satellite: the analytic collective share from the last
             # profile_decode_phases() run rides on every decode segment.
             coll = self.decode_collective_share
+            emitted = 0
             for slot_idx, s, steps_i in call.lanes:
                 if self._slots[slot_idx] is not s or s.retired:
                     continue  # lane EOSed in an earlier call; discard zombies
                 new = [int(t) for t in arr[:, slot_idx] if t >= 0]
+                emitted += len(new)
                 s.inflight_decode -= steps_i
                 if call.kind == "spec":
                     self.spec_tokens += len(new)
@@ -3219,12 +3561,12 @@ class InferenceEngine:
                     self.hist_decode_step.observe(
                         max(0.0, now - call.t0) / steps_i,
                         s.req.slo_class, self._trace_id(s.req))
-                attrs = {"steps": steps_i, "emitted": len(new)}
+                lane_attrs = {"steps": steps_i, "emitted": len(new)}
                 if coll:
-                    attrs["collective_share"] = coll
+                    lane_attrs["collective_share"] = coll
                 if call.kind == "spec":
-                    attrs["rounds"] = self.ecfg.spec_rounds_per_iter
-                self._span(span_name, call.t0, now, s.req, **attrs)
+                    lane_attrs["rounds"] = self.ecfg.spec_rounds_per_iter
+                self._span(span_name, call.t0, now, s.req, **lane_attrs)
                 if not new:
                     continue
                 s.ctx_len += len(new)
@@ -3233,6 +3575,16 @@ class InferenceEngine:
                 if self._is_finished(s) or (s.cancel_requested
                                             and s.inflight_decode == 0):
                     self._retire(slot_idx)
+            # Tokens that reached a request: a zombie lane's (module
+            # docstring) were computed and are not counted.
+            attrs["emitted"] = emitted
+            self.decode_tokens += emitted
+        if self._loop_sampled:
+            # enqueue -> result on the host: includes the time queued
+            # behind earlier calls; how long the device ran it is the
+            # device trace's to say.
+            self._tracer.record("engine.call", call.t0, now, self._maint_ctx,
+                                attrs=attrs)
 
     def _observe_ttft(self, ttft_s: float,
                       slo_class: str = DEFAULT_CLASS,

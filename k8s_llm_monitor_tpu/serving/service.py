@@ -531,17 +531,27 @@ class EngineService:
 
     def _run(self) -> None:
         try:
+            engine = self.engine
             while not self._stop.is_set():
                 self.last_heartbeat = time.monotonic()
                 self._faults.maybe_raise("step_loop_crash")
-                self._drain_submissions()
-                self._drain_calls()
-                if self.engine.has_work:
-                    self.engine.step()
+                # The loop's own phases sit beside the engine's on the step
+                # thread's record (engine._phase).  An idle loop turns every
+                # 50 ms: its empty intakes are not phases and its waits
+                # merge into one span, so idling leaves the span ring alone.
+                if engine.has_work or not (self._submissions.empty()
+                                           and self._cancels.empty()
+                                           and self._calls.empty()):
+                    with engine._phase("service.intake"):
+                        self._drain_submissions()
+                        self._drain_calls()
+                if engine.has_work:
+                    engine.step()
                 else:
                     # Idle: sleep until a submission arrives.
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with engine._phase("service.idle", merge=True):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
         except Exception as exc:  # engine is corrupt — fail or hand off
             msg = f"engine step failed: {exc!r}"
             with self._handles_lock:

@@ -668,3 +668,346 @@ def test_lint_flags_bad_label_block():
     errs = lint_exposition(
         _with_meta('k8s_llm_monitor_x{class=interactive} 1'))
     assert any("label" in e for e in errs)
+
+
+# ---------------------------------------------------------------------------
+# The step thread's loop on the record: phase spans, engine.call, xla.compile
+# (docs/observability.md, "Span catalog"; serving/engine.py SPAN_CATALOG)
+# ---------------------------------------------------------------------------
+
+import re  # noqa: E402
+
+import jax.numpy as jnp  # noqa: E402
+
+from k8s_llm_monitor_tpu.monitor import exporter  # noqa: E402
+from k8s_llm_monitor_tpu.serving import engine as engine_mod  # noqa: E402
+
+# A shape no other test of this file runs, so its programs compile here.
+LOOP_ECFG = dict(ECFG, prefill_buckets=(24,))
+LOOP_PROMPTS = [[7 + i + j for j in range(5 + 3 * i)] for i in range(6)]
+LOOP_MAX_TOKENS = [3, 9, 5, 12, 1, 7]
+
+
+@pytest.fixture(scope="module")
+def loop_record(params):
+    """One traced run through the service: six requests on four lanes, so
+    lanes turn over, steps wait for the device and the loop idles before and
+    after.  Everything the cases below read, taken once."""
+    import k8s_llm_monitor_tpu.observability.tracing as tr
+
+    prev = tr._TRACER
+    tracer = Tracer(ring_size=1 << 14, sample=1.0, seed=99)
+    set_tracer(tracer)
+    try:
+        eng = InferenceEngine(CFG, params, EngineConfig(**LOOP_ECFG), eos_id=-1)
+        svc = EngineService(eng)
+        time.sleep(0.25)  # a stretch of idling: several 50 ms waits
+        handles = [svc.submit(p, SamplingParams(max_tokens=m),
+                              request_id=f"loop-{i}")
+                   for i, (p, m) in enumerate(zip(LOOP_PROMPTS,
+                                                  LOOP_MAX_TOKENS))]
+        results = [h.result(timeout=120) for h in handles]
+        time.sleep(0.12)
+        w = exporter._Writer()
+        exporter._engine_metrics(w, eng)
+        text = w.render()
+        svc.stop()
+        spans = tracer.snapshot()
+    finally:
+        set_tracer(prev)
+    assert tracer.overwritten == 0
+    return {"spans": spans, "results": results, "engine": eng,
+            "exposition": text,
+            "by_name": lambda name: [s for s in spans if s["name"] == name]}
+
+
+def _loop_case_step_children_have_a_step_parent(rec):
+    steps = {s["span_id"] for s in rec["by_name"]("engine.step")}
+    kids = [s for s in rec["spans"] if s["name"].startswith("engine.step.")]
+    assert steps and len(kids) >= 4 * len(steps)
+    assert all(k["parent_id"] in steps for k in kids)
+    assert ({k["name"] for k in kids}
+            == {n for n in engine_mod.LOOP_PHASES
+                if n.startswith("engine.step.")})
+    assert set(engine_mod.LOOP_PHASES) <= set(engine_mod.SPAN_CATALOG)
+
+
+def _loop_case_children_fit_inside_their_step(rec):
+    inside: dict[str, float] = {}
+    for s in rec["spans"]:
+        if s["name"].startswith("engine.step."):
+            inside[s["parent_id"]] = (inside.get(s["parent_id"], 0.0)
+                                      + s["duration_s"])
+    for step in rec["by_name"]("engine.step"):
+        assert inside[step["span_id"]] <= step["duration_s"] + 1e-6
+
+
+def _loop_case_loop_spans_hang_off_the_engine_root(rec):
+    (root,) = rec["by_name"]("engine.maintenance")
+    for name in ("engine.step", "engine.call", "service.intake",
+                 "service.idle"):
+        spans = rec["by_name"](name)
+        assert spans, name
+        assert all(s["parent_id"] == root["span_id"]
+                   and s["trace_id"] == root["trace_id"] for s in spans)
+    step = rec["by_name"]("engine.step")[0]
+    assert set(step["attrs"]) == {"dispatched", "inflight"}
+
+
+def _loop_case_an_idle_loop_merges_its_waits(rec):
+    idle = rec["by_name"]("service.idle")
+    # Two stretches (before the burst, after it), not one span a wait.
+    assert len(idle) <= 3
+    assert max(s["attrs"]["merged"] for s in idle) >= 3
+    assert max(s["duration_s"] for s in idle) >= 0.15
+    assert len(rec["by_name"]("service.intake")) < 40
+
+
+def _loop_case_decode_calls_emit_no_more_than_they_compute(rec):
+    decodes = [c for c in rec["by_name"]("engine.call")
+               if c["attrs"]["kind"] == "decode"]
+    assert decodes
+    for c in decodes:
+        a = c["attrs"]
+        assert 0 <= a["emitted"] <= a["slots"] * a["steps"]
+        assert 1 <= a["lanes"] <= a["slots"] == LOOP_ECFG["max_slots"]
+        assert a["program"] == f"decode_k{a['steps']}_greedy"
+
+
+def _loop_case_emitted_plus_first_tokens_is_what_clients_got(rec):
+    calls = rec["by_name"]("engine.call")
+    emitted = sum(c["attrs"]["emitted"] for c in calls
+                  if c["attrs"]["kind"] == "decode")
+    first = sum(c["attrs"]["prompts"] for c in calls
+                if c["attrs"]["kind"] == "admit")
+    got = sum(len(r.token_ids) for r in rec["results"])
+    assert got == sum(LOOP_MAX_TOKENS)
+    assert emitted + first == got
+    # The per-lane spans say the same, lane by lane.
+    assert emitted == sum(s["attrs"]["emitted"]
+                          for s in rec["by_name"]("engine.decode"))
+
+
+def _loop_case_prompt_tokens_are_all_accounted_for(rec):
+    admits = [c["attrs"] for c in rec["by_name"]("engine.call")
+              if c["attrs"]["kind"] in ("admit", "chunk")]
+    assert (sum(a["real_tokens"] + a["cached_tokens"] for a in admits)
+            == sum(len(p) for p in LOOP_PROMPTS))
+    for a in admits:
+        assert a["real_tokens"] <= a["padded_tokens"] == a["bucket"] * a["rows"]
+        assert a["prompts"] <= a["rows"]
+        assert a["program"].startswith(f"prefill_b{a['bucket']}_r{a['rows']}_")
+
+
+def _loop_case_calls_are_numbered_and_say_what_they_found(rec):
+    calls = sorted(rec["by_name"]("engine.call"),
+                   key=lambda c: c["attrs"]["call_id"])
+    assert [c["attrs"]["call_id"] for c in calls] == list(range(len(calls)))
+    assert calls[0]["attrs"]["device_empty"] == 1  # nothing was queued yet
+    assert all(c["attrs"]["device_empty"] in (0, 1) for c in calls)
+    for c in calls:
+        a = c["attrs"]
+        assert a["kv_blocks"] == LOOP_ECFG["num_blocks"]
+        assert 0 < a["kv_live_blocks"] < a["kv_blocks"]
+        assert a["kv_cached_blocks"] == 0  # the prefix cache is off here
+        assert set(a) <= set(engine_mod.SPAN_CATALOG["engine.call"])
+
+
+def _loop_case_a_new_shape_names_its_compile_and_its_phase(rec):
+    phases = {s["span_id"]: s["name"] for s in rec["spans"]
+              if s["name"].startswith("engine.step.")}
+    compiles = [s for s in rec["by_name"]("xla.compile")
+                if s["attrs"].get("program")]
+    by_program = {s["attrs"]["program"]: phases.get(s["parent_id"])
+                  for s in compiles}
+    assert by_program.get("prefill_b24_r4_greedy") == "engine.step.admit"
+    assert by_program.get("decode_k4_greedy") == "engine.step.decode"
+    assert all(s["attrs"]["seconds"] > 0 for s in compiles)
+
+
+def _loop_case_the_exporter_counts_what_the_spans_carry(rec):
+    eng, calls = rec["engine"], rec["by_name"]("engine.call")
+    kinds = [c["attrs"]["kind"] for c in calls]
+    assert eng.calls_by_kind == {k: kinds.count(k) for k in set(kinds)}
+    decodes = [c["attrs"] for c in calls if c["attrs"]["kind"] == "decode"]
+    assert eng.decode_tokens == sum(a["emitted"] for a in decodes)
+    assert eng.decode_slot_steps == sum(a["slots"] * a["steps"]
+                                        for a in decodes)
+    assert eng.prefill_tokens["real"] == sum(len(p) for p in LOOP_PROMPTS)
+    assert eng.dispatch_on_empty_device == sum(
+        c["attrs"]["device_empty"] for c in calls)
+    waited = sum(s["duration_s"]
+                 for s in rec["by_name"]("engine.step.wait_device"))
+    assert eng.loop_seconds["engine.step.wait_device"] == pytest.approx(
+        waited, rel=0.05, abs=2e-3)
+
+
+def _loop_case_the_exporter_prints_the_counters_not_the_gauges(rec):
+    text = rec["exposition"]
+    assert lint_exposition(text) == []
+    for family in ("engine_loop_seconds_total", "engine_calls_total",
+                   "engine_decode_slot_steps_total",
+                   "engine_decode_tokens_total",
+                   "engine_prefill_tokens_total",
+                   "engine_dispatch_on_empty_device_total"):
+        assert f"# TYPE k8s_llm_monitor_{family} counter" in text
+    for phase in ("step", "schedule", "admit", "chunk", "decode",
+                  "wait_device", "apply", "intake", "idle"):
+        assert f'engine_loop_seconds_total{{phase="{phase}"}}' in text
+    assert 'engine_prefill_tokens_total{kind="padded"}' in text
+    assert "decode_host_gap" not in text and "prefill_attn_ms" not in text
+
+
+LOOP_CASES = [fn for name, fn in sorted(globals().items())
+              if name.startswith("_loop_case_")]
+
+
+@pytest.mark.parametrize("case", LOOP_CASES,
+                         ids=lambda fn: fn.__name__[len("_loop_case_"):])
+def test_loop_record(loop_record, case):
+    case(loop_record)
+
+
+def test_loop_is_silent_with_sampling_off(params):
+    """At ``sample=0.0`` the engine records nothing and every phase is the
+    one shared clock — which still keeps the seconds and the counts."""
+    tracer = Tracer(sample=0.0)
+    set_tracer(tracer)
+    eng = InferenceEngine(CFG, params, EngineConfig(**ECFG), eos_id=-1)
+    clock = eng._phases
+    assert type(clock) is engine_mod._PhaseClock
+    assert eng._phase("engine.step.schedule") is clock
+    clock.__exit__(None, None, None)
+    eng.generate([[5, 6, 7], [8, 9]], SamplingParams(max_tokens=6))
+    assert tracer.recorded == 0 and tracer.overwritten == 0
+    assert eng.loop_seconds["engine.step.wait_device"] > 0
+    assert eng.calls_by_kind == {"admit": 1, "decode": 2}
+    assert eng.decode_tokens == 10 and eng.prefill_tokens["real"] == 5
+
+
+def test_nested_phase_pauses_the_outer_one():
+    """A reconcile inside a dispatch: the outer phase's span ends where the
+    inner begins and a second one of its name follows — nothing overlaps,
+    nothing is counted twice, and all are children of the step."""
+    tracer = get_tracer()
+    root = tracer.new_trace()
+    phases = engine_mod._PhaseTrace(tracer, root)
+    with phases.begin("engine.step", container=True) as step:
+        with phases.begin("engine.step.decode"):
+            time.sleep(0.01)
+            with phases.begin("engine.step.wait_device"):
+                assert tracer.current().parent_id  # a span is current
+                time.sleep(0.01)
+            time.sleep(0.01)
+        step.set_attrs(dispatched=1)
+    assert tracer.current() is None
+    spans = tracer.snapshot()
+    (step_span,) = [s for s in spans if s["name"] == "engine.step"]
+    kids = [s for s in spans if s["name"] != "engine.step"]
+    assert [k["name"] for k in kids] == [
+        "engine.step.decode", "engine.step.wait_device", "engine.step.decode"]
+    assert all(k["parent_id"] == step_span["span_id"] for k in kids)
+    assert step_span["parent_id"] == root.span_id
+    assert step_span["attrs"] == {"dispatched": 1}
+    for a, b in zip(kids, kids[1:]):
+        assert a["start_mono"] + a["duration_s"] <= b["start_mono"] + 1e-6
+    assert sum(k["duration_s"] for k in kids) <= step_span["duration_s"] + 1e-6
+    sec = phases.seconds
+    assert sec["engine.step.decode"] == pytest.approx(0.02, abs=0.015)
+    assert sec["engine.step.wait_device"] == pytest.approx(0.01, abs=0.008)
+    assert sum(sec.values()) == pytest.approx(step_span["duration_s"],
+                                              abs=2e-3)
+
+
+def test_record_merged_stretches_only_an_unbroken_run():
+    tracer = Tracer(ring_size=16, sample=1.0)
+    root = tracer.new_trace()
+    for i in range(3):
+        tracer.record_merged("service.idle", float(i), i + 1.0, root)
+    tracer.record("service.intake", 3.0, 3.5, root)
+    tracer.record_merged("service.idle", 3.5, 4.0, root)
+    spans = [(s["name"], s["start_mono"], s["duration_s"], s["attrs"])
+             for s in tracer.snapshot()]
+    assert ("service.idle", 0.0, 3.0, {"merged": 3}) in spans
+    assert ("service.idle", 3.5, 0.5, {"merged": 1}) in spans
+    assert tracer.recorded == 3
+    tracer.record_merged("service.idle", 4.0, 5.0, None)  # untraced: inert
+    assert tracer.recorded == 3
+
+
+def test_overwritten_counts_what_the_ring_lost():
+    tracer = Tracer(ring_size=16, sample=1.0)
+    root = tracer.new_trace()
+    for i in range(16):
+        tracer.record("x", float(i), i + 0.5, root)
+    assert tracer.overwritten == 0
+    for i in range(5):
+        tracer.record("x", float(i), i + 0.5, root)
+    assert tracer.recorded == 21 and tracer.overwritten == 5
+    assert len(tracer.snapshot()) == 16
+
+
+def test_kv_census_counts_lanes_and_cache_apart(params):
+    """``kv_live_blocks`` is the distinct blocks in live lanes' tables — a
+    shared prefix counts once — and falls to 0 after the last request, while
+    the allocator's used share stays above 0: the prefix cache keeps pages."""
+    eng = InferenceEngine(
+        CFG, params, EngineConfig(**dict(ECFG, prefix_cache_entries=64)),
+        eos_id=-1)
+    census = eng._kv_census
+    seen = []
+
+    def checked():
+        out = census()
+        tables = [s.blocks for s in eng._slots if s is not None]
+        distinct = {b for blocks in tables for b in blocks}
+        assert out["kv_live_blocks"] == len(distinct)
+        seen.append((out, sum(len(t) for t in tables)))
+        return out
+
+    eng._kv_census = checked
+    shared = list(range(40, 56))  # two full blocks of 8
+    eng.generate([shared + [3]], SamplingParams(max_tokens=2))
+    eng.generate([shared + [4, 5], shared + [6]],
+                 SamplingParams(max_tokens=6))
+    assert len(seen) >= 4
+    # The second batch's lanes share the first request's two cached blocks.
+    assert any(out["kv_live_blocks"] < in_tables for out, in_tables in seen)
+    after = census()
+    alloc = eng.allocator
+    assert after["kv_live_blocks"] == 0 and after["kv_cached_blocks"] >= 2
+    assert 1 - alloc.free_blocks / alloc.num_blocks > 1 / alloc.num_blocks
+    calls = [s for s in get_tracer().snapshot() if s["name"] == "engine.call"]
+    assert sum(c["attrs"].get("cached_tokens", 0) for c in calls) == 32
+    assert (sum(c["attrs"].get("real_tokens", 0) for c in calls)
+            + 32 == 3 * 16 + 4)
+
+
+@pytest.fixture(scope="module")
+def lowered_programs(params):
+    eng = InferenceEngine(CFG, params, EngineConfig(**ECFG), eos_id=-1)
+    B, W = ECFG["max_slots"], ECFG["max_blocks_per_seq"]
+    zi = jnp.zeros((B,), jnp.int32)
+    decode = eng._decode_program(4, sampled=True).lower(
+        eng.params, eng._tok_state, zi, zi, eng.pages,
+        jnp.zeros((B, W), jnp.int32), jnp.ones((B,)), zi, jnp.ones((B,)),
+        jax.random.PRNGKey(0), jnp.asarray(-1, jnp.int32))
+    prefill = eng._prefill_sample.lower(
+        eng.params, jnp.zeros((1, 16), jnp.int32), jnp.ones((1,), jnp.int32),
+        eng.pages, jnp.zeros((1, W), jnp.int32), jnp.ones((1,)),
+        jnp.zeros((1,), jnp.int32), jnp.ones((1,)), jax.random.PRNGKey(0))
+    return {"decode": decode.as_text(debug_info=True),
+            "prefill": prefill.as_text(debug_info=True)}
+
+
+@pytest.mark.parametrize("scope", ["embed", "qkv", "attention", "attn_out",
+                                   "mlp", "lm_head", "sampler",
+                                   "sampler/filter"])
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_programs_carry_their_scopes(lowered_programs, program, scope):
+    """The scopes are trace-time metadata: present in the lowered text of
+    the tiny programs, as a path component of an operation's location (a
+    scan body's locations are relative, so the scope may lead the path)."""
+    text = lowered_programs[program]
+    assert re.search(r'loc\("(?:[^"]*/)?' + re.escape(scope) + r'(?:/[^"]*)?"',
+                     text), scope
