@@ -379,6 +379,13 @@ def _loop_metrics(w: _Writer, engine) -> None:
              "Calls enqueued when every earlier call had already "
              "finished: the device idled while the host prepared them",
              [("", engine.dispatch_on_empty_device)])
+    w.metric("engine_sampler_filter_calls_total", "counter",
+             "Calls of a sampling program, by whether a lane that samples "
+             "had top-k or top-p on (on = the rank filter's full-vocabulary "
+             "sort ran, unless the program is a _bounded one); greedy "
+             "programs count in neither",
+             [(f'{{filter="{k}"}}', n)
+              for k, n in sorted(engine.sampler_filter_calls.items())])
 
 
 def _latency_histograms(w: _Writer, engine) -> None:

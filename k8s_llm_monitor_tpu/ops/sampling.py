@@ -9,6 +9,15 @@ argsort: ranks are unique even when logits tie, so a tied distribution can
 never defeat the nucleus mask (a strict value-threshold comparison would keep
 every tied token and make ``top_p=0.1`` a no-op on uniform logits).
 
+That sort, and the scatter that turns it into ranks, run only in a call
+where some lane that samples has a filter on (``_filter_logits``: a
+``lax.cond`` on the [B] parameters, the same program either way).  The
+product's own requests — ``temperature=0.1``, no top-k, no top-p — draw
+straight from the temperature-scaled logits: the same tokens for the same
+key, without 152k-wide sorts on every step.  The engine reports which
+branch a call took as ``sampler_filter`` on its ``engine.call`` span and in
+``engine_sampler_filter_calls_total``.
+
 ``greedy_tokens`` is the sort-free fast path — serving/engine.py dispatches
 to it when every active lane in a decode step is greedy (a pure argmax, no
 [B, V] sort traffic).
@@ -49,7 +58,8 @@ def filtered_scaled_logits(
     speculated and sequential chains target the identical distribution.
 
     Args: logits [B, V]; temperature/top_k/top_p [B] (semantics as in
-    ``sample_tokens``).  Returns [B, V] f32, filtered entries -inf.
+    ``sample_tokens``).  Returns [B, V] f32, filtered entries -inf; with
+    no filter on any sampling lane, the scaled logits as they are.
     """
     return _filter_logits(logits, temperature=temperature, top_k=top_k,
                           top_p=top_p)
@@ -63,11 +73,33 @@ def _filter_logits(
     top_k: jnp.ndarray,
     top_p: jnp.ndarray,
 ) -> jnp.ndarray:
-    """``filtered_scaled_logits`` for callers already inside ``sampler``."""
-    B, V = logits.shape
+    """``filtered_scaled_logits`` for callers already inside ``sampler``.
+
+    The rank filter runs only when some sampling lane of the call has a
+    filter on — a ``lax.cond`` on the parameters the program already
+    receives, so no caller needs a second program.  With both filters off
+    on every sampling lane ``_rank_filter`` keeps every token and returns
+    ``scaled`` unchanged, after a sort and a scatter of the whole
+    vocabulary; the other branch returns the same array without them.  A
+    greedy lane's filters do not raise the predicate: ``sample_tokens``
+    never reads that lane's draw.  One filtered lane sends the whole call
+    through the rank filter, so every lane's row is what it always was.
+    Do not ``vmap`` this: a batched ``cond`` is a ``select`` that runs both
+    branches.
+    """
     logits = logits.astype(jnp.float32)
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = logits / temp
+    need = jnp.any((temperature > 0.0) & ((top_k > 0) | (top_p < 1.0)))
+    return jax.lax.cond(need, _rank_filter, lambda s, k, p: s,
+                        scaled, top_k, top_p)
+
+
+def _rank_filter(scaled: jnp.ndarray, top_k: jnp.ndarray,
+                 top_p: jnp.ndarray) -> jnp.ndarray:
+    """Top-k then top-p over temperature-scaled logits [B, V] f32, as rank
+    cutoffs; filtered entries -inf."""
+    B, V = scaled.shape
 
     # One descending argsort serves both filters.  order[b, r] = token id with
     # rank r; rank[b, t] = rank of token t.

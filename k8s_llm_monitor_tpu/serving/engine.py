@@ -116,6 +116,14 @@ class SamplingParams:
     # EOS is always reachable.
     constrained: bool = False
 
+    @property
+    def filtered(self) -> bool:
+        """This lane samples and has top-k or top-p on: the per-lane term
+        of the predicate on which ops/sampling.py takes its rank filter (a
+        greedy lane's filters are never read)."""
+        return self.temperature > 0.0 and (self.top_k > 0
+                                           or self.top_p < 1.0)
+
 
 @dataclasses.dataclass
 class GenerationRequest:
@@ -229,7 +237,10 @@ class EngineConfig:
     # ``sample_topk_cap`` logits (one lax.top_k) instead of rank-sorting
     # the full vocab each scan step (V=128k on the 8B target).  The
     # bounded program is distribution-exact in that regime
-    # (ops/sampling.py:sample_tokens_bounded); 0 disables.
+    # (ops/sampling.py:sample_tokens_bounded); 0 disables.  The unbounded
+    # program sorts only in a call where some sampling lane has a filter
+    # this cap does not cover (top_p alone, or top_k above it); with none
+    # it draws straight from the scaled logits (_filter_logits).
     sample_topk_cap: int = 64
     # Prompt-prefix KV reuse (serving/kv_cache.py:PrefixCache): LRU entry
     # cap (one entry per cached prefix *length*; host-side tuples, cheap);
@@ -433,7 +444,7 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
     # One per device call (InferenceEngine._call_attrs).
     "engine.call": ("kind", "program", "call_id", "device_empty",
                     "kv_blocks", "kv_live_blocks", "kv_cached_blocks",
-                    "steps", "lanes", "slots", "emitted",
+                    "sampler_filter", "steps", "lanes", "slots", "emitted",
                     "bucket", "rows", "prompts", "real_tokens",
                     "padded_tokens", "cached_tokens", "shared"),
     "xla.compile": ("seconds", "program"),
@@ -1075,6 +1086,11 @@ class InferenceEngine:
         self.decode_tokens = 0
         self.prefill_tokens = {"real": 0, "padded": 0, "cached": 0}
         self.dispatch_on_empty_device = 0
+        # Calls of a sampling program, by whether some sampling lane had
+        # top-k or top-p on — the host's copy of the predicate on which
+        # ops/sampling.py takes its rank filter.  Greedy programs count in
+        # neither.
+        self.sampler_filter_calls = {"on": 0, "off": 0}
         # Per-step collective (ICI) share of the TP decode step, estimated
         # by profile_decode_phases() from the measured step time and the
         # ring-all-reduce byte model; 0.0 off-mesh or before profiling,
@@ -1436,15 +1452,22 @@ class InferenceEngine:
                 "kv_cached_blocks": max(0, used - len(held))}
 
     def _call_attrs(self, kind: str, program: str, device_empty: bool,
+                    sampler_filter: Optional[bool] = None,
                     **counts: int) -> dict:
         """Count one device call where it is built — the exporter's
         counters, always — and return the attributes of its ``engine.call``
         span (SPAN_CATALOG), which gain the pool census when the loop is
         sampled.  Call it after the program call succeeded and the slots
-        are in place, with the call's id still ``_next_call_id``."""
+        are in place, with the call's id still ``_next_call_id``.
+        ``sampler_filter``: whether any lane of a sampling program's call
+        is ``SamplingParams.filtered``; None for a greedy program."""
         self.calls_by_kind[kind] = self.calls_by_kind.get(kind, 0) + 1
         if device_empty:
             self.dispatch_on_empty_device += 1
+        if sampler_filter is not None:
+            self.sampler_filter_calls[
+                "on" if sampler_filter else "off"] += 1
+            counts["sampler_filter"] = int(sampler_filter)
         if kind in ("admit", "chunk"):
             for key in self.prefill_tokens:
                 self.prefill_tokens[key] += counts[f"{key}_tokens"]
@@ -2504,7 +2527,10 @@ class InferenceEngine:
         cached = sum(st for _, _, _, st in batch)
         self._finish_admit_dispatch(
             first, [(s, r, b) for s, r, b, _ in batch], idx, fsm_next=fnext,
-            counts={"bucket": bucket, "rows": P, "prompts": len(batch),
+            counts={"sampler_filter": (
+                        None if all_greedy and not constrained else
+                        any(r.sampling.filtered for _, r, _, _ in batch)),
+                    "bucket": bucket, "rows": P, "prompts": len(batch),
                     "real_tokens": sum(len(r.prompt_ids)
                                        for _, r, _, _ in batch) - cached,
                     "padded_tokens": bucket * P, "cached_tokens": cached,
@@ -2664,7 +2690,11 @@ class InferenceEngine:
         self._queue_inflight(
             "chunk", first, idx, lanes, touched, fsm_next=fnext,
             span_attrs=self._call_attrs(
-                "chunk", program, device_empty, bucket=bucket, rows=P,
+                "chunk", program, device_empty,
+                sampler_filter=(
+                    None if final_greedy and not final_constrained
+                    else any(r.sampling.filtered for _, _, r in lanes)),
+                bucket=bucket, rows=P,
                 prompts=len(cands), real_tokens=sum(n for _, n, _ in muts),
                 padded_tokens=bucket * P, cached_tokens=cached))
         return True
@@ -3296,6 +3326,9 @@ class InferenceEngine:
         # FSM program; its free co-lanes run masked-by-nothing at state 0.
         constrained = (self._fsm_trans is not None and any(
             s.req.sampling.constrained for _, s in lanes))
+        # Filters only matter on lanes that actually sample: a greedy lane
+        # carrying top_p (a common client default) never has them read.
+        any_filtered = any(s.req.sampling.filtered for _, s in lanes)
         device_empty = self._device_empty()
         try:
             self._faults.maybe_raise("decode_dispatch")
@@ -3303,7 +3336,7 @@ class InferenceEngine:
             payload, kind, program = self._dispatch_decode_call(
                 spec and not constrained, all_greedy, lanes, K, ctx,
                 steps_arr, table, temp, topk, topp, eos,
-                constrained=constrained)
+                constrained=constrained, any_filtered=any_filtered)
         except Exception as exc:
             # Nothing reached the device: undo the in-flight accounting so
             # the same lanes re-dispatch next step (ctx_pred derives from
@@ -3329,14 +3362,18 @@ class InferenceEngine:
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=payload, lanes=meta,
             t0=time.monotonic(),
-            span_attrs=self._call_attrs(kind, program, device_empty, steps=K,
-                                        lanes=len(lanes), slots=B)))
+            span_attrs=self._call_attrs(
+                kind, program, device_empty,
+                sampler_filter=(None if all_greedy and not constrained
+                                else any_filtered),
+                steps=K, lanes=len(lanes), slots=B)))
         self._next_call_id += 1
         return True
 
     def _dispatch_decode_call(self, spec: bool, all_greedy: bool, lanes,
                               K: int, ctx, steps_arr, table, temp, topk,
-                              topp, eos, constrained: bool = False):
+                              topp, eos, constrained: bool = False,
+                              any_filtered: bool = False):
         """The device-call half of :meth:`_dispatch_decode`, split out so
         the dispatch fault/rollback boundary wraps exactly the program
         call.  Returns ``(payload, kind, program name)``."""
@@ -3370,22 +3407,16 @@ class InferenceEngine:
                 pass
             return payload, kind, program
         if spec:
-            # Filters only matter on lanes that actually sample: a greedy
-            # lane carrying top_p (a common client default) must not force
-            # the filtered program variant (extra compile + per-round
-            # full-vocab sorts the argmax rule never reads).
-            any_filtered = any(
-                s.req.sampling.temperature > 0.0
-                and (s.req.sampling.top_k > 0 or s.req.sampling.top_p < 1.0)
-                for _, s in lanes)
-            filtered = any_filtered and not all_greedy
+            # The filtered variant (an extra compile, full-vocabulary sorts
+            # every round) only when a lane that samples has a filter —
+            # never on an all-greedy call.
             prog = self._spec_program(ec.spec_k, ec.spec_rounds_per_iter,
                                       sampled=not all_greedy,
-                                      filtered=filtered)
+                                      filtered=any_filtered)
             program = _calling.program = (
                 f"spec_k{ec.spec_k}_r{ec.spec_rounds_per_iter}_"
                 + ("greedy" if all_greedy
-                   else "filtered" if filtered else "sampled"))
+                   else "filtered" if any_filtered else "sampled"))
             self._rng, sub = jax.random.split(self._rng)
             toks, self._tok_state, self.pages, self._hist, nver = prog(
                 self.params, self._tok_state, jnp.asarray(ctx),

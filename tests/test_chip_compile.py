@@ -19,6 +19,7 @@ reason, and compile in the test's own process.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from k8s_llm_monitor_tpu.models.config import PRESETS
 from k8s_llm_monitor_tpu.ops import attention as ops
 from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+from k8s_llm_monitor_tpu.ops.sampling import sample_tokens
 
 CFG = PRESETS["qwen2-7b"]
 H, KVH, D = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_
@@ -89,11 +91,12 @@ def four_chips(topo, no_persistent_cache):
     return mesh, arg
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernel=True):
     """Lower + compile for the described device(s); raises what the chip's
     compiler would raise.  Returns the compiled text."""
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    if kernel:
+        assert "tpu_custom_call" in text, "no Pallas kernel in the program"
     return text
 
 
@@ -203,3 +206,65 @@ def test_tp_flash_prefill_compiles_on_four_chips(four_chips, kv_quant):
             A((NBLK, BS, KVH), F32, lanes), A((NBLK, BS, KVH), F32, lanes))
     else:
         _compile(attn, *args)
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _reachable(comps, roots):
+    """Every computation the instructions of ``roots`` call, transitively
+    (fusions, reducers, loop bodies, branches)."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += re.findall(r"%([\w.\-]+)", line.split(" metadata=")[0])
+    return seen
+
+
+def test_sampler_sorts_only_inside_the_filter_branch(one_chip):
+    """The cell's sampler shape, 64 lanes x Qwen2's 152,064 vocabulary: the
+    chip's compiler keeps ``_filter_logits``' ``lax.cond`` a real
+    ``conditional`` (not a select over both branches), the branch taken with
+    every filter off holds no sort, and no sort or scatter of the vocabulary
+    width is left outside the other one.  No Pallas kernel here."""
+    S = one_chip
+    B, V = 64, CFG.vocab_size
+    text = _compile(
+        lambda key, logits, t, k, p: sample_tokens(
+            key, logits, temperature=t, top_k=k, top_p=p),
+        S((2,), jnp.uint32), S((B, V), F32), S((B,), F32), S((B,), I32),
+        S((B,), F32), kernel=False)
+    comps = _computations(text)
+    conds = [(name, line) for name, lines in comps.items() for line in lines
+             if re.search(r"\sconditional\(", line)]
+    assert len(conds) == 1, [line[:120] for _, line in conds]
+    # The predicate reaches the conditional as an index: 0 is the false one.
+    off, on = re.search(r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}",
+                        conds[0][1]).groups()
+
+    def wide(names):
+        return [line.strip()[:120] for n in names for line in comps[n]
+                if re.search(r"\s(sort|scatter)\(", line)
+                and re.search(rf"\b({V}|{B * V})\b", line)]
+
+    assert not [line for n in _reachable(comps, [off]) for line in comps[n]
+                if re.search(r"\ssort\(", line)]
+    inside = _reachable(comps, [on])
+    assert wide(inside), "the rank filter's sort is gone from its branch"
+    assert wide(set(comps) - inside) == []
