@@ -170,7 +170,9 @@ def run_preflight(args: argparse.Namespace,
         r.ok(f"{cfg.num_layers}L hidden={cfg.hidden_size} "
              f"heads={cfg.num_heads}/{cfg.num_kv_heads}kv "
              f"head_dim={head_dim} vocab={cfg.vocab_size}"
-             + (f" experts={cfg.num_experts}" if cfg.num_experts else ""))
+             + (f" experts={cfg.num_experts}" if cfg.num_experts else "")
+             + (f" latent={cfg.kv_lora_rank}+{cfg.qk_rope_head_dim}"
+                if cfg.latent else ""))
     else:
         r.fail(f"num_heads {cfg.num_heads} not a multiple of "
                f"num_kv_heads {cfg.num_kv_heads}")
@@ -192,7 +194,15 @@ def run_preflight(args: argparse.Namespace,
         r.warn(f"mesh needs {n_mesh} device(s), this host sees "
                f"{len(devices)} - fine if deploying elsewhere or "
                "multi-host")
-    if model > 1:
+    from k8s_llm_monitor_tpu.serving.engine import InferenceEngine
+
+    unbuilt = InferenceEngine._unbuilt_reason(cfg)
+    if unbuilt and n_mesh > 1:
+        # The engine refuses this at construction (serving/engine.py): say
+        # so here instead of listing divisibilities that will never matter.
+        r.fail(f"a mesh is not built for {unbuilt}: serve {cfg.name} on "
+               "one chip (mesh 1,1,1)")
+    elif model > 1:
         bad = [(nm, dim) for nm, dim in
                [("num_heads", cfg.num_heads),
                 ("intermediate_size", cfg.intermediate_size),
@@ -255,6 +265,9 @@ def run_preflight(args: argparse.Namespace,
                      else cfg.num_kv_heads)
     kv_chip = (args.kv_blocks * args.block_size * cfg.num_layers * 2
                * kv_heads_chip * head_dim * kv_bytes_per)
+    if cfg.latent:   # one row a token a layer, no kv-head axis
+        kv_chip = (args.kv_blocks * args.block_size
+                   * cfg.kv_token_bytes(kv_bytes_per))
     cap_tokens = args.kv_blocks * args.block_size
     r.ok(f"{args.kv_blocks} blocks x {args.block_size} = "
          f"{cap_tokens} tokens capacity, {kv_chip / GIB:.2f} GiB/chip "

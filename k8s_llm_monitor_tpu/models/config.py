@@ -12,12 +12,34 @@ from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
-class ModelConfig:
-    """Architecture hyperparameters for a Llama/Qwen2-family decoder LM.
+class LayerSpec:
+    """What one decoder layer is made of (``ModelConfig.layer_spec``): the
+    code that builds, quantizes, shards and runs a layer asks this, not the
+    family a preset came from.
 
-    The family covers:
+    mixer: ``"full"`` (per-head K and V, GQA) | ``"latent"`` (DeepSeek-V3
+      multi-head latent attention: one compressed latent and one rotated key
+      per token).
+    mlp: ``"dense"`` | ``"routed"`` (top-k experts) | ``"shared+routed"``.
+    cache: ``"kv"`` (two page arrays, ``kv_heads * head_dim`` lanes) |
+      ``"latent"`` (one page array, ``ModelConfig.latent_page_width`` lanes).
+    """
+
+    mixer: str
+    mlp: str
+    cache: str
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelConfig:
+    """Architecture hyperparameters for the decoder LM families served.
+
+    The families covered:
       - Llama-3:  GQA, RoPE (high theta), SwiGLU MLP, RMSNorm, no biases.
       - Qwen2:    same skeleton + QKV projection biases.
+      - Mixtral:  softmax top-k routed experts in every layer.
+      - DeepSeek-V3 block: latent attention, leading dense layers, then
+        sigmoid-scored routed experts beside a shared MLP.
     """
 
     name: str = "tiny"
@@ -82,6 +104,77 @@ class ModelConfig:
     # sliding when sliding_window > 0).
     sliding_window: int = 0
     layer_types: Optional[tuple] = None
+    # --- per-layer description (LayerSpec) ------------------------------
+    # Mixer of every layer: "full" | "latent".  The latent sizes are the
+    # published DeepSeek-V3 keys; q_lora_rank is not supported (the query
+    # projection is direct, as in the configurations served so far).
+    mixer: str = "full"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Expert layers: the first ``first_dense_layers`` layers keep a dense MLP
+    # of ``intermediate_size``; the rest route ``num_experts_per_tok`` of
+    # ``num_experts`` experts of width ``moe_intermediate_size`` (0 =
+    # ``intermediate_size``, Mixtral) beside ``n_shared_experts`` shared ones
+    # (one MLP of n_shared x that width; 0 = none).
+    first_dense_layers: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    # Scoring: "softmax" (Mixtral: softmax over experts, top-k of it) |
+    # "sigmoid+bias" (DeepSeek-V3: s = sigmoid(logits); the choice is the
+    # top-k of s + e_bias, the weights are s of the chosen).
+    moe_scoring: str = "softmax"
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+
+    def layer_spec(self, i: int) -> LayerSpec:
+        """Mixer, MLP and cache kind of layer ``i``."""
+        if self.num_experts > 0 and i >= self.first_dense_layers:
+            mlp = "shared+routed" if self.n_shared_experts > 0 else "routed"
+        else:
+            mlp = "dense"
+        return LayerSpec(mixer=self.mixer, mlp=mlp,
+                         cache="latent" if self.mixer == "latent" else "kv")
+
+    @property
+    def latent(self) -> bool:
+        return self.mixer == "latent"
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers whose MLP routes (0 for a dense model)."""
+        if self.num_experts <= 0:
+            return 0
+        return max(0, self.num_layers - self.first_dense_layers)
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of one head's score: nope + rope parts (latent mixer)."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_rope_width(self) -> int:
+        """Lanes the rotated key takes in a latent page: its width padded to
+        a whole 128-lane tile (zeros), so that a page row is lane-aligned
+        for the chip's DMA and what the pool costs is what its shape says."""
+        return -(-self.qk_rope_head_dim // 128) * 128
+
+    @property
+    def latent_page_width(self) -> int:
+        """Lanes of one cached token in one layer of a latent pool:
+        ``[latent (kv_lora_rank) | rotated key | zeros]``."""
+        return self.kv_lora_rank + self.latent_rope_width
+
+    def kv_token_bytes(self, itemsize: int = 2) -> int:
+        """Bytes one cached token costs over all layers (scales excluded)."""
+        if self.latent:
+            return self.num_layers * self.latent_page_width * itemsize
+        return 2 * self.num_layers * self.num_kv_heads * self.head_dim_ * itemsize
 
     @property
     def attn_scale(self) -> Optional[float]:
@@ -125,6 +218,21 @@ TINY_QWEN = ModelConfig(name="tiny-qwen", qkv_bias=True)
 
 # 8 experts so the expert axis divides TP-8 like the production MoE preset.
 TINY_MOE = ModelConfig(name="tiny-moe", num_experts=8, num_experts_per_tok=2)
+
+# The DeepSeek-V3 block at test size: latent attention (rope part narrower
+# than the nope part, value width its own), one leading dense layer, then
+# sigmoid-scored experts beside a shared MLP.  vocab >= 259 so the byte
+# tokenizer's ids fit (chip_smoke / harness rehearsals).
+TINY_LATENT_MOE = ModelConfig(
+    name="tiny-latent-moe", vocab_size=320, hidden_size=64,
+    intermediate_size=96, num_layers=3, num_heads=4, num_kv_heads=4,
+    rope_theta=10_000.0, rms_norm_eps=1e-6,
+    mixer="latent", kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, head_dim=24,
+    num_experts=8, num_experts_per_tok=3, first_dense_layers=1,
+    moe_intermediate_size=24, n_shared_experts=2,
+    moe_scoring="sigmoid+bias", norm_topk_prob=True,
+    routed_scaling_factor=2.448)
 
 LLAMA3_8B = ModelConfig(
     name="llama3-8b",
@@ -245,6 +353,38 @@ QWEN2_72B = ModelConfig(
     qkv_bias=True,
 )
 
+# kakaocorp/kanana-2-30b-a3b-instruct-2601 (config.json, model_type
+# deepseek_v3), cut in depth alone: the leading dense layer and 11 of the 47
+# expert layers (48 -> 12), every width, all 128 experts and the whole
+# vocabulary as published.  One pipeline stage of four, with the head
+# (benchmarks/configs/kanana2-30b-a3b-w8a8.json has the reckoning).
+KANANA2_30B_A3B_12L = ModelConfig(
+    name="kanana-2-30b-a3b-12l",
+    vocab_size=128_256,
+    hidden_size=2048,
+    intermediate_size=6144,
+    num_layers=12,
+    num_heads=32,
+    num_kv_heads=32,
+    head_dim=192,
+    rope_theta=1_000_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=32_768,
+    mixer="latent",
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    num_experts=128,
+    num_experts_per_tok=6,
+    first_dense_layers=1,
+    moe_intermediate_size=768,
+    n_shared_experts=2,
+    moe_scoring="sigmoid+bias",
+    norm_topk_prob=True,
+    routed_scaling_factor=2.448,
+)
+
 # A ~1.1B config used for single-chip benchmarks when full 8B weights would not
 # leave headroom for the KV cache on a 16 GB v5e chip with random-init weights.
 LLAMA_1B = ModelConfig(
@@ -262,9 +402,9 @@ LLAMA_1B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in [TINY, TINY_QWEN, TINY_MOE, LLAMA3_8B, LLAMA3_70B, MISTRAL_7B,
-              MIXTRAL_8X7B, QWEN2_7B, QWEN2_72B, GEMMA2_2B, GEMMA2_9B,
-              LLAMA_1B]
+    for c in [TINY, TINY_QWEN, TINY_MOE, TINY_LATENT_MOE, LLAMA3_8B,
+              LLAMA3_70B, MISTRAL_7B, MIXTRAL_8X7B, QWEN2_7B, QWEN2_72B,
+              GEMMA2_2B, GEMMA2_9B, LLAMA_1B, KANANA2_30B_A3B_12L]
 }
 
 

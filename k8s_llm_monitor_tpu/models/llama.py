@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from k8s_llm_monitor_tpu.models.config import ModelConfig
 from k8s_llm_monitor_tpu.ops.attention import (
+    blockwise_attention,
     causal_attention,
     gather_pages,
     paged_decode_attention,
@@ -66,6 +67,16 @@ class KVPages(NamedTuple):
     stay global (serving/kv_cache.py module docstring), every chip
     scatters/gathers with the same block table, and the host allocator
     stays mesh-agnostic.
+
+    Latent page kind (``ModelConfig.layer_spec(i).cache == "latent"``): a
+    cached token is ONE row per layer with no kv-head axis,
+    ``k[i]: [num_blocks, block_size, cfg.latent_page_width]`` holding
+    ``[normalised latent c (kv_lora_rank) | rotated key (qk_rope_head_dim) |
+    zeros]`` — the rotated key's lanes are padded to a whole 128-lane tile so
+    that a row is lane-aligned for the decode kernel's page DMA and the
+    pool's cost is what its shape says.  ``v`` is an empty list: the value of
+    a row is its own first ``kv_lora_rank`` lanes.  Block ids, block tables
+    and the allocator are the same as for the kv kind.
     """
 
     k: list[jnp.ndarray]
@@ -134,7 +145,17 @@ def init_kv_pages(cfg: ModelConfig, num_blocks: int, block_size: int,
                   kv_quant: str = "") -> KVPages:
     """Allocate the paged KV pool.  ``kv_quant`` ("int8"/"fp8") selects the
     quantized tier: page arrays in the storage dtype plus per-(token, head)
-    float32 scale arrays; "" keeps the historical unquantized layout."""
+    float32 scale arrays; "" keeps the historical unquantized layout.
+    The page kind is the description's (``cfg.layer_spec(i).cache``): a
+    latent pool is one array a layer (see :class:`KVPages`)."""
+    if cfg.latent:
+        if kv_quant:
+            raise ValueError(
+                f"latent pages are not built for kv_dtype={kv_quant!r}")
+        dtype = jnp.dtype(cfg.kv_dtype or cfg.dtype)
+        shape = (num_blocks, block_size, cfg.latent_page_width)
+        return KVPages(k=[jnp.zeros(shape, dtype)
+                          for _ in range(cfg.num_layers)], v=[])
     shape = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim_)
     if kv_quant:
         qdtype, _ = kv_quant_spec(kv_quant)
@@ -179,6 +200,11 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             key, (cfg.num_experts, in_f, out_f), jnp.float32) * (in_f ** -0.5)
         return {"kernel": w.astype(dtype)}
 
+    def mlp(keys, width):
+        return {"gate": dense(keys[0], H, width, False),
+                "up": dense(keys[1], H, width, False),
+                "down": dense(keys[2], width, H, False)}
+
     # Gemma stores norm weights as zero-centered deltas (effective scale
     # 1 + w), so identity-init is zeros there, ones elsewhere.
     norm_init = jnp.zeros if cfg.rmsnorm_unit_offset else jnp.ones
@@ -186,27 +212,49 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     layers = []
     for i in range(cfg.num_layers):
+        # 8 keys as ever (a dense or Mixtral layer's weights do not move);
+        # what a shared+routed layer adds draws from a stream of its own.
         lk = jax.random.split(keys[2 + i], 8)
+        xk = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 4)
+        spec = cfg.layer_spec(i)
         layer = {
             "input_norm": norm_init((H,), dtype),
             "post_norm": norm_init((H,), dtype),
-            "q": dense(lk[0], H, nH * D, cfg.qkv_bias),
-            "k": dense(lk[1], H, nKV * D, cfg.qkv_bias),
-            "v": dense(lk[2], H, nKV * D, cfg.qkv_bias),
-            "o": dense(lk[3], nH * D, H, False),
         }
+        if spec.mixer == "latent":
+            R, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim)
+            layer["q"] = dense(lk[0], H, nH * (dn + dr), False)
+            layer["kv_a"] = dense(lk[1], H, R + dr, False)
+            layer["kv_norm"] = norm_init((R,), dtype)
+            layer["kv_b"] = dense(lk[2], R, nH * (dn + cfg.v_head_dim), False)
+            layer["o"] = dense(lk[3], nH * cfg.v_head_dim, H, False)
+        else:
+            layer["q"] = dense(lk[0], H, nH * D, cfg.qkv_bias)
+            layer["k"] = dense(lk[1], H, nKV * D, cfg.qkv_bias)
+            layer["v"] = dense(lk[2], H, nKV * D, cfg.qkv_bias)
+            layer["o"] = dense(lk[3], nH * D, H, False)
         if cfg.sandwich_norms:
             layer["post_attn_norm"] = norm_init((H,), dtype)
             layer["post_mlp_norm"] = norm_init((H,), dtype)
-        if cfg.num_experts > 0:
-            layer["router"] = dense(lk[7], H, cfg.num_experts, False)
-            layer["gate_e"] = expert_dense(lk[4], H, I)
-            layer["up_e"] = expert_dense(lk[5], H, I)
-            layer["down_e"] = expert_dense(lk[6], I, H)
+        if spec.mlp == "dense":
+            layer.update(mlp(lk[4:7], I))
         else:
-            layer["gate"] = dense(lk[4], H, I, False)
-            layer["up"] = dense(lk[5], H, I, False)
-            layer["down"] = dense(lk[6], I, H, False)
+            Ie = cfg.expert_width
+            layer["router"] = dense(lk[7], H, cfg.num_experts, False)
+            if cfg.moe_scoring == "sigmoid+bias":
+                # Published code scores in float32; the selection bias is
+                # zeros plus a small normal (a trained one is not reachable),
+                # used for the choice only.
+                layer["router"] = {
+                    "kernel": layer["router"]["kernel"].astype(jnp.float32),
+                    "e_bias": 0.01 * jax.random.normal(
+                        xk[0], (cfg.num_experts,), jnp.float32)}
+            layer["gate_e"] = expert_dense(lk[4], H, Ie)
+            layer["up_e"] = expert_dense(lk[5], H, Ie)
+            layer["down_e"] = expert_dense(lk[6], Ie, H)
+            if spec.mlp == "shared+routed":
+                layer["shared"] = mlp(xk[1:4], cfg.n_shared_experts * Ie)
         layers.append(layer)
     params: Params = {
         "embed": {
@@ -379,14 +427,125 @@ def _qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
     return q, k, v
 
 
+def _rope_width(cfg: ModelConfig) -> int:
+    """Lanes the rotary embedding turns: the whole head, or the rope part of
+    a latent mixer's score."""
+    return cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim_
+
+
+def _latent_qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
+    """Latent mixer, what is computed per token.  x [B, S, H] ->
+    q_nope [B,S,nH,dn], q_rope [B,S,nH,dr] (rotated), c [B,S,R] (the
+    normalised latent) and k_rope [B,S,dr] (one rotated key shared by all
+    heads).  ``c`` and ``k_rope`` are all that is cached."""
+    B, S, _ = x.shape
+    R, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    aq = cfg.act_quant
+    with jax.named_scope("qkv"):
+        q = _linear(layer["q"], x, aq).reshape(B, S, cfg.num_heads, -1)
+        kva = _linear(layer["kv_a"], x, aq)                 # [B, S, R + dr]
+        c = rms_norm(kva[..., :R], layer["kv_norm"], cfg.rms_norm_eps,
+                     cfg.rmsnorm_unit_offset)
+        q_rope = apply_rope(q[..., dn:], cos, sin)
+        k_rope = apply_rope(kva[..., None, R:], cos, sin)[:, :, 0]
+    return q[..., :dn], q_rope, c, k_rope
+
+
+def _page_width(cfg: ModelConfig, latent: jnp.ndarray,
+                rope: jnp.ndarray) -> jnp.ndarray:
+    """``[latent part | rope part | zeros]`` at the latent page's width: the
+    layout of a cached row, and of the absorbed query that meets it."""
+    pad = cfg.latent_page_width - latent.shape[-1] - rope.shape[-1]
+    zeros = jnp.zeros((*latent.shape[:-1], pad), latent.dtype)
+    return jnp.concatenate([latent, rope, zeros], axis=-1)
+
+
+def _latent_rows(cfg: ModelConfig, c: jnp.ndarray,
+                 k_rope: jnp.ndarray) -> jnp.ndarray:
+    """The page row of each token: ``[c | k_rope | zeros]`` [B, S, 1, F]."""
+    return _page_width(cfg, c, k_rope)[:, :, None, :]
+
+
+def _kv_b(layer: Params, cfg: ModelConfig):
+    """``W_kvb`` by head: (W_UK [R, nH, dn], W_UV [R, nH, dv]).  It stays in
+    the activation dtype under every quantisation (utils/quantize.py): the
+    absorbed form multiplies queries and outputs by it, not the cached
+    latent, so an activation-quantised product would differ between the two
+    forms."""
+    w = layer["kv_b"]["kernel"].reshape(
+        cfg.kv_lora_rank, cfg.num_heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
+                            c, k_rope, positions, kv_len, attn_fn=None,
+                            kernel=None):
+    """Expanded form over the batch's own tokens: per-head keys
+    ``[k_nope | k_rope]`` and values from ``c W_kvb``.  ``kernel``: the
+    Pallas kernel of a fresh prefill (positions are indices,
+    ops/pallas_attention.py:latent_prefill_attention_pallas); ``attn_fn``:
+    the dense oracle (``causal_attention``); neither: blockwise XLA
+    operations."""
+    B, S = c.shape[:2]
+    w_uk, w_uv = _kv_b(layer, cfg)
+    k_nope = jnp.einsum("bsr,rhd->bshd", c, w_uk)
+    v = jnp.einsum("bsr,rhd->bshd", c, w_uv)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (B, S, cfg.num_heads, k_rope.shape[-1]))],
+        axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    scale = cfg.qk_head_dim ** -0.5
+    if attn_fn is not None:
+        return attn_fn(q, k, v, q_positions=positions, scale=scale)
+    if kernel is not None:
+        return kernel(q, k, v, kv_len, scale=scale)
+    return blockwise_attention(q, k, v, q_positions=positions, kv_len=kv_len,
+                               scale=scale)
+
+
+def _latent_absorb(layer: Params, cfg: ModelConfig, q_nope,
+                   q_rope) -> jnp.ndarray:
+    """Queries of the absorbed form, against page rows: ``[q_nope W_UK^T |
+    q_rope | zeros] / sqrt(dn + dr)`` [B, S, nH, F]."""
+    w_uk, _ = _kv_b(layer, cfg)
+    q_abs = _page_width(
+        cfg, jnp.einsum("bshd,rhd->bshr", q_nope, w_uk), q_rope)
+    return q_abs * jnp.asarray(cfg.qk_head_dim ** -0.5, q_abs.dtype)
+
+
+def _latent_unabsorb(layer: Params, cfg: ModelConfig,
+                     o_lat: jnp.ndarray) -> jnp.ndarray:
+    """``(P c) W_UV`` by head: [B, S, nH, R] -> [B, S, nH, dv]."""
+    _, w_uv = _kv_b(layer, cfg)
+    return jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
+
+
+def _marked(attn_impl, mark: str) -> bool:
+    """True when ``attn_impl``, or the function a functools.partial wraps
+    (tests and CPU runs bind interpret=True that way), carries ``mark``."""
+    return bool(getattr(attn_impl, mark, False)
+                or getattr(getattr(attn_impl, "func", None), mark, False))
+
+
+def is_latent_prefill_impl(attn_impl) -> bool:
+    """True when ``attn_impl`` is the latent mixer's fresh-prefill kernel
+    (expanded form; ops/attention.py:select_prefill_impl)."""
+    return _marked(attn_impl, "latent_prefill")
+
+
+def is_latent_decode_impl(attn_impl) -> bool:
+    """True when ``attn_impl`` reads latent pages in the absorbed form
+    (ops/attention.py:latent_decode_attention and its Pallas kernel)."""
+    return _marked(attn_impl, "latent")
+
+
 def is_fused_decode_impl(attn_impl) -> bool:
     """True when ``attn_impl`` uses the fused decode calling convention
     (ops/pallas_attention.py:paged_decode_attention_fused — raw q/k/v +
     rope angles in, attention + updated pages out).  Survives a
     functools.partial wrap (tests bind interpret=True that way)."""
-    return bool(getattr(attn_impl, "fused_decode", False)
-                or getattr(getattr(attn_impl, "func", None),
-                           "fused_decode", False))
+    return _marked(attn_impl, "fused_decode")
 
 
 def is_fused_quant_decode_impl(attn_impl) -> bool:
@@ -395,9 +554,7 @@ def is_fused_quant_decode_impl(attn_impl) -> bool:
     page scales, returns updated scales).  A fused impl WITHOUT this marker
     must never be handed a quantized pool; decode_step falls back to the
     gather/dequant path in that case."""
-    return bool(getattr(attn_impl, "quant_kv", False)
-                or getattr(getattr(attn_impl, "func", None),
-                           "quant_kv", False))
+    return _marked(attn_impl, "quant_kv")
 
 
 def is_flash_prefill_impl(attn_impl) -> bool:
@@ -406,9 +563,7 @@ def is_flash_prefill_impl(attn_impl) -> bool:
     softmax reading K/V straight from the pool, scale planes as kwargs
     for quantized pools).  Survives a functools.partial wrap (CPU runs
     bind interpret=True that way)."""
-    return bool(getattr(attn_impl, "flash_prefill", False)
-                or getattr(getattr(attn_impl, "func", None),
-                           "flash_prefill", False))
+    return _marked(attn_impl, "flash_prefill")
 
 
 def _expert_weights(p: Params, dtype, act_quant: bool = False):
@@ -549,11 +704,8 @@ def _moe_mlp_dropless(layer: Params, cfg: ModelConfig,
     decode shapes are fine — Mixtral-class weights need a mesh anyway).
     """
     B, S, H = x.shape
-    E, K = cfg.num_experts, cfg.num_experts_per_tok
-    logits = _linear(layer["router"], x)                       # [B, S, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, K)
-    topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
+    E = cfg.num_experts
+    topi, topv = _route(layer, cfg, x)                         # [B, S, K]
     # Router weights scattered back to [B, S, E] (zero for unchosen).
     w = jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32)
                 * topv[..., None], axis=2)
@@ -591,7 +743,149 @@ def _moe_mlp_dropless(layer: Params, cfg: ModelConfig,
         ys = jnp.einsum("ebsi,eih->ebsh", jax.nn.silu(gate) * up, dk)
         if ds is not None:
             ys = ys * ds[:, None, None, :].astype(ys.dtype)
-    return jnp.einsum("ebsh,bse->bsh", ys, w.astype(x.dtype))
+    y = jnp.einsum("ebsh,bse->bsh", ys, w.astype(x.dtype))
+    if "shared" in layer:
+        y = y + _dense_mlp(layer["shared"], cfg, x)
+    return y
+
+
+def _route(layer: Params, cfg: ModelConfig,
+           x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The router of an expert layer: x [..., H] -> (chosen experts
+    [..., K] int32, their weights [..., K] float32), by the description's
+    scoring rule.  ``softmax``: top-k of the softmax, renormalised
+    (Mixtral).  ``sigmoid+bias``: s = sigmoid(logits); the choice is the
+    top-k of ``s + e_bias``, the weights are s of the chosen, normalised
+    (``norm_topk_prob``) and scaled by ``routed_scaling_factor``
+    (DeepSeek-V3 with one group).  A float32 router kernel is multiplied in
+    float32 at full precision, as the published code does: near-ties among
+    many experts must not be decided by the activation dtype."""
+    r = layer["router"]
+    K = cfg.num_experts_per_tok
+    with jax.named_scope("router"):
+        if r["kernel"].dtype == jnp.float32:
+            logits = jnp.dot(x.astype(jnp.float32), r["kernel"],
+                             precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = _linear(r, x).astype(jnp.float32)
+        if cfg.moe_scoring == "sigmoid+bias":
+            scores = jax.nn.sigmoid(logits)
+            _, topi = jax.lax.top_k(scores + r["e_bias"], K)
+            # The chosen experts' scores by a one-hot contraction, not a
+            # gather: K x tokens single-element gathers are ~30 ns each on
+            # the chip (3 ms a layer at 16k tokens), this is one pass.
+            topv = jnp.einsum(
+                "...ke,...e->...k",
+                jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32),
+                scores)
+        elif cfg.moe_scoring == "softmax":
+            topv, topi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+        else:
+            raise ValueError(f"unknown moe_scoring {cfg.moe_scoring!r}")
+        if cfg.norm_topk_prob:
+            topv = topv / (jnp.sum(topv, axis=-1, keepdims=True) + 1e-20)
+        if cfg.routed_scaling_factor != 1.0:
+            topv = topv * cfg.routed_scaling_factor
+    return topi, topv
+
+
+def _expert_rows(p: Params, rows: jnp.ndarray, row_scale, rows_e: jnp.ndarray,
+                 group_sizes: jnp.ndarray, dtype) -> jnp.ndarray:
+    """One grouped matrix product over the experts that were chosen: row i
+    of the expert-sorted assignments against the kernel of its expert
+    (``jax.lax.ragged_dot``: on TPU the compiler's own grouped-product
+    kernel, int8 x int8 -> int32 included; nothing is computed for an expert
+    with no rows).  Under W8A8 ``rows`` are int8 and ``row_scale`` [rows, 1]
+    their per-row scales (``_linear``'s contract: both scales factor out of
+    the contraction); else ``rows`` are wide and ``row_scale`` is None."""
+    if row_scale is not None:
+        y32 = jax.lax.ragged_dot(rows, p["kernel_q"], group_sizes,
+                                 preferred_element_type=jnp.int32)
+        return (y32.astype(jnp.float32) * row_scale
+                * p["scale"][rows_e]).astype(dtype)
+    if "kernel_q" in p:      # weight-only int8: dequantise on the result
+        y = jax.lax.ragged_dot(rows, p["kernel_q"].astype(dtype), group_sizes)
+        return y * p["scale"][rows_e].astype(dtype)
+    return jax.lax.ragged_dot(rows, p["kernel"], group_sizes)
+
+
+def _moe_mlp_routed(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
+                    valid: Optional[jnp.ndarray] = None,
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The serving expert layer: only the chosen experts are computed.
+
+    x [B, S, H] -> (y [B, S, H], counts float32[4]).  Token-expert
+    assignments are sorted by expert; each projection is one grouped
+    product over the sorted rows; the results go back to their tokens
+    weighted by the router.  No token is dropped, and an expert nobody chose
+    costs nothing.  ``valid`` [B, S] marks real tokens: the assignments of
+    padding (a prefill bucket's tail, an idle decode lane) sort behind every
+    expert's group and are not computed at all.  A shared MLP, where the
+    layer has one, runs beside the routed experts through ``_linear``.
+
+    ``counts`` = (assignments computed, experts with at least one row, the
+    fullest expert's rows, experts in the layer): the engine sums them over
+    layers and steps onto the call's ``engine.call`` span.
+    """
+    B, S, H = x.shape
+    T, E, K = B * S, cfg.num_experts, cfg.num_experts_per_tok
+    xt = x.reshape(T, H)
+    topi, topv = _route(layer, cfg, xt)                         # [T, K]
+    with jax.named_scope("routed"):
+        flat_e = topi.reshape(T * K)
+        if valid is not None:
+            flat_e = jnp.where(jnp.repeat(valid.reshape(T), K), flat_e, E)
+        # (act_quant without int8 kernels runs wide, as in _linear, which
+        # warns about it on this layer's other projections.)
+        aq = cfg.act_quant and "kernel_q" in layer["gate_e"]
+        xq, xs = _quant_act(xt) if aq else (None, None)
+        # One stable sort by expert carries what each row needs with it
+        # (its place in token order, its router weight, its token's
+        # activation scale): single-element gathers and scatters are what
+        # the chip does worst, so none is left here — the counts per expert
+        # are a one-hot sum, the way back is a second sort.
+        iota = jnp.arange(T * K, dtype=jnp.int32)
+        carried = [flat_e, iota, topv.reshape(T * K)]
+        if aq:
+            carried.append(jnp.repeat(xs[:, 0], K))
+        rows_e, order, w_rows, *rs = jax.lax.sort(
+            carried, num_keys=1, is_stable=True)
+        live = (rows_e < E)[:, None]            # rows of real assignments
+        rows_e = jnp.minimum(rows_e, E - 1)
+        tok = order // K
+        group_sizes = jnp.sum(
+            flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        rows, rs = (xq[tok], rs[0][:, None]) if aq else (xt[tok], None)
+        gate = _expert_rows(layer["gate_e"], rows, rs, rows_e, group_sizes,
+                            x.dtype)
+        up = _expert_rows(layer["up_e"], rows, rs, rows_e, group_sizes,
+                          x.dtype)
+        h = jax.nn.silu(gate) * up                              # [T*K, I]
+        w_rows = w_rows[:, None]
+        if aq:      # the router's weight rides on the dequantisation
+            hq, hs = _quant_act(h)
+            ys = _expert_rows(layer["down_e"], hq, hs * w_rows, rows_e,
+                              group_sizes, x.dtype)
+        else:
+            ys = _expert_rows(layer["down_e"], h, None, rows_e, group_sizes,
+                              x.dtype)
+            ys = (ys.astype(jnp.float32) * w_rows).astype(x.dtype)
+        # Rows behind the last group are whatever the product left there.
+        ys = jnp.where(live, ys, jnp.zeros((), ys.dtype))
+        # Back to token order: row inv[t * K + k] is token t's k-th expert.
+        _, inv = jax.lax.sort([order, iota], num_keys=1)
+        y = jnp.sum(ys[inv].reshape(T, K, H).astype(jnp.float32),
+                    axis=1).astype(x.dtype)
+        counts = jnp.stack([
+            jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
+            jnp.max(group_sizes), jnp.asarray(E, jnp.int32),
+        ]).astype(jnp.float32)
+    y = y.reshape(B, S, H)
+    if "shared" in layer:
+        with jax.named_scope("shared"):
+            y = y + _dense_mlp(layer["shared"], cfg, x)
+    return y, counts
 
 
 def _mlp_act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
@@ -600,33 +894,58 @@ def _mlp_act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.silu(x)
 
 
-def _mlp(layer: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
-    if cfg.num_experts > 0:
-        return _moe_mlp_dropless(layer, cfg, x)
+def _dense_mlp(p: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
+    """Gated MLP over ``p``'s gate/up/down: a dense layer's own, or the
+    shared experts of an expert layer."""
     aq = cfg.act_quant
-    gate = _linear(layer["gate"], x, aq)
-    up = _linear(layer["up"], x, aq)
-    return _linear(layer["down"], _mlp_act(cfg, gate) * up, aq)
+    gate = _linear(p["gate"], x, aq)
+    up = _linear(p["up"], x, aq)
+    return _linear(p["down"], _mlp_act(cfg, gate) * up, aq)
+
+
+def _mlp(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
+         valid: Optional[jnp.ndarray] = None,
+         moe_stats: Optional[list] = None) -> jnp.ndarray:
+    """The layer's MLP at inference.  Which kind a layer has is the
+    description's (``cfg.layer_spec(i).mlp``), and its parameters were built
+    from it: a layer with a router routes."""
+    if "router" in layer:
+        y, counts = _moe_mlp_routed(layer, cfg, x, valid)
+        if moe_stats is not None:
+            moe_stats.append(counts)
+        return y
+    return _dense_mlp(layer, cfg, x)
 
 
 def _residual_tail(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
-                   o: jnp.ndarray, collect_aux: bool = False
+                   o: jnp.ndarray, collect_aux: bool = False,
+                   valid: Optional[jnp.ndarray] = None,
+                   moe_stats: Optional[list] = None,
                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Everything after the attention output projection: the (optionally
     sandwich-normed) attention residual, the pre-MLP norm, the MLP (or MoE
     path), and the MLP residual.  The ONE definition shared by
     layer_block, _prefill_impl, and decode_step so the serving loops can
-    never drift from the dense reference.  Returns (x, aux)."""
+    never drift from the dense reference.  Returns (x, aux).  ``valid``
+    ([B, S], real tokens) and ``moe_stats`` (a list each expert layer
+    appends its counts to) are the serving expert layer's
+    (``_moe_mlp_routed``)."""
     uo = cfg.rmsnorm_unit_offset
     if cfg.sandwich_norms:
         o = rms_norm(o, layer["post_attn_norm"], cfg.rms_norm_eps, uo)
     x = x + o
     h = rms_norm(x, layer["post_norm"], cfg.rms_norm_eps, uo)
     with jax.named_scope("mlp"):
-        if cfg.num_experts > 0 and collect_aux:
+        if "router" in layer and collect_aux:
+            if cfg.moe_scoring != "softmax" or "shared" in layer:
+                raise NotImplementedError(
+                    "the training dispatch (_moe_mlp) is softmax top-k "
+                    "without shared experts; this description is served, "
+                    "not trained")
             y, aux = _moe_mlp(layer, cfg, h)
         else:
-            y, aux = _mlp(layer, cfg, h), jnp.zeros((), jnp.float32)
+            y, aux = (_mlp(layer, cfg, h, valid, moe_stats),
+                      jnp.zeros((), jnp.float32))
     if cfg.sandwich_norms:
         y = rms_norm(y, layer["post_mlp_norm"], cfg.rms_norm_eps, uo)
     return x + y, aux
@@ -705,10 +1024,17 @@ def layer_block(
     B, S = x.shape[:2]
     h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps,
                  cfg.rmsnorm_unit_offset)
-    q, k, v = _qkv(layer, cfg, h, cos, sin)
-    with jax.named_scope("attention"):
-        attn = attn_fn(q, k, v, q_positions=positions,
-                       **_attn_extras(cfg, layer_idx))
+    if cfg.latent:
+        q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+        with jax.named_scope("attention"), jax.named_scope("latent"):
+            attn = _latent_attend_expanded(
+                layer, cfg, q_nope, q_rope, c, k_rope, positions, None,
+                attn_fn=attn_fn)
+    else:
+        q, k, v = _qkv(layer, cfg, h, cos, sin)
+        with jax.named_scope("attention"):
+            attn = attn_fn(q, k, v, q_positions=positions,
+                           **_attn_extras(cfg, layer_idx))
     o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
     return _residual_tail(layer, cfg, x, o, collect_aux)
 
@@ -738,7 +1064,7 @@ def forward_full(
     x = _embed_lookup(params, cfg, tokens)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    cos, sin = rope_angles(positions, cfg.head_dim_, cfg.rope_theta,
+    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
                            scaling=cfg.rope_scaling)
     aux_total = jnp.zeros((), jnp.float32)
     for li, layer in enumerate(params["layers"]):
@@ -838,6 +1164,8 @@ def _prefill_impl(
     attend_to_pages: bool,
     return_all_logits: bool = False,
     paged_attn_fn=None,
+    moe_stats: Optional[list] = None,
+    hidden: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Shared prefill layer loop.
 
@@ -850,9 +1178,24 @@ def _prefill_impl(
     ``return_all_logits`` switches the unembed from the last valid token
     ([B, V]) to every position ([B, S, V]) — the speculative-decode verify
     pass needs per-position logits to score its draft tokens.
+
+    A latent mixer (``cfg.latent``) caches one row a token and attends in
+    one of two forms, neither with an ``[S, T]`` score tensor: the expanded
+    form over a fresh batch's own tokens (``paged_attn_fn``: its Pallas
+    kernel, or None for blockwise XLA operations), the absorbed form over
+    gathered pages for continuation chunks and prefix hits (blockwise).
+    ``moe_stats``: see ``_residual_tail``.  ``hidden``: a list that receives
+    the residual stream before each layer and after the last ([B, S, H]
+    each) — what a layer-by-layer comparison with a reference feeds on
+    (``InferenceEngine.score_logits``).
     """
     B, S = tokens.shape
-    cos, sin = rope_angles(positions, cfg.head_dim_, cfg.rope_theta,
+    if cfg.latent and paged_attn_fn is not None \
+            and not is_latent_prefill_impl(paged_attn_fn):
+        raise ValueError(
+            "a latent mixer's prefill takes its own kernel or none "
+            f"(ops/attention.py:select_prefill_impl); got {paged_attn_fn!r}")
+    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
                            scaling=cfg.rope_scaling)
 
     x = _embed_lookup(params, cfg, tokens)
@@ -861,7 +1204,30 @@ def _prefill_impl(
     new_k, new_v = [], []
     new_ks, new_vs = [], []
     for li, layer in enumerate(params["layers"]):
+        if hidden is not None:
+            hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
+        if cfg.latent:
+            q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+            with jax.named_scope("attention"), jax.named_scope("latent"):
+                pk = _scatter_pages(pages.k[li], _latent_rows(cfg, c, k_rope),
+                                    block_tables, positions, valid)
+                new_k.append(pk)
+                if attend_to_pages:
+                    rows = gather_pages(pk, block_tables)[:, :, None, :]
+                    o_lat = blockwise_attention(
+                        _latent_absorb(layer, cfg, q_nope, q_rope), rows,
+                        rows[..., :cfg.kv_lora_rank], q_positions=positions,
+                        kv_len=kv_len, scale=1.0)
+                    attn = _latent_unabsorb(layer, cfg, o_lat)
+                else:
+                    attn = _latent_attend_expanded(
+                        layer, cfg, q_nope, q_rope, c, k_rope, positions,
+                        kv_len, kernel=paged_attn_fn)
+            o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
+            x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
+                                  moe_stats=moe_stats)
+            continue
         q, k, v = _qkv(layer, cfg, h, cos, sin)
         # KV append and attention together: the fused decode kernel does
         # both in one call, so the scope means the same on every path.
@@ -934,8 +1300,11 @@ def _prefill_impl(
                                         kv_len=kv_len,
                                         **_attn_extras(cfg, li))
         o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
-        x, _ = _residual_tail(layer, cfg, x, o)
+        x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
+                              moe_stats=moe_stats)
 
+    if hidden is not None:
+        hidden.append(x)
     out_pages = KVPages(k=new_k, v=new_v,
                         k_scale=new_ks if quant else (),
                         v_scale=new_vs if quant else ())
@@ -956,6 +1325,8 @@ def prefill(
     block_tables: jnp.ndarray,
     *,
     attn_impl=None,
+    moe_stats: Optional[list] = None,
+    hidden: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Ingest padded prompts, writing K/V into the paged cache.
 
@@ -977,7 +1348,8 @@ def prefill(
     valid = positions < lengths[:, None]
     return _prefill_impl(params, cfg, tokens, positions, valid, lengths,
                          lengths, pages, block_tables, attend_to_pages=False,
-                         paged_attn_fn=attn_impl)
+                         paged_attn_fn=attn_impl, moe_stats=moe_stats,
+                         hidden=hidden)
 
 
 def prefill_chunk(
@@ -990,6 +1362,8 @@ def prefill_chunk(
     block_tables: jnp.ndarray,
     *,
     attn_impl=None,
+    moe_stats: Optional[list] = None,
+    hidden: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Continuation prefill: ingest a chunk of a prompt whose first ``start``
     tokens are already in the paged cache.
@@ -1017,7 +1391,8 @@ def prefill_chunk(
     valid = offs[None, :] < lengths[:, None]
     return _prefill_impl(params, cfg, tokens, positions, valid, lengths,
                          start + lengths, pages, block_tables,
-                         attend_to_pages=True, paged_attn_fn=attn_impl)
+                         attend_to_pages=True, paged_attn_fn=attn_impl,
+                         moe_stats=moe_stats, hidden=hidden)
 
 
 def verify_step(
@@ -1030,6 +1405,7 @@ def verify_step(
     block_tables: jnp.ndarray,
     *,
     attn_impl=None,
+    moe_stats: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Speculative-decode verify pass: score ``S`` candidate tokens at once.
 
@@ -1058,7 +1434,7 @@ def verify_step(
     return _prefill_impl(params, cfg, tokens, positions, valid, lengths,
                          start + lengths, pages, block_tables,
                          attend_to_pages=True, return_all_logits=True,
-                         paged_attn_fn=attn_impl)
+                         paged_attn_fn=attn_impl, moe_stats=moe_stats)
 
 
 # ---------------------------------------------------------------------------
@@ -1075,6 +1451,8 @@ def decode_step(
     block_tables: jnp.ndarray,
     *,
     attn_impl=paged_decode_attention,
+    moe_stats: Optional[list] = None,
+    hidden: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """One decode step for a batch of slots.
 
@@ -1091,9 +1469,14 @@ def decode_step(
     B = tokens.shape[0]
     positions = context_lens[:, None]  # [B, 1]
     active = (context_lens > 0)[:, None]
-    cos, sin = rope_angles(positions, cfg.head_dim_, cfg.rope_theta,
+    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
                            scaling=cfg.rope_scaling)
     quant = pages.quantized
+    if cfg.latent and not is_latent_decode_impl(attn_impl):
+        raise ValueError(
+            "a latent pool is read only by a latent decode impl "
+            "(ops/attention.py:select_decode_impl picks one from the "
+            f"description); got {attn_impl!r}")
     fused_q = quant and is_fused_quant_decode_impl(attn_impl)
     # A fused impl without scale support must not touch a quantized pool;
     # fall through to the gather/dequant path instead.
@@ -1105,7 +1488,25 @@ def decode_step(
     new_k, new_v = [], []
     new_ks, new_vs = [], []
     for li, layer in enumerate(params["layers"]):
+        if hidden is not None:      # see _prefill_impl
+            hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
+        if cfg.latent:
+            # Absorbed form: append this token's row, then every head reads
+            # the lane's rows once (the kernel, or its XLA reference).
+            q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+            with jax.named_scope("attention"), jax.named_scope("latent"):
+                pk = _scatter_pages(pages.k[li], _latent_rows(cfg, c, k_rope),
+                                    block_tables, positions, active)
+                o_lat = attn_impl(
+                    _latent_absorb(layer, cfg, q_nope, q_rope), pk,
+                    block_tables, new_lens, v_width=cfg.kv_lora_rank)
+                attn = _latent_unabsorb(layer, cfg, o_lat)
+            new_k.append(pk)
+            o = _attn_out(layer, cfg, attn.reshape(B, 1, -1))
+            x, _ = _residual_tail(layer, cfg, x, o, valid=active,
+                                  moe_stats=moe_stats)
+            continue
         # The fused kernels rope in-kernel and take the raw projections.
         if fused_q or fused:
             q, k, v = _qkv_proj(layer, cfg, h)
@@ -1156,8 +1557,11 @@ def decode_step(
             new_ks.append(psk)
             new_vs.append(psv)
         o = _attn_out(layer, cfg, attn.reshape(B, 1, -1))
-        x, _ = _residual_tail(layer, cfg, x, o)
+        x, _ = _residual_tail(layer, cfg, x, o, valid=active,
+                              moe_stats=moe_stats)
 
+    if hidden is not None:
+        hidden.append(x)
     logits = _unembed(params, cfg, x)[:, 0, :]
     return logits, KVPages(k=new_k, v=new_v,
                            k_scale=new_ks if quant else (),

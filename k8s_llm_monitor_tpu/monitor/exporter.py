@@ -386,6 +386,23 @@ def _loop_metrics(w: _Writer, engine) -> None:
              "programs count in neither",
              [(f'{{filter="{k}"}}', n)
               for k, n in sorted(engine.sampler_filter_calls.items())])
+    moe = getattr(engine, "moe_totals", None)
+    if moe and getattr(engine, "_routed", False):
+        # A routed model's calls bring their routing counts back with their
+        # result (models/llama.py:_moe_mlp_routed); a dense model has none.
+        w.metric("engine_moe_assignments_total", "counter",
+                 "Token-expert assignments the expert layers computed "
+                 "(real tokens x experts per token x expert layers x steps)",
+                 [("", moe["assignments"])])
+        w.metric("engine_moe_experts_hit_total", "counter",
+                 "Experts that had at least one row, summed over expert "
+                 "layers and steps (over engine_moe_expert_slots_total: the "
+                 "share of the expert weights a call streamed)",
+                 [("", moe["experts_hit"])])
+        w.metric("engine_moe_expert_slots_total", "counter",
+                 "Experts there were: experts x expert layers x steps of "
+                 "every call",
+                 [("", moe["expert_slots"])])
 
 
 def _latency_histograms(w: _Writer, engine) -> None:

@@ -28,6 +28,8 @@ from jax.sharding import PartitionSpec as P
 
 from k8s_llm_monitor_tpu.ops.pallas_attention import (
     flash_prefill_attention,
+    latent_decode_attention_pallas,
+    latent_prefill_attention_pallas,
     paged_decode_attention_fused,
     paged_decode_attention_fused_quant,
     paged_decode_attention_pallas,
@@ -237,6 +239,117 @@ def paged_decode_attention_quant(
                             logit_softcap=logit_softcap, window=window)
 
 
+def blockwise_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    *,
+    q_positions: jnp.ndarray,
+    kv_len: jnp.ndarray,
+    scale: float,
+    block_q: int = 512,
+    block_k: int = 512,
+) -> jnp.ndarray:
+    """Causal attention by blocks with an online softmax, in XLA operations:
+    no ``[S, T]`` score tensor is ever held, and key blocks a query block
+    cannot see are not visited (a dynamic trip count: serving only).
+
+    Args:
+      q: [B, S, H, Dk].
+      k: [B, T, Hk, Dk], v: [B, T, Hk, Dv] with ``Hk == H`` (the expanded
+        latent form: per-head keys of nope + rope width, values of their own
+        width) or ``Hk == 1`` (one row shared by all heads: the absorbed
+        form over gathered latent pages).
+      q_positions: [B, S] absolute position of each query; key ``t`` is seen
+        by the query at position ``p`` when ``t <= p`` and ``t < kv_len[b]``.
+      kv_len: [B] valid keys.
+      scale: multiplies the scores.
+
+    Returns:
+      [B, S, H, Dv] in q.dtype.  Operands stay in their dtype; products
+      accumulate and the softmax runs in float32.
+    """
+    B, S, H, _ = q.shape
+    T, Hk, Dv = k.shape[1], k.shape[2], v.shape[-1]
+    bq, bk = min(block_q, S), min(block_k, T)
+    nq, nk = -(-S // bq), -(-T // bk)
+    if nq * bq != S:
+        q = jnp.pad(q, ((0, 0), (0, nq * bq - S), (0, 0), (0, 0)))
+        q_positions = jnp.pad(q_positions, ((0, 0), (0, nq * bq - S)),
+                              constant_values=-1)
+    if nk * bk != T:
+        pad = ((0, 0), (0, nk * bk - T), (0, 0), (0, 0))
+        k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+    # One shared row: contract without a head axis on the key side.
+    qk = "bshd,btd->bhst" if Hk == 1 else "bshd,bthd->bhst"
+    pv = "bhst,btd->bhsd" if Hk == 1 else "bhst,bthd->bhsd"
+    if Hk == 1:
+        k, v = k[:, :, 0], v[:, :, 0]
+    nk_live = jnp.minimum(nk, (jnp.max(kv_len) + bk - 1) // bk)
+
+    def q_block(i):
+        qi = jax.lax.dynamic_slice_in_dim(q, i * bq, bq, axis=1)
+        pi = jax.lax.dynamic_slice_in_dim(q_positions, i * bq, bq, axis=1)
+        hi = jnp.clip((jnp.max(pi) + bk) // bk, 1, jnp.maximum(nk_live, 1))
+
+        def kv_block(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * bk, bk, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * bk, bk, axis=1)
+            s = jnp.einsum(qk, qi, kj,
+                           preferred_element_type=jnp.float32) * scale
+            tpos = j * bk + jnp.arange(bk, dtype=jnp.int32)
+            seen = ((tpos[None, None, :] <= pi[:, :, None])
+                    & (tpos[None, None, :] < kv_len[:, None, None]))
+            s = jnp.where(seen[:, None], s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc = alpha * acc + jnp.einsum(
+                pv, p.astype(vj.dtype), vj,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        init = (jnp.full((B, H, bq, 1), NEG_INF, jnp.float32),
+                jnp.zeros((B, H, bq, 1), jnp.float32),
+                jnp.zeros((B, H, bq, Dv), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, hi, kv_block, init)
+        return (acc / l).astype(q.dtype)                  # [B, H, bq, Dv]
+
+    out = jax.lax.map(q_block, jnp.arange(nq, dtype=jnp.int32))
+    out = out.transpose(1, 0, 3, 2, 4).reshape(B, nq * bq, H, Dv)
+    return out[:, :S]
+
+
+def latent_decode_attention(
+    q: jnp.ndarray,
+    pages: jnp.ndarray,
+    block_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    v_width: int,
+) -> jnp.ndarray:
+    """Single-token decode over a latent pool, absorbed form — XLA reference
+    (the CPU path, and the oracle of the Pallas kernel).
+
+    q: [B, 1, H, F] absorbed and scaled queries; pages: [num_blocks, bs, F]
+    rows ``[latent | rotated key | zeros]``; the value of a row is its first
+    ``v_width`` lanes.  Returns [B, 1, H, v_width] (``P c``, before W_UV).
+    """
+    rows = gather_pages(pages, block_table).astype(jnp.float32)   # [B, T, F]
+    logits = jnp.einsum("bshf,btf->bhst", q.astype(jnp.float32), rows)
+    seen = (jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
+            < lengths[:, None])
+    logits = jnp.where(seen[:, None, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhst,btr->bshr", probs, rows[..., :v_width])
+    return out.astype(q.dtype)
+
+
+latent_decode_attention.latent = True
+
+
 def paged_verify_attention(
     q: jnp.ndarray,
     k_pages: jnp.ndarray,
@@ -432,6 +545,32 @@ def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
     if platform is None:
         platform = jax.default_backend()
 
+    if cfg is not None and getattr(cfg, "latent", False):
+        # A latent pool has one page kind and one kernel: the absorbed-form
+        # decode attention over ``[latent | rotated key]`` rows.  Nothing
+        # else can read those pages, so what is not built raises.
+        if mesh is not None:
+            raise ValueError(
+                "latent attention is not built for a mesh: the pool has no "
+                "kv-head axis to shard (ROADMAP Queue 2)")
+        if kv_quant:
+            raise ValueError(
+                f"latent pages are not built for kv_dtype={kv_quant!r}: the "
+                "latent kernel has no scale planes (ROADMAP Queue 2)")
+        if mode == "gather" or (mode == "auto" and platform != "tpu"):
+            return latent_decode_attention
+        if mode in ("auto", "pallas"):
+            if platform != "tpu":      # CPU tests: the Pallas interpreter
+                impl = functools.partial(latent_decode_attention_pallas,
+                                         interpret=True)
+                impl.latent = True
+                return impl
+            return latent_decode_attention_pallas
+        raise ValueError(
+            f"decode_path {mode!r} does not exist for latent attention "
+            "(auto | pallas | gather): rope and append are not fused into "
+            "its kernel")
+
     def _fused_ok():
         return (mesh is None
                 and cfg is not None
@@ -558,11 +697,30 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
     if platform is None:
         platform = jax.default_backend()
 
-    if mode == "dense":
-        return None
-    if mode not in ("auto", "flash"):
+    if mode not in ("auto", "flash", "dense"):
         raise ValueError(f"unknown prefill_path {mode!r}; expected "
                          "'auto', 'flash', or 'dense'")
+    if cfg is not None and getattr(cfg, "latent", False):
+        # The latent mixer has two prefill forms (models/llama.py): the
+        # expanded one over a fresh batch's own tokens, which this impl
+        # computes — the Pallas kernel ("flash"; "auto" on TPU) or, for
+        # None, blockwise XLA operations ("dense"; "auto" elsewhere) — and
+        # the absorbed one over pages (continuation chunks, prefix hits),
+        # blockwise XLA operations either way.
+        if mesh is not None:
+            raise ValueError(
+                "latent attention is not built for a mesh: the pool has no "
+                "kv-head axis to shard (ROADMAP Queue 2)")
+        if mode == "dense" or (mode == "auto" and platform != "tpu"):
+            return None
+        if platform != "tpu":          # CPU tests: the Pallas interpreter
+            impl = functools.partial(latent_prefill_attention_pallas,
+                                     interpret=True)
+            impl.latent_prefill = True
+            return impl
+        return latent_prefill_attention_pallas
+    if mode == "dense":
+        return None
 
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
 
@@ -603,3 +761,6 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
     if platform != "tpu" or not _flash_ok():
         return None
     return _build()
+
+
+latent_prefill_attention_pallas.latent_prefill = True

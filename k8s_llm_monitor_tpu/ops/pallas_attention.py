@@ -283,6 +283,308 @@ def paged_decode_attention_pallas(
 
 
 # ---------------------------------------------------------------------------
+# Latent (compressed-KV) decode attention: one shared row per token
+# ---------------------------------------------------------------------------
+
+# Pages per burst of the latent kernel.  A latent pool holds one row per
+# token for all heads, so a lane's whole context is one stream and the
+# window can be wide: 32 pages of 16 tokens are 512 keys a softmax block.
+_LATENT_WINDOW = 32
+
+
+def _latent_decode_kernel(
+    R,                     # static: value width (the latent part of a row)
+    # scalar prefetch
+    tables_ref,            # [B, NB] int32 block ids
+    lens_ref,              # [B] int32 valid cached tokens (new one included)
+    # inputs
+    q_ref,                 # [TB, H, F] absorbed queries, scaled (VMEM)
+    kv_hbm,                # [num_blocks, bs, F] latent pages (ANY/HBM)
+    # out
+    o_ref,                 # [TB, H, R]
+):
+    """One program handles TB lanes.  Every head of a lane reads the same
+    rows ``[latent | rotated key | zeros]``: the score is one
+    ``[H, F] x [F, keys]`` product against the whole row and the value is
+    the row's first R lanes, so a page is streamed once for all heads.
+    Operands stay in the pool's dtype (bf16 on the chip); products
+    accumulate in float32."""
+    TB, H, F = q_ref.shape
+    b0 = pl.program_id(0) * TB
+    bs = kv_hbm.shape[1]
+    NB = tables_ref.shape[1]
+    W = min(_LATENT_WINDOW, NB)
+
+    def scoped(buf, sem):
+        # buf: [2, W*bs, F] double-buffered slab; sem: [2, W].
+        def copies(slot, b, w):
+            for i in range(W):
+                j = jnp.minimum(w * W + i, NB - 1)
+                yield pltpu.make_async_copy(
+                    kv_hbm.at[tables_ref[b, j]],
+                    buf.at[slot, pl.ds(i * bs, bs)], sem.at[slot, i])
+
+        def start_window(slot, b, w):
+            for c in copies(slot, b, w):
+                c.start()
+
+        def wait_window(slot, b, w):
+            for c in copies(slot, b, w):
+                c.wait()
+
+        for t in range(TB):
+            b = b0 + t
+            # An inactive lane (0 cached tokens) streams one masked window
+            # of the null block.
+            length = jnp.maximum(lens_ref[b], 1)
+            n_windows = ((length + bs - 1) // bs + W - 1) // W
+            start_window(0, b, 0)
+            q = q_ref[t]                                       # [H, F]
+
+            def body(w, carry, b=b, length=length, n_windows=n_windows,
+                     q=q):
+                m, l, acc = carry            # [H, 1], [H, 1], [H, R]
+                slot = jax.lax.rem(w, 2)
+
+                @pl.when(w + 1 < n_windows)
+                def _prefetch():
+                    start_window(1 - slot, b, w + 1)
+
+                wait_window(slot, b, w)
+                pos = (w * (W * bs)
+                       + jax.lax.broadcasted_iota(jnp.int32, (1, W * bs), 1))
+                rows = buf[slot]                               # [W*bs, F]
+                s = jax.lax.dot_general(
+                    q, rows, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [H, W*bs]
+                s = jnp.where(pos < length, s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(rows.dtype), rows[:, :R],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [H, R]
+                return m_new, l_new, alpha * acc + pv
+
+            m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((H, 1), jnp.float32)
+            acc0 = jnp.zeros((H, R), jnp.float32)
+            _, l, acc = jax.lax.fori_loop(0, n_windows, body, (m0, l0, acc0))
+            o_ref[t] = (acc / l).astype(o_ref.dtype)
+
+    pl.run_scoped(
+        scoped,
+        buf=pltpu.VMEM((2, W * bs, F), kv_hbm.dtype),
+        sem=pltpu.SemaphoreType.DMA((2, W)),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("v_width", "interpret"))
+def latent_decode_attention_pallas(
+    q: jnp.ndarray,
+    pages: jnp.ndarray,
+    block_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    v_width: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Single-token decode attention over a latent pool, absorbed form
+    (drop-in for ops/attention.py:latent_decode_attention).
+
+    Args:
+      q: [B, 1, H, F] absorbed queries ``[q_nope W_UK^T | q_rope | 0]``,
+        already scaled by 1/sqrt(qk_head_dim).
+      pages: [num_blocks, bs, F] rows ``[latent | rotated key | zeros]``
+        (models/llama.py:init_kv_pages, latent page kind).
+      block_table: [B, max_blocks_per_seq] int32.
+      lengths: [B] int32 valid cached tokens, the new token's row included
+        (0 = inactive lane).
+      v_width: the latent width R; the value of a row is its first R lanes.
+
+    Returns:
+      [B, 1, H, v_width] in q.dtype — per-head ``P c``, before ``W_UV``.
+    """
+    B, S, H, F = q.shape
+    assert S == 1, f"decode kernel expects one query token, got {S}"
+    assert pages.shape[2] == F and v_width <= F, (pages.shape, F, v_width)
+    TB = next(tb for tb in (8, 4, 2, 1)
+              if B % tb == 0 and (B // tb >= 2 or B == 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B // TB,),
+        in_specs=[
+            pl.BlockSpec((TB, H, F), lambda p, tbl, ln: (p, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # pages stay in HBM
+        ],
+        out_specs=pl.BlockSpec((TB, H, v_width),
+                               lambda p, tbl, ln: (p, 0, 0)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(block_table, lengths.astype(jnp.int32), q.reshape(B, H, F).astype(
+        pages.dtype), pages)
+    return out[:, None]
+
+
+latent_decode_attention_pallas.latent = True
+
+
+def _latent_prefill_kernel(
+    # scalar prefetch
+    lens_ref,              # [B] int32 valid tokens of each row
+    # inputs (one batch row, one head)
+    q_ref,                 # [1, 1, bq, Dk], already scaled
+    k_ref,                 # [1, 1, bk, Dk]
+    v_ref,                 # [1, 1, bk, Dv]
+    # out
+    o_ref,                 # [1, 1, bq, Dv]
+    # scratch, kept across the key blocks of one query block
+    m_scr, l_scr,          # [bq, 128] float32, lane-replicated
+    acc_scr,               # [bq, Dv] float32
+):
+    """Causal attention of one query block against one key block of the
+    SAME sequence (a fresh prefill: positions are indices), online softmax
+    across the key blocks of grid axis 3.  A query block past the row's
+    length, and a key block above the diagonal or past the length, do
+    nothing (and are not fetched: the index map clamps them to the last
+    block that is needed).  Only a block the diagonal or the length cuts
+    through builds a mask: the softmax's elementwise passes, not the
+    products, bound this kernel on a chip without bf16 vector units."""
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+    Dv = v_ref.shape[3]
+    length = lens_ref[b]
+    live = (i * bq < length) & (j * bk < length) & (j * bk <= i * bq + bq - 1)
+    whole = (j * bk + bk - 1 <= i * bq) & (j * bk + bk <= length)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def block(masked: bool):
+        k, v = k_ref[0, 0], v_ref[0, 0]
+        s = jax.lax.dot_general(
+            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [bq, bk]
+        if masked:
+            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            s = jnp.where((cols <= rows) & (cols < length), s, NEG_INF)
+        m_prev = m_scr[...]                                     # [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - _lanes(m_new, bk))
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = _lanes(alpha, Dv) * acc_scr[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
+
+    pl.when(live & whole)(lambda: block(False))
+    pl.when(live & jnp.logical_not(whole))(lambda: block(True))
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _store():
+        l = l_scr[...]
+        l = jnp.where(l == 0.0, 1.0, l)          # rows past the length
+        o_ref[0, 0] = (acc_scr[...] / _lanes(l, Dv)).astype(o_ref.dtype)
+
+
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] statistic at width ``n``."""
+    if n == x.shape[1]:
+        return x
+    if n % x.shape[1] == 0:
+        return pltpu.repeat(x, n // x.shape[1], axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+def latent_prefill_attention_pallas(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    scale: float,
+    block: int = 512,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Causal attention over a fresh prefill batch's own tokens, the
+    expanded form of a latent mixer: per-head keys wider than the values.
+
+    Args:
+      q, k: [B, S, H, Dk]; v: [B, S, H, Dv] (Dk = nope + rope width, padded
+        here to whole 128-lane tiles; Dv its own).  Token ``s`` sits at
+        position ``s``.
+      lengths: [B] int32 valid tokens (0 = idle row).  Query blocks wholly
+        past a row's length are not computed and come back zero; other rows
+        past it are garbage the caller masks, as everywhere.
+      scale: multiplies the scores (folded into q here).
+
+    Returns:
+      [B, S, H, Dv] in q.dtype.  No [S, S] score tensor leaves VMEM; key
+      blocks above the diagonal or past a row's length are skipped.
+    """
+    B, S0, H, Dk = q.shape
+    Dv = v.shape[-1]
+    bq = bk = min(block, S0)
+    pad, tail = -Dk % 128, -S0 % bq       # whole lane tiles, whole blocks
+    q = q * jnp.asarray(scale, q.dtype)
+    if pad or tail:
+        q = jnp.pad(q, ((0, 0), (0, tail), (0, 0), (0, pad)))
+        k = jnp.pad(k, ((0, 0), (0, tail), (0, 0), (0, pad)))
+        v = jnp.pad(v, ((0, 0), (0, tail), (0, 0), (0, 0)))
+    S = S0 + tail
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))    # [B, H, S, D]
+    nq = nk = S // bq
+
+    def kv_map(b, h, i, j, lens):
+        # Blocks that will do nothing repeat the last one that does: the
+        # pipeline does not fetch a block whose index did not change.
+        last = jnp.minimum((i * bq + bq - 1) // bk,
+                           jnp.maximum(lens[b] - 1, 0) // bk)
+        return (b, h, jnp.minimum(j, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, H, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, bq, Dk + pad), lambda b, h, i, j, lens: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, Dk + pad), kv_map),
+            pl.BlockSpec((1, 1, bk, Dv), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, bq, Dv),
+                               lambda b, h, i, j, lens: (b, h, i, 0)),
+        scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        _latent_prefill_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="latent_prefill_attention",
+    )(lengths.astype(jnp.int32), q, k, v)
+    return out.transpose(0, 2, 1, 3)[:, :S0]
+
+
+# ---------------------------------------------------------------------------
 # Fused decode fast-path: RoPE + KV append + paged attention in one kernel
 # ---------------------------------------------------------------------------
 

@@ -113,8 +113,10 @@ def overlap_supported(cfg: ModelConfig, mesh, params=None) -> str:
       * TP not dividing the (kv-)head count — pages replicate instead of
         head-sharding (SpecLayout.kv_pages), so per-shard attention is no
         longer collective-free;
-      * MoE — the expert all-to-alls follow a different schedule
-        entirely (models/llama.py:_moe_mlp_dropless);
+      * a latent mixer — its pages have no kv-head axis (the engine
+        refuses the mesh itself, serving/engine.py);
+      * MoE — the expert layer (models/llama.py:_moe_mlp_routed) is no
+        part of the staged schedule, with or without shared experts;
       * sandwich norms — post_attn_norm consumes the FULL o projection
         before the residual add, so the o reduce cannot stay scattered;
       * a bias on a row-parallel projection — it must be added exactly
@@ -122,6 +124,9 @@ def overlap_supported(cfg: ModelConfig, mesh, params=None) -> str:
     """
     if mesh is None:
         return "no mesh"
+    if cfg.latent:
+        return ("latent attention: the pool has no kv-head axis to shard, "
+                "and the staged schedule's per-shard attention reads one")
     tp = mesh.shape.get(MODEL_AXIS, 1)
     if tp <= 1:
         return "model axis is 1"

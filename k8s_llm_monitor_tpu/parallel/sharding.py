@@ -148,10 +148,17 @@ def partition_rules(
 ) -> tuple[tuple[str, P], ...]:
     """(path-regex, spec) pairs, first match wins; paths join the pytree's
     dict keys with ``/`` (list indices dropped), e.g. ``layers/q/kernel``.
-    Expert rules precede column rules so ``up_e`` never matches ``up``."""
+    Expert rules precede column rules so ``up_e`` never matches ``up``; a
+    shared MLP beside the experts (``layers/shared/gate/kernel``) shards as
+    a dense one does."""
     return (
         (r"(^|/)embed/(weight|weight_q)$", layout.embedding()),
         (r"(^|/)embed/scale$", layout.embedding_scale()),
+        # Latent mixer (kv_a, kv_b, kv_norm) and the router with its
+        # selection bias: replicated.  The engine refuses a mesh for a
+        # latent pool (no kv-head axis to shard); these rules only keep
+        # eval_shape-level checks of every preset meaningful.
+        (r"(^|/)(kv_a|kv_b|router)/", layout.replicated()),
         (r"(^|/)(gate_e|up_e|down_e)/scale$", layout.expert_scale()),
         (r"(^|/)(gate_e|up_e|down_e)/", layout.expert_kernel()),
         (r"(^|/)(q|k|v|gate|up|lm_head)/(kernel|kernel_q)$",

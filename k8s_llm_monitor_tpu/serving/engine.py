@@ -345,6 +345,11 @@ class EngineConfig:
     # disables the clause (pre-PR-12: rely on OutOfBlocks pushback).
     kv_admission: str = "tier"
 
+    def __post_init__(self) -> None:
+        # A configuration file hands a JSON list; the ladder is a tuple
+        # (hashable, and never edited in place).
+        self.prefill_buckets = tuple(self.prefill_buckets)
+
 
 class _Slot:
     __slots__ = ("req", "blocks", "ctx_len", "generated", "pending_admit",
@@ -407,6 +412,9 @@ class _Inflight:
     # Attributes of the call's ``engine.call`` span, counted where the call
     # is built (InferenceEngine._call_attrs); ``emitted`` joins at reconcile.
     span_attrs: dict = dataclasses.field(default_factory=dict)
+    # Routing counts of a routed model's call (device array float32[4],
+    # MOE_COUNTS), an output of the same program as ``arr``; else None.
+    moe_counts: Any = None
 
 
 # Every span the engine and its service record, with the attributes a reader
@@ -444,11 +452,53 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
     # One per device call (InferenceEngine._call_attrs).
     "engine.call": ("kind", "program", "call_id", "device_empty",
                     "kv_blocks", "kv_live_blocks", "kv_cached_blocks",
+                    "kv_token_bytes",
                     "sampler_filter", "steps", "lanes", "slots", "emitted",
+                    "ctx_tokens",
                     "bucket", "rows", "prompts", "real_tokens",
-                    "padded_tokens", "cached_tokens", "shared"),
+                    "padded_tokens", "cached_tokens", "shared",
+                    # Programs of a routed model only (MOE_COUNTS).
+                    "moe_assignments", "moe_expert_layer_steps_hit",
+                    "moe_expert_layer_steps", "moe_max_rows",
+                    "moe_mean_rows"),
     "xla.compile": ("seconds", "program"),
 }
+
+
+# What a program of a routed model returns beside its result, summed on the
+# device over its expert layers and steps (models/llama.py:_moe_mlp_routed):
+# token-expert assignments computed, experts with at least one row, the
+# fullest expert's rows, experts there were.
+MOE_COUNTS = ("moe_assignments", "moe_expert_layer_steps_hit", "moe_max_rows",
+              "moe_expert_layer_steps")
+
+
+def _with_counts(outs: tuple, stats: Optional[list]) -> tuple:
+    """A program's outputs, with the call's routing counts last when the
+    model routes (``stats``: what each expert layer appended, or None — a
+    dense model's programs return exactly what they always did)."""
+    if stats is None:
+        return outs
+    return (*outs, jnp.sum(jnp.stack(stats), axis=0))
+
+
+class _RoutedProgram:
+    """A jitted program of a routed model.  Its last output is the call's
+    routing counts: they are set aside for the ``engine.call`` span the
+    dispatch is about to build (``InferenceEngine._take_moe_counts``), and
+    the caller gets the outputs every program of its family returns."""
+
+    def __init__(self, jitted, engine: "InferenceEngine") -> None:
+        self._jitted = jitted
+        self._engine = engine
+
+    def __call__(self, *args):
+        *outs, counts = self._jitted(*args)
+        self._engine._moe_counts = counts
+        return tuple(outs)
+
+    def __getattr__(self, name):      # lower, _cache_size, ...
+        return getattr(self._jitted, name)
 
 
 # The phases of the step thread's loop, in the order a turn takes them.
@@ -688,6 +738,27 @@ class InferenceEngine:
         else:
             raise ValueError(
                 f"unknown kv_dtype {kvd!r} (auto | int8 | fp8)")
+        # What is not built for this description is refused here, with the
+        # reason, before any pool or program exists (never a hidden
+        # fallback): latent pages have no kv-head axis to shard, no scale
+        # planes, no host-tier row format and no KVX1 geometry; the verify
+        # pass has no latent form; the expert layer beside a shared MLP has
+        # no mesh schedule.
+        self._unbuilt = self._unbuilt_reason(cfg)
+        if self._unbuilt:
+            for what, asked in (
+                    ("a mesh", mesh is not None),
+                    ("tp_overlap='on'",
+                     (os.environ.get("K8SLLM_TP_OVERLAP", ec.tp_overlap)
+                      or "auto") == "on"),
+                    (f"kv_dtype={self.kv_quant!r}", bool(self.kv_quant)),
+                    ("host_spill_bytes > 0 / a host KV tier",
+                     ec.host_spill_bytes > 0 or host_kv_tier is not None),
+                    (f"spec_k={ec.spec_k}", ec.spec_k > 0)):
+                if asked:
+                    raise ValueError(
+                        f"{cfg.name}: {what} is not built for "
+                        f"{self._unbuilt} (ROADMAP Queue 2)")
         # Prefill-family attention path, resolved before the bucket ladder
         # is frozen (and before the mesh seq-divisibility check below sees
         # it): the flash kernel's geometry gates live in
@@ -816,7 +887,8 @@ class InferenceEngine:
             self.decode_path = "gather"
         elif llama.is_fused_decode_impl(attn_impl):
             self.decode_path = "fused"
-        elif getattr(attn_impl, "__name__", "") == "paged_decode_attention":
+        elif getattr(attn_impl, "__name__", "") in (
+                "paged_decode_attention", "latent_decode_attention"):
             self.decode_path = "gather"
         else:
             self.decode_path = "pallas"
@@ -880,49 +952,62 @@ class InferenceEngine:
         # Captured by the prefill closures below; None keeps llama's
         # dense branches (in-flight attention / gather_pages).
         prefill_attn = self._prefill_attn
+        # A routed model's programs return the call's routing counts as
+        # their last output (MOE_COUNTS); a dense model's return what they
+        # always did: ``_stats()`` is None and ``_with_counts`` adds nothing.
+        self._routed = cfg.expert_layers > 0
+        self._moe_counts = None
+        routed = self._routed
+
+        def _stats() -> Optional[list]:
+            return [] if routed else None
 
         def _prefill_sample_fn(params, tokens, lengths, pages, tables,
                                temp, topk, topp, rng):
+            stats = _stats()
             logits, pages = llama.prefill(
                 params, cfg, tokens, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
             first = sample_tokens(
                 rng, logits, temperature=temp, top_k=topk, top_p=topp
             )
-            return first, pages
+            return _with_counts((first, pages), stats)
 
         def _prefill_greedy_fn(params, tokens, lengths, pages, tables):
             # Sort-free fast path for all-greedy admission rounds: skips the
             # [P, V] argsort nucleus filtering needs (V is 128k on the 8B
             # target — the sort costs more than the unembed).
+            stats = _stats()
             logits, pages = llama.prefill(
                 params, cfg, tokens, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
-            return greedy_tokens(logits), pages
+            return _with_counts((greedy_tokens(logits), pages), stats)
 
         def _prefill_chunk_sample_fn(params, tokens, start, lengths, pages,
                                      tables, temp, topk, topp, rng):
             # Batched admission over cached prefixes: each lane ingests only
             # its unshared suffix (start = shared tokens, 0 for misses) and
             # samples its first token in the same program.
+            stats = _stats()
             logits, pages = llama.prefill_chunk(
                 params, cfg, tokens, start, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
             first = sample_tokens(
                 rng, logits, temperature=temp, top_k=topk, top_p=topp
             )
-            return first, pages
+            return _with_counts((first, pages), stats)
 
         def _prefill_chunk_greedy_fn(params, tokens, start, lengths, pages,
                                      tables):
+            stats = _stats()
             logits, pages = llama.prefill_chunk(
                 params, cfg, tokens, start, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
-            return greedy_tokens(logits), pages
+            return _with_counts((greedy_tokens(logits), pages), stats)
 
         def _prefill_sample_fsm_fn(params, tokens, lengths, pages, tables,
                                    fstate, ftrans, temp, topk, topp, rng):
@@ -930,28 +1015,32 @@ class InferenceEngine:
             # each lane's FSM state (0 = FREE lane, unmasked) BEFORE the
             # shared sampler — greedy lanes take the argmax of the masked
             # logits inside sample_tokens, so constrained-greedy is exact.
+            stats = _stats()
             logits, pages = llama.prefill(
                 params, cfg, tokens, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
             masked = fsm_mask_logits(logits, fstate, ftrans)
             first = sample_tokens(
                 rng, masked, temperature=temp, top_k=topk, top_p=topp
             )
-            return first, fsm_advance(fstate, ftrans, first), pages
+            return _with_counts(
+                (first, fsm_advance(fstate, ftrans, first), pages), stats)
 
         def _prefill_chunk_sample_fsm_fn(params, tokens, start, lengths,
                                          pages, tables, fstate, ftrans,
                                          temp, topk, topp, rng):
+            stats = _stats()
             logits, pages = llama.prefill_chunk(
                 params, cfg, tokens, start, lengths, pages, tables,
-                attn_impl=prefill_attn
+                attn_impl=prefill_attn, moe_stats=stats
             )
             masked = fsm_mask_logits(logits, fstate, ftrans)
             first = sample_tokens(
                 rng, masked, temperature=temp, top_k=topk, top_p=topp
             )
-            return first, fsm_advance(fstate, ftrans, first), pages
+            return _with_counts(
+                (first, fsm_advance(fstate, ftrans, first), pages), stats)
 
         def _place_fn(tok_state, first, idx):
             # Scatter freshly sampled first tokens into the device-resident
@@ -959,17 +1048,18 @@ class InferenceEngine:
             return tok_state.at[idx].set(first, mode="drop")
 
         # pages are donated so the scatter-updates happen in place on device.
-        self._prefill_sample = jax.jit(_prefill_sample_fn, donate_argnums=(3,))
-        self._prefill_greedy = jax.jit(_prefill_greedy_fn, donate_argnums=(3,))
-        self._prefill_chunk_sample = jax.jit(
-            _prefill_chunk_sample_fn, donate_argnums=(4,))
-        self._prefill_chunk_greedy = jax.jit(
-            _prefill_chunk_greedy_fn, donate_argnums=(4,))
-        self._prefill_sample_fsm = jax.jit(
-            _prefill_sample_fsm_fn, donate_argnums=(3,))
-        self._prefill_chunk_sample_fsm = jax.jit(
-            _prefill_chunk_sample_fsm_fn, donate_argnums=(4,))
+        self._prefill_sample = self._program(_prefill_sample_fn, (3,))
+        self._prefill_greedy = self._program(_prefill_greedy_fn, (3,))
+        self._prefill_chunk_sample = self._program(
+            _prefill_chunk_sample_fn, (4,))
+        self._prefill_chunk_greedy = self._program(
+            _prefill_chunk_greedy_fn, (4,))
+        self._prefill_sample_fsm = self._program(_prefill_sample_fsm_fn, (3,))
+        self._prefill_chunk_sample_fsm = self._program(
+            _prefill_chunk_sample_fsm_fn, (4,))
         self._place_tokens = jax.jit(_place_fn, donate_argnums=(0,))
+        # The logits hook's programs (score_logits), built on first use.
+        self._score_programs: dict[str, Any] = {}
         # Grammar-constrained decoding state (set_grammar): host TokenFSM,
         # its device transition table, and the device-resident per-lane FSM
         # state — data-dependent like _tok_state, so it must live on device
@@ -1091,6 +1181,12 @@ class InferenceEngine:
         # ops/sampling.py takes its rank filter.  Greedy programs count in
         # neither.
         self.sampler_filter_calls = {"on": 0, "off": 0}
+        # Routing of a routed model's calls, summed as their results are
+        # applied (exporter counters; the same numbers ride on each
+        # ``engine.call`` span): token-expert assignments computed, experts
+        # that had at least one row, experts there were (per layer and step).
+        self.moe_totals = {"assignments": 0, "experts_hit": 0,
+                           "expert_slots": 0}
         # Per-step collective (ICI) share of the TP decode step, estimated
         # by profile_decode_phases() from the measured step time and the
         # ring-all-reduce byte model; 0.0 off-mesh or before profiling,
@@ -1132,6 +1228,42 @@ class InferenceEngine:
         else:
             self._phases = _PhaseClock()
         self.loop_seconds = self._phases.seconds
+
+    @staticmethod
+    def _unbuilt_reason(cfg: ModelConfig) -> str:
+        """"" for a description every serving option is built for; else the
+        part of it that some are not (``__init__`` and the KVX1 calls refuse
+        those with it)."""
+        parts = []
+        if cfg.latent:
+            parts.append("a latent (compressed-KV) pool")
+        if any(cfg.layer_spec(i).mlp == "shared+routed"
+               for i in range(cfg.num_layers)):
+            parts.append("shared + routed expert layers")
+        return " and ".join(parts)
+
+    def _refuse_unbuilt(self, what: str) -> None:
+        if self._unbuilt:
+            raise ValueError(f"{self.cfg.name}: {what} is not built for "
+                             f"{self._unbuilt} (ROADMAP Queue 2)")
+
+    def _program(self, fn, donate: tuple):
+        """``jax.jit(fn)`` with ``donate`` donated; for a routed model the
+        program's last output (the routing counts) is set aside for the
+        call's span (:class:`_RoutedProgram`)."""
+        jitted = jax.jit(fn, donate_argnums=donate)
+        return _RoutedProgram(jitted, self) if self._routed else jitted
+
+    def _take_moe_counts(self):
+        """The routing counts of the program call just made (a device
+        array), or None for a dense model."""
+        counts, self._moe_counts = self._moe_counts, None
+        if counts is not None:
+            try:
+                counts.copy_to_host_async()
+            except AttributeError:
+                pass
+        return counts
 
     # ------------------------------------------------------------------
     # public API
@@ -1373,6 +1505,123 @@ class InferenceEngine:
     # engine loop
     # ------------------------------------------------------------------
 
+    def score_logits(self, prompt_ids: list[int], n_decode: int = 0, *,
+                     tenant: str = DEFAULT_TENANT, hidden: bool = False):
+        """The model's logits as the engine computes them, instead of
+        sampled tokens: float32 ``[1 + n_decode, vocab]`` — the row of the
+        last prompt position, then one row per decode step, each step fed
+        the argmax of the row before it (so row ``i``'s argmax is the token
+        at position ``len(prompt_ids) + i``, and a reference forward over
+        the prompt plus those tokens yields the same rows).
+
+        The prompt goes through the engine's own prefill functions
+        (``llama.prefill`` / ``prefill_chunk`` with the engine's prefill
+        attention) into the engine's own pages under a real block table — a
+        prefix-cache hit starts from the shared blocks, a prompt longer
+        than the top bucket streams in chunks — then ``n_decode`` single
+        steps run ``llama.decode_step`` with the attention impl the fused
+        scan calls.  The blocks are freed afterwards and nothing is
+        registered.  The engine must be idle (no queued or running
+        request): call it on the step thread, through
+        ``EngineService.call(lambda e: e.score_logits(ids, n))``.
+
+        ``hidden=True`` returns ``(rows, states)``: ``states`` float32
+        ``[layers + 1, len(prompt_ids) + n_decode, hidden]``, the residual
+        stream of every position before each layer and after the last, as
+        the engine's programs held it (the prefix cache is not consulted
+        then: a cached position's states were never computed).  A reference
+        that runs ONE layer on the engine's own input to it
+        (``models/reference``) is compared layer by layer with these — where
+        whole-model logits of random weights are chaotic in the activation
+        precision, one layer is not.
+        """
+        self._reconcile_all()
+        if self.has_work:
+            raise RuntimeError("score_logits needs an idle engine")
+        ids = [int(t) for t in prompt_ids]
+        L = len(ids)
+        if L < 1 or L + n_decode + 1 > self.capacity_tokens:
+            raise ValueError(
+                f"prompt of {L} tokens + {n_decode} steps does not fit a "
+                f"sequence's {self.capacity_tokens} cached tokens")
+        cfg = self.cfg
+        shared, start = ([], 0)
+        if self.prefix_cache is not None and not hidden:
+            shared, start = self.prefix_cache.lookup(ids, tenant=tenant)
+        try:
+            if not self._ensure_free(L + n_decode + 1 - start):
+                raise OutOfBlocks(
+                    f"no room for {L + n_decode + 1 - start} tokens")
+            blocks = shared + self.allocator.alloc(L + n_decode + 1 - start)
+        except BaseException:
+            self.allocator.free(shared)
+            raise
+        progs = self._score_programs
+        if not progs:
+            attn, dec = self._prefill_attn, self._attn_impl
+
+            def scored(fn, **kw):
+                # (logits, pages, states [layers + 1, B, S, H] or None)
+                def run(p, *args, want_hidden):
+                    states = [] if want_hidden else None
+                    logits, pages = fn(p, cfg, *args, hidden=states, **kw)
+                    return logits, pages, (
+                        None if states is None
+                        else jnp.stack(states).astype(jnp.float32))
+                return run
+
+            progs["prefill"] = jax.jit(
+                scored(llama.prefill, attn_impl=attn),
+                donate_argnums=(3,), static_argnames=("want_hidden",))
+            progs["chunk"] = jax.jit(
+                scored(llama.prefill_chunk, attn_impl=attn),
+                donate_argnums=(4,), static_argnames=("want_hidden",))
+            progs["decode"] = jax.jit(
+                scored(llama.decode_step, attn_impl=dec),
+                donate_argnums=(3,), static_argnames=("want_hidden",))
+        table = np.zeros((1, self.ecfg.max_blocks_per_seq), np.int32)
+        table[0, :len(blocks)] = blocks
+        table = jnp.asarray(table)
+        top = self.ecfg.prefill_buckets[-1]
+        rows, states = [], []
+        self.in_program_call = True
+        try:
+            pos, logits = start, None
+            while pos < L:
+                n = min(top, L - pos)
+                toks = np.zeros((1, self._bucket(n)), np.int32)
+                toks[0, :n] = ids[pos:pos + n]
+                if pos == 0:
+                    logits, self.pages, st = progs["prefill"](
+                        self.params, self._tokens_to_device(toks),
+                        jnp.asarray([n], jnp.int32), self.pages, table,
+                        want_hidden=hidden)
+                else:
+                    logits, self.pages, st = progs["chunk"](
+                        self.params, self._tokens_to_device(toks),
+                        jnp.asarray([pos], jnp.int32),
+                        jnp.asarray([n], jnp.int32), self.pages, table,
+                        want_hidden=hidden)
+                if hidden:
+                    states.append(np.asarray(st[:, 0, :n]))
+                pos += n
+            rows.append(np.asarray(logits[0], np.float32))
+            for i in range(n_decode):
+                tok = jnp.asarray([int(np.argmax(rows[-1]))], jnp.int32)
+                logits, self.pages, st = progs["decode"](
+                    self.params, tok, jnp.asarray([L + i], jnp.int32),
+                    self.pages, table, want_hidden=hidden)
+                rows.append(np.asarray(logits[0], np.float32))
+                if hidden:
+                    states.append(np.asarray(st[:, 0]))
+        finally:
+            self.in_program_call = False
+            self.last_program_call = time.monotonic()
+            self.allocator.free(blocks)
+        if hidden:
+            return np.stack(rows), np.concatenate(states, axis=1)
+        return np.stack(rows)
+
     def step(self) -> None:
         """One scheduler iteration: dispatch up to ``max_admission_rounds``
         batched prefills and one fused decode, then reconcile in-flight
@@ -1448,8 +1697,14 @@ class InferenceEngine:
             held.update(blocks)
         alloc = self.allocator
         used = alloc.num_blocks - 1 - alloc.free_blocks  # block 0 is null
-        return {"kv_blocks": alloc.num_blocks, "kv_live_blocks": len(live),
-                "kv_cached_blocks": max(0, used - len(held))}
+        census = {"kv_blocks": alloc.num_blocks, "kv_live_blocks": len(live),
+                  "kv_cached_blocks": max(0, used - len(held))}
+        if self.cfg.latent:
+            # What a cached token costs over all layers in this pool: the
+            # page kind's own figure, beside the block counts it scales.
+            census["kv_token_bytes"] = self.cfg.kv_token_bytes(
+                self.pages.k[0].dtype.itemsize)
+        return census
 
     def _call_attrs(self, kind: str, program: str, device_empty: bool,
                     sampler_filter: Optional[bool] = None,
@@ -2077,6 +2332,7 @@ class InferenceEngine:
         mismatch before touching pages.  The lookup's increfs pin the
         blocks for the duration of the device fetch, then release —
         export never changes cache contents."""
+        self._refuse_unbuilt("KVX1 export_prefix")
         pc = self.prefix_cache
         if pc is None:
             return None
@@ -2107,6 +2363,7 @@ class InferenceEngine:
         Framing/CRC damage raises :class:`~..serving.kv_tier.BlobError` —
         the caller treats a torn transfer as a miss, never a partial
         install."""
+        self._refuse_unbuilt("KVX1 install_prefix")
         meta, raw = unpack_prefix_blob(blob)
         geo = self._kv_geometry()
         if any(meta.get(key) != geo[key] for key in geo):
@@ -2723,7 +2980,8 @@ class InferenceEngine:
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=first,
             lanes=list(lanes), touched=list(touched),
-            t0=time.monotonic(), span_attrs=span_attrs or {}))
+            t0=time.monotonic(), span_attrs=span_attrs or {},
+            moe_counts=self._take_moe_counts()))
         self._next_call_id += 1
 
     def _finish_admit_dispatch(self, first, batch, idx, fsm_next=None, *,
@@ -2778,7 +3036,13 @@ class InferenceEngine:
         k_cap = self.ecfg.sample_topk_cap
         overlap_step = self._overlap_step
 
-        def _step_core(params, tokens, ctx, act, pages, tables):
+        routed = self._routed
+        # Routing counts ride in the scan's carry: () for a dense model (no
+        # leaf, the program is what it always was), float32[4] for a routed
+        # one, summed over the steps.
+        cnt0 = jnp.zeros((len(MOE_COUNTS),), jnp.float32) if routed else ()
+
+        def _step_core(params, tokens, ctx, act, pages, tables, cnt):
             ctx_eff = jnp.where(act, ctx, 0)
             if overlap_step is not None:
                 # Hand-staged TP schedule (parallel/overlap.py): same
@@ -2787,11 +3051,17 @@ class InferenceEngine:
                 logits, pages = overlap_step(
                     params, tokens, ctx_eff, pages, tables)
             else:
+                stats = [] if routed else None
                 logits, pages = llama.decode_step(
                     params, cfg, tokens, ctx_eff, pages, tables,
-                    attn_impl=attn_impl,
+                    attn_impl=attn_impl, moe_stats=stats,
                 )
-            return logits, pages
+                if routed:
+                    cnt = cnt + jnp.sum(jnp.stack(stats), axis=0)
+            return logits, pages, cnt
+
+        def _outs(outs: tuple, cnt) -> tuple:
+            return (*outs, cnt) if routed else outs
 
         if sampled and constrained:
             def fn(params, tok_state, fsm_state, ctx, remaining, pages,
@@ -2799,10 +3069,10 @@ class InferenceEngine:
                 active0 = ctx > 0
 
                 def body(carry, i):
-                    tokens, fstate, ctx, done, rng, pages = carry
+                    tokens, fstate, ctx, done, rng, pages, cnt = carry
                     act = active0 & ~done & (i < remaining)
-                    logits, pages = _step_core(
-                        params, tokens, ctx, act, pages, tables)
+                    logits, pages, cnt = _step_core(
+                        params, tokens, ctx, act, pages, tables, cnt)
                     logits = fsm_mask_logits(logits, fstate, ftrans)
                     rng, sub = jax.random.split(rng)
                     if bounded:
@@ -2818,25 +3088,27 @@ class InferenceEngine:
                     done = done | (act & (nxt == eos))
                     ctx = jnp.where(act, ctx + 1, ctx)
                     out = jnp.where(act, nxt, -1)
-                    return (nxt, fstate, ctx, done, rng, pages), out
+                    return (nxt, fstate, ctx, done, rng, pages, cnt), out
 
                 done0 = jnp.zeros_like(active0)
-                (tok_state, fsm_state, _, _, _, pages), toks = jax.lax.scan(
-                    body, (tok_state, fsm_state, ctx, done0, rng, pages),
-                    jnp.arange(n_steps, dtype=jnp.int32))
-                return toks, tok_state, fsm_state, pages
+                (tok_state, fsm_state, _, _, _, pages, cnt), toks = (
+                    jax.lax.scan(
+                        body,
+                        (tok_state, fsm_state, ctx, done0, rng, pages, cnt0),
+                        jnp.arange(n_steps, dtype=jnp.int32)))
+                return _outs((toks, tok_state, fsm_state, pages), cnt)
 
-            prog = jax.jit(fn, donate_argnums=(1, 2, 5))
+            prog = self._program(fn, (1, 2, 5))
         elif sampled:
             def fn(params, tok_state, ctx, remaining, pages, tables,
                    temp, topk, topp, rng, eos):
                 active0 = ctx > 0
 
                 def body(carry, i):
-                    tokens, ctx, done, rng, pages = carry
+                    tokens, ctx, done, rng, pages, cnt = carry
                     act = active0 & ~done & (i < remaining)
-                    logits, pages = _step_core(
-                        params, tokens, ctx, act, pages, tables)
+                    logits, pages, cnt = _step_core(
+                        params, tokens, ctx, act, pages, tables, cnt)
                     rng, sub = jax.random.split(rng)
                     if bounded:
                         nxt = sample_tokens_bounded(
@@ -2849,38 +3121,38 @@ class InferenceEngine:
                     done = done | (act & (nxt == eos))
                     ctx = jnp.where(act, ctx + 1, ctx)
                     out = jnp.where(act, nxt, -1)
-                    return (nxt, ctx, done, rng, pages), out
+                    return (nxt, ctx, done, rng, pages, cnt), out
 
                 done0 = jnp.zeros_like(active0)
-                (tok_state, _, _, _, pages), toks = jax.lax.scan(
-                    body, (tok_state, ctx, done0, rng, pages),
+                (tok_state, _, _, _, pages, cnt), toks = jax.lax.scan(
+                    body, (tok_state, ctx, done0, rng, pages, cnt0),
                     jnp.arange(n_steps, dtype=jnp.int32))
-                return toks, tok_state, pages
+                return _outs((toks, tok_state, pages), cnt)
 
-            prog = jax.jit(fn, donate_argnums=(1, 4))
+            prog = self._program(fn, (1, 4))
         else:
             def fn(params, tok_state, ctx, remaining, pages, tables, eos):
                 active0 = ctx > 0
 
                 def body(carry, i):
-                    tokens, ctx, done, pages = carry
+                    tokens, ctx, done, pages, cnt = carry
                     act = active0 & ~done & (i < remaining)
-                    logits, pages = _step_core(
-                        params, tokens, ctx, act, pages, tables)
+                    logits, pages, cnt = _step_core(
+                        params, tokens, ctx, act, pages, tables, cnt)
                     nxt = greedy_tokens(logits)
                     nxt = jnp.where(act, nxt, tokens)
                     done = done | (act & (nxt == eos))
                     ctx = jnp.where(act, ctx + 1, ctx)
                     out = jnp.where(act, nxt, -1)
-                    return (nxt, ctx, done, pages), out
+                    return (nxt, ctx, done, pages, cnt), out
 
                 done0 = jnp.zeros_like(active0)
-                (tok_state, _, _, pages), toks = jax.lax.scan(
-                    body, (tok_state, ctx, done0, pages),
+                (tok_state, _, _, pages, cnt), toks = jax.lax.scan(
+                    body, (tok_state, ctx, done0, pages, cnt0),
                     jnp.arange(n_steps, dtype=jnp.int32))
-                return toks, tok_state, pages
+                return _outs((toks, tok_state, pages), cnt)
 
-            prog = jax.jit(fn, donate_argnums=(1, 4))
+            prog = self._program(fn, (1, 4))
         self._decode_cache[key] = prog
         return prog
 
@@ -3366,7 +3638,9 @@ class InferenceEngine:
                 kind, program, device_empty,
                 sampler_filter=(None if all_greedy and not constrained
                                 else any_filtered),
-                steps=K, lanes=len(lanes), slots=B)))
+                steps=K, lanes=len(lanes), slots=B,
+                ctx_tokens=int(ctx.sum())),
+            moe_counts=self._take_moe_counts()))
         self._next_call_id += 1
         return True
 
@@ -3610,6 +3884,21 @@ class InferenceEngine:
             # docstring) were computed and are not counted.
             attrs["emitted"] = emitted
             self.decode_tokens += emitted
+        if call.moe_counts is not None:
+            # Outputs of the same program as ``arr``: on the host already,
+            # or a moment behind it.
+            counts = dict(zip(MOE_COUNTS,
+                              (float(c) for c in np.asarray(call.moe_counts))))
+            slots = counts["moe_expert_layer_steps"]
+            # The mean rows of an expert, summed as the fullest expert's
+            # are: each layer and step adds its assignments / experts.
+            counts["moe_mean_rows"] = (counts["moe_assignments"]
+                                       / self.cfg.num_experts)
+            attrs.update(counts)
+            self.moe_totals["assignments"] += int(counts["moe_assignments"])
+            self.moe_totals["experts_hit"] += int(
+                counts["moe_expert_layer_steps_hit"])
+            self.moe_totals["expert_slots"] += int(slots)
         if self._loop_sampled:
             # enqueue -> result on the host: includes the time queued
             # behind earlier calls; how long the device ran it is the

@@ -108,14 +108,28 @@ def quantize_params(params: Params) -> Params:
         for name in ("post_attn_norm", "post_mlp_norm"):  # Gemma sandwich
             if name in layer:
                 ql[name] = layer[name]
-        for name in ("q", "k", "v", "o"):
-            ql[name] = quantize_linear(layer[name])
+        if "kv_a" in layer:
+            # Latent mixer: q, kv_a and o quantize like any projection; the
+            # latent's norm and W_kvb stay wide (models/llama.py:_kv_b: the
+            # absorbed form multiplies queries and outputs by W_kvb, so it
+            # has no per-token activation to quantize against).
+            for name in ("q", "kv_a", "o"):
+                ql[name] = quantize_linear(layer[name])
+            ql["kv_norm"] = layer["kv_norm"]
+            ql["kv_b"] = layer["kv_b"]
+        else:
+            for name in ("q", "k", "v", "o"):
+                ql[name] = quantize_linear(layer[name])
         if "router" in layer:
-            # MoE layers: the router stays bf16 (tiny, routing-decision
-            # sensitive); expert stacks quantize per-expert-per-channel.
+            # MoE layers: the router (and its selection bias) stays wide
+            # (tiny, routing-decision sensitive); expert stacks quantize
+            # per-expert-per-channel; shared experts like a dense MLP.
             ql["router"] = layer["router"]
             for name in ("gate_e", "up_e", "down_e"):
                 ql[name] = quantize_expert_stack(layer[name])
+            if "shared" in layer:
+                ql["shared"] = {name: quantize_linear(layer["shared"][name])
+                                for name in ("gate", "up", "down")}
         else:
             for name in ("gate", "up", "down"):
                 ql[name] = quantize_linear(layer[name])
@@ -157,33 +171,73 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig) -> Params:
             p["bias"] = jnp.zeros((out_f,), dtype)
         return p
 
-    if cfg.num_experts > 0:
-        # MoE: bf16 init then quantize (the direct-int8 trick below skips
-        # the bf16 materialization, but expert stacks need the real value
-        # distribution for per-expert scales; the transient bf16 peak is
-        # fine at dev/random-init scales — real MoE checkpoints stream
-        # through convert_hf_state_dict(quantize=True) tensor-by-tensor).
+    if cfg.num_experts > 0 and not cfg.latent and not cfg.n_shared_experts:
+        # Mixtral-style MoE: bf16 init then quantize (the direct-int8 trick
+        # below skips the bf16 materialization, but expert stacks need the
+        # real value distribution for per-expert scales; the transient bf16
+        # peak is fine at dev/random-init scales — real MoE checkpoints
+        # stream through convert_hf_state_dict(quantize=True)
+        # tensor-by-tensor).
         from k8s_llm_monitor_tpu.models.llama import init_params
 
         return quantize_params(init_params(rng, cfg))
+
+    def qexperts(key, in_f, out_f):
+        # The direct-int8 trick per expert: 128 experts at published widths
+        # cannot pass through bf16 on one chip either.
+        E = cfg.num_experts
+        return {"kernel_q": jax.random.randint(
+                    key, (E, in_f, out_f), -127, 128, jnp.int8),
+                "scale": jnp.full((E, out_f), 3.0 * (in_f ** -0.5) / 127.0,
+                                  jnp.float32)}
+
+    def wide(key, in_f, out_f, dt):
+        return {"kernel": (jax.random.normal(key, (in_f, out_f), jnp.float32)
+                           * (in_f ** -0.5)).astype(dt)}
+
+    def qmlp(keys, width):
+        return {"gate": qdense(keys[0], H, width, False),
+                "up": qdense(keys[1], H, width, False),
+                "down": qdense(keys[2], width, H, False)}
 
     keys = jax.random.split(rng, 2 + cfg.num_layers)
     layers = []
     for i in range(cfg.num_layers):
         lk = jax.random.split(keys[2 + i], 7)
-        layers.append(
-            {
-                "input_norm": jnp.ones((H,), dtype),
-                "post_norm": jnp.ones((H,), dtype),
-                "q": qdense(lk[0], H, nH * D, cfg.qkv_bias),
-                "k": qdense(lk[1], H, nKV * D, cfg.qkv_bias),
-                "v": qdense(lk[2], H, nKV * D, cfg.qkv_bias),
-                "o": qdense(lk[3], nH * D, H, False),
-                "gate": qdense(lk[4], H, I, False),
-                "up": qdense(lk[5], H, I, False),
-                "down": qdense(lk[6], I, H, False),
-            }
-        )
+        spec = cfg.layer_spec(i)
+        layer: Params = {"input_norm": jnp.ones((H,), dtype),
+                         "post_norm": jnp.ones((H,), dtype)}
+        if spec.mixer == "latent":
+            R, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim)
+            layer["q"] = qdense(lk[0], H, nH * (dn + dr), False)
+            layer["kv_a"] = qdense(lk[1], H, R + dr, False)
+            layer["kv_norm"] = jnp.ones((R,), dtype)
+            # Wide under every quantisation (quantize_params says why).
+            layer["kv_b"] = wide(lk[2], R, nH * (dn + cfg.v_head_dim), dtype)
+            layer["o"] = qdense(lk[3], nH * cfg.v_head_dim, H, False)
+        else:
+            layer["q"] = qdense(lk[0], H, nH * D, cfg.qkv_bias)
+            layer["k"] = qdense(lk[1], H, nKV * D, cfg.qkv_bias)
+            layer["v"] = qdense(lk[2], H, nKV * D, cfg.qkv_bias)
+            layer["o"] = qdense(lk[3], nH * D, H, False)
+        if spec.mlp == "dense":
+            layer.update(qmlp(lk[4:7], I))
+        else:
+            xk = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 5)
+            Ie = cfg.expert_width
+            f32 = cfg.moe_scoring == "sigmoid+bias"
+            layer["router"] = wide(xk[0], H, cfg.num_experts,
+                                   jnp.float32 if f32 else dtype)
+            if f32:
+                layer["router"]["e_bias"] = 0.01 * jax.random.normal(
+                    xk[1], (cfg.num_experts,), jnp.float32)
+            layer["gate_e"] = qexperts(lk[4], H, Ie)
+            layer["up_e"] = qexperts(lk[5], H, Ie)
+            layer["down_e"] = qexperts(lk[6], Ie, H)
+            if spec.mlp == "shared+routed":
+                layer["shared"] = qmlp(xk[2:5], cfg.n_shared_experts * Ie)
+        layers.append(layer)
     params: Params = {
         "embed": {
             "weight_q": jax.random.randint(

@@ -268,3 +268,62 @@ def test_sampler_sorts_only_inside_the_filter_branch(one_chip):
     inside = _reachable(comps, [on])
     assert wide(inside), "the rank filter's sort is gone from its branch"
     assert wide(set(comps) - inside) == []
+
+
+# -- the latent pool's kernel and the expert layer's grouped product, at the
+# -- shapes of kanana2-30b-a3b.evidence-loops ---------------------------------
+
+KCFG = PRESETS["kanana-2-30b-a3b-12l"]
+K_BLOCKS, K_TABLE = 17_408, 272          # the cell's pool and table width
+
+
+@pytest.mark.parametrize("lanes", [64, 1], ids=["64-lanes", "score-logits"])
+def test_latent_decode_kernel_compiles(one_chip, lanes):
+    """64 decode lanes (the fused scan's call) and one (score_logits'), 32
+    heads over rows of 640 lanes = [512 latent | 64 rotated key | 64 zeros],
+    the whole 17,408-block pool, a table of 272 blocks."""
+    S = one_chip
+    Fp = KCFG.latent_page_width
+    assert Fp == 640
+    _compile(functools.partial(pa.latent_decode_attention_pallas,
+                               v_width=KCFG.kv_lora_rank),
+             S((lanes, 1, KCFG.num_heads, Fp), BF16),
+             S((K_BLOCKS, BS, Fp), BF16), S((lanes, K_TABLE), I32),
+             S((lanes,), I32))
+
+
+@pytest.mark.parametrize("rows,bucket", [(4, 4096), (1, 1024)],
+                         ids=["4x4096", "1x1024"])
+def test_latent_prefill_kernel_compiles(one_chip, rows, bucket):
+    """The expanded form of a fresh prefill at the cell's largest and
+    smallest admission shapes: 32 heads, keys of 128 + 64 lanes (padded to
+    256 inside), values of 128."""
+    S = one_chip
+    nH, dk, dv = KCFG.num_heads, KCFG.qk_head_dim, KCFG.v_head_dim
+    assert (dk, dv) == (192, 128)
+    _compile(functools.partial(pa.latent_prefill_attention_pallas,
+                               scale=dk ** -0.5),
+             S((rows, bucket, nH, dk), BF16), S((rows, bucket, nH, dk), BF16),
+             S((rows, bucket, nH, dv), BF16), S((rows,), I32))
+
+
+@pytest.mark.parametrize("rows", [64 * 6, 4 * 4096 * 6],
+                         ids=["decode-384", "prefill-98304"])
+def test_grouped_expert_product_is_a_kernel_over_the_chosen(one_chip, rows):
+    """``jax.lax.ragged_dot`` of int8 rows against the 128 int8 expert
+    kernels: the chip's compiler lowers it to its own grouped-product kernel
+    (no [E, rows, I] tensor, no product for an expert without rows) — what
+    the expert layer counts on (models/llama.py:_expert_rows).  Its cost
+    analysis says so: the operations are those of the rows alone."""
+    import jax.numpy as jnp
+
+    S = one_chip
+    E, H_, I_ = KCFG.num_experts, KCFG.hidden_size, KCFG.expert_width
+    fn = lambda x, w, g: jax.lax.ragged_dot(
+        x, w, g, preferred_element_type=jnp.int32)
+    compiled = jax.jit(fn).lower(S((rows, H_), jnp.int8),
+                                 S((E, H_, I_), jnp.int8),
+                                 S((E,), I32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.cost_analysis()["flops"] == 2.0 * rows * H_ * I_
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -112,7 +112,10 @@ def test_all_presets_are_coherent_and_tp8_shardable():
     for name, cfg in PRESETS.items():
         assert cfg.hidden_size % cfg.num_heads == 0 or cfg.head_dim, name
         assert cfg.num_heads % cfg.num_kv_heads == 0, name
-        assert cfg.head_dim_ * cfg.num_heads <= 2 * cfg.hidden_size, name
+        # A latent mixer's queries are wide by design (nope + rope parts
+        # per head); its cache is what is narrow.
+        assert (cfg.latent or
+                cfg.head_dim_ * cfg.num_heads <= 2 * cfg.hidden_size), name
         shapes = jax.eval_shape(
             lambda rng, c=cfg: llama.init_params(rng, c),
             jax.random.PRNGKey(0))
