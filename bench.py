@@ -2043,7 +2043,10 @@ def main() -> None:
         S = ecfg.prefill_buckets[-1]
         toks = jnp.asarray(rng.integers(4, cfg.vocab_size - 4,
                                         size=(P, S)), jnp.int32)
-        lengths = jnp.full((P,), S, jnp.int32)
+        lengths = (jnp.full((P,), S, jnp.int32),)
+        if eng._packed_prefill:     # P full-bucket prompts end to end
+            toks = toks.reshape(P * S)
+            lengths = (jnp.arange(P, dtype=jnp.int32) * S, *lengths)
         blocks_per = min((S + 15) // 16, ecfg.max_blocks_per_seq)
         tbl = np.zeros((P, ecfg.max_blocks_per_seq), np.int32)
         for j in range(P):

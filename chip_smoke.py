@@ -575,8 +575,10 @@ def _kernel_calls(jax, engine, name: str, prog) -> int:
     pages = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), engine.pages)
     NB = ec.max_blocks_per_seq
-    if name == "prefill":
-        args = (engine.params, shape(1, 32), shape(1), pages, shape(1, NB))
+    if name == "prefill":       # one chip: a packed stream of 32 tokens
+        R = ec.max_prefills_per_step
+        args = (engine.params, shape(32), (shape(R), shape(R)), pages,
+                shape(R, NB))
     elif name == "prefill-chunk":   # the rounds' 8-lane prefix-hit shape
         args = (engine.params, shape(8, 256), shape(8), shape(8), pages,
                 shape(8, 32))

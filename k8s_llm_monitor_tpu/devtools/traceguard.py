@@ -349,10 +349,20 @@ def scan_engine_programs(engine) -> dict[str, list[str]]:
             params, tok, fstate, ctx, remaining, pages, dec_tables,
             engine._fsm_trans, temp, topk, topp, rng, eos))
 
-    P = 1
-    ptoks = jnp.zeros((P, bucket), jnp.int32)
-    plens = jnp.full((P,), bucket, jnp.int32)
-    ptbl = jnp.asarray(np.arange(1, W + 1, dtype=np.int32)[None, :])
+    # One prompt of ``bucket`` tokens: a packed stream with one live row
+    # (one chip), or one row (a mesh) — engine._dispatch_admit.
+    if engine._packed_prefill:
+        P = ec.max_prefills_per_step
+        live = np.arange(P) == 0
+        ptoks = jnp.zeros((bucket,), jnp.int32)
+        plens = (jnp.asarray(np.where(live, 0, bucket), jnp.int32),
+                 jnp.asarray(np.where(live, bucket, 0), jnp.int32))
+    else:
+        P = 1
+        ptoks = jnp.zeros((P, bucket), jnp.int32)
+        plens = (jnp.full((P,), bucket, jnp.int32),)
+    ptbl = jnp.asarray(np.arange(1, W + 1, dtype=np.int32)[None, :]
+                       * (np.arange(P) == 0)[:, None])
     out["prefill_greedy"] = forbidden_ops(jax.make_jaxpr(
         engine._prefill_greedy)(params, ptoks, plens, pages, ptbl))
     out["prefill_sampled"] = forbidden_ops(jax.make_jaxpr(

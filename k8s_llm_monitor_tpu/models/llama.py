@@ -26,6 +26,7 @@ only configured but never implemented (reference internal/config/config.go:
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -479,13 +480,15 @@ def _kv_b(layer: Params, cfg: ModelConfig):
 
 def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
                             c, k_rope, positions, kv_len, attn_fn=None,
-                            kernel=None):
+                            kernel=None, view: Optional["RowView"] = None):
     """Expanded form over the batch's own tokens: per-head keys
     ``[k_nope | k_rope]`` and values from ``c W_kvb``.  ``kernel``: the
     Pallas kernel of a fresh prefill (positions are indices,
     ops/pallas_attention.py:latent_prefill_attention_pallas); ``attn_fn``:
     the dense oracle (``causal_attention``); neither: blockwise XLA
-    operations."""
+    operations.  ``view``: the inputs are a packed stream; keys and values
+    are expanded on it (per token) and attention sees its rows
+    (``positions`` are then the rows')."""
     B, S = c.shape[:2]
     w_uk, w_uv = _kv_b(layer, cfg)
     k_nope = jnp.einsum("bsr,rhd->bshd", c, w_uk)
@@ -496,12 +499,20 @@ def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     scale = cfg.qk_head_dim ** -0.5
+    if view is not None and kernel is not None:
+        # The kernel's blocks straight from the stream, no row views.
+        return _packed_form(kernel)(
+            q[0], k[0], v[0], view.offset, kv_len, scale=scale,
+            row_len=view.take.shape[1])[None]
+    q, k, v = (_rows(view, t) for t in (q, k, v))
     if attn_fn is not None:
-        return attn_fn(q, k, v, q_positions=positions, scale=scale)
-    if kernel is not None:
-        return kernel(q, k, v, kv_len, scale=scale)
-    return blockwise_attention(q, k, v, q_positions=positions, kv_len=kv_len,
-                               scale=scale)
+        attn = attn_fn(q, k, v, q_positions=positions, scale=scale)
+    elif kernel is not None:
+        attn = kernel(q, k, v, kv_len, scale=scale)
+    else:
+        attn = blockwise_attention(q, k, v, q_positions=positions,
+                                   kv_len=kv_len, scale=scale)
+    return _stream(view, attn)
 
 
 def _latent_absorb(layer: Params, cfg: ModelConfig, q_nope,
@@ -555,6 +566,17 @@ def is_fused_quant_decode_impl(attn_impl) -> bool:
     must never be handed a quantized pool; decode_step falls back to the
     gather/dequant path in that case."""
     return _marked(attn_impl, "quant_kv")
+
+
+def _packed_form(attn_impl):
+    """A prefill kernel's form for a packed stream
+    (ops/pallas_attention.py:flash_prefill_attention_packed,
+    latent_prefill_attention_packed), with what a functools.partial bound
+    (interpret=True)."""
+    fn = getattr(attn_impl, "func", attn_impl)
+    if fn is attn_impl:
+        return fn.packed
+    return functools.partial(fn.packed, **attn_impl.keywords)
 
 
 def is_flash_prefill_impl(attn_impl) -> bool:
@@ -1151,6 +1173,36 @@ def _scatter_pages_quant(
 # ---------------------------------------------------------------------------
 
 
+class RowView(NamedTuple):
+    """A packed token stream's segments seen as rows, and the way back.
+
+    A packed call lays its prompts end to end in one stream ``[1, T, ...]``;
+    everything computed per token runs on the stream.  Attention is per
+    prompt, so it sees ``[R, S, ...]`` rows cut out of the stream: row r is
+    the S stream tokens from where segment r starts.  A row's tail past its
+    length holds whatever follows it in the stream and is masked by the
+    row's length, as a bucket's padding is.
+    """
+
+    take: jnp.ndarray      # [R, S] stream index of row r's s-th token
+    back: jnp.ndarray      # [T] each stream token's place in the R*S rows
+    seg: jnp.ndarray       # [T] the segment (row) each stream token is of
+    offset: jnp.ndarray    # [R] where each segment starts in the stream
+
+
+def _rows(view: Optional[RowView], x: jnp.ndarray) -> jnp.ndarray:
+    """A stream ``[1, T, ...]`` as the view's rows ``[R, S, ...]``; without
+    a view ``x`` is rows already."""
+    return x if view is None else x[0][view.take]
+
+
+def _stream(view: Optional[RowView], y: jnp.ndarray) -> jnp.ndarray:
+    """Rows ``[R, S, ...]`` back in the stream's order ``[1, T, ...]``."""
+    if view is None:
+        return y
+    return y.reshape(-1, *y.shape[2:])[view.back][None]
+
+
 def _prefill_impl(
     params: Params,
     cfg: ModelConfig,
@@ -1166,6 +1218,7 @@ def _prefill_impl(
     paged_attn_fn=None,
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
+    view: Optional[RowView] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Shared prefill layer loop.
 
@@ -1188,6 +1241,13 @@ def _prefill_impl(
     the residual stream before each layer and after the last ([B, S, H]
     each) — what a layer-by-layer comparison with a reference feeds on
     (``InferenceEngine.score_logits``).
+
+    ``view`` (``prefill_packed``): ``tokens`` / ``positions`` / ``valid``
+    are one packed stream ``[1, T]`` (a token's position is its index in its
+    own segment) while ``lengths`` / ``kv_len`` / ``block_tables`` stay per
+    row.  Embedding, norms, projections, rope, the page scatter and the MLP
+    are per token and run on the stream; attention alone goes through the
+    view's rows and comes back.
     """
     B, S = tokens.shape
     if cfg.latent and paged_attn_fn is not None \
@@ -1197,6 +1257,17 @@ def _prefill_impl(
             f"(ops/attention.py:select_prefill_impl); got {paged_attn_fn!r}")
     cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
                            scaling=cfg.rope_scaling)
+    if view is None:
+        row_pos, sc_tables, sc_pos = positions, block_tables, positions
+    else:
+        # The scatter is per token: segment r's table is columns
+        # [r * W, (r + 1) * W) of one flat table, and a token's place in it
+        # is its own position past its segment's columns.
+        R, W = block_tables.shape
+        row_pos = jnp.broadcast_to(
+            jnp.arange(view.take.shape[1], dtype=jnp.int32), view.take.shape)
+        sc_tables = block_tables.reshape(1, R * W)
+        sc_pos = view.seg[None] * (W * pages.block_size) + positions
 
     x = _embed_lookup(params, cfg, tokens)
     uo = cfg.rmsnorm_unit_offset
@@ -1211,7 +1282,7 @@ def _prefill_impl(
             q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
             with jax.named_scope("attention"), jax.named_scope("latent"):
                 pk = _scatter_pages(pages.k[li], _latent_rows(cfg, c, k_rope),
-                                    block_tables, positions, valid)
+                                    sc_tables, sc_pos, valid)
                 new_k.append(pk)
                 if attend_to_pages:
                     rows = gather_pages(pk, block_tables)[:, :, None, :]
@@ -1222,8 +1293,8 @@ def _prefill_impl(
                     attn = _latent_unabsorb(layer, cfg, o_lat)
                 else:
                     attn = _latent_attend_expanded(
-                        layer, cfg, q_nope, q_rope, c, k_rope, positions,
-                        kv_len, kernel=paged_attn_fn)
+                        layer, cfg, q_nope, q_rope, c, k_rope, row_pos,
+                        kv_len, kernel=paged_attn_fn, view=view)
             o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
             x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
                                   moe_stats=moe_stats)
@@ -1234,17 +1305,17 @@ def _prefill_impl(
         with jax.named_scope("attention"):
             if quant:
                 pk, psk = _scatter_pages_quant(
-                    pages.k[li], pages.k_scale[li], k, block_tables,
-                    positions, valid)
+                    pages.k[li], pages.k_scale[li], k, sc_tables,
+                    sc_pos, valid)
                 pv, psv = _scatter_pages_quant(
-                    pages.v[li], pages.v_scale[li], v, block_tables,
-                    positions, valid)
+                    pages.v[li], pages.v_scale[li], v, sc_tables,
+                    sc_pos, valid)
                 new_ks.append(psk)
                 new_vs.append(psv)
             else:
-                pk = _scatter_pages(pages.k[li], k, block_tables, positions,
+                pk = _scatter_pages(pages.k[li], k, sc_tables, sc_pos,
                                     valid)
-                pv = _scatter_pages(pages.v[li], v, block_tables, positions,
+                pv = _scatter_pages(pages.v[li], v, sc_tables, sc_pos,
                                     valid)
             new_k.append(pk)
             new_v.append(pv)
@@ -1256,13 +1327,16 @@ def _prefill_impl(
                 # call — no gather_pages round-trip, no [S, T] score matrix.
                 # Quantized pools hand the kernel their scale planes and
                 # dequantize in-kernel; the pool never widens in HBM.
-                if quant:
-                    attn = paged_attn_fn(q, pk, pv, block_tables,
-                                         positions[:, 0], lengths,
-                                         k_scale=psk, v_scale=psv)
+                scales = dict(k_scale=psk, v_scale=psv) if quant else {}
+                if view is not None:
+                    # A packed stream: the kernel's tiles straight from the
+                    # stream, no row view of the queries.
+                    attn = _packed_form(paged_attn_fn)(
+                        q[0], pk, pv, block_tables, view.offset, lengths,
+                        **scales)[None]
                 else:
                     attn = paged_attn_fn(q, pk, pv, block_tables,
-                                         positions[:, 0], lengths)
+                                         positions[:, 0], lengths, **scales)
             elif attend_to_pages and paged_attn_fn is not None and not quant:
                 # Page-streaming path (Pallas verify kernel): queries are
                 # contiguous at positions[:, 0] + i, which both verify_step
@@ -1296,9 +1370,13 @@ def _prefill_impl(
                         B, -1, cfg.num_kv_heads, cfg.head_dim_)
                 else:
                     kk, vv = k, v
-                attn = causal_attention(q, kk, vv, q_positions=positions,
-                                        kv_len=kv_len,
-                                        **_attn_extras(cfg, li))
+                # Attention is per prompt: a packed stream's q, k and v go
+                # through the rows (fresh calls alone pack, so kk, vv are
+                # the stream's own) and the result comes back.
+                attn = _stream(view, causal_attention(
+                    _rows(view, q), _rows(view, kk), _rows(view, vv),
+                    q_positions=row_pos, kv_len=kv_len,
+                    **_attn_extras(cfg, li)))
         o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
         x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
                               moe_stats=moe_stats)
@@ -1311,8 +1389,12 @@ def _prefill_impl(
     if return_all_logits:
         return _unembed(params, cfg, x), out_pages
     last_idx = jnp.maximum(lengths - 1, 0)
-    x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)  # [B,1,H]
-    logits = _unembed(params, cfg, x_last)[:, 0, :]
+    if view is None:
+        x_last = jnp.take_along_axis(x, last_idx[:, None, None], axis=1)
+    else:       # each row's last token, where it lies in the stream
+        x_last = x[0][jnp.take_along_axis(
+            view.take, last_idx[:, None], axis=1)]
+    logits = _unembed(params, cfg, x_last)[:, 0, :]      # x_last [B, 1, H]
     return logits, out_pages
 
 
@@ -1350,6 +1432,55 @@ def prefill(
                          lengths, pages, block_tables, attend_to_pages=False,
                          paged_attn_fn=attn_impl, moe_stats=moe_stats,
                          hidden=hidden)
+
+
+def prefill_packed(
+    params: Params,
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,
+    offset: jnp.ndarray,
+    lengths: jnp.ndarray,
+    pages: KVPages,
+    block_tables: jnp.ndarray,
+    *,
+    row_len: int,
+    attn_impl=None,
+    moe_stats: Optional[list] = None,
+) -> tuple[jnp.ndarray, KVPages]:
+    """``prefill`` of prompts laid end to end in one token stream: what is
+    computed per token is computed for the stream's ``T`` positions, not for
+    rows x bucket, and a prompt's numbers do not depend on what else is in
+    the call.
+
+    Args:
+      tokens: [T] int32 — segment r is ``tokens[offset[r]:offset[r] +
+        lengths[r]]``; whatever lies past the last segment is padding.
+      offset: [R] int32, ascending; an idle row's is the end of the real
+        tokens.
+      lengths: [R] int32 (0 = idle row), each at most ``row_len``.
+      block_tables: [R, max_blocks] int32.
+      row_len: the width S of attention's row view (``RowView``), static.
+      attn_impl: as for ``prefill``.
+
+    Returns:
+      (last_logits [R, V] float32, updated pages)
+    """
+    (T,), R = tokens.shape, offset.shape[0]
+    t = jnp.arange(T, dtype=jnp.int32)
+    seg = jnp.clip(jnp.sum(t[:, None] >= offset[None, :], axis=1) - 1,
+                   0, R - 1).astype(jnp.int32)
+    positions = t - offset[seg]
+    valid = positions < lengths[seg]
+    view = RowView(
+        take=jnp.minimum(
+            offset[:, None] + jnp.arange(row_len, dtype=jnp.int32)[None, :],
+            T - 1),
+        back=seg * row_len + jnp.minimum(positions, row_len - 1),
+        seg=seg, offset=offset)
+    return _prefill_impl(params, cfg, tokens[None], positions[None],
+                         valid[None], lengths, lengths, pages, block_tables,
+                         attend_to_pages=False, paged_attn_fn=attn_impl,
+                         moe_stats=moe_stats, view=view)
 
 
 def prefill_chunk(

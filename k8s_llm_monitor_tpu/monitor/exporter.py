@@ -371,8 +371,9 @@ def _loop_metrics(w: _Writer, engine) -> None:
              "(over decode_slot_steps: the share of lane-steps of use)",
              [("", engine.decode_tokens)])
     w.metric("engine_prefill_tokens_total", "counter",
-             "Prompt tokens by kind: real = computed, padded = bucket x "
-             "rows computed, cached = served from the prefix cache",
+             "Prompt tokens by kind: real = computed for requests, padded = "
+             "computed by the programs (T of a packed call, bucket x rows of "
+             "a row call), cached = served from the prefix cache",
              [(f'{{kind="{k}"}}', n)
               for k, n in sorted(engine.prefill_tokens.items())])
     w.metric("engine_dispatch_on_empty_device_total", "counter",

@@ -439,17 +439,19 @@ latent_decode_attention_pallas.latent = True
 
 
 def _latent_prefill_kernel(
+    stream,                # static: blocks of a packed stream (below)
     # scalar prefetch
     lens_ref,              # [B] int32 valid tokens of each row
+    *refs,                 # (tile_row_ref, tile_t_ref, first_ref,) then:
     # inputs (one batch row, one head)
-    q_ref,                 # [1, 1, bq, Dk], already scaled
-    k_ref,                 # [1, 1, bk, Dk]
-    v_ref,                 # [1, 1, bk, Dv]
+    #   q_ref                [1, 1, bq, Dk], already scaled
+    #   k_ref                [1, 1, bk, Dk]
+    #   v_ref                [1, 1, bk, Dv]
     # out
-    o_ref,                 # [1, 1, bq, Dv]
+    #   o_ref                [1, 1, bq, Dv]
     # scratch, kept across the key blocks of one query block
-    m_scr, l_scr,          # [bq, 128] float32, lane-replicated
-    acc_scr,               # [bq, Dv] float32
+    #   m_scr, l_scr         [bq, 128] float32, lane-replicated
+    #   acc_scr              [bq, Dv] float32
 ):
     """Causal attention of one query block against one key block of the
     SAME sequence (a fresh prefill: positions are indices), online softmax
@@ -458,10 +460,27 @@ def _latent_prefill_kernel(
     nothing (and are not fetched: the index map clamps them to the last
     block that is needed).  Only a block the diagonal or the length cuts
     through builds a mask: the softmax's elementwise passes, not the
-    products, bound this kernel on a chip without bf16 vector units."""
-    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    bq, bk = q_ref.shape[2], k_ref.shape[2]
-    Dv = v_ref.shape[3]
+    products, bound this kernel on a chip without bf16 vector units.
+
+    ``stream`` (``latent_prefill_attention_packed``): q, k and v are one
+    block-aligned stream ``[H, NT*bq, D]`` of several sequences' blocks end
+    to end, the grid is (H, NT, key blocks of the longest row), and two
+    prefetched arrays say which sequence query block i' is of and which of
+    that sequence's blocks it is (a third, where each sequence's blocks
+    start, is the index maps').  The block's arithmetic is the same."""
+    if stream:
+        (tile_row_ref, tile_t_ref, _, q_ref, k_ref, v_ref, o_ref,
+         m_scr, l_scr, acc_scr) = refs
+        b, i = (tile_row_ref[pl.program_id(1)], tile_t_ref[pl.program_id(1)])
+        j, last_j = pl.program_id(2), pl.num_programs(2) - 1
+        blk = lambda ref: ref[0]                               # noqa: E731
+    else:
+        q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = refs
+        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        last_j = pl.num_programs(3) - 1
+        blk = lambda ref: ref[0, 0]                            # noqa: E731
+    bq, bk = q_ref.shape[-2], k_ref.shape[-2]
+    Dv = v_ref.shape[-1]
     length = lens_ref[b]
     live = (i * bq < length) & (j * bk < length) & (j * bk <= i * bq + bq - 1)
     whole = (j * bk + bk - 1 <= i * bq) & (j * bk + bk <= length)
@@ -473,9 +492,9 @@ def _latent_prefill_kernel(
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     def block(masked: bool):
-        k, v = k_ref[0, 0], v_ref[0, 0]
+        k, v = blk(k_ref), blk(v_ref)
         s = jax.lax.dot_general(
-            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+            blk(q_ref), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                 # [bq, bk]
         if masked:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -494,11 +513,15 @@ def _latent_prefill_kernel(
     pl.when(live & whole)(lambda: block(False))
     pl.when(live & jnp.logical_not(whole))(lambda: block(True))
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    @pl.when(j == last_j)
     def _store():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)          # rows past the length
-        o_ref[0, 0] = (acc_scr[...] / _lanes(l, Dv)).astype(o_ref.dtype)
+        out = (acc_scr[...] / _lanes(l, Dv)).astype(o_ref.dtype)
+        if stream:
+            o_ref[0] = out
+        else:
+            o_ref[0, 0] = out
 
 
 def _lanes(x, n: int):
@@ -572,7 +595,7 @@ def latent_prefill_attention_pallas(
                         pltpu.VMEM((bq, Dv), jnp.float32)],
     )
     out = pl.pallas_call(
-        _latent_prefill_kernel,
+        functools.partial(_latent_prefill_kernel, False),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -582,6 +605,119 @@ def latent_prefill_attention_pallas(
         name="latent_prefill_attention",
     )(lengths.astype(jnp.int32), q, k, v)
     return out.transpose(0, 2, 1, 3)[:, :S0]
+
+
+def _stream_tiles(offset, lengths, T: int, tile: int):
+    """How a packed stream's R segments lie in a tile-aligned stream in
+    which every segment starts a tile of its own (``T/tile + R`` tiles hold
+    any packing).  Returns (NT, tile_row [NT] the segment tile i is of,
+    tile_t [NT] which of its segment's tiles it is, first [R] each
+    segment's first tile, src [NT*tile] the stream token in each aligned
+    slot — a tile's tail past its segment's end reads whatever follows and
+    is masked by the segment's length — and back [T] each stream token's
+    aligned slot)."""
+    i32 = jnp.int32
+    R = offset.shape[0]
+    NT = -(-T // tile) + R
+    ntile = (lengths + tile - 1) // tile
+    first = (jnp.cumsum(ntile) - ntile).astype(i32)
+    idx = jnp.arange(NT, dtype=i32)
+    tile_row = jnp.clip(
+        jnp.sum(idx[:, None] >= first[None, :], axis=1) - 1, 0, R - 1
+    ).astype(i32)
+    tile_t = idx - first[tile_row]
+    src = ((offset[tile_row] + tile_t * tile)[:, None]
+           + jnp.arange(tile, dtype=i32))
+    src = jnp.clip(src.reshape(NT * tile), 0, T - 1)
+    tok = jnp.arange(T, dtype=i32)
+    seg = jnp.clip(jnp.sum(tok[:, None] >= offset[None, :], axis=1) - 1,
+                   0, R - 1)
+    back = jnp.clip(first[seg] * tile + tok - offset[seg], 0, NT * tile - 1)
+    return NT, tile_row, tile_t, first, src, back
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "row_len", "block", "interpret"))
+def latent_prefill_attention_packed(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    offset: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    scale: float,
+    row_len: int,
+    block: int = 512,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``latent_prefill_attention_pallas`` of a fresh call's packed stream
+    (models/llama.py:prefill_packed): the same kernel over the same blocks,
+    without ``[R, S]`` row views of q, k and v in between (12 + 12 + 8 KB a
+    row token a layer at the kanana widths: a fifth of a T = 16,384 call,
+    PERF.md section 6, PR 27).  The stream is gathered into a block-aligned
+    one (``_stream_tiles``) and the grid runs over its blocks.
+
+    Args:
+      q, k: [T, H, Dk]; v: [T, H, Dv]; segment r at ``[offset[r], offset[r]
+        + lengths[r])``, its token i at position i.
+      offset, lengths: [R] int32 (0 = idle row).
+      row_len: the longest a segment can be (static): sets the block and
+        the key-block axis of the grid.
+
+    Returns:
+      [T, H, Dv] in q.dtype; rows of no segment are garbage.
+    """
+    T, H, Dk = q.shape
+    Dv = v.shape[-1]
+    bq = bk = min(block, row_len)
+    pad = -Dk % 128
+    offset, lengths = offset.astype(jnp.int32), lengths.astype(jnp.int32)
+    NT, tile_row, tile_t, first, src, back = _stream_tiles(
+        offset, lengths, T, bq)
+    q = q * jnp.asarray(scale, q.dtype)
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad)))
+    q, k, v = (x[src].transpose(1, 0, 2) for x in (q, k, v))  # [H, NT*bq, D]
+    nk = -(-row_len // bk)
+
+    def q_map(h, i, j, lens, rows, ts, firsts):
+        return (h, i, 0)
+
+    def kv_map(h, i, j, lens, rows, ts, firsts):
+        # As in the row form: blocks that will do nothing repeat the last
+        # one that does.
+        b, t = rows[i], ts[i]
+        last = jnp.minimum((t * bq + bq - 1) // bk,
+                           jnp.maximum(lens[b] - 1, 0) // bk)
+        return (h, firsts[b] + jnp.minimum(j, last), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(H, NT, nk),
+        in_specs=[
+            pl.BlockSpec((1, bq, Dk + pad), q_map),
+            pl.BlockSpec((1, bk, Dk + pad), kv_map),
+            pl.BlockSpec((1, bk, Dv), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, bq, Dv), q_map),
+        scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, Dv), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, True),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((H, NT * bq, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="latent_prefill_attention",
+    )(lengths, tile_row, tile_t, first, q, k, v)
+    return out.transpose(1, 0, 2)[back]
+
+
+latent_prefill_attention_pallas.packed = latent_prefill_attention_packed
 
 
 # ---------------------------------------------------------------------------
@@ -1331,15 +1467,13 @@ def _flash_prefill_kernel(
     KVH,                   # static: kv heads (= F // D)
     qpk,                   # static: query heads per kv group
     quant,                 # static: dequantize-in-kernel from scale planes
+    stream,                # static: query tiles of a packed stream (below)
     # scalar prefetch
     tables_ref,            # [B, NB] int32 block ids
     starts_ref,            # [B] int32 cached tokens before this chunk
     qlens_ref,             # [B] int32 valid query tokens (0 = inactive lane)
-    # inputs
-    q_ref,                 # [1, 1, TQ, qpk*D] this (seq, group, tile) q slab
-    k_hbm,                 # [num_blocks, bs, KVH*D] (ANY/HBM, whole array)
-    v_hbm,                 # same
-    *rest,                 # (ks_ref, vs_ref,) o_ref
+    *refs,                 # (tile_row_ref, tile_t_ref,) q_ref, k_hbm, v_hbm,
+                           # (ks_ref, vs_ref,) o_ref
 ):
     """One program: one query tile of one sequence for one kv group.
 
@@ -1362,14 +1496,31 @@ def _flash_prefill_kernel(
     picked with a select.  K scales factor out of ``q @ k^T`` onto the
     score tile; V scales fold into the probabilities, exactly the
     ``_fused_decode_quant_kernel`` convention.
+
+    ``stream`` (``flash_prefill_attention_packed``): the queries are one
+    tile-aligned stream ``[KVH, NT*TQ, qpk*D]`` of several sequences' tiles
+    end to end and the grid is (KVH, NT); two more prefetched arrays say
+    which sequence tile i is of (``tile_row_ref`` [NT]) and which of that
+    sequence's tiles it is (``tile_t_ref`` [NT]).  The tile's arithmetic is
+    the same.  Else q_ref is [1, 1, TQ, qpk*D], this (seq, group, tile)
+    slab of ``[B, KVH, S, qpk*D]``.
     """
+    if stream:
+        tile_row_ref, tile_t_ref, q_ref, k_hbm, v_hbm, *rest = refs
+    else:
+        q_ref, k_hbm, v_hbm, *rest = refs
     if quant:
         ks_ref, vs_ref, o_ref = rest
     else:
         (o_ref,) = rest
-    b = pl.program_id(0)
-    g = pl.program_id(1)                         # kv group this program owns
-    t = pl.program_id(2)                         # query tile index
+    if stream:
+        g = pl.program_id(0)
+        b = tile_row_ref[pl.program_id(1)]
+        t = tile_t_ref[pl.program_id(1)]
+    else:
+        b = pl.program_id(0)
+        g = pl.program_id(1)                     # kv group this program owns
+        t = pl.program_id(2)                     # query tile index
     bs = k_hbm.shape[1]
     NB = tables_ref.shape[1]
     W = min(_WINDOW, NB)
@@ -1399,7 +1550,8 @@ def _flash_prefill_kernel(
             return jnp.sum(jnp.where(own_row, win, 0.0), axis=0,
                            keepdims=True)
 
-    qt = q_ref[0, 0].astype(jnp.float32)         # [TQ, qpk*D]
+    qt = (q_ref[0] if stream else q_ref[0, 0]).astype(jnp.float32)
+    # [TQ, qpk*D]
     q2 = jnp.concatenate(
         [qt[:, j * D:(j + 1) * D] for j in range(qpk)], axis=0)  # [R, D]
 
@@ -1474,8 +1626,12 @@ def _flash_prefill_kernel(
         # the guard only hardens against a fully-degenerate table.
         out = acc / jnp.where(l > 0.0, l, 1.0)
         for j in range(qpk):
-            o_ref[0, 0, :, j * D:(j + 1) * D] = out[
-                j * TQ:(j + 1) * TQ].astype(o_ref.dtype)
+            if stream:
+                o_ref[0, :, j * D:(j + 1) * D] = out[
+                    j * TQ:(j + 1) * TQ].astype(o_ref.dtype)
+            else:
+                o_ref[0, 0, :, j * D:(j + 1) * D] = out[
+                    j * TQ:(j + 1) * TQ].astype(o_ref.dtype)
 
     pl.run_scoped(
         scoped,
@@ -1572,7 +1728,8 @@ def flash_prefill_attention(
     operands = [block_table, start.astype(jnp.int32),
                 lengths.astype(jnp.int32), qg, k_pages, v_pages] + scale_ops
     out = pl.pallas_call(
-        functools.partial(_flash_prefill_kernel, TQ, D, KVH, qpk, quant),
+        functools.partial(_flash_prefill_kernel, TQ, D, KVH, qpk, quant,
+                          False),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, Sp, qpk * D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -1587,7 +1744,97 @@ def flash_prefill_attention(
     return out[:, :, :S].transpose(0, 2, 1, 3).reshape(B, S, H, D)
 
 
+def flash_prefill_attention_packed(
+    q: jnp.ndarray,
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    block_table: jnp.ndarray,
+    offset: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    k_scale: jnp.ndarray | None = None,
+    v_scale: jnp.ndarray | None = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """``flash_prefill_attention`` of a fresh call's packed stream
+    (models/llama.py:prefill_packed): the same kernel over the same query
+    tiles, with no ``[R, S]`` row view of the queries in between.
+
+    The stream's queries are gathered into a tile-aligned stream — every
+    segment starts a TQ-token tile of its own, ``T/TQ + R`` tiles hold any
+    packing — and the grid runs over that stream's tiles: a tile is of one
+    sequence, which a prefetched array names, so the only dead tiles are
+    the stream's own tail.  A row view costs R x S query rows of copies and
+    R x S / TQ grid steps a layer whatever the call holds (at Qwen2-7B
+    widths: half of a T = 2,048 call's time, PERF.md section 6, PR 27);
+    this costs two gathers of T rows.
+
+    Args:
+      q: [T, H, D], segment r at ``q[offset[r]:offset[r] + lengths[r]]``
+        (token i of it at position i; its K/V already in the pages).
+      block_table: [R, NB]; offset, lengths: [R] int32 (0 = idle row).
+
+    Returns:
+      [T, H, D] in q.dtype; rows of no segment are garbage.
+    """
+    T, H, D = q.shape
+    nblk, bs, F = k_pages.shape
+    assert F % D == 0 and D <= 128, (F, D)
+    KVH = F // D
+    assert H % KVH == 0, (H, KVH)
+    qpk = H // KVH
+    quant = k_scale is not None
+    R = offset.shape[0]
+    Tp = -(-T // 8) * 8
+    TQ = next(tt for tt in (128, 64, 32, 16, 8) if Tp % tt == 0)
+    i32 = jnp.int32
+    offset, lengths = offset.astype(i32), lengths.astype(i32)
+    NT, tile_row, tile_t, _, src, back = _stream_tiles(offset, lengths, T, TQ)
+
+    qg = (q * (D ** -0.5)).reshape(T, KVH, qpk * D)[src].transpose(1, 0, 2)
+
+    def qmap(g, i, *_):
+        return (g, i, 0)
+
+    scale_ops, scale_specs = [], []
+    if quant:
+        W = min(_WINDOW, block_table.shape[1])
+        scale_ops = [_window_scales(k_scale, block_table, W),
+                     _window_scales(v_scale, block_table, W)]
+        scale_specs = [pl.BlockSpec(
+            (1,) + scale_ops[0].shape[1:],
+            lambda g, i, tables, starts, qlens, rows, ts: (rows[i], 0, 0, 0)
+        )] * 2
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(KVH, NT),
+        in_specs=[
+            pl.BlockSpec((1, TQ, qpk * D), qmap),
+            pl.BlockSpec(memory_space=pl.ANY),   # K pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # V pages stay in HBM
+        ] + scale_specs,
+        out_specs=pl.BlockSpec((1, TQ, qpk * D), qmap),
+    )
+    out = pl.pallas_call(
+        functools.partial(_flash_prefill_kernel, TQ, D, KVH, qpk, quant,
+                          True),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((KVH, NT * TQ, qpk * D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+        ),
+        interpret=interpret,
+        name="flash_prefill_attention_quant" if quant
+        else "flash_prefill_attention",
+    )(block_table, jnp.zeros((R,), i32), lengths, tile_row, tile_t,
+      qg, k_pages, v_pages, *scale_ops)
+    return out.transpose(1, 0, 2)[back].reshape(T, H, D)
+
+
 # Marker consumed by models/llama.py:is_flash_prefill_impl — the prefill
 # family routes all three geometries (fresh/chunk/verify) through this
-# calling convention, passing scale planes for quantized pools.
+# calling convention, passing scale planes for quantized pools.  ``packed``:
+# the same kernel's form for a fresh call's packed stream.
 flash_prefill_attention.flash_prefill = True
+flash_prefill_attention.packed = flash_prefill_attention_packed

@@ -9,9 +9,13 @@ Throughput design (the north-star SLO is p50 TTFT < 500 ms at 100 concurrent
 diagnosis queries, BASELINE.md):
 
   * **Batched prefill** — up to ``max_prefills_per_step`` pending prompts are
-    ingested in ONE ``[P, bucket]`` prefill call (padded lanes are inactive),
-    and their first tokens are sampled inside the same compiled program, so an
-    admission round costs one dispatch regardless of how many it admits.
+    ingested in one admission round, and their first tokens are sampled
+    inside the same compiled program.  A fresh round on one chip lays its
+    prompts end to end in a packed stream of ``T`` tokens (a power-of-two
+    rung), so what is computed per token is computed for the round's real
+    tokens; the round goes as the calls whose rungs sum to the least
+    (``_admit_groups``: mostly one or two).  A round with a prefix hit, and every round
+    over a mesh, is ONE ``[P, bucket]`` call (padded lanes are inactive).
   * **Fused multi-step decode** — ``decode_steps_per_iter`` decode steps run
     inside one compiled ``lax.scan`` with on-device token feedback; per-lane
     EOS detection and budget exhaustion are masked on device, so the host
@@ -456,7 +460,7 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     "sampler_filter", "steps", "lanes", "slots", "emitted",
                     "ctx_tokens",
                     "bucket", "rows", "prompts", "real_tokens",
-                    "padded_tokens", "cached_tokens", "shared",
+                    "padded_tokens", "cached_tokens", "shared", "packed",
                     # Programs of a routed model only (MOE_COUNTS).
                     "moe_assignments", "moe_expert_layer_steps_hit",
                     "moe_expert_layer_steps", "moe_max_rows",
@@ -962,27 +966,45 @@ class InferenceEngine:
         def _stats() -> Optional[list]:
             return [] if routed else None
 
-        def _prefill_sample_fn(params, tokens, lengths, pages, tables,
-                               temp, topk, topp, rng):
-            stats = _stats()
-            logits, pages = llama.prefill(
-                params, cfg, tokens, lengths, pages, tables,
+        # A fresh admission call (no lane shares a cached prefix) takes the
+        # packed form on one chip: ``tokens [T]`` is the call's prompts end
+        # to end and ``seg`` = (offset [R], lengths [R]), R =
+        # max_prefills_per_step, so what is computed per token is computed
+        # for the call's tokens and not for rows x bucket
+        # (llama.prefill_packed).  Over a mesh it keeps the row form,
+        # ``tokens [P, bucket]`` and ``seg`` = (lengths [P],): the
+        # sequence-parallel split and the sharded flash kernel work on rows.
+        self._packed_prefill = packed = mesh is None
+        top_bucket = ec.prefill_buckets[-1]
+
+        def _fresh_prefill(params, tokens, seg, pages, tables, stats):
+            if packed:
+                return llama.prefill_packed(
+                    params, cfg, tokens, *seg, pages, tables,
+                    row_len=min(tokens.shape[0], top_bucket),
+                    attn_impl=prefill_attn, moe_stats=stats)
+            return llama.prefill(
+                params, cfg, tokens, *seg, pages, tables,
                 attn_impl=prefill_attn, moe_stats=stats
             )
+
+        def _prefill_sample_fn(params, tokens, seg, pages, tables,
+                               temp, topk, topp, rng):
+            stats = _stats()
+            logits, pages = _fresh_prefill(params, tokens, seg, pages,
+                                           tables, stats)
             first = sample_tokens(
                 rng, logits, temperature=temp, top_k=topk, top_p=topp
             )
             return _with_counts((first, pages), stats)
 
-        def _prefill_greedy_fn(params, tokens, lengths, pages, tables):
+        def _prefill_greedy_fn(params, tokens, seg, pages, tables):
             # Sort-free fast path for all-greedy admission rounds: skips the
             # [P, V] argsort nucleus filtering needs (V is 128k on the 8B
             # target — the sort costs more than the unembed).
             stats = _stats()
-            logits, pages = llama.prefill(
-                params, cfg, tokens, lengths, pages, tables,
-                attn_impl=prefill_attn, moe_stats=stats
-            )
+            logits, pages = _fresh_prefill(params, tokens, seg, pages,
+                                           tables, stats)
             return _with_counts((greedy_tokens(logits), pages), stats)
 
         def _prefill_chunk_sample_fn(params, tokens, start, lengths, pages,
@@ -1009,17 +1031,15 @@ class InferenceEngine:
             )
             return _with_counts((greedy_tokens(logits), pages), stats)
 
-        def _prefill_sample_fsm_fn(params, tokens, lengths, pages, tables,
+        def _prefill_sample_fsm_fn(params, tokens, seg, pages, tables,
                                    fstate, ftrans, temp, topk, topp, rng):
             # Grammar-constrained admission: mask the first-token logits by
             # each lane's FSM state (0 = FREE lane, unmasked) BEFORE the
             # shared sampler — greedy lanes take the argmax of the masked
             # logits inside sample_tokens, so constrained-greedy is exact.
             stats = _stats()
-            logits, pages = llama.prefill(
-                params, cfg, tokens, lengths, pages, tables,
-                attn_impl=prefill_attn, moe_stats=stats
-            )
+            logits, pages = _fresh_prefill(params, tokens, seg, pages,
+                                           tables, stats)
             masked = fsm_mask_logits(logits, fstate, ftrans)
             first = sample_tokens(
                 rng, masked, temperature=temp, top_k=topk, top_p=topp
@@ -2661,25 +2681,121 @@ class InferenceEngine:
         if not batch:
             return admitted_long > 0
 
-        P = self._lane_count(len(batch))
         any_shared = any(st > 0 for _, _, _, st in batch)
-        bucket = self._bucket(
-            max(len(r.prompt_ids) - st for _, r, _, st in batch))
-        # The chunked program (taken when any lane shares a cached prefix)
-        # gathers table_width * block_size keys per lane; narrow it to the
-        # deepest prompt.  The dense program never gathers — full width
-        # there avoids extra compile shapes.
-        W = (self._table_width(max(len(r.prompt_ids) for _, r, _, _ in batch))
-             if any_shared else 0)
-        (tokens, start, lengths, tables, idx,
-         temp, topk, topp) = self._lane_buffers(P, bucket, W)
+        # A fresh round on one chip goes as packed calls, one per group of
+        # consecutive prompts (_admit_groups); a round with a prefix hit, or
+        # over a mesh, as one row call.
+        sizes = (self._admit_groups([len(r.prompt_ids) for _, r, _, _ in batch])
+                 if self._packed_prefill and not any_shared else [len(batch)])
+        done = 0
+        for n in sizes:
+            exc = self._dispatch_admit(batch[done:done + n], any_shared)
+            if exc is not None:
+                self._abandon_admit(batch[done:], exc)
+                break
+            done += n
+        return done > 0 or admitted_long > 0
+
+    # What one more call costs a round, in tokens: a call streams the
+    # weights once whatever it holds, and a prefill token's arithmetic is two
+    # operations a weight byte, so on a chip that does a few hundred
+    # operations in the time it reads a byte (v5e: 393 TOP/s int8 over
+    # 819 GB/s = 480) one pass over the weights is worth ~240 tokens at the
+    # peak.  Measured there (PERF.md section 5): 9.3 ms a pass against
+    # ~60 us a prefill token for Qwen2-7B.
+    _CALL_TOKENS = 256
+
+    def _token_rung(self, n: int) -> int:
+        """Tokens a packed call of ``n`` real tokens computes: the smallest
+        bucket, doubled until it covers them.  The rung alone is the
+        program's compile-time shape, so a bucket ladder of powers of two
+        has one fresh-prefill program per power of two between its smallest
+        bucket and ``max_prefills_per_step`` x its largest."""
+        T = self.ecfg.prefill_buckets[0]
+        while T < n:
+            T <<= 1
+        return T
+
+    def _admit_groups(self, lengths: list[int]) -> list[int]:
+        """Cut a round's prompts, kept in order, into consecutive calls so
+        that the calls' rungs sum to the least (3 prompts of 1,300 tokens go
+        as 1,024 + 512, not 2,048); each call beyond the first is priced at
+        ``_CALL_TOKENS``, which also decides ties for fewer calls.  Returns
+        the calls' sizes in prompts."""
+        best: list[tuple[int, list[int]]] = [(0, [])]   # of the first i prompts
+        for i in range(1, len(lengths) + 1):
+            best.append(min(
+                ((best[j][0] + self._token_rung(sum(lengths[j:i]))
+                  + self._CALL_TOKENS, best[j][1] + [i - j])
+                 for j in range(i)), key=lambda c: c[0]))
+        return best[-1][1]
+
+    def _abandon_admit(self, batch: list[tuple], exc: Exception) -> None:
+        """A round's dispatch failed with host state still pre-dispatch for
+        ``batch`` (no slot occupied, no pages registered): release its pages
+        and requeue the candidates in order — bounded, so a deterministic
+        dispatch failure eventually surfaces to callers instead of
+        spinning."""
+        requeue: list[GenerationRequest] = []
+        for _, req, blocks, _ in batch:
+            self.allocator.free(blocks)
+            if req.requeues >= self.ecfg.max_requeues:
+                self._fail_request(
+                    req, f"prefill dispatch failed: {exc} "
+                         f"(gave up after {req.requeues} requeues)")
+            else:
+                req.requeues += 1
+                self.requeues += 1
+                requeue.append(req)
+        self._pending.extendleft(reversed(requeue))
+
+    def _dispatch_admit(self, batch: list[tuple],
+                        any_shared: bool) -> Optional[Exception]:
+        """One admission call for ``batch`` (``_admit_round``'s entries
+        ``(slot_idx, req, blocks, shared_toks)``): packed when the round is
+        fresh on one chip, else the row program — the chunked one if any
+        lane shares a cached prefix.  Returns the exception if the dispatch
+        failed (nothing of ``batch`` has then reached the device or a slot),
+        else None."""
+        ec = self.ecfg
+        packed = self._packed_prefill and not any_shared
+        suffix = [len(r.prompt_ids) - st for _, r, _, st in batch]
+        if packed:
+            # ``start`` is each segment's offset in the stream; an idle
+            # row's is the end of the real tokens.
+            P, W = ec.max_prefills_per_step, 0
+            padded = self._token_rung(sum(suffix))
+            bucket = min(padded, ec.prefill_buckets[-1])  # the row view's
+            (_, start, lengths, tables, idx,
+             temp, topk, topp) = self._lane_buffers(P, 0)
+            tokens = np.zeros((padded,), np.int32)
+            start[:] = sum(suffix)
+        else:
+            P = self._lane_count(len(batch))
+            bucket = self._bucket(max(suffix))
+            padded = bucket * P
+            # The chunked program (taken when any lane shares a cached
+            # prefix) gathers table_width * block_size keys per lane; narrow
+            # it to the deepest prompt.  The dense program never gathers —
+            # full width there avoids extra compile shapes.
+            W = (self._table_width(max(len(r.prompt_ids)
+                                       for _, r, _, _ in batch))
+                 if any_shared else 0)
+            (tokens, start, lengths, tables, idx,
+             temp, topk, topp) = self._lane_buffers(P, bucket, W)
         fstate = np.zeros((P,), np.int32)
+        at = 0
         for j, (slot_idx, req, blocks, st) in enumerate(batch):
             L = len(req.prompt_ids)
             if req.orig_prompt_len < 0:
                 req.orig_prompt_len = L
-            tokens[j, : L - st] = req.prompt_ids[st:]
-            start[j] = st
+            if packed:
+                tokens[at:at + L] = req.prompt_ids
+                start[j] = at
+                at += L
+            else:
+                tokens[j, : L - st] = req.prompt_ids[st:]
+                start[j] = st
             lengths[j] = L - st
             # blocks may cover L+1 tokens (the first decode write); the
             # prefill only reads/writes positions < L, so truncating to the
@@ -2699,8 +2815,9 @@ class InferenceEngine:
         constrained = any(r.sampling.constrained for _, r, _, _ in batch)
         fnext = None
         program = _calling.program = (
-            f"prefill{'_chunk' if any_shared else ''}_b{bucket}_r{P}"
-            + (f"_w{W}" if any_shared else "")
+            (f"prefill_t{padded}" if packed else
+             f"prefill{'_chunk' if any_shared else ''}_b{bucket}_r{P}"
+             + (f"_w{W}" if any_shared else ""))
             + ("_sample_fsm" if constrained
                else "_greedy" if all_greedy else "_sample"))
         device_empty = self._device_empty()
@@ -2708,23 +2825,26 @@ class InferenceEngine:
             self._faults.maybe_raise("prefill_dispatch")
             self.in_program_call = True
             if not any_shared:
+                # (offset, lengths) of a packed stream; (lengths,) of rows.
+                seg = ((jnp.asarray(start), jnp.asarray(lengths)) if packed
+                       else (jnp.asarray(lengths),))
                 if constrained:
                     self._rng, sub = jax.random.split(self._rng)
                     first, fnext, self.pages = self._prefill_sample_fsm(
-                        self.params, self._tokens_to_device(tokens), jnp.asarray(lengths),
+                        self.params, self._tokens_to_device(tokens), seg,
                         self.pages, jnp.asarray(tables), jnp.asarray(fstate),
                         self._fsm_trans, jnp.asarray(temp),
                         jnp.asarray(topk), jnp.asarray(topp), sub,
                     )
                 elif all_greedy:
                     first, self.pages = self._prefill_greedy(
-                        self.params, self._tokens_to_device(tokens), jnp.asarray(lengths),
+                        self.params, self._tokens_to_device(tokens), seg,
                         self.pages, jnp.asarray(tables),
                     )
                 else:
                     self._rng, sub = jax.random.split(self._rng)
                     first, self.pages = self._prefill_sample(
-                        self.params, self._tokens_to_device(tokens), jnp.asarray(lengths),
+                        self.params, self._tokens_to_device(tokens), seg,
                         self.pages, jnp.asarray(tables), jnp.asarray(temp),
                         jnp.asarray(topk), jnp.asarray(topp), sub,
                     )
@@ -2752,24 +2872,8 @@ class InferenceEngine:
                         jnp.asarray(topp), sub,
                     )
         except Exception as exc:
-            # Host state is still pre-dispatch (no slot occupied, no pages
-            # registered): release this round's pages and requeue the
-            # candidates — bounded, so a deterministic dispatch failure
-            # eventually surfaces to callers instead of spinning.
             self._record_dispatch_failure(exc)
-            requeue: list[GenerationRequest] = []
-            for _, req, blocks, _ in batch:
-                self.allocator.free(blocks)
-                if req.requeues >= self.ecfg.max_requeues:
-                    self._fail_request(
-                        req, f"prefill dispatch failed: {exc} "
-                             f"(gave up after {req.requeues} requeues)")
-                else:
-                    req.requeues += 1
-                    self.requeues += 1
-                    requeue.append(req)
-            self._pending.extendleft(reversed(requeue))
-            return admitted_long > 0
+            return exc
         finally:
             self.in_program_call = False
             self.last_program_call = time.monotonic()
@@ -2788,12 +2892,11 @@ class InferenceEngine:
                         None if all_greedy and not constrained else
                         any(r.sampling.filtered for _, r, _, _ in batch)),
                     "bucket": bucket, "rows": P, "prompts": len(batch),
-                    "real_tokens": sum(len(r.prompt_ids)
-                                       for _, r, _, _ in batch) - cached,
-                    "padded_tokens": bucket * P, "cached_tokens": cached,
-                    "shared": int(any_shared)},
+                    "real_tokens": sum(suffix), "padded_tokens": padded,
+                    "cached_tokens": cached, "shared": int(any_shared),
+                    "packed": int(packed)},
             program=program, device_empty=device_empty)
-        return True
+        return None
 
     def _dispatch_prefill_chunks(self) -> bool:
         """One batched chunk round for slots in prefilling state.
@@ -2953,7 +3056,7 @@ class InferenceEngine:
                     else any(r.sampling.filtered for _, _, r in lanes)),
                 bucket=bucket, rows=P,
                 prompts=len(cands), real_tokens=sum(n for _, n, _ in muts),
-                padded_tokens=bucket * P, cached_tokens=cached))
+                padded_tokens=bucket * P, cached_tokens=cached, packed=0))
         return True
 
     def _queue_inflight(self, kind: str, first, idx, lanes,

@@ -327,3 +327,101 @@ def test_grouped_expert_product_is_a_kernel_over_the_chosen(one_chip, rows):
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.cost_analysis()["flops"] == 2.0 * rows * H_ * I_
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- PR 27: a fresh admission call's packed stream ---------------------------
+
+
+@pytest.mark.parametrize("tokens", [256, 2048, 8192])
+@pytest.mark.parametrize("pool", [None, jnp.int8], ids=["bf16", "int8"])
+def test_flash_prefill_packed_compiles(one_chip, tokens, pool):
+    """The flash kernel over a packed stream's tiles (8 segments, the cell's
+    table of 96 blocks): tiles and their sequences come from prefetched
+    arrays, the q block from a [KVH, NT*TQ, qpk*D] stream."""
+    S = one_chip
+    args = [S((tokens, H, D), BF16), S((NBLK, BS, F), pool or BF16),
+            S((NBLK, BS, F), pool or BF16), S((PLANES, 96), I32),
+            S((PLANES,), I32), S((PLANES,), I32)]
+    if pool is None:
+        _compile(pa.flash_prefill_attention_packed, *args)
+    else:
+        _compile(lambda q, k, v, t, o, n, ks, vs:
+                 pa.flash_prefill_attention_packed(
+                     q, k, v, t, o, n, k_scale=ks, v_scale=vs),
+                 *args, S((NBLK, BS, KVH), F32), S((NBLK, BS, KVH), F32))
+
+
+@pytest.mark.parametrize("tokens", [1024, 16384])
+def test_latent_prefill_packed_compiles(one_chip, tokens):
+    """The latent kernel over a packed stream's blocks of 512 (4 segments of
+    at most 4,096 tokens)."""
+    S = one_chip
+    nH, dk, dv = KCFG.num_heads, KCFG.qk_head_dim, KCFG.v_head_dim
+    _compile(functools.partial(pa.latent_prefill_attention_packed,
+                               scale=dk ** -0.5,
+                               row_len=min(tokens, 4096)),
+             S((tokens, nH, dk), BF16), S((tokens, nH, dk), BF16),
+             S((tokens, nH, dv), BF16), S((4,), I32), S((4,), I32))
+
+
+# The largest temporaries of the row programs this PR's packed ones replace
+# (8 x 1,024 and 4 x 4,096), compiled the same way on the parent commit.
+ROW_TEMPS = {"qwen2-7b-w8a8": 708_665_856,
+             "kanana2-30b-a3b-w8a8": 2_443_907_072}
+
+
+@pytest.mark.parametrize("config,tokens,layers", [
+    ("qwen2-7b-w8a8", 1024, 2), ("qwen2-7b-w8a8", 8192, None),
+    ("kanana2-30b-a3b-w8a8", 16384, None)],
+    ids=["qwen2-t1024-2-layers", "qwen2-t8192", "kanana-t16384"])
+def test_packed_prefill_program_compiles(one_chip, config, tokens, layers):
+    """The sampled fresh-prefill program in its packed form
+    (serving/engine.py:_prefill_sample_fn), whole, at the cells' engines
+    (benchmarks/configs) and their smallest and largest rungs: the chip's
+    compiler takes it, its kernels are in it, and its temporaries leave the
+    pool rule (PERF.md section 4: pool <= bytes_limit - weights -
+    temporaries - 1 GiB) standing — within a twentieth of the row program's
+    it replaces."""
+    import dataclasses
+    import json
+    import pathlib
+
+    from k8s_llm_monitor_tpu.models import llama
+    from k8s_llm_monitor_tpu.serving.engine import EngineConfig
+    from k8s_llm_monitor_tpu.utils.quantize import init_params_quantized
+
+    S = one_chip
+    cell = json.loads((pathlib.Path(__file__).parents[1] / "benchmarks"
+                       / "configs" / f"{config}.json").read_text())
+    ec = EngineConfig(**cell["assumed"]["engine"])
+    R, W, top = (ec.max_prefills_per_step, ec.max_blocks_per_seq,
+                 ec.prefill_buckets[-1])
+    cfg = dataclasses.replace(PRESETS[cell["preset"]], act_quant=True)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    on_chip = functools.partial(jax.tree.map, lambda x: S(x.shape, x.dtype))
+    params = on_chip(jax.eval_shape(
+        lambda key: init_params_quantized(key, cfg), jax.random.PRNGKey(0)))
+    pages = on_chip(jax.eval_shape(
+        lambda: llama.init_kv_pages(cfg, ec.num_blocks, ec.block_size)))
+    impl = ops.select_prefill_impl("tpu", cfg=cfg)
+
+    def program(params, toks, seg, pages, tables, temp, topk, topp, key):
+        stats = [] if cfg.expert_layers else None
+        logits, pages = llama.prefill_packed(
+            params, cfg, toks, *seg, pages, tables,
+            row_len=min(tokens, top), attn_impl=impl, moe_stats=stats)
+        return sample_tokens(key, logits, temperature=temp, top_k=topk,
+                             top_p=topp), pages, stats
+
+    compiled = jax.jit(program, donate_argnums=(3,)).lower(
+        params, S((tokens,), I32), (S((R,), I32), S((R,), I32)), pages,
+        S((R, W), I32), S((R,), F32), S((R,), I32), S((R,), F32),
+        S((2,), jnp.uint32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= cfg.num_layers
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    if not layers:
+        rule = cell["assumed"]["pool_reckoning"]
+        assert temps <= (rule["bytes_limit"] - rule["weights_bytes"]
+                         - rule["pool_bytes"] - (1 << 30)), temps
+        assert temps <= 1.05 * ROW_TEMPS[config], temps

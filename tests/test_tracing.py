@@ -802,10 +802,13 @@ def _loop_case_prompt_tokens_are_all_accounted_for(rec):
               if c["attrs"]["kind"] in ("admit", "chunk")]
     assert (sum(a["real_tokens"] + a["cached_tokens"] for a in admits)
             == sum(len(p) for p in LOOP_PROMPTS))
-    for a in admits:
-        assert a["real_tokens"] <= a["padded_tokens"] == a["bucket"] * a["rows"]
-        assert a["prompts"] <= a["rows"]
-        assert a["program"].startswith(f"prefill_b{a['bucket']}_r{a['rows']}_")
+    for a in admits:     # fresh rounds on one chip: packed calls of T tokens
+        assert a["packed"] == 1
+        assert a["real_tokens"] <= a["padded_tokens"] < 2 * max(
+            a["real_tokens"], 24)
+        assert a["prompts"] <= a["rows"] == LOOP_ECFG["max_prefills_per_step"]
+        assert a["bucket"] == min(a["padded_tokens"], 24)
+        assert a["program"].startswith(f"prefill_t{a['padded_tokens']}_")
 
 
 def _loop_case_calls_are_numbered_and_say_what_they_found(rec):
@@ -829,7 +832,7 @@ def _loop_case_a_new_shape_names_its_compile_and_its_phase(rec):
                 if s["attrs"].get("program")]
     by_program = {s["attrs"]["program"]: phases.get(s["parent_id"])
                   for s in compiles}
-    assert by_program.get("prefill_b24_r4_greedy") == "engine.step.admit"
+    assert by_program.get("prefill_t48_greedy") == "engine.step.admit"
     assert by_program.get("decode_k4_greedy") == "engine.step.decode"
     assert all(s["attrs"]["seconds"] > 0 for s in compiles)
 
@@ -1100,10 +1103,12 @@ def lowered_programs(params):
         eng.params, eng._tok_state, zi, zi, eng.pages,
         jnp.zeros((B, W), jnp.int32), jnp.ones((B,)), zi, jnp.ones((B,)),
         jax.random.PRNGKey(0), jnp.asarray(-1, jnp.int32))
-    prefill = eng._prefill_sample.lower(
-        eng.params, jnp.zeros((1, 16), jnp.int32), jnp.ones((1,), jnp.int32),
-        eng.pages, jnp.zeros((1, W), jnp.int32), jnp.ones((1,)),
-        jnp.zeros((1,), jnp.int32), jnp.ones((1,)), jax.random.PRNGKey(0))
+    R = eng.ecfg.max_prefills_per_step
+    ri = jnp.zeros((R,), jnp.int32)
+    prefill = eng._prefill_sample.lower(     # a packed stream of 16 tokens
+        eng.params, jnp.zeros((16,), jnp.int32), (ri, ri),
+        eng.pages, jnp.zeros((R, W), jnp.int32), jnp.ones((R,)),
+        ri, jnp.ones((R,)), jax.random.PRNGKey(0))
     return {"decode": decode.as_text(debug_info=True),
             "prefill": prefill.as_text(debug_info=True)}
 
