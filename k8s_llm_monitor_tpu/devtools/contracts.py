@@ -22,13 +22,7 @@ sides and diffing them:
     literal histogram tables, and manual ``w.lines.append(f"{_PREFIX}_
     ...")`` samples) against the machine-parseable inventory table in
     ``docs/observability.md`` — both directions — plus every
-    ``k8s_llm_monitor_*`` token mentioned anywhere in the docs.  Bench
-    JSON keys cited in README.md/Makefile are verified against the keys
-    ``bench.py`` actually emits (literal dict keys and subscript stores;
-    f-string keys like ``prefill_speedup_{length}`` match as prefix
-    wildcards).  A doc token counts as a bench-key claim only when its
-    first two ``_``-segments match an emitted key family — identifiers
-    like ``slo_class`` never enter the contract.
+    ``k8s_llm_monitor_*`` token mentioned anywhere in the docs.
 
 ``env-contract``
     Every literal ``os.environ``/``os.getenv`` read of a project-
@@ -377,37 +371,7 @@ def extract_doc_metric_inventory(obs_text: str) -> dict[str, int]:
     return out
 
 
-def extract_bench_keys(src: str) -> tuple[set[str], set[str]]:
-    """(exact keys, f-string prefix wildcards) emitted by bench.py:
-    literal dict keys and literal subscript stores."""
-    tree = ast.parse(src)
-    exact: set[str] = set()
-    prefixes: set[str] = set()
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Dict):
-            for k in node.keys:
-                if isinstance(k, ast.Constant) and isinstance(k.value, str):
-                    exact.add(k.value)
-        elif isinstance(node, ast.Subscript) \
-                and isinstance(node.ctx, ast.Store):
-            sl = node.slice
-            if isinstance(sl, ast.Constant) and isinstance(sl.value, str):
-                exact.add(sl.value)
-            elif isinstance(sl, ast.JoinedStr) and sl.values and isinstance(
-                    sl.values[0], ast.Constant):
-                prefixes.add(str(sl.values[0].value))
-    return exact, prefixes
-
-
-def _bench_family(token: str) -> str:
-    return "_".join(token.split("_")[:2])
-
-
-_DOC_TOKEN_RE = re.compile(r"`([a-z][a-z0-9]*(?:_[a-z0-9*]+)+)\*?`|"
-                           r"\b([a-z][a-z0-9]*(?:_[a-z0-9]+)+_\*)")
-
-
-def check_metrics(exporter_src: str, obs_text: str, bench_src: str,
+def check_metrics(exporter_src: str, obs_text: str,
                   doc_texts: dict[str, str],
                   exporter_path: str =
                   "k8s_llm_monitor_tpu/monitor/exporter.py",
@@ -446,37 +410,6 @@ def check_metrics(exporter_src: str, obs_text: str, bench_src: str,
                         message=(f"doc mentions metric "
                                  f"'{METRIC_PREFIX}_{m.group(1)}' which "
                                  f"the exporter never emits")))
-    # bench-JSON keys cited in README/Makefile
-    exact, prefixes = extract_bench_keys(bench_src)
-    families = ({_bench_family(k) for k in exact}
-                | {_bench_family(p) for p in prefixes})
-    for file, text in doc_texts.items():
-        if not (file.endswith("README.md") or file.endswith("Makefile")):
-            continue
-        for lineno, line in enumerate(text.splitlines(), start=1):
-            for m in _DOC_TOKEN_RE.finditer(line):
-                token = (m.group(1) or m.group(2)).rstrip("*").rstrip("_")
-                wildcard = (m.group(0).rstrip("`").endswith("*"))
-                if _bench_family(token) not in families:
-                    continue  # not a bench-key claim
-                if not wildcard and token.count("_") < 2:
-                    continue  # 2-segment tokens (slo_class) are too
-                    # generic to be a bench-key claim
-                if _collapse_family(token) in emitted:
-                    continue  # exporter metric name, not a bench key
-                if wildcard:
-                    ok = any(k.startswith(token) for k in exact) or \
-                        any(p.startswith(token) or token.startswith(p)
-                            for p in prefixes)
-                else:
-                    ok = token in exact or \
-                        any(token.startswith(p) for p in prefixes)
-                if not ok:
-                    findings.append(Finding(
-                        path=file, line=lineno, col=0,
-                        rule="metrics-contract",
-                        message=(f"doc cites bench key '{token}' which "
-                                 f"bench.py never emits")))
     return findings
 
 
@@ -722,11 +655,8 @@ def run_contracts(repo_root: Path,
             {f: t for f, t in docs.items() if f.endswith(".md")}))
     if "metrics-contract" in wanted:
         obs = docs.get("docs/observability.md", "")
-        bench = (repo_root / "bench.py")
         findings.extend(check_metrics(
-            py_sources[f"{PACKAGE}/monitor/exporter.py"], obs,
-            bench.read_text(encoding="utf-8") if bench.is_file() else "",
-            docs))
+            py_sources[f"{PACKAGE}/monitor/exporter.py"], obs, docs))
     if "env-contract" in wanted:
         findings.extend(check_env(
             py_sources, py_sources[f"{PACKAGE}/monitor/config.py"],

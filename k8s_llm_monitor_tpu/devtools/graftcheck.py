@@ -87,8 +87,8 @@ def main(argv: list[str] | None = None) -> int:
               "(--dataflow)")
         print("route-contract: routes registered vs documented, both "
               "directions (--contracts)")
-        print("metrics-contract: exporter families vs docs inventory vs "
-              "bench keys (--contracts)")
+        print("metrics-contract: exporter families vs docs inventory "
+              "(--contracts)")
         print("env-contract: env reads vs ENV_KEYS registry vs docs "
               "(--contracts)")
         assert set(dataflow.DATAFLOW_RULE_NAMES) <= {

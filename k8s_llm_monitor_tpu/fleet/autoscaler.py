@@ -9,8 +9,8 @@ probe rows), and executes scale decisions through a pluggable executor:
 * ``KubeScaleExecutor`` — per-role StatefulSet ``/scale`` subresources
   through the hardened kube client (retry budget + breaker, PR 2
   semantics), dry-run-first so a malformed patch never hits the fleet.
-* ``LocalPoolExecutor`` — an in-process ``LocalReplica`` pool for tests,
-  bench, and the single-binary dev mode: scale-up spawns a replica via a
+* ``LocalPoolExecutor`` — an in-process ``LocalReplica`` pool for tests
+  and the single-binary dev mode: scale-up spawns a replica via a
   factory and registers it; scale-down *drains* the newest replica of the
   role (the router stops dispatching to it, in-flight streams finish) and
   ``reap()`` removes it once idle.  The whole loop is chaos-testable

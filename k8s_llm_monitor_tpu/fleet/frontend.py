@@ -167,7 +167,7 @@ def build_router_server(config, web_dir=None):
 def _build_autoscaler(config, registry, signals):
     """Controller over the kube scale executor (StatefulSet /scale through
     the hardened client).  Returns None — autoscaling disabled, router
-    unaffected — when no in-cluster credentials exist (dev/bench fleets
+    unaffected — when no in-cluster credentials exist (dev fleets
     drive a ``LocalPoolExecutor`` directly instead)."""
     from k8s_llm_monitor_tpu.fleet.autoscaler import (AutoscaleController,
                                                       KubeScaleExecutor)

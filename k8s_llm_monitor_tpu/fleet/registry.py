@@ -7,12 +7,12 @@ said (queue tokens, busy slots, prefix-cache hit rate — the weighted
 least-loaded signal), how many router-side requests are in flight on each,
 and each replica's ``CircuitBreaker`` state.
 
-Probing is pull-based: ``refresh()`` polls every replica once (tests and
-the bench call it synchronously); ``start_probes()`` runs the same poll on
-a background thread for the server role.  A probe failure marks the
-replica unready and records a breaker failure — the breaker, not the probe
-loop, decides when to start trusting the replica again (half-open trial on
-the next dispatch after the cooldown).
+Probing is pull-based: ``refresh()`` polls every replica once (tests call
+it synchronously); ``start_probes()`` runs the same poll on a background
+thread for the server role.  A probe failure marks the replica unready
+and records a breaker failure — the breaker, not the probe loop, decides
+when to start trusting the replica again (half-open trial on the next
+dispatch after the cooldown).
 """
 
 from __future__ import annotations

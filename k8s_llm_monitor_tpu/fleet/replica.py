@@ -1,7 +1,7 @@
 """Replica adapters: one interface, two transports.
 
 ``LocalReplica`` wraps an in-process ``EngineService`` (or a supervised
-``EngineSupervisor``) so tests and the bench can run a 2–4 replica fleet in
+``EngineSupervisor``) so tests can run a 2–4 replica fleet in
 one CPU process — it speaks the token-level generation interface the
 router's failover/hedging machinery needs (``generate`` → ``RequestHandle``).
 

@@ -191,7 +191,7 @@ POLICIES = {
 class HedgeConfig:
     enabled: bool = False
     min_delay_s: float = 0.05     # floor: never hedge faster than this
-    fixed_delay_s: float = 0.0    # >0 pins the delay (bench/tests)
+    fixed_delay_s: float = 0.0    # >0 pins the delay (tests)
     p95_mult: float = 3.0         # k in delay = ttft_ema + k * dev_ema
     cold_delay_s: float = 0.5     # before any TTFT sample exists
 

@@ -468,7 +468,7 @@ class LocalEngineBackend(LLMBackend):
         if qmode == "w8a8":
             # s8 x s8 prefill on the MXU int8 path (measured ~1.4x prefill rate
             # and the only mode meeting every short-leg SLO);
-            # see utils/quantize.py and the bench's W8A8 legs.
+            # see utils/quantize.py.
             import dataclasses as _dc
 
             cfg = _dc.replace(cfg, act_quant=True)

@@ -32,11 +32,8 @@ import yaml
 #: contract checker enforces: every explicit read is registered, every
 #: entry is read and documented, every target exists.
 ENV_KEYS: dict[str, str] = {
-    # engine kernel-path overrides (env wins over EngineConfig)
+    # a deployment's choice of KV pool (env wins over EngineConfig)
     "K8SLLM_KV_DTYPE": "EngineConfig.kv_dtype",
-    "K8SLLM_PREFILL_PATH": "EngineConfig.prefill_path",
-    "K8SLLM_DECODE_PATH": "EngineConfig.decode_path",
-    "K8SLLM_TP_OVERLAP": "EngineConfig.tp_overlap",
     # reference-compat aliases (config.go:172-182)
     "OPENAI_API_KEY": "LLMConfig.api_key",
     "OPENAI_BASE_URL": "LLMConfig.base_url",
@@ -256,7 +253,7 @@ class FleetConfig:
     max_failovers: int = 2
     # Hedged dispatch: fire a second replica when the first shows no token
     # after the EMA-p95 TTFT delay (docs/fleet.md).  fixed_delay_s > 0
-    # pins the delay (bench/tests); 0 uses the online estimate.
+    # pins the delay (tests); 0 uses the online estimate.
     hedge_enabled: bool = False
     hedge_min_delay_s: float = 0.05
     hedge_fixed_delay_s: float = 0.0

@@ -278,42 +278,16 @@ def _engine_metrics(w: _Writer, engine) -> None:
                  "Serving mesh axis sizes (data/seq/model)",
                  [(f'{{axis="{a}"}}', int(n))
                   for a, n in sorted(axes.items())])
-        # The two ICI estimates are absent (not 0) on a device kind the
-        # bandwidth tables in parallel/mesh.py do not list.
-        coll = getattr(engine, "decode_collective_share", None)
-        if coll is not None:
-            w.metric("engine_decode_collective_share", "gauge",
-                     "Estimated ICI (collective) share of a TP decode "
-                     "step, from the decode profile's byte model; 0 until "
-                     "profile_decode_phases() has run",
-                     [("", round(coll, 4))])
         w.metric("engine_tp_overlap", "gauge",
                  "1 when the hand-staged reduce-scatter/all-gather decode "
                  "schedule is active (parallel/overlap.py); 0 = GSPMD "
                  "reference program",
                  [("", 1 if getattr(engine, "tp_overlap", False) else 0)])
-        hidden = getattr(engine, "decode_collective_hidden_share", None)
-        if hidden is not None:
-            w.metric("engine_decode_collective_hidden_share", "gauge",
-                     "Fraction of the per-step ring wire time the overlap "
-                     "schedule hides under compute; 0 until "
-                     "estimate_hidden_share() has run",
-                     [("", round(hidden, 4))])
 
-    # Decode-step phase attribution (fused fast-path observability).
-    # attn/sample are populated by engine.profile_decode_phases() — a
-    # bench/admin probe, never run on scrape — so they read 0.0 until a
-    # profile has run.
     path = getattr(engine, "decode_path", "unknown")
     w.metric("engine_decode_path_info", "gauge",
              "Selected decode attention path (1 = active)",
              [(f'{{path="{path}"}}', 1)])
-    w.metric("engine_decode_attn_ms", "gauge",
-             "Profiled per-step paged-attention cost at long context",
-             [("", round(getattr(engine, "decode_attn_ms", 0.0), 4))])
-    w.metric("engine_decode_sample_ms", "gauge",
-             "Profiled per-step on-device sampling cost",
-             [("", round(getattr(engine, "decode_sample_ms", 0.0), 4))])
 
     # Prefill fast-path attribution: which path the engine selected
     # (flash paged-prefill kernel vs dense XLA) and which bucket sizes
@@ -331,19 +305,6 @@ def _engine_metrics(w: _Writer, engine) -> None:
                  "and chunk rounds)",
                  [(f'{{bucket="{b}"}}', n)
                   for b, n in sorted(bucket_rounds.items())])
-
-    # Prometheus histogram: cumulative buckets + sum + count.
-    cumulative = 0
-    samples = []
-    for le, n in zip(engine.ttft_buckets, engine.ttft_counts):
-        cumulative += n
-        samples.append((f'_bucket{{le="{le}"}}', cumulative))
-    cumulative += engine.ttft_counts[-1]
-    samples.append(('_bucket{le="+Inf"}', cumulative))
-    w.metric("engine_ttft_seconds", "histogram",
-             "Time to first token per request", samples)
-    w.lines.append(f"{_PREFIX}_engine_ttft_seconds_sum {engine.ttft_sum}")
-    w.lines.append(f"{_PREFIX}_engine_ttft_seconds_count {engine.ttft_count}")
 
 
 def _loop_metrics(w: _Writer, engine) -> None:
@@ -421,10 +382,6 @@ def _latency_histograms(w: _Writer, engine) -> None:
         ("request_queue_wait_seconds",
          "Queue wait before admission per request, by SLO class",
          getattr(engine, "hist_queue_wait", None)),
-        ("decode_step_seconds",
-         "Per-token decode segment time (segment wall time / steps), "
-         "by SLO class",
-         getattr(engine, "hist_decode_step", None)),
     )
     for name, help_, hist in hists:
         if hist is not None:

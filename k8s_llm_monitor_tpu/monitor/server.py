@@ -650,21 +650,7 @@ def _make_handler(srv: MonitorServer) -> type[BaseHTTPRequestHandler]:
             jax.profiler.start_trace(trace_dir)
             _time.sleep(seconds)
             jax.profiler.stop_trace()
-            payload: dict[str, Any] = {
-                "trace_dir": trace_dir, "seconds": seconds}
-            if body.get("decode_phases"):
-                # Refresh the per-phase decode cost split (and the
-                # engine_decode_* gauges + collective_share span
-                # attribute that ride on it) behind the same debug gate.
-                # Requires an idle engine; refusal is reported, not fatal.
-                try:
-                    payload["decode_phases"] = self._engine_call(
-                        lambda e: e.profile_decode_phases())
-                except LookupError:
-                    payload["decode_phases_error"] = "no local engine"
-                except Exception as exc:  # noqa: BLE001 — busy engine
-                    payload["decode_phases_error"] = str(exc)
-            self._send_json(payload)
+            self._send_json({"trace_dir": trace_dir, "seconds": seconds})
 
         def h_cluster_status(self) -> None:
             if srv.client is None:

@@ -81,7 +81,7 @@ class ClassHistogram:
 
     def quantile(self, slo_class: str, q: float) -> Optional[float]:
         """Linear-interpolated quantile estimate from bucket counts
-        (bench assertions; None with no data)."""
+        (None with no data)."""
         cum, _, count, _ = self.series(slo_class)
         if count == 0:
             return None

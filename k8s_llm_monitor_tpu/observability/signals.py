@@ -68,8 +68,8 @@ class SignalScraper:
 
     Construction order: the scraper is built before the ``MonitorServer``
     that owns it, so the server is wired in afterwards via ``attach()``.
-    ``scrape_once()`` is the synchronous seam tests and the bench drive
-    directly; ``start()`` runs it on a daemon thread every
+    ``scrape_once()`` is the synchronous seam tests drive directly;
+    ``start()`` runs it on a daemon thread every
     ``cfg.scrape_interval_s``.
     """
 

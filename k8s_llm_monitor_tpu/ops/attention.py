@@ -516,15 +516,15 @@ def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,
                        mode: str = "auto", kv_quant: str = ""):
     """Pick the decode-step attention path, including the fused fast-path.
 
-    ``mode`` (EngineConfig.decode_path / K8SLLM_DECODE_PATH env):
+    ``mode`` (EngineConfig.decode_path):
       * ``"auto"``   — the fused RoPE+append+attention kernel
         (ops/pallas_attention.py:paged_decode_attention_fused) on a
         single TPU chip when the model passes the geometry gate;
         otherwise whatever ``select_attn_impl`` picks.
       * ``"fused"``  — force the fused kernel (interpreter off-TPU; used
-        by parity tests and the bench's fused leg).  Raises if the model
-        can't take it (extras models, odd head_dim) rather than silently
-        falling back — the caller asked for a specific path.
+        by parity tests).  Raises if the model can't take it (extras
+        models, odd head_dim) rather than silently falling back — the
+        caller asked for a specific path.
       * ``"gather"`` — force the XLA gather fallback (the numerics
         oracle; also what the fused path is diffed against in tests).
       * ``"pallas"`` — force the split kernel pipeline (Pallas attention
@@ -672,13 +672,13 @@ def select_prefill_impl(platform: str | None = None, cfg=None, mesh=None,
                         mode: str = "auto", kv_quant: str = ""):
     """Pick the prefill-family attention path (fresh / chunk / verify).
 
-    ``mode`` (EngineConfig.prefill_path / K8SLLM_PREFILL_PATH env):
+    ``mode`` (EngineConfig.prefill_path):
       * ``"auto"``  — the flash paged-prefill kernel
         (ops/pallas_attention.py:flash_prefill_attention) on TPU when the
         geometry passes; the dense XLA path everywhere else.
-      * ``"flash"`` — force the kernel (interpreter off-TPU; parity tests,
-        traceguard, and the bench's flash legs).  Raises when the model or
-        mesh can't take it rather than silently falling back.
+      * ``"flash"`` — force the kernel (interpreter off-TPU; parity tests
+        and traceguard).  Raises when the model or mesh can't take it
+        rather than silently falling back.
       * ``"dense"`` — force the dense XLA oracle: in-flight
         ``causal_attention`` for fresh prefill, ``gather_pages`` + dense
         attention for chunks and verify.

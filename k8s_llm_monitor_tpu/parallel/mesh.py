@@ -20,53 +20,6 @@ from jax.sharding import Mesh
 
 AXES = ("data", "seq", "model")
 
-# Approximate aggregate ICI bandwidth per chip (GB/s, all links, one
-# direction), keyed by substrings of jax Device.device_kind — the byte-model
-# input for the engine's per-step collective-time share estimate.  A device
-# outside the table (the CPU test meshes included) has no figure: the
-# lookups return None and the engine publishes no estimate for it.
-ICI_GBS = {
-    "v5 lite": 200.0,   # v5e: 4 links x 400 Gbps
-    "v5e": 200.0,
-    "v5p": 600.0,
-    "v4": 300.0,
-    "v6": 448.0,        # v6e (Trillium)
-}
-
-
-def _lookup_gbs(table: dict[str, float], device_kind: str) -> float | None:
-    kind = device_kind.lower()
-    for key, gbs in table.items():
-        if key in kind:
-            return gbs
-    return None
-
-
-def ici_bandwidth_gbs(device_kind: str) -> float | None:
-    """Per-chip aggregate ICI bandwidth for ``device_kind`` (GB/s); None
-    for a device that is not in the table."""
-    return _lookup_gbs(ICI_GBS, device_kind)
-
-
-# Per-chip HBM bandwidth (GB/s), same keying as ICI_GBS.  Paired with it
-# in the overlap decode model (serving/engine.py:estimate_hidden_share):
-# decode is weight-streaming bound, so the window available to hide a
-# reduce-scatter/all-gather half under the next column-parallel matmul is
-# the time that matmul spends streaming its weight shard from HBM.
-HBM_GBS = {
-    "v5 lite": 819.0,
-    "v5e": 819.0,
-    "v5p": 2765.0,
-    "v4": 1228.0,
-    "v6": 1640.0,       # v6e (Trillium)
-}
-
-
-def hbm_bandwidth_gbs(device_kind: str) -> float | None:
-    """Per-chip HBM bandwidth for ``device_kind`` (GB/s); None for a
-    device that is not in the table."""
-    return _lookup_gbs(HBM_GBS, device_kind)
-
 
 def init_multihost(coordinator: str | None = None,
                    num_processes: int | None = None,

@@ -3,10 +3,9 @@
 The GSPMD decode path annotates o/down projections row-parallel and lets
 XLA insert a blocking ring all-reduce after each one — 2 per layer, each
 serializing ``2*(tp-1)/tp`` of a [B, H] activation over ICI before the
-next matmul may start (the ``engine_decode_collective_share`` model,
-PR 7).  This module replaces that schedule for the per-layer decode loop
-with an explicit ``shard_map`` program that keeps the residual stream
-REDUCE-SCATTERED between sub-blocks:
+next matmul may start.  This module replaces that schedule for the
+per-layer decode loop with an explicit ``shard_map`` program that keeps
+the residual stream REDUCE-SCATTERED between sub-blocks:
 
     per layer (all shard-local unless marked):
       x_full   = all_gather(x_scat)                   <- AG half
@@ -32,8 +31,8 @@ reduce-scatter + all-gather, each half lowers to an async
 collective-start/done pair, and XLA's latency-hiding scheduler hoists the
 data-independent weight prefetch (and the page-scatter DMAs) between
 start and done.  Decode is weight-streaming bound, so that window is
-normally larger than the wire time (``estimate_hidden_share``'s byte
-model: v5e-8 / 8B streams ~18 MB/layer against ~1 MB/layer of wire).
+expected to be larger than the wire time; how much of it is hidden on a
+chip is the device trace's to say (not measured).
 
 Exactness vs the GSPMD reference (the parity tests in
 tests/test_overlap.py prove byte-identical greedy tokens):
@@ -58,9 +57,8 @@ per layer, and keeping them on the reference path removes two parity
 surfaces for free.
 
 Flag-selectable exactly like the PR 1 decode-path oracle:
-``EngineConfig.tp_overlap`` / ``K8SLLM_TP_OVERLAP`` ("auto" | "on" |
-"off"), with the GSPMD program kept as the always-available correctness
-reference.
+``EngineConfig.tp_overlap`` ("auto" | "on" | "off"), with the GSPMD
+program kept as the always-available correctness reference.
 """
 
 from __future__ import annotations

@@ -12,8 +12,8 @@ The layout is factored two ways (SNIPPETS.md [2]/[3]):
 
   * ``SpecLayout`` — a frozen dataclass with one method per parameter
     *role* (embedding, column/row projection, expert stack, norm).  It is
-    the single place the axis names live; serving, tests, and the bench
-    all derive their ``NamedSharding``s from it.
+    the single place the axis names live; serving and the tests
+    derive their ``NamedSharding``s from it.
   * ``partition_rules()`` — the role methods bound to param-path regexes
     (the ``match_partition_rules`` idiom), so a checkpoint pytree maps to
     specs by name without the model code knowing about meshes.

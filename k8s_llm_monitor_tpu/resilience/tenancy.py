@@ -352,8 +352,8 @@ class TenantGovernor:
             return res.tenant if res is not None else None
 
     def charged_tokens(self, tenant: str) -> int:
-        """Settled (delivered) tokens for a tenant — the bench's exactness
-        probe: after all streams settle this equals tokens received."""
+        """Settled (delivered) tokens for a tenant: after all streams
+        settle this equals tokens received."""
         with self._lock:
             st = self._tenants.get(tenant)
             return st.charged_tokens if st is not None else 0

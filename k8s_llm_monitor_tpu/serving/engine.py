@@ -181,11 +181,11 @@ class GenerationResult:
 def prefill_bucket_for(n: int, buckets) -> int:
     """Smallest bucket in ``buckets`` covering ``n`` tokens — THE bucket
     rounding, shared by the engine's admission path (``_bucket``) and by
-    bench.py's engine-sizing math, so the two can't silently disagree
-    about which bucket a prompt lands in (they once computed it with
-    independent formulas).  ``buckets`` must be ascending; ``n`` past the
-    top bucket raises — longer prompts go through chunked prefill, never
-    silent clamping."""
+    the benchmark harness's warm-up (``benchmarks/harness/system.py``:
+    which buckets a cell's prompt lengths can reach), so the two can't
+    silently disagree about which bucket a prompt lands in.  ``buckets``
+    must be ascending; ``n`` past the top bucket raises — longer prompts
+    go through chunked prefill, never silent clamping."""
     for b in buckets:
         if n <= b:
             return b
@@ -215,14 +215,13 @@ class EngineConfig:
     # Decode attention path (ops/attention.py:select_decode_impl):
     # "auto" = the fused RoPE+append+attention Pallas kernel on a
     # compatible single TPU chip, split/gather otherwise; "fused",
-    # "pallas", "gather" force a path.  K8SLLM_DECODE_PATH overrides.
+    # "pallas", "gather" force a path.
     decode_path: str = "auto"
     # Prefill-family attention path (ops/attention.py:select_prefill_impl):
     # "auto" = the flash paged-prefill kernel (tiled online softmax reading
     # K/V straight from the pool) on a compatible TPU chip or mesh, the
     # dense XLA oracle otherwise; "flash"/"dense" force a path.  Serves
     # fresh prefill, continuation chunks, and spec verify alike.
-    # K8SLLM_PREFILL_PATH overrides.
     prefill_path: str = "auto"
     # Resident KV representation (serving/kv_tier.py rung 1): "auto" keeps
     # the model-dtype pool (the flag-selectable fp16/bf16 oracle, same
@@ -337,8 +336,7 @@ class EngineConfig:
     # overlap_supported() clears the (cfg, mesh) — byte-identical to the
     # GSPMD reference, with the per-layer wire time hidden under the next
     # sub-block's weight streaming.  "on": require it (ValueError when
-    # unsupported).  "off": always the GSPMD-auto psum program.  Env
-    # override: K8SLLM_TP_OVERLAP, same values.
+    # unsupported).  "off": always the GSPMD-auto psum program.
     tp_overlap: str = "auto"
     # --- tier-aware admission (ROADMAP item 2 / PR 9 ladder) ----------
     # What counts as KV headroom in should_shed()'s capacity clause:
@@ -434,10 +432,9 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                        "constrained"),
     "engine.prefill_chunk": ("request_id", "class", "bucket", "lanes",
                              "constrained"),
-    "engine.decode": ("request_id", "class", "steps", "emitted",
-                      "collective_share"),
+    "engine.decode": ("request_id", "class", "steps", "emitted"),
     "engine.spec_decode": ("request_id", "class", "steps", "emitted",
-                           "rounds", "collective_share"),
+                           "rounds"),
     "engine.preempt": ("request_id", "class", "tokens_folded"),
     "engine.requeue": ("request_id", "class", "cause", "requeues"),
     "engine.kv_spill": ("blocks",),
@@ -752,9 +749,7 @@ class InferenceEngine:
         if self._unbuilt:
             for what, asked in (
                     ("a mesh", mesh is not None),
-                    ("tp_overlap='on'",
-                     (os.environ.get("K8SLLM_TP_OVERLAP", ec.tp_overlap)
-                      or "auto") == "on"),
+                    ("tp_overlap='on'", ec.tp_overlap == "on"),
                     (f"kv_dtype={self.kv_quant!r}", bool(self.kv_quant)),
                     ("host_spill_bytes > 0 / a host KV tier",
                      ec.host_spill_bytes > 0 or host_kv_tier is not None),
@@ -768,10 +763,9 @@ class InferenceEngine:
         # it): the flash kernel's geometry gates live in
         # ops/attention.py:select_prefill_impl; None = dense XLA oracle.
         from k8s_llm_monitor_tpu.ops.attention import select_prefill_impl
-        pmode = os.environ.get("K8SLLM_PREFILL_PATH",
-                               ec.prefill_path) or "auto"
         self._prefill_attn = select_prefill_impl(
-            cfg=cfg, mesh=mesh, mode=pmode, kv_quant=self.kv_quant)
+            cfg=cfg, mesh=mesh, mode=ec.prefill_path,
+            kv_quant=self.kv_quant)
         self.prefill_path = ("flash" if self._prefill_attn is not None
                              else "dense")
         if self._prefill_attn is not None:
@@ -878,11 +872,11 @@ class InferenceEngine:
             # partitions automatically.  A quantized pool routes to the
             # fused-quant kernel or the gather/dequant reference
             # (select_decode_impl kv_quant gate).
-            mode = os.environ.get("K8SLLM_DECODE_PATH", ec.decode_path)
-            attn_impl = select_decode_impl(cfg=cfg, mesh=mesh, mode=mode,
+            attn_impl = select_decode_impl(cfg=cfg, mesh=mesh,
+                                           mode=ec.decode_path,
                                            kv_quant=self.kv_quant)
         self._attn_impl = attn_impl
-        # "fused" | "pallas" | "gather" — surfaced in /metrics and bench.
+        # "fused" | "pallas" | "gather" — surfaced in /metrics.
         if self.kv_quant and llama.is_fused_quant_decode_impl(attn_impl):
             self.decode_path = "fused"
         elif self.kv_quant:
@@ -904,8 +898,7 @@ class InferenceEngine:
         # in the traced layer body.
         self._overlap_step = None
         self.tp_overlap = False
-        overlap_mode = os.environ.get("K8SLLM_TP_OVERLAP",
-                                      ec.tp_overlap) or "auto"
+        overlap_mode = ec.tp_overlap
         if overlap_mode not in ("auto", "on", "off"):
             raise ValueError(
                 f"unknown tp_overlap {overlap_mode!r} (auto | on | off)")
@@ -928,12 +921,6 @@ class InferenceEngine:
             elif mesh is not None and mesh.shape.get("model", 1) > 1:
                 logger.warning("tp_overlap=auto: staying on the GSPMD "
                                "schedule (%s)", why_not)
-        # Measured share of the per-step ring time the overlap schedule
-        # hides; estimate_hidden_share() fills it from profile/bench runs
-        # and the exporter publishes it.  None (never published) when the
-        # mesh's device kind has no bandwidth figure (parallel/mesh.py).
-        self.decode_collective_hidden_share: Optional[float] = (
-            None if self._ring_ici_ms() is None else 0.0)
         # Multi-query attention for the speculative verify pass (Pallas
         # kernel on compatible single-chip TPU; XLA gather otherwise).
         # Quantized pools drop the dedicated verify kernel: llama's
@@ -1169,19 +1156,6 @@ class InferenceEngine:
         # classes that never carried traffic instead of mixing populations.
         self.slot_wait_ema_by_class: dict[str, float] = {}
         self.ttft_ema_by_class: dict[str, float] = {}
-        # TTFT histogram (Prometheus semantics: cumulative le buckets +
-        # sum/count), observed once per request at admission reconcile.
-        self.ttft_buckets: tuple[float, ...] = (
-            0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
-        self.ttft_counts = [0] * (len(self.ttft_buckets) + 1)  # +Inf last
-        self.ttft_sum = 0.0
-        self.ttft_count = 0
-        # Decode phase attribution (monitor/exporter.py gauges):
-        # decode_attn_ms / decode_sample_ms are the per-step attention /
-        # sampling cost, populated by profile_decode_phases() (bench or an
-        # admin probe); never computed on a /metrics scrape.
-        self.decode_attn_ms = 0.0
-        self.decode_sample_ms = 0.0
         # prefill_bucket_rounds counts dispatched rounds per bucket size,
         # so the signals plane can see which buckets production actually
         # runs (the 4096/8192 rungs exist only on the flash path).
@@ -1207,12 +1181,6 @@ class InferenceEngine:
         # that had at least one row, experts there were (per layer and step).
         self.moe_totals = {"assignments": 0, "experts_hit": 0,
                            "expert_slots": 0}
-        # Per-step collective (ICI) share of the TP decode step, estimated
-        # by profile_decode_phases() from the measured step time and the
-        # ring-all-reduce byte model; 0.0 off-mesh or before profiling,
-        # None for a device kind without an ICI figure.
-        self.decode_collective_share: Optional[float] = (
-            None if self._ring_ici_ms() is None else 0.0)
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
         # class, with exemplar trace ids, observed on the step thread only.
         # The exporter renders these as real Prometheus histograms.
@@ -1220,9 +1188,6 @@ class InferenceEngine:
         self.hist_ttft = ClassHistogram(_lat)
         self.hist_e2e = ClassHistogram(_lat)
         self.hist_queue_wait = ClassHistogram(_lat)
-        # Per fused-decode-step seconds (call wall time / steps in call).
-        self.hist_decode_step = ClassHistogram(
-            (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0))
         # Tracing (observability/tracing.py): phase spans are recorded
         # host-side at dispatch/reconcile time against each request's
         # captured TraceContext — never inside a traced program.  Engine
@@ -1551,8 +1516,8 @@ class InferenceEngine:
         the engine's programs held it (the prefix cache is not consulted
         then: a cached position's states were never computed).  A reference
         that runs ONE layer on the engine's own input to it
-        (``models/reference``) is compared layer by layer with these — where
-        whole-model logits of random weights are chaotic in the activation
+        (``benchmarks/references``) is compared layer by layer with these —
+        where whole-model logits of random weights are chaotic in the activation
         precision, one layer is not.
         """
         self._reconcile_all()
@@ -2475,7 +2440,7 @@ class InferenceEngine:
                        host_lost=s["lost"],
                        host_tenant_bytes=s["tenant_bytes"])
         # Per-tenant resident-block fairness accounting (exporter
-        # ``tenant_kv_blocks`` + the bench's monopoly probe).
+        # ``tenant_kv_blocks``).
         if self.prefix_cache is not None:
             out["tenant_blocks"] = self.prefix_cache.blocks_by_tenant()
         return out
@@ -3259,181 +3224,10 @@ class InferenceEngine:
         self._decode_cache[key] = prog
         return prog
 
-    def profile_decode_phases(self, reps: int = 3) -> dict[str, float]:
-        """Attribute the fused decode step: attention vs sampling cost.
-
-        Runs the warm compiled decode programs on synthetic full-batch
-        state (all ``max_slots`` lanes live) and differences timings:
-
-          * long-context minus short-context greedy -> ``decode_attn_ms``
-            (only paged attention scales with context length; the dense
-            matmuls and dispatch overhead are ctx-independent), and
-          * sampled minus greedy at short context -> ``decode_sample_ms``.
-
-        The programs append garbage rows into ``self.pages`` as a side
-        effect, so this must only run while the engine is IDLE — bench
-        calls it before serving traffic; it is never triggered by a
-        /metrics scrape.  Populates ``self.decode_attn_ms`` /
-        ``self.decode_sample_ms`` (exported as gauges) and returns all
-        four figures.
-        """
-        if self._inflight or any(s is not None for s in self._slots):
-            raise RuntimeError(
-                "profile_decode_phases() requires an idle engine "
-                "(it clobbers KV pages)")
-        ec = self.ecfg
-        K = ec.decode_steps_per_iter
-        B = ec.max_slots
-        width = ec.max_blocks_per_seq
-        # One shared table row (blocks 1..width): lanes alias the same
-        # pages, which is fine for timing — traffic per lane is identical
-        # to distinct pages and HBM reads don't conflict.
-        nblk = min(width, ec.num_blocks - 1)
-        row = np.zeros((1, width), np.int32)
-        row[0, :nblk] = np.arange(1, 1 + nblk, dtype=np.int32)
-        dtbl = jnp.asarray(np.tile(row, (B, 1)))
-        ctx_hi = max(nblk * ec.block_size - K - 1, 1)
-        ctx_lo = 1
-
-        cap = ec.sample_topk_cap
-        remaining = jnp.full((B,), 10 ** 6, jnp.int32)
-        eos = jnp.asarray(-1, jnp.int32)
-
-        def run(prog, ctx_val: int, sampled: bool) -> float:
-            ctx = jnp.full((B,), ctx_val, jnp.int32)
-            tok = jnp.zeros((B,), jnp.int32)
-            if sampled:
-                extras = (jnp.full((B,), 0.7, jnp.float32),
-                          jnp.full((B,), max(min(cap, 8), 1), jnp.int32),
-                          jnp.full((B,), 0.9, jnp.float32),
-                          jax.random.PRNGKey(0), eos)
-            else:
-                extras = (eos,)
-            # Warm (compile) call, then timed reps.  tok_state and pages
-            # are donated — thread both through every call.
-            _, tok, self.pages = prog(self.params, tok, ctx, remaining,
-                                      self.pages, dtbl, *extras)
-            tok.block_until_ready()
-            t0 = time.monotonic()
-            for _ in range(reps):
-                _, tok, self.pages = prog(self.params, tok, ctx, remaining,
-                                          self.pages, dtbl, *extras)
-            tok.block_until_ready()
-            return (time.monotonic() - t0) / (reps * K) * 1e3
-
-        greedy_prog = self._decode_program(K, sampled=False)
-        sampled_prog = self._decode_program(K, sampled=True,
-                                            bounded=cap > 0)
-        t_lo = run(greedy_prog, ctx_lo, sampled=False)
-        t_hi = run(greedy_prog, ctx_hi, sampled=False)
-        t_samp = run(sampled_prog, ctx_lo, sampled=True)
-        self.decode_attn_ms = max(t_hi - t_lo, 0.0)
-        self.decode_sample_ms = max(t_samp - t_lo, 0.0)
-        self.decode_collective_share = self._estimate_collective_share(t_lo)
-        return {
-            "decode_step_ms_short_ctx": t_lo,
-            "decode_step_ms_long_ctx": t_hi,
-            "decode_attn_ms": self.decode_attn_ms,
-            "decode_sample_ms": self.decode_sample_ms,
-            "decode_collective_share": self.decode_collective_share,
-        }
-
     def mesh_axes(self) -> dict[str, int]:
         """{axis: size} of the serving mesh ({} off-mesh) — the exporter's
         ``mesh_axes`` topology gauge."""
         return dict(self.mesh.shape) if self.mesh is not None else {}
-
-    def _estimate_collective_share(self, step_ms: float) -> Optional[float]:
-        """Per-step ICI time share of the TP decode step (byte model).
-
-        Row-parallel o/down projections each psum a [B, hidden] activation
-        per layer; a ring all-reduce moves ``2*(tp-1)/tp`` of the payload
-        over each chip's links.  Dividing that wire time (at the chip's
-        aggregate ICI bandwidth) by the *measured* step time gives the
-        share the dashboard shows next to ``decode_attn_ms``.  It is an
-        estimate — collectives overlap compute on real meshes.  None for a
-        device kind without an ICI figure (the CPU test meshes).
-        """
-        ici_ms = self._ring_ici_ms()
-        if ici_ms is None:
-            return None
-        if ici_ms <= 0.0 or step_ms <= 0.0:
-            return 0.0
-        return min(1.0, ici_ms / step_ms)
-
-    def _ring_ici_ms(self) -> Optional[float]:
-        """Per-step wire time of the TP decode collectives (byte model,
-        ms): row-parallel o/down each move ``2*(tp-1)/tp`` of a
-        [max_slots, hidden] activation over each chip's ICI links per
-        layer — the same bytes whether staged as one ring all-reduce
-        (GSPMD) or as a reduce-scatter + all-gather pair (overlap path).
-        0.0 off-mesh / TP=1; None when the mesh's device kind has no ICI
-        figure in parallel/mesh.py (no device gets another's)."""
-        if self.mesh is None:
-            return 0.0
-        tp = self.mesh.shape.get("model", 1)
-        if tp <= 1:
-            return 0.0
-        from k8s_llm_monitor_tpu.parallel.mesh import ici_bandwidth_gbs
-
-        cfg = self.cfg
-        act_bytes = 4 if cfg.dtype == "float32" else 2
-        payload = self.ecfg.max_slots * cfg.hidden_size * act_bytes
-        per_chip_bytes = (2 * cfg.num_layers          # o-proj + down-proj
-                          * 2.0 * (tp - 1) / tp * payload)
-        gbs = ici_bandwidth_gbs(self.mesh.devices.flat[0].device_kind)
-        if gbs is None:
-            return None
-        return per_chip_bytes / (gbs * 1e9) * 1e3
-
-    def estimate_hidden_share(self, step_ms_on: float | None = None,
-                              step_ms_off: float | None = None
-                              ) -> Optional[float]:
-        """``decode_collective_hidden_share``: fraction of the per-step
-        ring wire time the overlap schedule hides under compute.
-
-        With measured overlap-on and overlap-off step times, the hidden
-        share is the observed saving against the byte model:
-        ``(off - on) / ring_ici_ms``, clamped to [0, 1].  Without them it
-        is the analytic window model: a reduce-scatter/all-gather half is
-        hidden up to the time the next column-parallel matmuls spend
-        streaming their weight shard HBM->VMEM (decode is weight-streaming
-        bound).  Per layer that window is the per-chip column weight bytes
-        over HBM bandwidth; the wire is the per-layer share of
-        ``_ring_ici_ms``.  Either figure lands in
-        ``self.decode_collective_hidden_share`` for /metrics.  A device
-        kind without ICI/HBM figures (the CPU test meshes) gets None.
-        """
-        ici_ms = self._ring_ici_ms()
-        if ici_ms is None:
-            self.decode_collective_hidden_share = None
-            return None
-        if ici_ms <= 0.0 or not self.tp_overlap:
-            self.decode_collective_hidden_share = 0.0
-            return 0.0
-        if (step_ms_on is not None and step_ms_off is not None
-                and step_ms_off > 0.0):
-            share = max(0.0, min(1.0, (step_ms_off - step_ms_on) / ici_ms))
-        else:
-            from k8s_llm_monitor_tpu.parallel.mesh import hbm_bandwidth_gbs
-
-            cfg = self.cfg
-            tp = self.mesh.shape.get("model", 1)
-            hbm_gbs = hbm_bandwidth_gbs(
-                self.mesh.devices.flat[0].device_kind)
-            # int8 weights stream 1 byte/element; float params their dtype.
-            layer0 = self.params["layers"][0]
-            wbytes = (1 if "kernel_q" in layer0["q"]
-                      else (4 if cfg.dtype == "float32" else 2))
-            D = cfg.head_dim_
-            col_weights = (cfg.hidden_size * cfg.num_heads * D       # q
-                           + 2 * cfg.hidden_size * cfg.num_kv_heads * D
-                           + 2 * cfg.hidden_size * cfg.intermediate_size)
-            stream_ms = col_weights * wbytes / tp / (hbm_gbs * 1e9) * 1e3
-            wire_ms = ici_ms / (2 * cfg.num_layers)   # one RS/AG pair
-            share = min(1.0, stream_ms / wire_ms) if wire_ms > 0 else 0.0
-        self.decode_collective_hidden_share = share
-        return share
 
     @staticmethod
     def _spec_class(lanes) -> str:
@@ -3953,9 +3747,6 @@ class InferenceEngine:
         else:
             span_name = ("engine.spec_decode" if call.kind == "spec"
                          else "engine.decode")
-            # Satellite: the analytic collective share from the last
-            # profile_decode_phases() run rides on every decode segment.
-            coll = self.decode_collective_share
             emitted = 0
             for slot_idx, s, steps_i in call.lanes:
                 if self._slots[slot_idx] is not s or s.retired:
@@ -3965,13 +3756,7 @@ class InferenceEngine:
                 s.inflight_decode -= steps_i
                 if call.kind == "spec":
                     self.spec_tokens += len(new)
-                if steps_i > 0:
-                    self.hist_decode_step.observe(
-                        max(0.0, now - call.t0) / steps_i,
-                        s.req.slo_class, self._trace_id(s.req))
                 lane_attrs = {"steps": steps_i, "emitted": len(new)}
-                if coll:
-                    lane_attrs["collective_share"] = coll
                 if call.kind == "spec":
                     lane_attrs["rounds"] = self.ecfg.spec_rounds_per_iter
                 self._span(span_name, call.t0, now, s.req, **lane_attrs)
@@ -4013,14 +3798,6 @@ class InferenceEngine:
                       slo_class: str = DEFAULT_CLASS,
                       trace_id: str = "") -> None:
         self.hist_ttft.observe(ttft_s, slo_class, trace_id)
-        for i, le in enumerate(self.ttft_buckets):
-            if ttft_s <= le:
-                self.ttft_counts[i] += 1
-                break
-        else:
-            self.ttft_counts[-1] += 1
-        self.ttft_sum += ttft_s
-        self.ttft_count += 1
         prev = self.ttft_ema_by_class.get(slo_class)
         self.ttft_ema_by_class[slo_class] = (
             ttft_s if prev is None else 0.9 * prev + 0.1 * ttft_s)

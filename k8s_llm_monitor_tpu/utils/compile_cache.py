@@ -1,6 +1,6 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (cmd/server.py, bench.py, chip_smoke.py):
+One rule for every entry point (cmd/server.py, benchmarks/, chip_smoke.py):
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — JAX reads it itself; this module
   never overrides it, so whoever starts the process places the cache.

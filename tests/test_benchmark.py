@@ -109,3 +109,22 @@ def test_new_entries_came_last_and_the_old_ones_stand():
                          "prefill_call_ms", "decode_call_ms",
                          "compiles_in_window"]
     assert len(names) == len(set(names))
+
+
+# The files that described or ran the pre-chip script (deleted in PR 28) as a
+# way to measure: the build, CI, and each document that named it.
+ONCE_NAMED_THE_FORK = ["Makefile", ".github/workflows/ci.yml", "README.md"] + [
+    f"docs/{name}.md" for name in (
+        "development-guide", "devtools", "diagnosis", "fleet", "observability",
+        "remediation", "resilience", "serving")]
+
+
+@pytest.mark.parametrize("path", ONCE_NAMED_THE_FORK)
+def test_one_yardstick_no_second_benchmark_comes_back(path):
+    """``benchmarks/`` (BENCHMARK.json's command) is the only code that
+    measures speed: no file of the build, CI or the documents names the old
+    script, one of its environment variables or one of its make targets."""
+    text = (ROOT / path).read_text()
+    for pattern in (r"bench\.py", r"BENCH_[A-Z]", r"make bench-"):
+        assert not re.search(pattern, text), (path, pattern)
+    assert not (ROOT / "bench.py").exists()

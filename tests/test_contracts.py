@@ -3,7 +3,7 @@
 Each checker runs against deliberately drifted fixture sources/docs to
 prove both directions fire, against reconciled fixtures to prove it goes
 quiet, and finally against the live repo — the assertion that every
-route, metric family, bench key and env key the docs promise actually
+route, metric family and env key the docs promise actually
 exists (and vice versa), with zero suppressions.
 """
 
@@ -15,7 +15,7 @@ from pathlib import Path
 from k8s_llm_monitor_tpu.devtools import contracts
 from k8s_llm_monitor_tpu.devtools.contracts import (
     _norm_route, check_env, check_metrics, check_routes, derived_env_keys,
-    extract_agent_routes, extract_bench_keys, extract_exporter_metrics,
+    extract_agent_routes, extract_exporter_metrics,
     extract_server_routes, run_contracts)
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
@@ -146,9 +146,9 @@ EXPORTER_SRC = dedent("""
     def export(w, hist):
         w.metric("engine_queue_depth", "gauge", "depth", [(1.0, {})])
         w.histogram("request_ttft_seconds", "ttft", hist)
-        w.lines.append(f"{_PREFIX}_engine_ttft_seconds_sum 1.0")
+        w.lines.append(f"{_PREFIX}_request_e2e_seconds_sum 1.0")
         hists = (
-            ("decode_step_seconds", "per-step decode latency", hist),
+            ("request_queue_wait_seconds", "queue wait before admission", hist),
         )
         for name, help_, h in hists:
             w.histogram(name, help_, h)
@@ -159,24 +159,14 @@ GOOD_OBS = dedent("""
     |---|---|---|
     | `k8s_llm_monitor_engine_queue_depth` | gauge | queue depth |
     | `k8s_llm_monitor_request_ttft_seconds` | histogram | ttft |
-    | `k8s_llm_monitor_engine_ttft_seconds` | histogram | engine ttft |
-    | `k8s_llm_monitor_decode_step_seconds` | histogram | decode step |
+    | `k8s_llm_monitor_request_e2e_seconds` | histogram | end to end |
+    | `k8s_llm_monitor_request_queue_wait_seconds` | histogram | queue wait |
     """)
-
-BENCH_SRC = dedent("""
-    def main():
-        doc = {"decode_tok_s": 1.0, "ttft_p50_ms": 2.0}
-        doc["prefill_speedup_8k"] = 3.0
-        for n in (2, 8, 32):
-            doc[f"prefill_ttft_{n}k_ms"] = 4.0
-        print(doc)
-    """)
-
 
 def check_m(obs=GOOD_OBS, extra_docs=None):
     docs = {"docs/observability.md": obs}
     docs.update(extra_docs or {})
-    return check_metrics(EXPORTER_SRC, obs, BENCH_SRC, docs)
+    return check_metrics(EXPORTER_SRC, obs, docs)
 
 
 def test_exporter_extraction_covers_all_emission_styles():
@@ -184,7 +174,7 @@ def test_exporter_extraction_covers_all_emission_styles():
     # literal metric(), literal histogram(), manual f-string sample
     # (collapsed to the family), and the tuple-table rows
     assert fams == {"engine_queue_depth", "request_ttft_seconds",
-                    "engine_ttft_seconds", "decode_step_seconds"}
+                    "request_e2e_seconds", "request_queue_wait_seconds"}
 
 
 def test_metrics_reconciled_fixture_is_clean():
@@ -193,11 +183,11 @@ def test_metrics_reconciled_fixture_is_clean():
 
 def test_metrics_flags_emitted_but_not_inventoried():
     obs = GOOD_OBS.replace(
-        "| `k8s_llm_monitor_decode_step_seconds` | histogram | decode step |\n",
+        "| `k8s_llm_monitor_request_queue_wait_seconds` | histogram | queue wait |\n",
         "")
     findings = check_m(obs=obs)
     assert len(findings) == 1
-    assert "decode_step_seconds" in findings[0].message
+    assert "request_queue_wait_seconds" in findings[0].message
     assert "does not list it" in findings[0].message
 
 
@@ -218,22 +208,6 @@ def test_metrics_flags_stale_doc_mention():
     assert len(findings) == 1
     assert findings[0].path == "docs/usage.md"
     assert "never emits" in findings[0].message
-
-
-def test_bench_key_extraction_and_claims():
-    exact, prefixes = extract_bench_keys(BENCH_SRC)
-    assert "prefill_speedup_8k" in exact
-    assert "prefill_ttft_" in prefixes  # f-string key -> prefix wildcard
-    # a cited key bench.py never emits
-    findings = check_m(extra_docs={
-        "README.md": "reports `decode_tok_s_avg` per run\n"})
-    assert len(findings) == 1
-    assert "decode_tok_s_avg" in findings[0].message
-    # valid exact + wildcard + f-string-prefix claims stay quiet
-    assert check_m(extra_docs={"README.md": dedent("""
-        reports `decode_tok_s`, the `prefill_ttft_*` ladder and
-        `prefill_speedup_8k`
-        """)}) == []
 
 
 # -- env-contract ------------------------------------------------------------
@@ -345,7 +319,6 @@ def mini_repo(tmp_path: Path, readme_extra: str = "") -> Path:
     (tmp_path / "README.md").write_text(
         GOOD_ROUTE_DOCS["README.md"] + ENV_DOCS["README.md"]
         + readme_extra, encoding="utf-8")
-    (tmp_path / "bench.py").write_text(BENCH_SRC, encoding="utf-8")
     return tmp_path
 
 

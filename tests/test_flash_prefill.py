@@ -308,13 +308,14 @@ def test_select_auto_on_tpu_gates_on_head_dim():
         select_prefill_impl(platform="tpu", cfg=CFG, mode="flash")
 
 
-def test_env_overrides_config_prefill_path(monkeypatch):
+def test_unknown_prefill_path_field_is_refused():
+    """``EngineConfig.prefill_path`` is the one selector: a value outside
+    auto|flash|dense is refused at construction, before any pool exists."""
     params = llama.init_params(jax.random.PRNGKey(0), CFG)
-    monkeypatch.setenv("K8SLLM_PREFILL_PATH", "dense")
-    eng = InferenceEngine(CFG, params,
-                          EngineConfig(prefill_path="flash", **ENGINE_KW),
-                          eos_id=-1)
-    assert eng.prefill_path == "dense"
+    with pytest.raises(ValueError, match="unknown prefill_path 'bogus'"):
+        InferenceEngine(CFG, params,
+                        EngineConfig(prefill_path="bogus", **ENGINE_KW),
+                        eos_id=-1)
 
 
 @pytest.mark.slow

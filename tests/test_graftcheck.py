@@ -426,7 +426,7 @@ def test_tenant_namespace_live_repo_clean_without_suppressions():
     root = pathlib.Path(astlint.__file__).resolve().parents[2]
     rule = astlint.TenantNamespaceRule()
     offenders = []
-    for sub in ("k8s_llm_monitor_tpu", "tests", "bench.py"):
+    for sub in ("k8s_llm_monitor_tpu", "tests"):
         for p in astlint.iter_py_files(root / sub):
             src = p.read_text(encoding="utf-8")
             per_line, per_file = astlint._suppressions(src)
@@ -491,7 +491,7 @@ def test_raw_kube_write_live_repo_clean_without_suppressions():
     root = pathlib.Path(astlint.__file__).resolve().parents[2]
     rule = astlint.RawKubeWriteRule()
     offenders = []
-    for sub in ("k8s_llm_monitor_tpu", "tests", "bench.py"):
+    for sub in ("k8s_llm_monitor_tpu", "tests"):
         for p in astlint.iter_py_files(root / sub):
             src = p.read_text(encoding="utf-8")
             per_line, per_file = astlint._suppressions(src)

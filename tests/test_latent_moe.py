@@ -1,5 +1,5 @@
 """The DeepSeek-V3 block through the serving path, against its plain
-reference (models/reference/deepseek_v3.py), at the tiny preset on the CPU.
+reference (benchmarks/references/deepseek_v3.py), at the tiny preset on the CPU.
 
 Exactness is claimed in float32 only; every tolerance says why it is what it
 is, and a negative control shows that it bites.
@@ -8,16 +8,15 @@ is, and a negative control shows that it bites.
 import dataclasses
 import io
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks.references import deepseek_v3 as ref
 from k8s_llm_monitor_tpu.models import llama
 from k8s_llm_monitor_tpu.models.config import PRESETS, ModelConfig
-from k8s_llm_monitor_tpu.models.reference import deepseek_v3 as ref
 from k8s_llm_monitor_tpu.ops import attention as ops
 from k8s_llm_monitor_tpu.ops.pallas_attention import (
     latent_decode_attention_pallas,
@@ -32,7 +31,6 @@ from k8s_llm_monitor_tpu.serving.engine import (
 )
 from k8s_llm_monitor_tpu.utils.quantize import quantize_params
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
 CFG = dataclasses.replace(PRESETS["tiny-latent-moe"], dtype="float32")
 RCFG = ref.config_of(CFG)
 # float32 logits of size ~4 after 3 layers: the system and the reference add
@@ -173,12 +171,6 @@ def test_a_single_reference_layer_on_a_given_input(params):
         got, _ = llama.layer_block(params["layers"][li], CFG, x[None], cos,
                                    sin, pos, layer_idx=li)
         np.testing.assert_allclose(got[0], want, atol=ATOL, rtol=0)
-
-
-def test_the_two_copies_of_the_reference_are_one_text():
-    theirs = (ROOT / "benchmarks/references/deepseek_v3.py").read_text()
-    ours = (ROOT / "k8s_llm_monitor_tpu/models/reference/deepseek_v3.py").read_text()
-    assert theirs == ours
 
 
 # -- (b) absorbed form equals expanded form ----------------------------------

@@ -14,9 +14,9 @@ import jax
 import numpy as np
 import pytest
 
+from benchmarks.references import deepseek_v3 as ref
 from k8s_llm_monitor_tpu.models import llama
 from k8s_llm_monitor_tpu.models.config import PRESETS
-from k8s_llm_monitor_tpu.models.reference import deepseek_v3 as ref
 from k8s_llm_monitor_tpu.serving.engine import SPAN_CATALOG, EngineConfig
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]

@@ -137,16 +137,13 @@ def test_mesh_topology_gauges_on_tp_engine():
     text = w.render()
     assert f'k8s_llm_monitor_mesh_axes{{axis="model"}} {n_dev}' in text
     assert 'k8s_llm_monitor_mesh_axes{axis="data"} 1' in text
-    # The CPU mesh's device kind has no ICI figure: the estimate is absent,
-    # not a number derived from another chip's bandwidth.
-    assert "k8s_llm_monitor_engine_decode_collective_share" not in text
     assert "k8s_llm_monitor_engine_tp_overlap" in text
     assert eng.mesh_axes()["model"] == n_dev
 
 
 def test_ttft_histogram_counts_queries(engine_server):
     srv, engine = engine_server
-    before = engine.ttft_count
+    before = engine.hist_ttft.total_count()
     req = urllib.request.Request(
         f"http://127.0.0.1:{srv.port}/api/v1/query",
         data=json.dumps({"question": "what is wrong?"}).encode(),
@@ -154,9 +151,12 @@ def test_ttft_histogram_counts_queries(engine_server):
     with urllib.request.urlopen(req, timeout=300) as r:
         assert json.loads(r.read())["status"] == "success"
     vals = _parse(_metrics_text(srv.port))
-    assert vals["k8s_llm_monitor_engine_ttft_seconds_count"] >= before + 1
-    assert vals['k8s_llm_monitor_engine_ttft_seconds_bucket{le="+Inf"}'] == (
-        vals["k8s_llm_monitor_engine_ttft_seconds_count"])
+    fam = "k8s_llm_monitor_request_ttft_seconds"
+    counts = {cls: vals[f'{fam}_count{{class="{cls}"}}']
+              for cls in engine.hist_ttft.classes()}
+    assert sum(counts.values()) >= before + 1
+    for cls, n in counts.items():
+        assert vals[f'{fam}_bucket{{class="{cls}",le="+Inf"}}'] == n
 
 
 def test_sse_streaming_query(engine_server):

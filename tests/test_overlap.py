@@ -76,15 +76,16 @@ def test_auto_mode_falls_back_and_on_mode_raises(cpu_mesh_devices):
                         eos_id=-1, mesh=mesh)
 
 
-def test_env_flag_overrides_config(cpu_mesh_devices, monkeypatch):
+def test_unknown_tp_overlap_value_is_refused(cpu_mesh_devices):
+    """The field is the one selector: "off" keeps the GSPMD program on a
+    geometry the schedule supports, and a value outside auto|on|off is
+    refused at construction, on or off a mesh."""
     params = llama.init_params(jax.random.PRNGKey(0), CFG)
     mesh = create_mesh(MeshConfig(model=8))
-    monkeypatch.setenv("K8SLLM_TP_OVERLAP", "off")
-    eng = _engine(params, "on", mesh)   # env wins over the config field
-    assert not eng.tp_overlap
-    monkeypatch.setenv("K8SLLM_TP_OVERLAP", "bogus")
-    with pytest.raises(ValueError, match="K8SLLM_TP_OVERLAP|tp_overlap"):
-        _engine(params, "auto", mesh)
+    assert not _engine(params, "off", mesh).tp_overlap
+    for m in (mesh, None):
+        with pytest.raises(ValueError, match="unknown tp_overlap 'bogus'"):
+            _engine(params, "bogus", m)
 
 
 # -- parity: the tentpole gate ------------------------------------------------
@@ -180,42 +181,6 @@ def test_traceguard_overlap_path_zero_recompiles():
     assert not any(report.forbidden.values()), report.forbidden
     assert report.donated_pages_rebound and report.donated_tokens_rebound
     assert report.ok
-
-
-# -- hidden-share model -------------------------------------------------------
-
-
-def test_hidden_share_absent_for_unlisted_device(cpu_mesh_devices):
-    """A device kind outside the bandwidth tables (the CPU test mesh) gets
-    no defaulted v5e figure: both estimates are None, never a number."""
-    params = llama.init_params(jax.random.PRNGKey(0), CFG)
-    mesh = create_mesh(MeshConfig(model=8))
-    eng = _engine(params, "on", mesh)
-    assert eng.decode_collective_share is None
-    assert eng.estimate_hidden_share() is None
-    assert eng.decode_collective_hidden_share is None
-    assert eng._estimate_collective_share(10.0) is None
-
-
-def test_hidden_share_analytic_floor(cpu_mesh_devices, monkeypatch):
-    """For a LISTED device the unmeasured share is the analytic
-    weight-streaming window (column weight bytes / shard over HBM
-    bandwidth vs the per-layer ring wire time).  The ISSUE's floor:
-    >= 0.5 of the analytic ring time.  The test lists this mesh's kind
-    with v5e's figures; the program itself never defaults them."""
-    from k8s_llm_monitor_tpu.parallel import mesh as mesh_mod
-
-    kind = jax.devices()[0].device_kind.lower()
-    monkeypatch.setitem(mesh_mod.ICI_GBS, kind, 200.0)
-    monkeypatch.setitem(mesh_mod.HBM_GBS, kind, 819.0)
-    params = llama.init_params(jax.random.PRNGKey(0), CFG)
-    mesh = create_mesh(MeshConfig(model=8))
-    eng = _engine(params, "on", mesh)
-    share = eng.estimate_hidden_share()
-    assert 0.5 <= share <= 1.0
-    assert eng.decode_collective_hidden_share == share
-    off = _engine(params, "off", mesh)
-    assert off.estimate_hidden_share() == 0.0
 
 
 # -- tier-aware admission -----------------------------------------------------

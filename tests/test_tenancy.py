@@ -592,14 +592,19 @@ def test_flooding_tenant_rate_limited_quiet_tenant_unharmed(params):
             res = h.result(timeout=60)
             assert res.finish_reason == "length"
             assert res.token_ids == _naive_greedy(params, p, 4)
-        for h in flood:
-            h.result(timeout=60)
+        flood_delivered = sum(len(h.result(timeout=60).token_ids)
+                              for h in flood)
 
+        assert _wait(lambda: not any(v["inflight"]
+                                     for v in gov.snapshot().values()))
         snap = gov.snapshot()
         assert snap["noisy"]["quota_refusals"] == refused
         assert snap["quiet"]["quota_refusals"] == 0
         assert snap["quiet"]["sheds"] == 0
-        assert snap["noisy"]["inflight"] == 0 and snap["quiet"]["inflight"] == 0
+        # Zero lost tokens under the flood: the settled charge is what each
+        # tenant's streams delivered.
+        assert gov.charged_tokens("quiet") == 4 * 4
+        assert gov.charged_tokens("noisy") == flood_delivered
     finally:
         svc.stop(timeout=10)
 
