@@ -1,36 +1,56 @@
-"""Pallas TPU kernel: paged decode attention over a block KV cache.
+"""Pallas TPU kernels: attention straight off the paged KV pool.
 
-The XLA fallback (ops/attention.py:paged_decode_attention) materializes every
-sequence's pages into a contiguous ``[B, max_blocks*bs, KVH, D]`` gather per
-layer per step — O(B * max_ctx) HBM traffic regardless of actual context
-lengths.  This kernel instead streams exactly the pages a sequence actually
-uses through VMEM with online (flash-style) softmax accumulation:
+The XLA fallbacks (ops/attention.py) gather every sequence's pages into a
+contiguous ``[B, max_blocks*bs, KVH, D]`` array per layer per step: HBM
+traffic of the table's capacity, whatever the contexts hold.  The kernels
+here leave the page arrays in HBM (``memory_space=ANY``), walk each lane's
+block table and stream exactly the pages it uses through VMEM in
+double-buffered *windows*, with online (flash-style) softmax accumulation.
+What they share:
 
-  * grid = (batch,): one program per sequence.  K/V page arrays stay in HBM
-    (``memory_space=ANY``); the program walks its block table in
-    double-buffered *windows* of ``_WINDOW`` pages, issuing all of a
-    window's ``make_async_copy`` bursts together and waiting once — per-copy
-    HBM latency overlaps within the burst instead of serializing (the
-    page-at-a-time variant spent ~n_pages x DMA latency per program, which
-    at B=128 x 32 layers dominated the decode step).  The loop is bounded
-    by the sequence's real page count (``cdiv(length, bs)``), so unused
-    table slots cost nothing.
-  * the window w+1 burst is started before window w's math, hiding HBM
-    latency behind the compute.
-  * GQA without any in-kernel head splitting: pages are DMA'd as
-    ``[bs, KVH*D]`` rows (the fused lane dim keeps HBM slices 128-aligned
-    for D < 128), queries enter **block-diagonal** — q head h occupies its
-    kv-group's D-slice of a ``[H, KVH*D]`` matrix and zeros elsewhere — so
-    ``scores = q_bd @ page.T`` and ``acc += p @ page`` are single MXU dots
-    whose cross-head terms vanish; the per-head output slice is extracted
-    by XLA after the kernel.  The online-softmax state (m, l, acc) is a
-    ``fori_loop`` carry.
+  * a program handles ``TB`` lanes (8 where the batch allows, at least two
+    programs so that a two-core chip keeps both busy);
+  * GQA without in-kernel head splitting: pages are DMA'd as ``[bs, KVH*D]``
+    rows (the fused lane dim keeps HBM slices 128-aligned for D < 128) and
+    queries enter **block-diagonal** — head h occupies its kv group's
+    D-slice of a ``[H, KVH*D]`` matrix, zeros elsewhere — so
+    ``q_bd @ page.T`` and ``p @ page`` are single MXU products whose
+    cross-head terms vanish; the wrapper slices each head's own group out;
+  * rows past a lane's position are masked by position, so what a window
+    fetches beyond the lane's pages only has to be finite.
 
-Selected by ops/attention.py:select_attn_impl on TPU (single-chip engine);
-CPU tests run it in interpreter mode for parity with the XLA reference.
-Capability context: the reference has no kernels of any kind (pure Go control
-plane); this is part of the new TPU serving obligation (SURVEY.md §7 hard
-part #1).
+The kernels:
+
+  * ``paged_decode_attention_fused`` (``_fused_decode_kernel``) — the decode
+    step of every dense one-chip model with a bf16 or f32 pool
+    (ops/attention.py:select_decode_impl): RoPE, the KV append (an aligned
+    read-modify-write of the tile that holds the new row) and attention over
+    the cached pages in one call, the page arrays updated in place.  Its
+    DMAs are ONE pipeline across a program's lanes: every lane's append tile
+    is read at program start, window 0 of lane t+1 is in flight while lane
+    t multiplies its last window, windows are ``_FUSED_WINDOW`` = 32 pages
+    fetched in groups of 8 with one wait a group, and groups past a lane's
+    pages are skipped.  The hazard argument is in the kernel's docstring.
+    On a v5e at the served Qwen2-7B cell's shape (64 lanes of ~590 cached
+    tokens, 28 q / 4 kv heads x 128, bf16 pool) it takes 2.4-2.5 us a
+    lane, 52-54% of the time 819 GB/s needs for the live pages; the
+    pipeline it replaced (a lane at a time, each waiting alone for its
+    append tile and its window 0, 8-page windows, a wait a copy) took
+    4.0-4.2 us, 31-32% (PERF.md section 6, PR 29).
+  * ``paged_decode_attention_fused_quant`` — its twin for int8 / fp8 pools
+    (quantize on append, dequantize in kernel); still a lane at a time.
+  * ``paged_decode_attention_pallas`` / ``paged_verify_attention_pallas``
+    (``_paged_attn_kernel``) — the split path: the new rows are already in
+    the pages; one query token a lane, or a few (speculative verify).
+  * ``latent_decode_attention_pallas`` / ``latent_prefill_attention_*`` —
+    the latent (compressed-KV) mixer's decode and fresh prefill.
+  * ``flash_prefill_attention*`` — tiled prefill off the pool, row and
+    packed-stream forms, plain and quantized.
+
+Selected by ops/attention.py (``select_attn_impl``, ``select_decode_impl``,
+``select_prefill_impl``) on a TPU; CPU tests run them in the interpreter for
+parity with the XLA references, and tests/test_chip_compile.py puts each to
+the chip's compiler at the served shapes.
 """
 
 from __future__ import annotations
@@ -45,11 +65,25 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-# Pages DMA'd per burst: W pages' copies are issued together and waited
-# once, so per-copy HBM latency overlaps within the burst instead of
-# serializing (a serial page-at-a-time loop costs ~n_pages x DMA latency of
-# pure wait per program — measured ~3x the whole step budget at B=128).
+# Pages DMA'd per burst by the split decode / verify kernel, the quantized
+# fused decode kernel and flash prefill: a window's copies are issued together
+# and double-buffered against the previous window's products, so per-copy HBM
+# latency overlaps within the burst.  Eight pages (128 keys a softmax block)
+# predates any chip trace; the two decode kernels that have been traced took
+# deeper windows of their own (below, and ``_LATENT_WINDOW``).
 _WINDOW = 8
+
+# The fused decode kernel's own depth, and the pages of one fetch group.  A
+# window's cost on the chip is its copies' descriptors (scalar work) plus its
+# two products, one after the other; the copies themselves hide behind both.
+# Per key the products get cheaper as the window deepens (a lane's softmax
+# chain has fewer links), and dead pages cost descriptors and bandwidth, so:
+# deep windows, fetched in groups, dead groups skipped.  Measured at the served
+# cell's shape (64 lanes of ~590 cached tokens, PR 29, us a lane), fetched
+# whole: 16 pages 2.78, 32 2.70; in groups of 8: 16 pages 2.67, 32 2.40, 48
+# 2.73 (32 in groups of 4: 3.02); the parent's pipeline at 8 pages 4.07.
+_FUSED_WINDOW = 32
+_FUSED_GROUP = 8
 
 
 def _paged_attn_kernel(
@@ -751,12 +785,14 @@ def _append_tile_rows(bs: int, dtype) -> int:
     return bs if bs <= rows or bs % rows else rows
 
 
-def _append_rows(pages_out, tiles, new_rows, blk, off, sems):
+def _append_rows(pages_out, tiles, blk, off, sems):
     """Append one token row to each of ``pages_out`` (HBM page arrays) at
     ``[blk, off]`` by an aligned read-modify-write of the tile that holds
-    the row: DMA the tile into its VMEM buffer in ``tiles``, overwrite row
-    ``off`` with ``new_rows[i]`` [1, F], and start the write-back.  Returns
-    the started write-back copies; the caller waits on them before its
+    the row.  Returns ``(reads, merge)``: ``reads`` are the copies of the
+    tile into its VMEM buffer in ``tiles``, for the caller to start and wait
+    on (a kernel may start every lane's reads together); ``merge(new_rows)``
+    then overwrites row ``off`` with ``new_rows[i]`` [1, F], starts the
+    write-back and returns its copies, which the caller waits on before its
     program ends.
 
     The tile's other rows are written back unchanged, so a concurrent page
@@ -773,19 +809,19 @@ def _append_rows(pages_out, tiles, new_rows, blk, off, sems):
         dsts, row = [p.at[blk, pl.ds(r0, rows)] for p in pages_out], off - r0
     reads = [pltpu.make_async_copy(d, t, sems.at[i])
              for i, (d, t) in enumerate(zip(dsts, tiles))]
-    for c in reads:
-        c.start()
-    for c in reads:
-        c.wait()
-    own = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == row
-    for t, new in zip(tiles, new_rows):
-        t[...] = jnp.where(own, new.astype(jnp.float32),
-                           t[...].astype(jnp.float32)).astype(t.dtype)
-    writes = [pltpu.make_async_copy(t, d, sems.at[i])
-              for i, (d, t) in enumerate(zip(dsts, tiles))]
-    for c in writes:
-        c.start()
-    return writes
+
+    def merge(new_rows):
+        own = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == row
+        for t, new in zip(tiles, new_rows):
+            t[...] = jnp.where(own, new.astype(jnp.float32),
+                               t[...].astype(jnp.float32)).astype(t.dtype)
+        writes = [pltpu.make_async_copy(t, d, sems.at[i])
+                  for i, (d, t) in enumerate(zip(dsts, tiles))]
+        for c in writes:
+            c.start()
+        return writes
+
+    return reads, merge
 
 
 def _fused_decode_kernel(
@@ -807,54 +843,165 @@ def _fused_decode_kernel(
     k_out,                 # aliased page arrays (ANY/HBM)
     v_out,
 ):
-    """Decode step for TB sequences: RoPE the query and the new token's k
+    """Decode step for TB lanes: RoPE the query and the new token's k
     in-kernel, merge the roped k / raw v row into the aligned tile of its
-    page (write-back overlapped with the attention math), stream the
-    CACHED pages (positions < pos) with
-    online softmax, and fold the current token in as one extra softmax
-    update from VMEM — so the appended row is never read back from HBM
-    and the append DMA can land any time before the program ends.
+    page, stream the CACHED pages (positions < pos) with online softmax,
+    and fold the current token in as one extra softmax update from VMEM —
+    so the appended row is never read back from HBM and the append DMA can
+    land any time before the program ends.
 
-    Inactive lanes (pos == 0) stream nothing and write their row to the
-    null block 0, matching models/llama.py:_scatter_pages; their output is
-    finite garbage (only the current-token term) that the engine discards.
+    All arithmetic is float32: pages are widened as they are used.  (PR 29
+    fed the MXU the pool's bf16 instead and measured nothing for it on the
+    chip, 2.690 against 2.695 us a lane: the products are not what a
+    window waits for.  PERF.md section 6.)
+
+    The program's DMAs form ONE pipeline over its TB lanes:
+
+      * the append-tile reads of all TB lanes are started together at
+        program start and waited once; each lane merges its row and starts
+        its write-back in its turn; all write-backs are waited once, before
+        the program ends;
+      * the double buffer runs over the program's windows as one sequence.
+        While lane t computes its last window (or at once, when it has
+        none) window 0 of lane t+1 is copied into the free slot; ``slot0``
+        carries the slot parity across lanes;
+      * a window is ``_FUSED_WINDOW`` pages deep, fetched in groups of
+        ``_FUSED_GROUP`` with one wait a group and array; groups past the
+        lane's pages are neither fetched nor waited for.
+
+    Why nothing races.  A lane appends to its tail block, which it alone
+    owns, and prefix blocks that lanes share are never appended to: the
+    pages lane t+1 reads early are not the page lane t writes back.  The
+    write-back rewrites the tile's other rows with the bytes they had, so
+    the lane's own stream may read them meanwhile, and the row at ``pos``
+    is masked in the stream (``p_idx < pos``) and folded in from VMEM.
+    Inactive lanes (pos == 0) and positions past the table write only rows
+    of the null block 0, in no order among themselves; every reader masks
+    those rows.  A slot is refilled, or the rows of its unfetched groups
+    zeroed, only after the window it held has been multiplied (program
+    order); a window's copies are started and waited for over one trip
+    count of groups, so every started copy is waited for.
+
+    Inactive lanes stream nothing and write their row to the null block,
+    matching models/llama.py:_scatter_pages; their output is finite
+    garbage (only the current-token term) that the engine discards.
     """
     TB = q_ref.shape[0]
     b0 = pl.program_id(0) * TB
     bs = k_hbm.shape[1]
     F = q_ref.shape[2]
     NB = tables_ref.shape[1]
-    W = min(_WINDOW, NB)
-
+    W = min(_FUSED_WINDOW, NB)
     R = _append_tile_rows(bs, k_hbm.dtype)
 
-    def scoped(k_buf, v_buf, k_tile, v_tile, sem, append_sem):
-        def start_window(slot, b, w):
-            for i in range(W):
-                j = jnp.minimum(w * W + i, NB - 1)
-                blk = tables_ref[b, j]
-                pltpu.make_async_copy(
-                    k_hbm.at[blk], k_buf.at[slot, pl.ds(i * bs, bs)],
-                    sem.at[slot, i, 0]).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[blk], v_buf.at[slot, pl.ds(i * bs, bs)],
-                    sem.at[slot, i, 1]).start()
+    def scoped(k_buf, v_buf, k_tiles, v_tiles, sem, append_sem):
+        G = _FUSED_GROUP if W % _FUSED_GROUP == 0 else W   # pages a group
 
-        def wait_window(slot, b, w):
-            for i in range(W):
-                j = jnp.minimum(w * W + i, NB - 1)
-                blk = tables_ref[b, j]
-                pltpu.make_async_copy(
-                    k_hbm.at[blk], k_buf.at[slot, pl.ds(i * bs, bs)],
-                    sem.at[slot, i, 0]).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[blk], v_buf.at[slot, pl.ds(i * bs, bs)],
-                    sem.at[slot, i, 1]).wait()
+        def group_rows(gi):
+            return pl.ds(pl.multiple_of(gi * (G * bs), G * bs), G * bs)
 
+        def live_groups(w, n_blk):
+            # Groups of window ``w`` that hold pages of a lane with
+            # ``n_blk`` pages (at least one: callers ask for windows that
+            # exist).
+            return jax.lax.min(jax.lax.div(n_blk - w * W + (G - 1), G), W // G)
+
+        def start_window(slot, b, w, n_blk):
+            # The page copies of window ``w`` of lane ``b``, both arrays, a
+            # group of G a turn of a rolled loop (its trip count is the
+            # window's live groups; the body is the parent's 8-page burst,
+            # so the kernel's text, which is lowered 28 x 8 times a decode
+            # program, does not grow with the window).  A group past the
+            # lane's pages is not fetched; its V rows are zeroed instead,
+            # because a probability of exactly 0 times whatever an earlier
+            # window left there must stay 0 (K's stale rows give scores
+            # that the position mask replaces).  A slot's K copies signal
+            # one semaphore and its V copies another.
+            live = live_groups(w, n_blk)
+
+            def fetch(gi, _):
+                j0, row0 = w * W + gi * G, gi * (G * bs)
+                for i in range(G):
+                    j = j0 + i
+                    blk = tables_ref[b, j if NB % W == 0 else
+                                     jax.lax.min(j, NB - 1)]
+                    rows = pl.ds(pl.multiple_of(row0 + i * bs, bs), bs)
+                    pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, rows],
+                                          sem.at[slot, 0]).start()
+                    pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, rows],
+                                          sem.at[slot, 1]).start()
+                return 0
+
+            def clear(gi, _):
+                v_buf[slot, group_rows(gi), :] = jnp.zeros((G * bs, F),
+                                                           v_buf.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, live, fetch, 0)
+            jax.lax.fori_loop(live, W // G, clear, 0)
+
+        def wait_window(slot, w, n_blk):
+            # A DMA semaphore counts what has arrived: one wait sized as a
+            # group's rows takes its G page copies' signals (a wait a copy
+            # was 2 W scalar-core stalls a window, serial with the window's
+            # products).  The same trip count as the start: every started
+            # copy is waited for, and nothing else.
+            def arrived(gi, _):
+                for i, buf in enumerate((k_buf, v_buf)):
+                    view = buf.at[slot, group_rows(gi)]
+                    pltpu.make_async_copy(view, view, sem.at[slot, i]).wait()
+                return 0
+
+            jax.lax.fori_loop(0, live_groups(w, n_blk), arrived, 0)
+
+        pos = [pos_ref[b0 + t] for t in range(TB)]   # cached before this one
+        # Pages and windows a lane streams: 0 for an inactive lane.
+        n_blocks = [jax.lax.div(p + (bs - 1), bs) for p in pos]
+        n_windows = [jax.lax.div(n + (W - 1), W) for n in n_blocks]
+
+        def start_next(slot, t, w):
+            # The window after window ``w`` of lane ``t`` (-1: the lane has
+            # none) in the program's one sequence goes into ``slot``: this
+            # lane's next, or window 0 of lane t + 1, if there is one.
+            more = w + 1 < n_windows[t]
+            if t + 1 == TB:
+                go, lane, n_blk = more, t, n_blocks[t]
+            else:
+                go = more | (n_windows[t + 1] > 0)
+                lane = jax.lax.select(more, jnp.int32(t), jnp.int32(t + 1))
+                n_blk = jax.lax.select(more, n_blocks[t], n_blocks[t + 1])
+
+            @pl.when(go)
+            def _start():
+                nxt = jax.lax.select(more, jnp.int32(w + 1), jnp.int32(0))
+                start_window(slot, b0 + lane, nxt, n_blk)
+
+        # --- every lane's append tile: read now, waited once --------------
+        merges, reads = [], []
         for t in range(TB):
-            b = b0 + t
-            pos = pos_ref[b]                 # tokens cached before this one
-            active = pos > 0
+            raw_blk = pos[t] // bs
+            blk = jnp.where(
+                (pos[t] > 0) & (raw_blk < NB),
+                tables_ref[b0 + t, jnp.minimum(raw_blk, NB - 1)], 0)
+            lane_reads, merge = _append_rows(
+                (k_out, v_out), (k_tiles.at[t], v_tiles.at[t]), blk,
+                jax.lax.rem(pos[t], bs), append_sem.at[t])
+            reads += lane_reads
+            merges.append(merge)
+        for c in reads:
+            c.start()
+
+        @pl.when(n_windows[0] > 0)
+        def _first():
+            start_window(0, b0, 0, n_blocks[0])
+
+        for c in reads:
+            c.wait()
+
+        writes = []
+        slot0 = jnp.int32(0)           # slot of this lane's window 0
+        for t in range(TB):
+            n_win = n_windows[t]
 
             # --- in-kernel RoPE (f32, like ops/rope.py) -------------------
             cos = cos_ref[t].astype(jnp.float32)          # [1, F]
@@ -867,34 +1014,21 @@ def _fused_decode_kernel(
             kn = kn_ref[t].astype(jnp.float32)            # [1, F]
             kf = kn * cos + _rotate_half_fused(kn, D) * sin
 
-            # --- KV append: start the DMA, overlap with attention ---------
-            raw_blk = pos // bs
-            in_table = raw_blk < NB
-            blk = jnp.where(active & in_table,
-                            tables_ref[b, jnp.minimum(raw_blk, NB - 1)], 0)
-            off = jax.lax.rem(pos, bs)
-            # The tail block is owned by this lane alone; the write-back
-            # overlaps the attention math below.
-            appends = _append_rows((k_out, v_out), (k_tile, v_tile),
-                                   (kf, vn_ref[t]), blk, off, append_sem)
+            # --- KV append: the write-back overlaps the attention math ----
+            writes += merges[t]((kf, vn_ref[t]))
 
             # --- stream the cached pages (positions < pos) ----------------
-            n_blocks = (pos + bs - 1) // bs              # 0 for inactive
-            n_windows = (n_blocks + W - 1) // W
+            if t + 1 < TB:
+                # A lane with no window hands the free slot on at once.
+                @pl.when(n_win == 0)
+                def _skip(t=t, slot0=slot0):
+                    start_next(slot0, t, -1)
 
-            @pl.when(n_windows > 0)
-            def _first():
-                start_window(0, b, 0)
-
-            def body(w, carry, b=b, pos=pos, n_windows=n_windows):
+            def body(w, carry, t=t, pos=pos[t], slot0=slot0, qf=qf):
                 m, l, acc = carry
-                slot = jax.lax.rem(w, 2)
-
-                @pl.when(w + 1 < n_windows)
-                def _prefetch():
-                    start_window(1 - slot, b, w + 1)
-
-                wait_window(slot, b, w)
+                slot = jax.lax.rem(slot0 + w, 2)
+                start_next(1 - slot, t, w)
+                wait_window(slot, w, n_blocks[t])
                 p_idx = (w * (W * bs)
                          + jax.lax.broadcasted_iota(jnp.int32, (1, W * bs), 1))
                 # The row being appended (p_idx == pos) is masked, so the
@@ -919,7 +1053,8 @@ def _fused_decode_kernel(
             m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
             l0 = jnp.zeros((H, 1), jnp.float32)
             acc0 = jnp.zeros((H, F), jnp.float32)
-            m, l, acc = jax.lax.fori_loop(0, n_windows, body, (m0, l0, acc0))
+            m, l, acc = jax.lax.fori_loop(0, n_win, body, (m0, l0, acc0))
+            slot0 = jax.lax.rem(slot0 + n_win, 2)
 
             # --- current token: one more online-softmax update from VMEM --
             # Always included (even for inactive lanes) so l > 0 and the
@@ -934,19 +1069,19 @@ def _fused_decode_kernel(
             l = alpha * l + p_cur
             vf = vn_ref[t].astype(jnp.float32)            # [1, F]
             acc = alpha * acc + p_cur * vf
-
-            for c in appends:
-                c.wait()
             o_ref[t] = (acc / l).astype(o_ref.dtype)
+
+        for c in writes:
+            c.wait()
 
     pl.run_scoped(
         scoped,
         k_buf=pltpu.VMEM((2, W * bs, F), k_hbm.dtype),
         v_buf=pltpu.VMEM((2, W * bs, F), v_hbm.dtype),
-        k_tile=pltpu.VMEM((R, F), k_hbm.dtype),
-        v_tile=pltpu.VMEM((R, F), v_hbm.dtype),
-        sem=pltpu.SemaphoreType.DMA((2, W, 2)),
-        append_sem=pltpu.SemaphoreType.DMA((2,)),
+        k_tiles=pltpu.VMEM((TB, R, F), k_hbm.dtype),
+        v_tiles=pltpu.VMEM((TB, R, F), v_hbm.dtype),
+        sem=pltpu.SemaphoreType.DMA((2, 2)),
+        append_sem=pltpu.SemaphoreType.DMA((TB, 2)),
     )
 
 
@@ -1220,8 +1355,13 @@ def _fused_decode_quant_kernel(
             blk = jnp.where(active & in_table,
                             tables_ref[b, jnp.minimum(raw_blk, NB - 1)], 0)
             off = jax.lax.rem(pos, bs)
-            appends = _append_rows((k_out, v_out), (k_tile, v_tile),
-                                   (kq, vq), blk, off, append_sem)
+            reads, merge = _append_rows((k_out, v_out), (k_tile, v_tile),
+                                        blk, off, append_sem)
+            for c in reads:
+                c.start()
+            for c in reads:
+                c.wait()
+            appends = merge((kq, vq))
 
             n_blocks = (pos + bs - 1) // bs
             n_windows = (n_blocks + W - 1) // W

@@ -100,17 +100,30 @@ def _compile(fn, *args, kernel=True):
     return text
 
 
-def _decode_args(S, pool_dtype, bs=BS):
-    return (S((LANES, 1, H, D), BF16), S((LANES, 1, KVH, D), BF16),
-            S((LANES, 1, KVH, D), BF16), S((LANES, 1, D), F32),
-            S((LANES, 1, D), F32), S((NBLK, bs, F), pool_dtype),
-            S((NBLK, bs, F), pool_dtype))
+def _decode_args(S, pool_dtype, bs=BS, lanes=LANES, nblk=NBLK):
+    return (S((lanes, 1, H, D), BF16), S((lanes, 1, KVH, D), BF16),
+            S((lanes, 1, KVH, D), BF16), S((lanes, 1, D), F32),
+            S((lanes, 1, D), F32), S((nblk, bs, F), pool_dtype),
+            S((nblk, bs, F), pool_dtype))
 
 
 def test_fused_decode_compiles(one_chip):
     S = one_chip
     _compile(pa.paged_decode_attention_fused, *_decode_args(S, BF16),
              S((LANES, NB), I32), S((LANES,), I32))
+
+
+def test_fused_decode_compiles_at_the_served_cell(one_chip):
+    """The call ``qwen2-7b.loops-saturated`` makes (benchmarks/configs/
+    qwen2-7b-w8a8.json: 64 lanes, 96 blocks a sequence, a bf16 pool of
+    6,144 blocks): the kernel's VMEM (two slabs of ``_FUSED_WINDOW`` pages
+    an array, an append tile a lane of the program) at the size that ships,
+    where the defaults above ask for less."""
+    S = one_chip
+    lanes, nb, nblk = 64, 96, 6144
+    _compile(pa.paged_decode_attention_fused,
+             *_decode_args(S, BF16, lanes=lanes, nblk=nblk),
+             S((lanes, nb), I32), S((lanes,), I32))
 
 
 @pytest.mark.parametrize("bs,dtype", [(32, BF16), (16, F32)],

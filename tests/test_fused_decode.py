@@ -5,7 +5,8 @@ Covers the three tentpole layers:
   * kernel numerics in Pallas interpreter mode against the gather oracle
     (apply_rope -> _scatter_pages -> paged_decode_attention), including
     page boundaries, ragged lanes, the null-block inactive encoding, past-
-    table redirect, and bf16;
+    table redirect, and bf16; the pipeline across a program's 8 lanes and
+    several windows a lane, and the served cell's geometry;
   * path selection (ops/attention.py:select_decode_impl mode gating) and
     greedy token-stream identity fused-vs-gather through
     models/llama.py:decode_step;
@@ -245,6 +246,187 @@ def test_fused_append_tile_read_modify_write_is_byte_exact(dtype, bs, rows):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=tol, atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# The pipeline the served cell runs: 8 lanes a program, several windows a lane
+# ---------------------------------------------------------------------------
+
+# Small widths keep the interpreter quick; the pipeline's shape is the cell's:
+# 16 lanes = two programs of TB = 8, a table of 2 W + 2 blocks, so lanes run
+# one, two or three windows and the double buffer's second slot, the prefetch
+# across a lane boundary and the slot parity a lane hands on are all taken.
+_PIPE_B, _PIPE_H, _PIPE_KVH, _PIPE_D, _PIPE_BS = 16, 4, 2, 64, 4
+
+
+def _pipe_geometry():
+    from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+
+    W = pa._FUSED_WINDOW
+    return W, 2 * W + 2                       # W, max_blocks (>= 2 W + 1)
+
+
+def _windows_to_positions(rng, counts, span, cap):
+    """A position that makes a lane stream ``n`` windows of ``span`` tokens:
+    anywhere in ``((n - 1) * span, n * span]`` below the table's capacity;
+    0 windows = inactive."""
+    return [0 if n == 0 else int(rng.integers(
+        (n - 1) * span + 1, min(n * span + 1, cap))) for n in counts]
+
+
+def _pipe_positions(name, rng):
+    W, NB = _pipe_geometry()
+    span, cap = W * _PIPE_BS, NB * _PIPE_BS
+    some = lambda n: list(rng.integers(1, cap - 1, size=n))  # noqa: E731
+    if name == "inactive-between-active":
+        pos = [span + 3, 0, 2 * span + 1, 0, 0, 5] + some(10)
+        pos[9], pos[11] = 0, 0
+    elif name == "inactive-program-first":
+        pos = [0] * 8 + some(8)
+    elif name == "inactive-program-last":
+        pos = some(8) + [0] * 8
+    elif name == "one-cached-token":
+        pos = [1, 1, span, 1] + some(3) + [1] + [1] * 4 + some(4)
+    elif name == "window-edges":
+        pos = [span - 1, span, span + 1, 2 * span - 1, 2 * span,
+               2 * span + 1, 1, span] + some(8)
+    elif name == "table-end":
+        # The append lands in the last table slot (first and last row) and
+        # one past it: raw_blk == NB, redirected to the null block.
+        pos = some(3) + [cap - _PIPE_BS, cap - 1, cap] + some(7) + [
+            cap, 0, cap - 1]
+    elif name == "slot-parity":
+        # Odd and even window counts side by side, a lane without windows
+        # in between: each lane starts in the slot the last one left.
+        pos = _windows_to_positions(
+            rng, [1, 2, 1, 3, 2, 2, 3, 1, 3, 3, 1, 1, 2, 0, 2, 1], span, cap)
+    else:
+        raise AssertionError(name)
+    assert len(pos) == _PIPE_B
+    return np.asarray(pos, np.int64)
+
+
+# Jitted once: a dtype is one trace, the position patterns are data.
+_FUSED_INTERPRETED = jax.jit(functools.partial(paged_decode_attention_fused,
+                                               interpret=True))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("pattern", [
+    "inactive-between-active", "inactive-program-first",
+    "inactive-program-last", "one-cached-token", "window-edges", "table-end",
+    "slot-parity"])
+def test_fused_pipeline_across_lanes_and_windows(pattern, dtype, tol):
+    """Attention on active lanes and BOTH page arrays everywhere agree with
+    the split path when a program's DMAs are one pipeline over its lanes.
+    (Lanes that write the null block all write its row 0 here, in lane
+    order in the interpreter as in the oracle's scatter; on the chip those
+    writes are unordered, and nothing reads them unmasked.)"""
+    W, NB = _pipe_geometry()
+    rng = np.random.default_rng(sum(map(ord, pattern)))
+    positions = _pipe_positions(pattern, rng)
+    cap = NB * _PIPE_BS
+    case = _fused_case(rng, _PIPE_B, _PIPE_H, _PIPE_KVH, _PIPE_D, _PIPE_BS,
+                       NB, positions, dtype=dtype)
+    assert _PIPE_B % 8 == 0 and NB >= 2 * W + 1
+
+    want, wk, wv = _gather_reference(*case)
+    q, k_new, v_new, k_pages, v_pages, table, pos = case
+    cos, sin = rope_angles(pos[:, None], _PIPE_D, THETA)
+    got, gk, gv = _FUSED_INTERPRETED(q, k_new, v_new, cos, sin, k_pages,
+                                     v_pages, table, pos)
+
+    # A lane past its table has no oracle for its attention (the gather
+    # would read beyond the table); its append is compared like the rest.
+    cmp = (positions > 0) & (positions < cap)
+    got32 = np.asarray(got, np.float32)
+    assert np.isfinite(got32).all()
+    np.testing.assert_allclose(got32[cmp], np.asarray(want, np.float32)[cmp],
+                               rtol=tol, atol=tol)
+    # K is roped in float32 and stored in the pool's dtype on both sides
+    # (one rounding apart in bf16); V is stored as it came: bit-equal.
+    np.testing.assert_allclose(np.asarray(gk, np.float32),
+                               np.asarray(wk, np.float32), rtol=tol, atol=tol)
+    assert np.array_equal(np.asarray(gv, np.float32),
+                          np.asarray(wv, np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_fused_at_the_cell_geometry_against_the_float32_oracle(dtype):
+    """Qwen2-7B's heads and the served cell's pool geometry (28 q / 4 kv x
+    128, blocks of 16, 96 table slots = 1,536 tokens; lanes of one window,
+    of three, of a dead fetch group, and with one cached token) against the
+    gather oracle run in FLOAT32 on the same values.
+
+    Tolerance for a bf16 pool, 2e-2 absolute plus 2e-2 relative.  The
+    kernel widens pages as it uses them and does its arithmetic in float32;
+    between it and the oracle stand three bf16 roundings (each at most
+    2**-9 = 0.002 relative): the wrapper scales the query by D**-0.5 in the
+    model's dtype (scores of unit variance move by ~1e-3, the softmax
+    weights by ~0.1%), and the result and the appended key row are stored.
+    On a lane with one cached token the result is of order 1 and all of
+    that shows: 0.007 absolute is what this case reads, a third of the
+    bound.  A wrong mask, page, head slice, slot or fetch group is an
+    error of order 0.1 to 1.  A float32 pool holds today's 2e-5."""
+    from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+
+    B, H, KVH, D, bs, NB = 4, 28, 4, 128, 16, 96
+    rng = np.random.default_rng(29)
+    span = bs * min(pa._FUSED_WINDOW, NB)
+    positions = np.asarray([NB * bs - 1, 1, 700, span + 1])
+    case = _fused_case(rng, B, H, KVH, D, bs, NB, positions, dtype=dtype)
+    want, wk, wv = _gather_reference(*(
+        x.astype(jnp.float32) if x.dtype == dtype else x for x in case))
+    q, k_new, v_new, k_pages, v_pages, table, pos = case
+    cos, sin = rope_angles(pos[:, None], D, THETA)
+    got, gk, gv = _FUSED_INTERPRETED(q, k_new, v_new, cos, sin, k_pages,
+                                     v_pages, table, pos)
+
+    rtol = atol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
+                               rtol=rtol, atol=atol)
+    np.testing.assert_allclose(np.asarray(gk, np.float32), np.asarray(wk),
+                               rtol=rtol, atol=atol)
+    assert np.array_equal(np.asarray(gv, np.float32), np.asarray(wv))
+
+
+def _product_operand_dtypes(jaxpr, inside_loop=False):
+    """``(inside a loop, operand dtypes)`` of every ``dot_general`` under
+    ``jaxpr``, sub-jaxprs (the kernel, its scope, its loops) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append((inside_loop,
+                          tuple(v.aval.dtype for v in eqn.invars)))
+        looped = inside_loop or eqn.primitive.name in ("while", "scan")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _product_operand_dtypes(sub, looped)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_fused_kernel_window_products_take_float32_operands(dtype):
+    """What the MXU is fed, read off the kernel's jaxpr: both products of a
+    window (the ``dot_general``s inside the window loop) take float32
+    operands for a bf16 pool as for a float32 one.  PR 29 fed them the
+    pool's bf16 and measured nothing for it on the chip (2.695 against
+    2.690 us a lane; PERF.md section 6), so the arithmetic stayed what the
+    parity tests above hold.  Whoever changes the operands changes this
+    test with a chip measurement in hand."""
+    case = _fused_case(np.random.default_rng(0), 2, 4, 2, 64, 16, 4,
+                       np.asarray([17, 40]), dtype=dtype)
+    q, k_new, v_new, k_pages, v_pages, table, pos = case
+    cos, sin = rope_angles(pos[:, None], 64, THETA)
+    jaxpr = jax.make_jaxpr(paged_decode_attention_fused)(
+        q, k_new, v_new, cos, sin, k_pages, v_pages, table, pos).jaxpr
+    window = [ops for looped, ops in _product_operand_dtypes(jaxpr)
+              if looped]
+    assert window and len(window) % 2 == 0, window   # two a lane of a program
+    assert all(ops == (jnp.float32, jnp.float32) for ops in window), window
 
 
 # ---------------------------------------------------------------------------
