@@ -242,6 +242,48 @@ def kernel_checks(cfg, seed: int, num_blocks: int, bs: int, max_blocks: int,
                list(positions[:8]), [5, 5, 3, 5, 1, 5, 5, 2], True)
 
 
+def state_kernel_checks(cfg, seed: int, lanes: int) -> None:
+    """The recurrent-state kernels at ``cfg``'s widths (a description with
+    Mamba-2 layers): ``ssm_decode_update`` on a pool of ``lanes`` lanes in
+    place against its XLA form, a whole lane a grid step and half a lane,
+    with idle rows (decay 1, input 0: the state must come back bit-equal)
+    and a lane order that is not the identity."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from k8s_llm_monitor_tpu.ops import ssm
+
+    H, P, N, G = (cfg.mamba_num_heads, cfg.mamba_head_dim,
+                  cfg.ssm_state_size, cfg.mamba_n_groups)
+    pack = ssm.state_pack(H, G, P)
+    interpret = jax.default_backend() != "tpu"
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape, np.float32))
+
+    pool = normal(lanes, H // pack, N, pack * P)
+    order = jnp.asarray(rng.permutation(lanes).astype(np.int32))
+    idle = jnp.asarray(rng.random(lanes) < 0.25)
+    decay = jnp.where(idle[:, None], 1.0, jnp.exp(-jnp.abs(normal(lanes, H))))
+    dtx = jnp.where(idle[:, None, None], 0.0, normal(lanes, H, P))
+    Bm, Cm = normal(lanes, G, N), normal(lanes, G, N)
+    want_y, want_pool = jax.jit(ssm.ssm_decode_update_xla)(
+        pool, order, decay, dtx, Bm, Cm)
+    for rows in (0, H // pack // 2):
+        got_y, got_pool = jax.jit(functools.partial(
+            ssm.ssm_decode_update, block_rows=rows, interpret=interpret),
+            donate_argnums=(0,))(pool + 0.0, order, decay, dtx, Bm, Cm)
+        name = f"ssm_decode_update, {rows or H // pack} rows a grid step"
+        _close(np, f"{name}: y", got_y, want_y, rows=~np.asarray(idle))
+        _close(np, f"{name}: the pool", got_pool, want_pool)
+        kept = np.asarray(order)[np.asarray(idle)]
+        assert np.array_equal(np.asarray(got_pool)[kept],
+                              np.asarray(pool)[kept]), (
+            f"{name}: an idle row's state changed")
+
+
 # ---------------------------------------------------------------------------
 # phase 2: the real server, over HTTP
 # ---------------------------------------------------------------------------
@@ -411,6 +453,11 @@ def one_chip(seed: int, model: str = MODEL, max_tokens: int = 9,
             f"{tcfg.max_batch} lanes, atol=rtol={ATOL}")
         kernel_checks(PRESETS[model], seed, tcfg.kv_blocks, 16, 64,
                       tcfg.max_batch)
+        # The served nemotron_h description's own kernel, and the attention
+        # kernels at its head shape (16 query heads a kv head).
+        hybrid = PRESETS["nemotron3-super-120b-a12b-22l"]
+        state_kernel_checks(hybrid, seed, 2 * tcfg.max_batch)
+        kernel_checks(hybrid, seed, tcfg.kv_blocks, 16, 64, tcfg.max_batch)
         say(f"phase kernels: ok in {time.monotonic() - t0:.1f} s "
             "(information)")
 

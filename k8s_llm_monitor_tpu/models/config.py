@@ -19,10 +19,15 @@ class LayerSpec:
 
     mixer: ``"full"`` (per-head K and V, GQA) | ``"latent"`` (DeepSeek-V3
       multi-head latent attention: one compressed latent and one rotated key
-      per token).
-    mlp: ``"dense"`` | ``"routed"`` (top-k experts) | ``"shared+routed"``.
+      per token) | ``"mamba2"`` (a state-space mixer: no cached tokens, one
+      recurrent state a lane) | ``"none"`` (the layer is a feed-forward
+      alone).
+    mlp: ``"dense"`` | ``"routed"`` (top-k experts) | ``"shared+routed"`` |
+      ``"none"`` (the layer is a mixer alone).
     cache: ``"kv"`` (two page arrays, ``kv_heads * head_dim`` lanes) |
-      ``"latent"`` (one page array, ``ModelConfig.latent_page_width`` lanes).
+      ``"latent"`` (one page array, ``ModelConfig.latent_page_width`` lanes)
+      | ``"state"`` (a row of the per-lane state pool, whatever the context)
+      | ``"none"``.
     """
 
     mixer: str
@@ -83,7 +88,8 @@ class ModelConfig:
     # num_experts); overflow tokens skip the MLP (residual passes through).
     capacity_factor: float = 1.25
     # --- Gemma-2 family knobs (defaults = Llama conventions) -----------
-    # MLP activation: "silu" (SwiGLU) or "gelu_tanh" (Gemma GeGLU).
+    # MLP activation: "silu" (SwiGLU), "gelu_tanh" (Gemma GeGLU) or "relu2"
+    # (relu(x)^2, nemotron_h's un-gated MLPs: ``mlp_gated`` False).
     mlp_activation: str = "silu"
     # Sandwich norms: extra RMSNorm on the attention and MLP OUTPUTS
     # (post_attn_norm / post_mlp_norm) before the residual add; the
@@ -127,6 +133,35 @@ class ModelConfig:
     moe_scoring: str = "softmax"
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # The experts this chip holds of an expert-parallel layer: ``experts_held``
+    # (0 = all ``num_experts``) from ``expert_start``.  The router keeps its
+    # width and its experts per token; the layer sums the chosen experts it
+    # holds, with weights normalised over all the chosen, and what the
+    # absent ones would add is left out (models/llama.py:_moe_mlp_share).
+    experts_held: int = 0
+    expert_start: int = 0
+    # Routed experts live in a latent of this width between a down- and an
+    # up-projection of the hidden size (0 = they read the hidden size).
+    moe_latent_size: int = 0
+    # Width of the shared MLP (0 = n_shared_experts x the expert width).
+    moe_shared_intermediate_size: int = 0
+    # False: an MLP is two kernels, ``down(act(up(x)))``, with no gate
+    # (``mlp_activation`` "relu2": relu(x)^2).
+    mlp_gated: bool = True
+    # False: attention layers rotate nothing (position reaches the model
+    # through its recurrent layers).
+    use_rope: bool = True
+    # --- one sub-block a layer (nemotron_h) -----------------------------
+    # One letter a layer: ``M`` a Mamba-2 mixer, ``*`` an attention mixer,
+    # ``E`` an expert feed-forward — each alone, under one norm and one
+    # residual.  None = every layer is a mixer and an MLP.
+    layer_pattern: Optional[str] = None
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    mamba_n_groups: int = 1
+    conv_kernel: int = 4
+    ssm_chunk_size: int = 128
 
     def layer_spec(self, i: int) -> LayerSpec:
         """Mixer, MLP and cache kind of layer ``i``."""
@@ -134,19 +169,76 @@ class ModelConfig:
             mlp = "shared+routed" if self.n_shared_experts > 0 else "routed"
         else:
             mlp = "dense"
+        if self.layer_pattern is not None:
+            kind = self.layer_pattern[i]
+            if kind == "M":
+                return LayerSpec(mixer="mamba2", mlp="none", cache="state")
+            if kind == "*":
+                return LayerSpec(mixer="full", mlp="none", cache="kv")
+            if kind == "E":
+                return LayerSpec(mixer="none", mlp=mlp, cache="none")
+            raise ValueError(f"layer_pattern[{i}] = {kind!r} (M | * | E)")
         return LayerSpec(mixer=self.mixer, mlp=mlp,
                          cache="latent" if self.mixer == "latent" else "kv")
+
+    def layers_with(self, cache: str) -> list[int]:
+        """The layers whose cache is of kind ``cache``, in order: a pool has
+        one entry for each (``KVPages``)."""
+        return [i for i in range(self.num_layers)
+                if self.layer_spec(i).cache == cache]
 
     @property
     def latent(self) -> bool:
         return self.mixer == "latent"
 
     @property
+    def recurrent(self) -> bool:
+        """Some layer carries a recurrent state from token to token."""
+        return bool(self.layers_with("state"))
+
+    @property
     def expert_layers(self) -> int:
         """Layers whose MLP routes (0 for a dense model)."""
         if self.num_experts <= 0:
             return 0
-        return max(0, self.num_layers - self.first_dense_layers)
+        return sum(self.layer_spec(i).mlp in ("routed", "shared+routed")
+                   for i in range(self.num_layers))
+
+    @property
+    def experts_held_(self) -> int:
+        """Experts whose kernels this chip holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """The expert layer is the share-aware one: a share of the experts,
+        a latent, or an MLP without a gate."""
+        return bool(self.experts_held or self.moe_latent_size
+                    or not self.mlp_gated)
+
+    @property
+    def shared_width(self) -> int:
+        return (self.moe_shared_intermediate_size
+                or self.n_shared_experts * self.expert_width)
+
+    @property
+    def mamba_inner(self) -> int:
+        """Channels of a Mamba-2 mixer's ``x`` and ``z``."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels the short convolution runs over: ``[x | B | C]``."""
+        return (self.mamba_inner
+                + 2 * self.mamba_n_groups * self.ssm_state_size)
+
+    def state_lane_bytes(self, tail_itemsize: int = 2) -> int:
+        """Bytes one lane of the state pool holds over all recurrent layers:
+        the float32 state and the convolution's tail of ``conv_kernel - 1``
+        un-convolved rows."""
+        return len(self.layers_with("state")) * (
+            self.mamba_inner * self.ssm_state_size * 4
+            + (self.conv_kernel - 1) * self.mamba_conv_dim * tail_itemsize)
 
     @property
     def expert_width(self) -> int:
@@ -174,7 +266,8 @@ class ModelConfig:
         """Bytes one cached token costs over all layers (scales excluded)."""
         if self.latent:
             return self.num_layers * self.latent_page_width * itemsize
-        return 2 * self.num_layers * self.num_kv_heads * self.head_dim_ * itemsize
+        return (2 * len(self.layers_with("kv")) * self.num_kv_heads
+                * self.head_dim_ * itemsize)
 
     @property
     def attn_scale(self) -> Optional[float]:
@@ -233,6 +326,22 @@ TINY_LATENT_MOE = ModelConfig(
     moe_intermediate_size=24, n_shared_experts=2,
     moe_scoring="sigmoid+bias", norm_topk_prob=True,
     routed_scaling_factor=2.448)
+
+# The nemotron_h block at test size: all three letters, more groups than one,
+# fewer experts held than routed (the second quarter of 32: 8, so that the
+# expert axis divides TP-8 like every preset's), heads packed four to a row
+# of the state pool.  vocab >= 259 as above.
+TINY_NEMOTRON_H = ModelConfig(
+    name="tiny-nemotron-h", vocab_size=320, hidden_size=64,
+    intermediate_size=24, num_layers=6, num_heads=4, num_kv_heads=2,
+    head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-5, use_rope=False,
+    layer_pattern="ME*EME", mamba_num_heads=8, mamba_head_dim=8,
+    ssm_state_size=16, mamba_n_groups=2, conv_kernel=4, ssm_chunk_size=8,
+    num_experts=32, num_experts_per_tok=5, experts_held=8, expert_start=8,
+    moe_intermediate_size=24, moe_latent_size=32, n_shared_experts=1,
+    moe_shared_intermediate_size=40, mlp_gated=False, mlp_activation="relu2",
+    moe_scoring="sigmoid+bias", norm_topk_prob=True,
+    routed_scaling_factor=5.0)
 
 LLAMA3_8B = ModelConfig(
     name="llama3-8b",
@@ -385,6 +494,49 @@ KANANA2_30B_A3B_12L = ModelConfig(
     routed_scaling_factor=2.448,
 )
 
+# nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 (config.json, model_type
+# nemotron_h), one chip's share of the first of four pipeline stages: the
+# first 22 of 88 layers (two whole periods of the pattern, 5 M : 5 E : 1 *),
+# experts 0-127 of each layer's 512 (the router keeps 512 and top 22), rows
+# 0-32,767 of the 131,072-row vocabulary; every width as published
+# (benchmarks/configs/nemotron3-super-120b-a12b-w8a8.json has the
+# reckoning).  ``rope_theta`` and ``intermediate_size`` are the file's keys;
+# nothing rotates (``use_rope``) and no layer has a dense MLP.
+NEMOTRON3_SUPER_22L = ModelConfig(
+    name="nemotron3-super-120b-a12b-22l",
+    vocab_size=32_768,
+    hidden_size=4096,
+    intermediate_size=2688,
+    num_layers=22,
+    num_heads=32,
+    num_kv_heads=2,
+    head_dim=128,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-5,
+    max_seq_len=262_144,
+    use_rope=False,
+    layer_pattern="MEMEMEM*EMEMEMEM*EMEME",
+    mamba_num_heads=128,
+    mamba_head_dim=64,
+    ssm_state_size=128,
+    mamba_n_groups=8,
+    conv_kernel=4,
+    ssm_chunk_size=128,
+    num_experts=512,
+    num_experts_per_tok=22,
+    experts_held=128,
+    expert_start=0,
+    moe_intermediate_size=2688,
+    moe_latent_size=1024,
+    n_shared_experts=1,
+    moe_shared_intermediate_size=5376,
+    mlp_gated=False,
+    mlp_activation="relu2",
+    moe_scoring="sigmoid+bias",
+    norm_topk_prob=True,
+    routed_scaling_factor=5.0,
+)
+
 # A ~1.1B config used for single-chip benchmarks when full 8B weights would not
 # leave headroom for the KV cache on a 16 GB v5e chip with random-init weights.
 LLAMA_1B = ModelConfig(
@@ -402,9 +554,10 @@ LLAMA_1B = ModelConfig(
 
 PRESETS = {
     c.name: c
-    for c in [TINY, TINY_QWEN, TINY_MOE, TINY_LATENT_MOE, LLAMA3_8B,
-              LLAMA3_70B, MISTRAL_7B, MIXTRAL_8X7B, QWEN2_7B, QWEN2_72B,
-              GEMMA2_2B, GEMMA2_9B, LLAMA_1B, KANANA2_30B_A3B_12L]
+    for c in [TINY, TINY_QWEN, TINY_MOE, TINY_LATENT_MOE, TINY_NEMOTRON_H,
+              LLAMA3_8B, LLAMA3_70B, MISTRAL_7B, MIXTRAL_8X7B, QWEN2_7B,
+              QWEN2_72B, GEMMA2_2B, GEMMA2_9B, LLAMA_1B, KANANA2_30B_A3B_12L,
+              NEMOTRON3_SUPER_22L]
 }
 
 

@@ -42,6 +42,12 @@ from k8s_llm_monitor_tpu.ops.attention import (
 )
 from k8s_llm_monitor_tpu.ops.norms import rms_norm
 from k8s_llm_monitor_tpu.ops.rope import apply_rope, rope_angles
+from k8s_llm_monitor_tpu.ops.ssm import (
+    pack_state,
+    ssm_chunk_scan,
+    ssm_decode_update_xla,
+    state_pack,
+)
 
 Params = dict[str, Any]
 
@@ -78,6 +84,20 @@ class KVPages(NamedTuple):
     pool's cost is what its shape says.  ``v`` is an empty list: the value of
     a row is its own first ``kv_lora_rank`` lanes.  Block ids, block tables
     and the allocator are the same as for the kv kind.
+
+    A pool whose layers differ in kind (``ModelConfig.layer_pattern``): ``k``
+    and ``v`` hold one entry for each layer whose cache is of kind ``"kv"``,
+    in layer order (``cfg.layers_with("kv")``) — a layer without cached
+    tokens has no pages.  ``ssm`` / ``conv`` are the **state pool**, one
+    entry for each layer of kind ``"state"``: a Mamba-2 mixer's recurrent
+    state ``[lanes, H / pack, N, pack * P]`` float32 (ops/ssm.py has the
+    layout) and the last ``conv_kernel - 1`` un-convolved rows of its short
+    convolution ``[lanes, conv_kernel - 1, channels]``.  It is indexed by
+    decode lane, not by block: a lane's state costs the same whatever its
+    context holds, is overwritten whole when a fresh prompt is admitted to
+    the lane, and is updated in place by the programs that are given the
+    pool (donated with the pages).  Empty tuples (the default) for a model
+    without recurrent layers: no extra leaves, the same treedef as ever.
     """
 
     k: list[jnp.ndarray]
@@ -89,6 +109,8 @@ class KVPages(NamedTuple):
     # jitted program keeps its exact treedef and donation layout.
     k_scale: tuple | list = ()
     v_scale: tuple | list = ()
+    ssm: tuple | list = ()
+    conv: tuple | list = ()
 
     @property
     def num_blocks(self) -> int:
@@ -143,12 +165,30 @@ def dequantize_kv(x_q: jnp.ndarray, scale: jnp.ndarray,
 
 
 def init_kv_pages(cfg: ModelConfig, num_blocks: int, block_size: int,
-                  kv_quant: str = "") -> KVPages:
+                  kv_quant: str = "", state_lanes: int = 0) -> KVPages:
     """Allocate the paged KV pool.  ``kv_quant`` ("int8"/"fp8") selects the
     quantized tier: page arrays in the storage dtype plus per-(token, head)
     float32 scale arrays; "" keeps the historical unquantized layout.
     The page kind is the description's (``cfg.layer_spec(i).cache``): a
-    latent pool is one array a layer (see :class:`KVPages`)."""
+    latent pool is one array a layer, a layer without cached tokens has no
+    pages, and a recurrent layer has a row for each of ``state_lanes`` decode
+    lanes in the state pool (see :class:`KVPages`)."""
+    kv_layers = len(cfg.layers_with("kv"))
+    state = {}
+    if cfg.recurrent:
+        if kv_quant:
+            raise ValueError(
+                f"a pool beside recurrent state is not built for "
+                f"kv_dtype={kv_quant!r}")
+        Hm, P, N = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size
+        pack = state_pack(Hm, cfg.mamba_n_groups, P)
+        n = len(cfg.layers_with("state"))
+        state = dict(
+            ssm=[jnp.zeros((state_lanes, Hm // pack, N, pack * P),
+                           jnp.float32) for _ in range(n)],
+            conv=[jnp.zeros((state_lanes, cfg.conv_kernel - 1,
+                             cfg.mamba_conv_dim), jnp.dtype(cfg.dtype))
+                  for _ in range(n)])
     if cfg.latent:
         if kv_quant:
             raise ValueError(
@@ -162,17 +202,18 @@ def init_kv_pages(cfg: ModelConfig, num_blocks: int, block_size: int,
         qdtype, _ = kv_quant_spec(kv_quant)
         sshape = (num_blocks, block_size, cfg.num_kv_heads)
         return KVPages(
-            k=[jnp.zeros(shape, qdtype) for _ in range(cfg.num_layers)],
-            v=[jnp.zeros(shape, qdtype) for _ in range(cfg.num_layers)],
+            k=[jnp.zeros(shape, qdtype) for _ in range(kv_layers)],
+            v=[jnp.zeros(shape, qdtype) for _ in range(kv_layers)],
             k_scale=[jnp.zeros(sshape, jnp.float32)
-                     for _ in range(cfg.num_layers)],
+                     for _ in range(kv_layers)],
             v_scale=[jnp.zeros(sshape, jnp.float32)
-                     for _ in range(cfg.num_layers)],
+                     for _ in range(kv_layers)],
         )
     dtype = jnp.dtype(cfg.kv_dtype or cfg.dtype)
     return KVPages(
-        k=[jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)],
-        v=[jnp.zeros(shape, dtype) for _ in range(cfg.num_layers)],
+        k=[jnp.zeros(shape, dtype) for _ in range(kv_layers)],
+        v=[jnp.zeros(shape, dtype) for _ in range(kv_layers)],
+        **state,
     )
 
 
@@ -195,10 +236,11 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         return p
 
     def expert_dense(key, in_f, out_f):
-        # Stacked expert kernels [E, in, out]; leading axis shards over the
-        # mesh's ``model`` axis (expert parallelism).
+        # Stacked expert kernels [E, in, out], E the experts held here;
+        # leading axis shards over the mesh's ``model`` axis (expert
+        # parallelism).
         w = jax.random.normal(
-            key, (cfg.num_experts, in_f, out_f), jnp.float32) * (in_f ** -0.5)
+            key, (cfg.experts_held_, in_f, out_f), jnp.float32) * (in_f ** -0.5)
         return {"kernel": w.astype(dtype)}
 
     def mlp(keys, width):
@@ -218,6 +260,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
         lk = jax.random.split(keys[2 + i], 8)
         xk = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 4)
         spec = cfg.layer_spec(i)
+        if cfg.layer_pattern is not None:
+            layers.append(init_single_layer(keys[2 + i], cfg, spec, dense,
+                                            expert_dense))
+            continue
         layer = {
             "input_norm": norm_init((H,), dtype),
             "post_norm": norm_init((H,), dtype),
@@ -269,6 +315,77 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], H, cfg.vocab_size, False)
     return params
+
+
+def mamba_init(key: jax.Array, cfg: ModelConfig) -> Params:
+    """What a Mamba-2 mixer holds beside its two projections, seeded the
+    family's usual way (a trained one is not reachable): the depthwise
+    convolution (float32, uniform in +-conv_kernel**-0.5) and its bias
+    (zeros), ``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform in
+    0.001-0.1 (the published ``time_step_min`` / ``time_step_max``),
+    ``A_log`` = log of a uniform 1-16, ``D`` ones, the gated norm ones."""
+    k = jax.random.split(key, 3)
+    Hm, Kc = cfg.mamba_num_heads, cfg.conv_kernel
+    dt = jnp.exp(jax.random.uniform(k[0], (Hm,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return {
+        "conv": {"kernel": jax.random.uniform(
+                     k[1], (Kc, cfg.mamba_conv_dim), jnp.float32,
+                     -(Kc ** -0.5), Kc ** -0.5),
+                 "bias": jnp.zeros((cfg.mamba_conv_dim,), jnp.float32)},
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus's inverse
+        "A_log": jnp.log(jax.random.uniform(k[2], (Hm,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((Hm,), jnp.float32),
+        "ssm_norm": jnp.ones((cfg.mamba_inner,), jnp.dtype(cfg.dtype)),
+    }
+
+
+def init_single_layer(key: jax.Array, cfg: ModelConfig, spec, dense,
+                      expert_dense) -> Params:
+    """A layer that is one sub-block under one norm
+    (``ModelConfig.layer_pattern``).  ``dense(key, in, out, bias)`` and
+    ``expert_dense(key, in, out)`` build a projection and a stack of the
+    held experts' kernels in the caller's form (wide here, int8 in
+    utils/quantize.py:init_params_quantized); what stays wide under every
+    quantisation is built here."""
+    H, D = cfg.hidden_size, cfg.head_dim_
+    k = jax.random.split(key, 8)
+    layer = {"input_norm": jnp.ones((H,), jnp.dtype(cfg.dtype))}
+    if spec.mixer == "mamba2":
+        layer["in_proj"] = dense(
+            k[0], H, cfg.mamba_inner + cfg.mamba_conv_dim
+            + cfg.mamba_num_heads, False)
+        layer["out_proj"] = dense(k[1], cfg.mamba_inner, H, False)
+        layer.update(mamba_init(k[2], cfg))
+    elif spec.mixer == "full":
+        layer["q"] = dense(k[0], H, cfg.num_heads * D, cfg.qkv_bias)
+        layer["k"] = dense(k[1], H, cfg.num_kv_heads * D, cfg.qkv_bias)
+        layer["v"] = dense(k[2], H, cfg.num_kv_heads * D, cfg.qkv_bias)
+        layer["o"] = dense(k[3], cfg.num_heads * D, H, False)
+    else:
+        if cfg.moe_scoring != "sigmoid+bias" or cfg.mlp_gated:
+            raise NotImplementedError(
+                "a feed-forward layer of a layer_pattern is the un-gated "
+                "sigmoid-routed one")
+        L, Ie = cfg.moe_latent_size or H, cfg.expert_width
+        # Scored in float32; the selection bias is zeros plus a small
+        # normal (a trained one is not reachable), used for the choice only.
+        layer["router"] = {
+            "kernel": jax.random.normal(k[0], (H, cfg.num_experts),
+                                        jnp.float32) * H ** -0.5,
+            "e_bias": 0.01 * jax.random.normal(
+                k[1], (cfg.num_experts,), jnp.float32)}
+        if cfg.moe_latent_size:
+            layer["latent_down"] = dense(k[2], H, L, False)
+            layer["latent_up"] = dense(k[3], L, H, False)
+        layer["up_e"] = expert_dense(k[4], L, Ie)
+        layer["down_e"] = expert_dense(k[5], Ie, L)
+        if spec.mlp == "shared+routed":
+            layer["shared"] = {"up": dense(k[6], H, cfg.shared_width, False),
+                               "down": dense(k[7], cfg.shared_width, H,
+                                             False)}
+    return layer
 
 
 # ---------------------------------------------------------------------------
@@ -422,6 +539,8 @@ def _qkv_proj(layer: Params, cfg: ModelConfig, x: jnp.ndarray):
 def _qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
     """Project + rope.  x: [B, S, H] -> q [B,S,nH,D], k/v [B,S,nKV,D]."""
     q, k, v = _qkv_proj(layer, cfg, x)
+    if not cfg.use_rope:
+        return q, k, v
     with jax.named_scope("qkv"):
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
@@ -910,16 +1029,253 @@ def _moe_mlp_routed(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
     return y, counts
 
 
+# Rows of expert-sorted assignments one pass of ``_moe_mlp_share`` computes:
+# what bounds its buffers ([rows, expert width] in int32 and in the
+# activation dtype) whatever the call holds.
+_EXPERT_WINDOW_ROWS = 16_384
+
+
+def _moe_mlp_share(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
+                   valid: Optional[jnp.ndarray] = None,
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The serving expert layer of a chip that holds a share of the experts
+    (``cfg.experts_held`` from ``cfg.expert_start``; all of them when 0).
+
+    x [B, S, H] -> (y [B, S, H], counts float32[5]).  The router scores all
+    ``cfg.num_experts`` and keeps its experts per token; the weights are
+    normalised over all the chosen, held or not.  An assignment to an expert
+    that is not held sorts behind every group, exactly as padding's does,
+    and computes nothing: what the absent experts would add is left out.
+    The held assignments are computed in windows of ``_EXPERT_WINDOW_ROWS``
+    sorted rows — as many as there are held rows, so the work and the
+    buffers follow what is held, not tokens x experts per token, and no
+    assignment to a held expert is dropped.  With ``latent_down`` /
+    ``latent_up`` the experts read and write a latent between the two
+    projections; without ``gate_e`` an expert is ``down(act(up(x)))``.  The
+    projections and the shared MLP are what every chip computes alike.
+
+    ``counts`` = (held assignments computed, held experts with at least one
+    row, the fullest held expert's rows, experts held, assignments of the
+    real tokens held or not): the engine sums them over layers and steps
+    onto the call's ``engine.call`` span.
+    """
+    B, S, H = x.shape
+    T, K = B * S, cfg.num_experts_per_tok
+    E, e0 = cfg.experts_held_, cfg.expert_start
+    xt = x.reshape(T, H)
+    topi, topv = _route(layer, cfg, xt)                         # [T, K]
+    if "latent_down" in layer:
+        with jax.named_scope("latent_down"):
+            xl = _linear(layer["latent_down"], xt, cfg.act_quant)
+    else:
+        xl = xt
+    Lw = xl.shape[-1]
+    with jax.named_scope("routed"):
+        real = (jnp.ones((T,), bool) if valid is None
+                else valid.reshape(T))
+        local = topi - e0
+        held = (local >= 0) & (local < E) & real[:, None]
+        flat_e = jnp.where(held, local, E).reshape(T * K)
+        aq = cfg.act_quant and "kernel_q" in layer["up_e"]
+        xq, xs = _quant_act(xl) if aq else (None, None)
+        # One stable sort by expert carries what a row needs with it, as in
+        # ``_moe_mlp_routed``.
+        iota = jnp.arange(T * K, dtype=jnp.int32)
+        carried = [flat_e, iota, topv.reshape(T * K)]
+        if aq:
+            carried.append(jnp.repeat(xs[:, 0], K))
+        rows_e, order, w_rows, *rs = jax.lax.sort(
+            carried, num_keys=1, is_stable=True)
+        group_sizes = jnp.sum(
+            flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(group_sizes)
+        starts, n_held = ends - group_sizes, ends[-1]
+        RW = min(T * K, _EXPERT_WINDOW_ROWS)
+        windows = -(-T * K // RW)
+        fit = lambda a: jnp.pad(a, (0, windows * RW - T * K))  # noqa: E731
+        rows_e, tok, w_rows = (fit(jnp.minimum(rows_e, E - 1)),
+                               fit(order // K), fit(w_rows))
+        rs = [fit(r) for r in rs]
+
+        def window(w, ys):
+            at = w * RW
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, at, RW)  # noqa: E731
+            e_w, tok_w, wr_w = cut(rows_e), cut(tok), cut(w_rows)[:, None]
+            sizes = (jnp.clip(ends - at, 0, RW)
+                     - jnp.clip(starts - at, 0, RW))
+            rows, rs_w = ((xq[tok_w], cut(rs[0])[:, None]) if aq
+                          else (xl[tok_w], None))
+            h = _expert_rows(layer["up_e"], rows, rs_w, e_w, sizes, x.dtype)
+            if "gate_e" in layer:
+                h = _mlp_act(cfg, _expert_rows(
+                    layer["gate_e"], rows, rs_w, e_w, sizes, x.dtype)) * h
+            else:
+                h = _mlp_act(cfg, h)
+            if aq:      # the router's weight rides on the dequantisation
+                hq, hs = _quant_act(h)
+                ys_w = _expert_rows(layer["down_e"], hq, hs * wr_w, e_w,
+                                    sizes, x.dtype)
+            else:
+                ys_w = _expert_rows(layer["down_e"], h, None, e_w, sizes,
+                                    x.dtype)
+                ys_w = (ys_w.astype(jnp.float32) * wr_w).astype(x.dtype)
+            # Rows behind the last group are whatever the product left.
+            live = (at + jnp.arange(RW) < n_held)[:, None]
+            ys_w = jnp.where(live, ys_w, jnp.zeros((), ys_w.dtype))
+            return jax.lax.dynamic_update_slice_in_dim(ys, ys_w, at, 0)
+
+        ys = jnp.zeros((windows * RW, Lw), x.dtype)
+        if windows == 1:
+            ys = window(0, ys)
+        else:
+            ys = jax.lax.fori_loop(0, -(-n_held // RW), window, ys)
+        # Back to token order: row inv[t * K + k] is token t's k-th expert.
+        _, inv = jax.lax.sort([order, iota], num_keys=1)
+        y = jnp.sum(ys[inv].reshape(T, K, Lw).astype(jnp.float32),
+                    axis=1).astype(x.dtype)
+        counts = jnp.stack([
+            n_held, jnp.sum(group_sizes > 0), jnp.max(group_sizes),
+            jnp.asarray(E, jnp.int32), K * jnp.sum(real, dtype=jnp.int32),
+        ]).astype(jnp.float32)
+    if "latent_up" in layer:
+        with jax.named_scope("latent_up"):
+            y = _linear(layer["latent_up"], y, cfg.act_quant)
+    y = y.reshape(B, S, H)
+    if "shared" in layer:
+        with jax.named_scope("shared"):
+            y = y + _dense_mlp(layer["shared"], cfg, x)
+    return y, counts
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 mixer
+# ---------------------------------------------------------------------------
+
+
+def _mamba_in(layer: Params, cfg: ModelConfig, h: jnp.ndarray):
+    """``[z | xBC | dt] = in_proj(h)`` with ``dt = softplus(dt + dt_bias)``
+    (float32; the published ``time_step_limit`` is (0, inf): no clamp)."""
+    d_in, C = cfg.mamba_inner, cfg.mamba_conv_dim
+    zxbcdt = _linear(layer["in_proj"], h, cfg.act_quant)
+    dt = jax.nn.softplus(zxbcdt[..., d_in + C:].astype(jnp.float32)
+                         + layer["dt_bias"])
+    return zxbcdt[..., :d_in], zxbcdt[..., d_in:d_in + C], dt
+
+
+def _mamba_heads(cfg: ModelConfig, xbc: jnp.ndarray):
+    """The convolved ``xBC`` as ``x [..., H, P]``, ``B`` and ``C``
+    ``[..., G, N]``."""
+    d_in, G, N = cfg.mamba_inner, cfg.mamba_n_groups, cfg.ssm_state_size
+    lead = xbc.shape[:-1]
+    return (xbc[..., :d_in].reshape(*lead, cfg.mamba_num_heads,
+                                    cfg.mamba_head_dim),
+            xbc[..., d_in:d_in + G * N].reshape(*lead, G, N),
+            xbc[..., d_in + G * N:].reshape(*lead, G, N))
+
+
+def _mamba_out(layer: Params, cfg: ModelConfig, y: jnp.ndarray,
+               x: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """``out_proj(group_rms_norm((y + D x) * silu(z)) * w)``: y, x
+    [..., H, P], z [..., d_in]; the arithmetic is float32."""
+    G, d_in = cfg.mamba_n_groups, cfg.mamba_inner
+    lead = z.shape[:-1]
+    y = (y.astype(jnp.float32)
+         + layer["D"][:, None] * x.astype(jnp.float32)).reshape(*lead, d_in)
+    g = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(*lead, G, d_in // G)
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    g = g.reshape(*lead, d_in) * layer["ssm_norm"].astype(jnp.float32)
+    return _linear(layer["out_proj"], g.astype(z.dtype), cfg.act_quant)
+
+
+def _mamba2_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
+                    positions: jnp.ndarray, valid: jnp.ndarray,
+                    last: jnp.ndarray):
+    """A Mamba-2 mixer over whole sequences from a zero state: rows
+    ``[B, S]``, or one packed stream ``[1, T]`` whose segments start where
+    ``positions`` is 0 — neither the convolution nor the state crosses a
+    start, and padding (``valid`` False) leaves the state as the last real
+    token left it.  ``last`` [R]: flat indices of each sequence's last real
+    token.  Returns (out [B, S, hidden], the state after each ``last`` in
+    the pool's layout [R, H / pack, N, pack * P] float32, the un-convolved
+    ``xBC`` rows ending there [R, conv_kernel - 1, channels])."""
+    Bt, S = positions.shape
+    Kc = cfg.conv_kernel
+    with jax.named_scope("mixer"), jax.named_scope("mamba2"):
+        z, xbc, dt = _mamba_in(layer, cfg, h)
+        # Causal depthwise convolution: a source before the sequence's start
+        # is zero.
+        src = jnp.pad(xbc, ((0, 0), (Kc - 1, 0), (0, 0)))
+        w = layer["conv"]["kernel"]
+        conv = layer["conv"]["bias"]
+        for k in range(Kc):
+            reach = (positions >= Kc - 1 - k)[..., None]
+            conv = conv + w[k] * jnp.where(
+                reach, src[:, k:k + S].astype(jnp.float32), 0.0)
+        # The scan's products take the activation dtype (float32 sums).
+        x, Bm, Cm = _mamba_heads(cfg, jax.nn.silu(conv).astype(h.dtype))
+        y, states = ssm_chunk_scan(
+            x, jnp.where(valid[..., None], dt, 0.0), -jnp.exp(layer["A_log"]),
+            Bm, Cm, positions == 0, last, chunk=cfg.ssm_chunk_size)
+        out = _mamba_out(layer, cfg, y, x, z)
+        back = jnp.arange(Kc - 1, dtype=jnp.int32) - (Kc - 2)   # -2, -1, 0
+        rows = xbc.reshape(Bt * S, -1)[
+            jnp.maximum(last[:, None] + back[None, :], 0)]
+        seen = positions.reshape(-1)[last][:, None] + back[None, :] >= 0
+        tail = jnp.where(seen[..., None], rows, jnp.zeros((), rows.dtype))
+        # The tail is an output of the program alone: left to itself the
+        # compiler gathers it last and keeps every layer's whole ``xBC``
+        # alive until then (160 MB a layer at 8,192 tokens).
+        out, tail = jax.lax.optimization_barrier((out, tail))
+    pack = state_pack(cfg.mamba_num_heads, cfg.mamba_n_groups,
+                      cfg.mamba_head_dim)
+    return out, pack_state(states, pack), tail
+
+
+def _mamba2_decode(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
+                   active: jnp.ndarray, ssm: jnp.ndarray, conv: jnp.ndarray,
+                   lanes: Optional[jnp.ndarray], update):
+    """One token a row through a Mamba-2 mixer, against the state pool.
+    h [B, 1, hidden]; ``active`` [B] (an idle row leaves its lane's state
+    and tail as they were); ``lanes`` [B] the pool lane of each row, None =
+    row b is lane b of a pool of B lanes.  Returns (out [B, 1, hidden], the
+    layer's ``ssm`` and ``conv`` pools, updated)."""
+    B = h.shape[0]
+    with jax.named_scope("mixer"), jax.named_scope("mamba2"):
+        z, xbc, dt = _mamba_in(layer, cfg, h[:, 0])
+        tail = conv if lanes is None else conv[lanes]
+        seen = jnp.concatenate([tail, xbc[:, None].astype(tail.dtype)], axis=1)
+        convd = layer["conv"]["bias"] + jnp.sum(
+            layer["conv"]["kernel"] * seen.astype(jnp.float32), axis=1)
+        x, Bm, Cm = _mamba_heads(cfg, jax.nn.silu(convd))
+        decay = jnp.where(active[:, None],
+                          jnp.exp(-dt * jnp.exp(layer["A_log"])), 1.0)
+        dtx = jnp.where(active[:, None, None], dt[..., None] * x, 0.0)
+        y, ssm = update(
+            ssm, jnp.arange(B, dtype=jnp.int32) if lanes is None else lanes,
+            decay, dtx, Bm, Cm)
+        out = _mamba_out(layer, cfg, y, x, z)[:, None]
+        tail = jnp.where(active[:, None, None], seen[:, 1:], tail)
+        conv = tail if lanes is None else conv.at[lanes].set(tail, mode="drop")
+    return out, ssm, conv
+
+
 def _mlp_act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.mlp_activation == "gelu_tanh":      # Gemma GeGLU
         return jax.nn.gelu(x, approximate=True)
+    if cfg.mlp_activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
 def _dense_mlp(p: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     """Gated MLP over ``p``'s gate/up/down: a dense layer's own, or the
-    shared experts of an expert layer."""
+    shared experts of an expert layer.  Without a gate (``mlp_gated``
+    False) it is ``down(act(up(x)))``."""
     aq = cfg.act_quant
+    if "gate" not in p:
+        return _linear(p["down"], _mlp_act(cfg, _linear(p["up"], x, aq)), aq)
     gate = _linear(p["gate"], x, aq)
     up = _linear(p["up"], x, aq)
     return _linear(p["down"], _mlp_act(cfg, gate) * up, aq)
@@ -932,7 +1288,8 @@ def _mlp(layer: Params, cfg: ModelConfig, x: jnp.ndarray,
     description's (``cfg.layer_spec(i).mlp``), and its parameters were built
     from it: a layer with a router routes."""
     if "router" in layer:
-        y, counts = _moe_mlp_routed(layer, cfg, x, valid)
+        y, counts = (_moe_mlp_share if cfg.expert_share
+                     else _moe_mlp_routed)(layer, cfg, x, valid)
         if moe_stats is not None:
             moe_stats.append(counts)
         return y
@@ -1046,6 +1403,16 @@ def layer_block(
     B, S = x.shape[:2]
     h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps,
                  cfg.rmsnorm_unit_offset)
+    spec = cfg.layer_spec(layer_idx)
+    if spec.mixer in ("mamba2", "none"):
+        # One sub-block under one norm and one residual.
+        if spec.mixer == "none":
+            with jax.named_scope("mlp"):
+                return x + _mlp(layer, cfg, h), jnp.zeros((), jnp.float32)
+        last = jnp.arange(B, dtype=jnp.int32) * S + S - 1
+        out, _, _ = _mamba2_prefill(layer, cfg, h, positions,
+                                    jnp.ones((B, S), bool), last)
+        return x + out, jnp.zeros((), jnp.float32)
     if cfg.latent:
         q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
         with jax.named_scope("attention"), jax.named_scope("latent"):
@@ -1058,6 +1425,8 @@ def layer_block(
             attn = attn_fn(q, k, v, q_positions=positions,
                            **_attn_extras(cfg, layer_idx))
     o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
+    if spec.mlp == "none":
+        return x + o, jnp.zeros((), jnp.float32)
     return _residual_tail(layer, cfg, x, o, collect_aux)
 
 
@@ -1219,6 +1588,7 @@ def _prefill_impl(
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
     view: Optional[RowView] = None,
+    lanes: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Shared prefill layer loop.
 
@@ -1248,8 +1618,25 @@ def _prefill_impl(
     row.  Embedding, norms, projections, rope, the page scatter and the MLP
     are per token and run on the stream; attention alone goes through the
     view's rows and comes back.
+
+    ``lanes`` [rows]: the state-pool lane each row's recurrent state is
+    written to (a description with recurrent layers; a lane past the pool
+    drops the write, as an idle row's must).  The state starts from zero:
+    continuing one (``attend_to_pages``) is not built.
     """
     B, S = tokens.shape
+    if cfg.recurrent:
+        if attend_to_pages or lanes is None:
+            raise ValueError(
+                "recurrent layers are prefilled whole, from a zero state, "
+                "into the lanes the call names: chunked prefill, a cached "
+                "prefix and the verify pass are not built for them")
+        # Where each row's last real token lies in the flattened tokens.
+        last = jnp.maximum(lengths - 1, 0) + (
+            jnp.arange(B, dtype=jnp.int32) * S if view is None
+            else view.offset)
+        last = jnp.minimum(last, B * S - 1)
+    kv_of = {li: n for n, li in enumerate(cfg.layers_with("kv"))}
     if cfg.latent and paged_attn_fn is not None \
             and not is_latent_prefill_impl(paged_attn_fn):
         raise ValueError(
@@ -1274,10 +1661,25 @@ def _prefill_impl(
     quant = pages.quantized
     new_k, new_v = [], []
     new_ks, new_vs = [], []
+    new_ssm, new_conv = [], []
     for li, layer in enumerate(params["layers"]):
         if hidden is not None:
             hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
+        spec = cfg.layer_spec(li)
+        if spec.mixer == "mamba2":
+            out, state, tail = _mamba2_prefill(layer, cfg, h, positions,
+                                               valid, last)
+            n = len(new_ssm)
+            new_ssm.append(pages.ssm[n].at[lanes].set(state, mode="drop"))
+            new_conv.append(pages.conv[n].at[lanes].set(
+                tail.astype(pages.conv[n].dtype), mode="drop"))
+            x = x + out
+            continue
+        if spec.mixer == "none":
+            with jax.named_scope("mlp"):
+                x = x + _mlp(layer, cfg, h, valid, moe_stats)
+            continue
         if cfg.latent:
             q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
             with jax.named_scope("attention"), jax.named_scope("latent"):
@@ -1300,22 +1702,23 @@ def _prefill_impl(
                                   moe_stats=moe_stats)
             continue
         q, k, v = _qkv(layer, cfg, h, cos, sin)
+        ki = kv_of[li]
         # KV append and attention together: the fused decode kernel does
         # both in one call, so the scope means the same on every path.
         with jax.named_scope("attention"):
             if quant:
                 pk, psk = _scatter_pages_quant(
-                    pages.k[li], pages.k_scale[li], k, sc_tables,
+                    pages.k[ki], pages.k_scale[ki], k, sc_tables,
                     sc_pos, valid)
                 pv, psv = _scatter_pages_quant(
-                    pages.v[li], pages.v_scale[li], v, sc_tables,
+                    pages.v[ki], pages.v_scale[ki], v, sc_tables,
                     sc_pos, valid)
                 new_ks.append(psk)
                 new_vs.append(psv)
             else:
-                pk = _scatter_pages(pages.k[li], k, sc_tables, sc_pos,
+                pk = _scatter_pages(pages.k[ki], k, sc_tables, sc_pos,
                                     valid)
-                pv = _scatter_pages(pages.v[li], v, sc_tables, sc_pos,
+                pv = _scatter_pages(pages.v[ki], v, sc_tables, sc_pos,
                                     valid)
             new_k.append(pk)
             new_v.append(pv)
@@ -1378,6 +1781,9 @@ def _prefill_impl(
                     q_positions=row_pos, kv_len=kv_len,
                     **_attn_extras(cfg, li)))
         o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
+        if spec.mlp == "none":
+            x = x + o
+            continue
         x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
                               moe_stats=moe_stats)
 
@@ -1385,7 +1791,9 @@ def _prefill_impl(
         hidden.append(x)
     out_pages = KVPages(k=new_k, v=new_v,
                         k_scale=new_ks if quant else (),
-                        v_scale=new_vs if quant else ())
+                        v_scale=new_vs if quant else (),
+                        **(dict(ssm=new_ssm, conv=new_conv)
+                           if cfg.recurrent else {}))
     if return_all_logits:
         return _unembed(params, cfg, x), out_pages
     last_idx = jnp.maximum(lengths - 1, 0)
@@ -1409,6 +1817,7 @@ def prefill(
     attn_impl=None,
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
+    lanes: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Ingest padded prompts, writing K/V into the paged cache.
 
@@ -1421,6 +1830,8 @@ def prefill(
         select_prefill_impl); None = dense in-flight attention.  The
         scatter-before-attention order makes the two equivalent: the
         pages already hold exactly this call's K/V when attention runs.
+      lanes: [B] int32, the state-pool lane of each row (recurrent layers;
+        see ``_prefill_impl``).
 
     Returns:
       (last_logits [B, V] float32, updated pages)
@@ -1431,7 +1842,7 @@ def prefill(
     return _prefill_impl(params, cfg, tokens, positions, valid, lengths,
                          lengths, pages, block_tables, attend_to_pages=False,
                          paged_attn_fn=attn_impl, moe_stats=moe_stats,
-                         hidden=hidden)
+                         hidden=hidden, lanes=lanes)
 
 
 def prefill_packed(
@@ -1446,6 +1857,7 @@ def prefill_packed(
     row_len: int,
     attn_impl=None,
     moe_stats: Optional[list] = None,
+    lanes: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """``prefill`` of prompts laid end to end in one token stream: what is
     computed per token is computed for the stream's ``T`` positions, not for
@@ -1460,7 +1872,7 @@ def prefill_packed(
       lengths: [R] int32 (0 = idle row), each at most ``row_len``.
       block_tables: [R, max_blocks] int32.
       row_len: the width S of attention's row view (``RowView``), static.
-      attn_impl: as for ``prefill``.
+      attn_impl, lanes: as for ``prefill``.
 
     Returns:
       (last_logits [R, V] float32, updated pages)
@@ -1480,7 +1892,7 @@ def prefill_packed(
     return _prefill_impl(params, cfg, tokens[None], positions[None],
                          valid[None], lengths, lengths, pages, block_tables,
                          attend_to_pages=False, paged_attn_fn=attn_impl,
-                         moe_stats=moe_stats, view=view)
+                         moe_stats=moe_stats, view=view, lanes=lanes)
 
 
 def prefill_chunk(
@@ -1584,6 +1996,8 @@ def decode_step(
     attn_impl=paged_decode_attention,
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
+    lanes: Optional[jnp.ndarray] = None,
+    ssm_update=ssm_decode_update_xla,
 ) -> tuple[jnp.ndarray, KVPages]:
     """One decode step for a batch of slots.
 
@@ -1593,6 +2007,10 @@ def decode_step(
         0 means the slot is inactive (its writes go to the null block).
       pages / block_tables: paged cache state.
       attn_impl: paged attention implementation (XLA fallback or Pallas).
+      lanes: [B] int32, the state-pool lane of each slot (recurrent layers);
+        None = slot b is lane b, the pool has B lanes.
+      ssm_update: the recurrent layers' one-step state update
+        (ops/ssm.py:ssm_decode_update, or its XLA form).
 
     Returns:
       (logits [B, V] float32, updated pages)
@@ -1602,6 +2020,11 @@ def decode_step(
     active = (context_lens > 0)[:, None]
     cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
                            scaling=cfg.rope_scaling)
+    if not cfg.use_rope:
+        # The fused kernels rotate inside, by the tables they are handed:
+        # ones and zeros make the rotation the identity.
+        cos, sin = jnp.ones_like(cos), jnp.zeros_like(sin)
+    kv_of = {li: n for n, li in enumerate(cfg.layers_with("kv"))}
     quant = pages.quantized
     if cfg.latent and not is_latent_decode_impl(attn_impl):
         raise ValueError(
@@ -1618,10 +2041,25 @@ def decode_step(
     new_lens = context_lens + 1
     new_k, new_v = [], []
     new_ks, new_vs = [], []
+    new_ssm, new_conv = [], []
     for li, layer in enumerate(params["layers"]):
         if hidden is not None:      # see _prefill_impl
             hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
+        spec = cfg.layer_spec(li)
+        if spec.mixer == "mamba2":
+            n = len(new_ssm)
+            out, ssm, conv = _mamba2_decode(
+                layer, cfg, h, active[:, 0], pages.ssm[n], pages.conv[n],
+                lanes, ssm_update)
+            new_ssm.append(ssm)
+            new_conv.append(conv)
+            x = x + out
+            continue
+        if spec.mixer == "none":
+            with jax.named_scope("mlp"):
+                x = x + _mlp(layer, cfg, h, active, moe_stats)
+            continue
         if cfg.latent:
             # Absorbed form: append this token's row, then every head reads
             # the lane's rows once (the kernel, or its XLA reference).
@@ -1638,6 +2076,7 @@ def decode_step(
             x, _ = _residual_tail(layer, cfg, x, o, valid=active,
                                   moe_stats=moe_stats)
             continue
+        ki = kv_of[li]
         # The fused kernels rope in-kernel and take the raw projections.
         if fused_q or fused:
             q, k, v = _qkv_proj(layer, cfg, h)
@@ -1649,8 +2088,8 @@ def decode_step(
                 # dequantize-in-kernel attention in one Pallas call; pages
                 # AND scales are updated in place (aliased outputs).
                 attn, pk, pv, psk, psv = attn_impl(
-                    q, k, v, cos, sin, pages.k[li], pages.v[li],
-                    pages.k_scale[li], pages.v_scale[li],
+                    q, k, v, cos, sin, pages.k[ki], pages.v[ki],
+                    pages.k_scale[ki], pages.v_scale[ki],
                     block_tables, context_lens)
             elif fused:
                 # Fused fast-path: rope + KV append + attention in one
@@ -1659,22 +2098,22 @@ def decode_step(
                 # never select this path (ops/attention.py gates on
                 # has_attn_extras).
                 attn, pk, pv = attn_impl(q, k, v, cos, sin,
-                                         pages.k[li], pages.v[li],
+                                         pages.k[ki], pages.v[ki],
                                          block_tables, context_lens)
             elif quant:
                 pk, psk = _scatter_pages_quant(
-                    pages.k[li], pages.k_scale[li], k, block_tables,
+                    pages.k[ki], pages.k_scale[ki], k, block_tables,
                     positions, active)
                 pv, psv = _scatter_pages_quant(
-                    pages.v[li], pages.v_scale[li], v, block_tables,
+                    pages.v[ki], pages.v_scale[ki], v, block_tables,
                     positions, active)
                 attn = paged_decode_attention_quant(
                     q, pk, pv, psk, psv, block_tables, new_lens,
                     **_attn_extras(cfg, li))
             else:
-                pk = _scatter_pages(pages.k[li], k, block_tables, positions,
+                pk = _scatter_pages(pages.k[ki], k, block_tables, positions,
                                     active)
-                pv = _scatter_pages(pages.v[li], v, block_tables, positions,
+                pv = _scatter_pages(pages.v[ki], v, block_tables, positions,
                                     active)
                 # Extras models are guaranteed the gather impl
                 # (select_attn_impl), which accepts the per-layer kwargs;
@@ -1688,6 +2127,9 @@ def decode_step(
             new_ks.append(psk)
             new_vs.append(psv)
         o = _attn_out(layer, cfg, attn.reshape(B, 1, -1))
+        if spec.mlp == "none":
+            x = x + o
+            continue
         x, _ = _residual_tail(layer, cfg, x, o, valid=active,
                               moe_stats=moe_stats)
 
@@ -1696,4 +2138,6 @@ def decode_step(
     logits = _unembed(params, cfg, x)[:, 0, :]
     return logits, KVPages(k=new_k, v=new_v,
                            k_scale=new_ks if quant else (),
-                           v_scale=new_vs if quant else ())
+                           v_scale=new_vs if quant else (),
+                           **(dict(ssm=new_ssm, conv=new_conv)
+                              if cfg.recurrent else {}))

@@ -176,6 +176,19 @@ def _engine_metrics(w: _Writer, engine) -> None:
     w.metric("engine_kv_blocks_total", "gauge",
              "Configured KV cache blocks",
              [("", engine.ecfg.num_blocks)])
+    if getattr(engine, "_recurrent", False):
+        # The per-lane state pool of a description with recurrent layers,
+        # beside the pages (the ``state_*`` attributes of ``engine.call``).
+        lane = engine.cfg.state_lane_bytes(
+            engine.pages.conv[0].dtype.itemsize)
+        w.metric("engine_state_pool_bytes", "gauge",
+                 "Bytes of the recurrent-state pool: every decode lane's "
+                 "state over all recurrent layers, resident whatever the "
+                 "lanes hold",
+                 [("", lane * engine.ecfg.max_slots)])
+        w.metric("engine_state_lane_bytes", "gauge",
+                 "Bytes one lane of the recurrent-state pool holds",
+                 [("", lane)])
     w.metric("engine_prefills_total", "counter",
              "Prompts ingested via prefill",
              [("", engine.prefills)])
@@ -365,6 +378,14 @@ def _loop_metrics(w: _Writer, engine) -> None:
                  "Experts there were: experts x expert layers x steps of "
                  "every call",
                  [("", moe["expert_slots"])])
+        if "assignments_all" in moe:
+            # An expert layer that holds a share of its experts: the three
+            # above are over the experts held.
+            w.metric("engine_moe_assignments_all_total", "counter",
+                     "Token-expert assignments of the real tokens, to held "
+                     "experts or not (engine_moe_assignments_total over it: "
+                     "the share of the routed work this chip holds)",
+                     [("", moe["assignments_all"])])
 
 
 def _latency_histograms(w: _Writer, engine) -> None:

@@ -454,6 +454,9 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
     "engine.call": ("kind", "program", "call_id", "device_empty",
                     "kv_blocks", "kv_live_blocks", "kv_cached_blocks",
                     "kv_token_bytes",
+                    # A description with recurrent layers (the state pool).
+                    "state_pool_bytes", "state_live_lanes",
+                    "state_lane_bytes",
                     "sampler_filter", "steps", "lanes", "slots", "emitted",
                     "ctx_tokens",
                     "bucket", "rows", "prompts", "real_tokens",
@@ -461,7 +464,10 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     # Programs of a routed model only (MOE_COUNTS).
                     "moe_assignments", "moe_expert_layer_steps_hit",
                     "moe_expert_layer_steps", "moe_max_rows",
-                    "moe_mean_rows"),
+                    "moe_mean_rows",
+                    # ... that holds a share of its experts (the four above
+                    # are then over the experts held).
+                    "moe_assignments_all"),
     "xla.compile": ("seconds", "program"),
 }
 
@@ -472,6 +478,10 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
 # fullest expert's rows, experts there were.
 MOE_COUNTS = ("moe_assignments", "moe_expert_layer_steps_hit", "moe_max_rows",
               "moe_expert_layer_steps")
+# The share-aware expert layer (models/llama.py:_moe_mlp_share) counts the
+# four above over the experts this chip holds and adds the real tokens'
+# assignments held or not (tokens x experts per token).
+MOE_SHARE_COUNTS = MOE_COUNTS + ("moe_assignments_all",)
 
 
 def _with_counts(outs: tuple, stats: Optional[list]) -> tuple:
@@ -744,10 +754,17 @@ class InferenceEngine:
         # fallback): latent pages have no kv-head axis to shard, no scale
         # planes, no host-tier row format and no KVX1 geometry; the verify
         # pass has no latent form; the expert layer beside a shared MLP has
-        # no mesh schedule.
+        # no mesh schedule; recurrent state has no snapshot (prefix cache,
+        # host tier, KVX1), no roll-back (verify), no continuation (chunked
+        # prefill) and no mesh layout.
         self._unbuilt = self._unbuilt_reason(cfg)
+        self._recurrent = cfg.recurrent
         if self._unbuilt:
+            cap = min(ec.max_blocks_per_seq, ec.num_blocks - 1) * ec.block_size
             for what, asked in (
+                    (f"chunked prefill (a sequence's {cap} tokens exceed the "
+                     f"largest prefill bucket, {max(ec.prefill_buckets)})",
+                     self._recurrent and cap > max(ec.prefill_buckets)),
                     ("a mesh", mesh is not None),
                     ("tp_overlap='on'", ec.tp_overlap == "on"),
                     (f"kv_dtype={self.kv_quant!r}", bool(self.kv_quant)),
@@ -784,7 +801,8 @@ class InferenceEngine:
                     ec, prefill_buckets=tuple(ec.prefill_buckets) + extra)
                 self.ecfg = ec
         pages = llama.init_kv_pages(cfg, ec.num_blocks, ec.block_size,
-                                    kv_quant=self.kv_quant)
+                                    kv_quant=self.kv_quant,
+                                    state_lanes=ec.max_slots)
         # Sequence-sharded prefill (SURVEY §7 step 5): on a mesh with a
         # nontrivial ``seq`` axis, prefill/chunk token batches are placed
         # sharded over ``seq`` — GSPMD then splits the per-position matmul
@@ -843,10 +861,13 @@ class InferenceEngine:
         self.params = params
         self.pages = pages
         self.allocator = BlockAllocator(ec.num_blocks, ec.block_size)
+        # No lookup for a description with a recurrent layer: a cached
+        # block holds a prefix's keys and values, and nothing holds the
+        # recurrent state at its end, so a hit could not be continued.
         self.prefix_cache: Optional[PrefixCache] = (
             PrefixCache(self.allocator, ec.prefix_cache_entries,
                         max_tenant_share=ec.kv_max_tenant_share)
-            if ec.prefix_cache_entries > 0 else None)
+            if ec.prefix_cache_entries > 0 and not self._recurrent else None)
         # Cold-burst shared-prefix dedup: requests whose admission waited
         # for an in-flight lane to publish their prefix.
         self.prefix_deferrals = 0
@@ -876,6 +897,10 @@ class InferenceEngine:
                                            mode=ec.decode_path,
                                            kv_quant=self.kv_quant)
         self._attn_impl = attn_impl
+        # The recurrent layers' one-step state update: the Pallas kernel on
+        # the pool in place on a TPU, its XLA form elsewhere.
+        from k8s_llm_monitor_tpu.ops.ssm import select_ssm_update
+        self._ssm_update = select_ssm_update()
         # "fused" | "pallas" | "gather" — surfaced in /metrics.
         if self.kv_quant and llama.is_fused_quant_decode_impl(attn_impl):
             self.decode_path = "fused"
@@ -947,6 +972,7 @@ class InferenceEngine:
         # their last output (MOE_COUNTS); a dense model's return what they
         # always did: ``_stats()`` is None and ``_with_counts`` adds nothing.
         self._routed = cfg.expert_layers > 0
+        self._moe_names = MOE_SHARE_COUNTS if cfg.expert_share else MOE_COUNTS
         self._moe_counts = None
         routed = self._routed
 
@@ -964,15 +990,21 @@ class InferenceEngine:
         self._packed_prefill = packed = mesh is None
         top_bucket = ec.prefill_buckets[-1]
 
+        recurrent = self._recurrent
+
         def _fresh_prefill(params, tokens, seg, pages, tables, stats):
+            # A description with recurrent layers: ``seg`` ends with the
+            # state-pool lane of each row (its slot; max_slots = none).
+            kw = {"lanes": seg[-1]} if recurrent else {}
+            seg = seg[:-1] if recurrent else seg
             if packed:
                 return llama.prefill_packed(
                     params, cfg, tokens, *seg, pages, tables,
                     row_len=min(tokens.shape[0], top_bucket),
-                    attn_impl=prefill_attn, moe_stats=stats)
+                    attn_impl=prefill_attn, moe_stats=stats, **kw)
             return llama.prefill(
                 params, cfg, tokens, *seg, pages, tables,
-                attn_impl=prefill_attn, moe_stats=stats
+                attn_impl=prefill_attn, moe_stats=stats, **kw
             )
 
         def _prefill_sample_fn(params, tokens, seg, pages, tables,
@@ -1181,6 +1213,8 @@ class InferenceEngine:
         # that had at least one row, experts there were (per layer and step).
         self.moe_totals = {"assignments": 0, "experts_hit": 0,
                            "expert_slots": 0}
+        if cfg.expert_share:    # held or not; the three above: held
+            self.moe_totals["assignments_all"] = 0
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
         # class, with exemplar trace ids, observed on the step thread only.
         # The exporter renders these as real Prometheus histograms.
@@ -1220,11 +1254,15 @@ class InferenceEngine:
         part of it that some are not (``__init__`` and the KVX1 calls refuse
         those with it)."""
         parts = []
+        if cfg.recurrent:
+            parts.append("recurrent state (a per-lane state pool)")
         if cfg.latent:
             parts.append("a latent (compressed-KV) pool")
         if any(cfg.layer_spec(i).mlp == "shared+routed"
                for i in range(cfg.num_layers)):
             parts.append("shared + routed expert layers")
+        if cfg.experts_held:
+            parts.append("an expert layer that holds a share of its experts")
         return " and ".join(parts)
 
     def _refuse_unbuilt(self, what: str) -> None:
@@ -1544,6 +1582,10 @@ class InferenceEngine:
         progs = self._score_programs
         if not progs:
             attn, dec = self._prefill_attn, self._attn_impl
+            # The engine is idle: the state of the scored sequence lives in
+            # lane 0 of the state pool.
+            lane0 = ({"lanes": jnp.zeros((1,), jnp.int32)}
+                     if self._recurrent else {})
 
             def scored(fn, **kw):
                 # (logits, pages, states [layers + 1, B, S, H] or None)
@@ -1556,13 +1598,14 @@ class InferenceEngine:
                 return run
 
             progs["prefill"] = jax.jit(
-                scored(llama.prefill, attn_impl=attn),
+                scored(llama.prefill, attn_impl=attn, **lane0),
                 donate_argnums=(3,), static_argnames=("want_hidden",))
             progs["chunk"] = jax.jit(
                 scored(llama.prefill_chunk, attn_impl=attn),
                 donate_argnums=(4,), static_argnames=("want_hidden",))
             progs["decode"] = jax.jit(
-                scored(llama.decode_step, attn_impl=dec),
+                scored(llama.decode_step, attn_impl=dec,
+                       ssm_update=self._ssm_update, **lane0),
                 donate_argnums=(3,), static_argnames=("want_hidden",))
         table = np.zeros((1, self.ecfg.max_blocks_per_seq), np.int32)
         table[0, :len(blocks)] = blocks
@@ -1689,6 +1732,14 @@ class InferenceEngine:
             # page kind's own figure, beside the block counts it scales.
             census["kv_token_bytes"] = self.cfg.kv_token_bytes(
                 self.pages.k[0].dtype.itemsize)
+        if self._recurrent:
+            # The state pool beside the pages: a lane costs the same
+            # whatever its context holds, and every lane is resident.
+            lane = self.cfg.state_lane_bytes(self.pages.conv[0].dtype.itemsize)
+            census.update(
+                state_pool_bytes=lane * self.ecfg.max_slots,
+                state_live_lanes=sum(s is not None for s in self._slots),
+                state_lane_bytes=lane)
         return census
 
     def _call_attrs(self, kind: str, program: str, device_empty: bool,
@@ -2793,6 +2844,8 @@ class InferenceEngine:
                 # (offset, lengths) of a packed stream; (lengths,) of rows.
                 seg = ((jnp.asarray(start), jnp.asarray(lengths)) if packed
                        else (jnp.asarray(lengths),))
+                if self._recurrent:     # each row's lane of the state pool
+                    seg += (jnp.asarray(idx),)
                 if constrained:
                     self._rng, sub = jax.random.split(self._rng)
                     first, fnext, self.pages = self._prefill_sample_fsm(
@@ -3108,7 +3161,9 @@ class InferenceEngine:
         # Routing counts ride in the scan's carry: () for a dense model (no
         # leaf, the program is what it always was), float32[4] for a routed
         # one, summed over the steps.
-        cnt0 = jnp.zeros((len(MOE_COUNTS),), jnp.float32) if routed else ()
+        cnt0 = (jnp.zeros((len(self._moe_names),), jnp.float32)
+                if routed else ())
+        ssm_update = self._ssm_update
 
         def _step_core(params, tokens, ctx, act, pages, tables, cnt):
             ctx_eff = jnp.where(act, ctx, 0)
@@ -3123,6 +3178,7 @@ class InferenceEngine:
                 logits, pages = llama.decode_step(
                     params, cfg, tokens, ctx_eff, pages, tables,
                     attn_impl=attn_impl, moe_stats=stats,
+                    ssm_update=ssm_update,
                 )
                 if routed:
                     cnt = cnt + jnp.sum(jnp.stack(stats), axis=0)
@@ -3775,14 +3831,17 @@ class InferenceEngine:
         if call.moe_counts is not None:
             # Outputs of the same program as ``arr``: on the host already,
             # or a moment behind it.
-            counts = dict(zip(MOE_COUNTS,
+            counts = dict(zip(self._moe_names,
                               (float(c) for c in np.asarray(call.moe_counts))))
             slots = counts["moe_expert_layer_steps"]
             # The mean rows of an expert, summed as the fullest expert's
             # are: each layer and step adds its assignments / experts.
             counts["moe_mean_rows"] = (counts["moe_assignments"]
-                                       / self.cfg.num_experts)
+                                       / self.cfg.experts_held_)
             attrs.update(counts)
+            if "moe_assignments_all" in counts:
+                self.moe_totals["assignments_all"] += int(
+                    counts["moe_assignments_all"])
             self.moe_totals["assignments"] += int(counts["moe_assignments"])
             self.moe_totals["experts_hit"] += int(
                 counts["moe_expert_layer_steps_hit"])

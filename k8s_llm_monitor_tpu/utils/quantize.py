@@ -101,6 +101,9 @@ def quantize_params(params: Params) -> Params:
     """
     layers = []
     for layer in params["layers"]:
+        if "post_norm" not in layer:
+            layers.append(_quantize_single_layer(layer))
+            continue
         ql: Params = {
             "input_norm": layer["input_norm"],
             "post_norm": layer["post_norm"],
@@ -144,6 +147,27 @@ def quantize_params(params: Params) -> Params:
     return out
 
 
+# What a one-sub-block layer (``ModelConfig.layer_pattern``) keeps wide: the
+# norms, the router, and a Mamba-2 mixer's convolution and per-head vectors
+# (float32: they shape the recurrence's decay, not a matrix product).
+_SINGLE_WIDE = ("input_norm", "router", "conv", "dt_bias", "A_log", "D",
+                "ssm_norm")
+
+
+def _quantize_single_layer(layer: Params) -> Params:
+    ql: Params = {}
+    for name, p in layer.items():
+        if name in _SINGLE_WIDE:
+            ql[name] = p
+        elif name in ("up_e", "down_e"):
+            ql[name] = quantize_expert_stack(p)
+        elif name == "shared":
+            ql[name] = {k: quantize_linear(v) for k, v in p.items()}
+        else:       # in_proj, out_proj, q, k, v, o, latent_down, latent_up
+            ql[name] = quantize_linear(p)
+    return ql
+
+
 # ---------------------------------------------------------------------------
 # Direct quantized random init (benchmarks)
 # ---------------------------------------------------------------------------
@@ -185,7 +209,7 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig) -> Params:
     def qexperts(key, in_f, out_f):
         # The direct-int8 trick per expert: 128 experts at published widths
         # cannot pass through bf16 on one chip either.
-        E = cfg.num_experts
+        E = cfg.experts_held_
         return {"kernel_q": jax.random.randint(
                     key, (E, in_f, out_f), -127, 128, jnp.int8),
                 "scale": jnp.full((E, out_f), 3.0 * (in_f ** -0.5) / 127.0,
@@ -205,6 +229,12 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig) -> Params:
     for i in range(cfg.num_layers):
         lk = jax.random.split(keys[2 + i], 7)
         spec = cfg.layer_spec(i)
+        if cfg.layer_pattern is not None:
+            from k8s_llm_monitor_tpu.models.llama import init_single_layer
+
+            layers.append(init_single_layer(keys[2 + i], cfg, spec, qdense,
+                                            qexperts))
+            continue
         layer: Params = {"input_norm": jnp.ones((H,), dtype),
                          "post_norm": jnp.ones((H,), dtype)}
         if spec.mixer == "latent":

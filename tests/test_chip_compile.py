@@ -438,3 +438,51 @@ def test_packed_prefill_program_compiles(one_chip, config, tokens, layers):
         assert temps <= (rule["bytes_limit"] - rule["weights_bytes"]
                          - rule["pool_bytes"] - (1 << 30)), temps
         assert temps <= 1.05 * ROW_TEMPS[config], temps
+
+
+# -- PR 31: the nemotron_h cell's kernels ------------------------------------
+
+NCFG = PRESETS["nemotron3-super-120b-a12b-22l"]
+
+
+@pytest.mark.parametrize("rows", [0, 32], ids=["a-lane-a-step", "half-a-lane"])
+def test_ssm_decode_update_compiles_at_the_served_cell(one_chip, rows):
+    """The Mamba-2 state update on the served pool, in place: 64 lanes of
+    128 heads x 64 x 128 float32 laid [64, 128, 128] (ops/ssm.py), a 4 MB
+    block a lane a grid step — past the default scoped VMEM, so the kernel
+    sets its own limit — or half of one; no temporary the size of the pool."""
+    from k8s_llm_monitor_tpu.ops import ssm
+
+    S = one_chip
+    Hm, P, N, G = (NCFG.mamba_num_heads, NCFG.mamba_head_dim,
+                   NCFG.ssm_state_size, NCFG.mamba_n_groups)
+    pack = ssm.state_pack(Hm, G, P)
+    compiled = jax.jit(
+        functools.partial(ssm.ssm_decode_update, block_rows=rows),
+        donate_argnums=(0,)).lower(
+            S((64, Hm // pack, N, pack * P), F32), S((64,), I32),
+            S((64, Hm), F32), S((64, Hm, P), F32), S((64, G, N), F32),
+            S((64, G, N), F32)).compile()
+    assert "ssm_decode_update" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("kernel", ["fused_decode", "flash_prefill_packed"])
+def test_attention_kernels_compile_at_sixteen_queries_a_kv_head(one_chip,
+                                                                kernel):
+    """32 q / 2 kv heads x 128 (no cell had run 16 query heads a kv head): the
+    fused decode kernel on the served pool of 14,337 blocks and 224-block
+    tables, and the flash kernel over a packed stream of 8,192 tokens."""
+    S = one_chip
+    nH, nKV, Dh = NCFG.num_heads, NCFG.num_kv_heads, NCFG.head_dim_
+    assert ops._pallas_geometry_ok(NCFG, 1)
+    pool = S((14_337, 16, nKV * Dh), BF16)
+    if kernel == "fused_decode":
+        _compile(pa.paged_decode_attention_fused,
+                 S((64, 1, nH, Dh), BF16), S((64, 1, nKV, Dh), BF16),
+                 S((64, 1, nKV, Dh), BF16), S((64, 1, Dh), F32),
+                 S((64, 1, Dh), F32), pool, pool, S((64, 224), I32),
+                 S((64,), I32))
+    else:
+        _compile(pa.flash_prefill_attention_packed, S((8192, nH, Dh), BF16),
+                 pool, pool, S((2, 224), I32), S((2,), I32), S((2,), I32))
