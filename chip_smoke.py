@@ -284,6 +284,129 @@ def state_kernel_checks(cfg, seed: int, lanes: int) -> None:
             f"{name}: an idle row's state changed")
 
 
+def sparse_kernel_checks(cfg, seed: int, lanes: int,
+                         context: int = 1536, rows: int = 1024) -> None:
+    """The selected-attention and window kernels at ``cfg``'s widths (a
+    latent description with an indexer and window layers) against their XLA
+    forms: the index-score decode kernel over index-key pages, the latent
+    decode kernel under a keep mask (several bursts, one of them with nothing
+    kept, an idle lane) and over one burst of a lane's ring of the window
+    store, and the packed prefill kernels under a band and under the
+    selection (two prompts of ``rows`` and ``rows // 3`` tokens in blocks of
+    512 against the dense masked form)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from k8s_llm_monitor_tpu.ops import attention as ops
+    from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+    from k8s_llm_monitor_tpu.ops import sparse
+
+    layers = range(cfg.num_layers)
+    full = next(cfg.latent_geometry(i) for i in layers
+                if cfg.latent_geometry(i).indexed)
+    band = next(cfg.latent_geometry(i) for i in layers
+                if cfg.latent_geometry(i).window)
+    interpret = jax.default_backend() != "tpu"
+    dtype = jnp.float32 if interpret else jnp.bfloat16
+    rng = np.random.default_rng(seed)
+    bs = 16
+
+    def normal(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape, np.float32) * scale, dtype)
+
+    def pool(width, lens, NB):
+        pages = normal(1 + lanes * NB, bs, width)
+        tables = np.zeros((lanes, NB), np.int32)
+        for b in range(lanes):
+            n = -(-int(lens[b]) // bs)
+            tables[b, :n] = 1 + b * NB + np.arange(n)
+        return pages, jnp.asarray(tables)
+
+    NB = -(-context // bs)
+    lens = rng.integers(context // 3, context + 1, size=lanes).astype(np.int32)
+    lens[1] = 0
+    live = lens > 0
+    # -- decode: index scores, then attention under the selection -----------
+    idx_pages, tables = pool(full.index_dim, lens, NB)
+    qI = normal(lanes, 1, full.index_heads, full.index_dim)
+    wI = jnp.asarray(rng.standard_normal((lanes, 1, full.index_heads)),
+                     jnp.float32)
+    want = ops.index_scores_decode(qI, wI, idx_pages, tables, jnp.asarray(lens))
+    got = jax.jit(functools.partial(pa.index_scores_decode_pallas,
+                                    interpret=interpret))(
+        qI, wI, idx_pages, tables, jnp.asarray(lens))
+    seen = np.arange(NB * bs)[None, :] < lens[:, None]
+    _close(np, f"index scores, decode: {lanes} lanes x {full.index_heads} heads",
+           np.where(seen, np.asarray(got, np.float32)[:, :NB * bs], 0.0),
+           np.where(seen, np.asarray(want, np.float32), 0.0))
+    keep = np.array(sparse.topk_keep(want, jnp.asarray(seen),
+                                     min(full.index_topk, context // 3)))
+    burst = min(512, context // 2)
+    keep[0, :burst] = False                    # a whole burst of nothing
+    keep[0, burst] = True
+    pages, tables = pool(full.page_width, lens, NB)
+    q = normal(lanes, 1, full.num_heads, full.page_width, scale=0.05)
+    kw = dict(v_width=full.kv_lora_rank, keep=jnp.asarray(keep),
+              name="sparse_latent_decode_attention")
+    want = jax.jit(functools.partial(ops.latent_decode_attention, **kw))(
+        q, pages, tables, jnp.asarray(lens))
+    got = jax.jit(functools.partial(pa.latent_decode_attention_pallas,
+                                    interpret=interpret, **kw))(
+        q, pages, tables, jnp.asarray(lens))
+    _close(np, f"selected decode attention: {full.num_heads} heads x "
+           f"{full.page_width} lanes, mask form", got, want, rows=live)
+    # -- decode over the window store: one burst of a lane's ring -----------
+    ring = -(-band.window // bs)
+    wlens = np.minimum(lens, band.window).astype(np.int32)
+    pages, tables = pool(band.page_width, wlens, ring)
+    q = normal(lanes, 1, band.num_heads, band.page_width, scale=0.05)
+    kw = dict(v_width=band.kv_lora_rank, burst=ring,
+              name="window_latent_decode_attention")
+    want = jax.jit(functools.partial(ops.latent_decode_attention, **kw))(
+        q, pages, tables, jnp.asarray(wlens))
+    got = jax.jit(functools.partial(pa.latent_decode_attention_pallas,
+                                    interpret=interpret, **kw))(
+        q, pages, tables, jnp.asarray(wlens))
+    _close(np, f"window decode attention: {band.num_heads} heads x "
+           f"{band.page_width} lanes, a ring of {ring} blocks", got, want,
+           rows=live)
+    # -- packed prefill under a band and under the selection -----------------
+    n0, n1 = rows, rows // 3
+    T = -(-(n0 + n1) // 512) * 512
+    offset = jnp.asarray([0, n0, n0 + n1], jnp.int32)
+    length = jnp.asarray([n0, n1, 0], jnp.int32)
+    for g, name in ((band, "window"), (full, "selected")):
+        heads = min(g.num_heads, 8)          # the dense oracle's [H, S, S]
+        q = normal(T, heads, g.qk_head_dim, scale=0.3)
+        k = normal(T, heads, g.qk_head_dim, scale=0.3)
+        v = normal(T, heads, g.v_head_dim)
+        index, topk = None, 0
+        if g.indexed:
+            topk = min(g.index_topk, rows // 4)
+            index = (normal(T, g.index_heads, g.index_dim),
+                     normal(T, g.index_dim),
+                     jnp.asarray(rng.standard_normal((T, g.index_heads)),
+                                 jnp.float32))
+        got = jax.jit(functools.partial(
+            pa.latent_prefill_attention_packed, scale=g.qk_head_dim ** -0.5,
+            row_len=rows, window=g.window, topk=topk, interpret=interpret))(
+            q, k, v, offset, length, index=index)
+        for at, n in ((0, n0), (n0, n1)):
+            pos = jnp.arange(n, dtype=jnp.int32)[None]
+            allowed = sparse.allowed_keys(pos, jnp.asarray([n]), n, g.window)
+            if index is not None:
+                cut = lambda x: x[None, at:at + n]              # noqa: E731
+                allowed = sparse.topk_keep(
+                    sparse.index_scores(cut(index[0]), cut(index[2]),
+                                        cut(index[1])), allowed, topk)
+            want = sparse.masked_attention(
+                q[None, at:at + n], k[None, at:at + n], v[None, at:at + n],
+                allowed, scale=g.qk_head_dim ** -0.5)
+            _close(np, f"{name} packed prefill: a prompt of {n} tokens, "
+                   f"{heads} heads", got[at:at + n], want[0])
+
+
 def grouped_kernel_checks(cfg, seed: int, lanes: int,
                           rows_per_expert=(1, 3, 8, 16, 32, 64, 128)) -> None:
     """The expert layer's grouped product at ``cfg``'s widths (a routed
@@ -532,6 +655,9 @@ def one_chip(seed: int, model: str = MODEL, max_tokens: int = 9,
         # shape (64 lanes) and at one row tile an expert.
         for routed in (hybrid, PRESETS["kanana-2-30b-a3b-12l"]):
             grouped_kernel_checks(routed, seed, 64, rows_per_expert=(32,))
+        # The selected-attention and window kernels of the description with
+        # two latent geometries.
+        sparse_kernel_checks(PRESETS["dots3-note-prev-5l"], seed, 16)
         say(f"phase kernels: ok in {time.monotonic() - t0:.1f} s "
             "(information)")
 

@@ -25,14 +25,64 @@ class LayerSpec:
     mlp: ``"dense"`` | ``"routed"`` (top-k experts) | ``"shared+routed"`` |
       ``"none"`` (the layer is a mixer alone).
     cache: ``"kv"`` (two page arrays, ``kv_heads * head_dim`` lanes) |
-      ``"latent"`` (one page array, ``ModelConfig.latent_page_width`` lanes)
-      | ``"state"`` (a row of the per-lane state pool, whatever the context)
+      ``"latent"`` (one page array, the geometry's ``page_width`` lanes, and
+      beside it an index-key page array where the geometry has an indexer)
+      | ``"window"`` (a latent mixer under a sliding window: a ring of the
+      last ``window`` rows a decode lane, whatever the context) |
+      ``"state"`` (a row of the per-lane state pool, whatever the context)
       | ``"none"``.
+
+    A latent mixer's sizes are its layer's ``ModelConfig.latent_geometry``.
     """
 
     mixer: str
     mlp: str
     cache: str
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentGeometry:
+    """The sizes of one latent mixer (``ModelConfig.latent_geometry(i)``): a
+    model may have two, chosen by ``layer_types``.  ``q_lora_rank`` 0 = the
+    query projection is direct; ``window`` 0 = every earlier key is allowed;
+    ``index_topk`` > 0 = a learned indexer (``index_heads`` heads of
+    ``index_dim``) picks that many of the allowed keys."""
+
+    num_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    window: int = 0
+    index_topk: int = 0
+    index_heads: int = 0
+    index_dim: int = 0
+    gate: str = ""              # "headwise": one sigmoid gate a head
+    q_scale: float = 1.0        # on the normed query latent
+    kv_scale: float = 1.0       # on the normed key/value latent
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of one head's score: nope + rope parts."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def rope_width(self) -> int:
+        """Lanes the rotated key takes in a page row: its width padded to a
+        whole 128-lane tile (zeros), so that a row is lane-aligned for the
+        chip's DMA and what the pool costs is what its shape says."""
+        return -(-self.qk_rope_head_dim // 128) * 128
+
+    @property
+    def page_width(self) -> int:
+        """Lanes of one cached token: ``[latent | rotated key | zeros]``."""
+        return self.kv_lora_rank + self.rope_width
+
+    @property
+    def indexed(self) -> bool:
+        return self.index_topk > 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,13 +162,37 @@ class ModelConfig:
     layer_types: Optional[tuple] = None
     # --- per-layer description (LayerSpec) ------------------------------
     # Mixer of every layer: "full" | "latent".  The latent sizes are the
-    # published DeepSeek-V3 keys; q_lora_rank is not supported (the query
-    # projection is direct, as in the configurations served so far).
+    # published DeepSeek-V3 keys; ``q_lora_rank`` 0 = the query projection is
+    # direct.  ``latent_geometry(i)`` is what the code asks.
     mixer: str = "full"
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    q_lora_rank: int = 0
+    # A latent model's ``sliding_attention`` layers (``layer_types``) have a
+    # geometry of their own (the published ``swa_*`` keys) and keep a
+    # window-bounded store instead of pages (LayerSpec.cache "window").
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+    # "headwise": the attention output of head j is scaled by
+    # sigmoid(W_g h)_j, one scalar a head from the layer's normed input
+    # (both geometries).
+    attn_gate: str = ""
+    # The normed latents are scaled by sqrt(hidden / rank) (per geometry).
+    lora_rescale: bool = False
+    # A learned indexer on the full-attention layers of a latent model:
+    # ``index_n_heads`` query heads of ``index_head_dim`` off the query
+    # latent, one key a token; the ``index_topk`` best-scored earlier keys
+    # are the ones attention sees (0 = no indexer).
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # Expert layers: the first ``first_dense_layers`` layers keep a dense MLP
     # of ``intermediate_size``; the rest route ``num_experts_per_tok`` of
     # ``num_experts`` experts of width ``moe_intermediate_size`` (0 =
@@ -178,8 +252,37 @@ class ModelConfig:
             if kind == "E":
                 return LayerSpec(mixer="none", mlp=mlp, cache="none")
             raise ValueError(f"layer_pattern[{i}] = {kind!r} (M | * | E)")
-        return LayerSpec(mixer=self.mixer, mlp=mlp,
-                         cache="latent" if self.mixer == "latent" else "kv")
+        if self.mixer != "latent":
+            return LayerSpec(mixer=self.mixer, mlp=mlp, cache="kv")
+        return LayerSpec(mixer="latent", mlp=mlp,
+                         cache="window" if self.layer_window(i) else "latent")
+
+    def latent_geometry(self, i: int) -> LatentGeometry:
+        """The sizes of layer ``i``'s latent mixer."""
+        window = self.layer_window(i)
+        scale = lambda rank: ((self.hidden_size / rank) ** 0.5     # noqa: E731
+                              if self.lora_rescale and rank else 1.0)
+        if window:
+            return LatentGeometry(
+                num_heads=self.swa_num_heads,
+                q_lora_rank=self.swa_q_lora_rank,
+                kv_lora_rank=self.swa_kv_lora_rank,
+                qk_nope_head_dim=self.swa_qk_nope_head_dim,
+                qk_rope_head_dim=self.swa_qk_rope_head_dim,
+                v_head_dim=self.swa_v_head_dim,
+                rope_theta=self.swa_rope_theta, window=window,
+                gate=self.attn_gate, q_scale=scale(self.swa_q_lora_rank),
+                kv_scale=scale(self.swa_kv_lora_rank))
+        return LatentGeometry(
+            num_heads=self.num_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank,
+            qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim,
+            v_head_dim=self.v_head_dim, rope_theta=self.rope_theta,
+            index_topk=self.index_topk, index_heads=self.index_n_heads,
+            index_dim=self.index_head_dim, gate=self.attn_gate,
+            q_scale=scale(self.q_lora_rank),
+            kv_scale=scale(self.kv_lora_rank))
 
     def layers_with(self, cache: str) -> list[int]:
         """The layers whose cache is of kind ``cache``, in order: a pool has
@@ -195,6 +298,25 @@ class ModelConfig:
     def recurrent(self) -> bool:
         """Some layer carries a recurrent state from token to token."""
         return bool(self.layers_with("state"))
+
+    @property
+    def lane_state(self) -> bool:
+        """Some layer keeps something a decode lane (not a block): recurrent
+        state, or a window-bounded store.  Such a description is admitted
+        whole into the lanes a call names (no cached prefix, no chunks)."""
+        return bool(self.recurrent or self.layers_with("window"))
+
+    def window_rows(self, block_size: int) -> int:
+        """Rows one lane's ring of a window layer takes: the window, in
+        whole blocks."""
+        return -(-self.sliding_window // block_size) * block_size
+
+    def window_lane_bytes(self, block_size: int, itemsize: int = 2) -> int:
+        """Bytes one lane of the window store holds over all window layers,
+        whatever the context."""
+        return sum(self.window_rows(block_size)
+                   * self.latent_geometry(i).page_width * itemsize
+                   for i in self.layers_with("window"))
 
     @property
     def expert_layers(self) -> int:
@@ -265,7 +387,11 @@ class ModelConfig:
     def kv_token_bytes(self, itemsize: int = 2) -> int:
         """Bytes one cached token costs over all layers (scales excluded)."""
         if self.latent:
-            return self.num_layers * self.latent_page_width * itemsize
+            # A paged latent layer's row, and its index key where it has one
+            # (a window layer's rows are per lane: ``window_lane_bytes``).
+            return sum((self.latent_geometry(i).page_width
+                        + self.latent_geometry(i).index_dim) * itemsize
+                       for i in self.layers_with("latent"))
         return (2 * len(self.layers_with("kv")) * self.num_kv_heads
                 * self.head_dim_ * itemsize)
 
@@ -289,7 +415,8 @@ class ModelConfig:
     def has_attn_extras(self) -> bool:
         """True when attention needs non-Llama parameters threaded (forces
         the gather attention impls — ops/attention.py selection gates)."""
-        return bool(self.attn_logit_softcap or self.sliding_window
+        return bool(self.attn_logit_softcap
+                    or (self.sliding_window and not self.latent)
                     or self.query_pre_attn_scalar is not None)
 
     @property
@@ -342,6 +469,30 @@ TINY_NEMOTRON_H = ModelConfig(
     moe_shared_intermediate_size=40, mlp_gated=False, mlp_activation="relu2",
     moe_scoring="sigmoid+bias", norm_topk_prob=True,
     routed_scaling_factor=5.0)
+
+# The dots3_note block at test size: both latent geometries (two full layers
+# with low-rank queries and an indexer whose top-k is smaller than a test
+# prompt, three window layers whose window is smaller still and no multiple
+# of a block), head-wise gates, one leading dense layer, fewer experts held
+# than routed (the second half of 16: 8, so that the expert axis divides
+# TP-8 like every preset's).  vocab >= 259 as above.
+TINY_DOTS3_NOTE = ModelConfig(
+    name="tiny-dots3-note", vocab_size=320, hidden_size=64,
+    intermediate_size=96, num_layers=5, num_heads=4, num_kv_heads=4,
+    head_dim=24, rope_theta=10_000.0, rms_norm_eps=1e-5,
+    mixer="latent", q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16,
+    swa_num_heads=2, swa_q_lora_rank=24, swa_kv_lora_rank=48,
+    swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8, swa_v_head_dim=16,
+    swa_rope_theta=500.0, sliding_window=13,
+    layer_types=("full_attention", "full_attention", "sliding_attention",
+                 "sliding_attention", "sliding_attention"),
+    attn_gate="headwise", lora_rescale=True,
+    index_n_heads=4, index_head_dim=16, index_topk=12,
+    num_experts=16, num_experts_per_tok=3, experts_held=8, expert_start=8,
+    first_dense_layers=1, moe_intermediate_size=24, n_shared_experts=1,
+    moe_scoring="sigmoid+bias", norm_topk_prob=True,
+    routed_scaling_factor=1.0)
 
 LLAMA3_8B = ModelConfig(
     name="llama3-8b",
@@ -537,6 +688,57 @@ NEMOTRON3_SUPER_22L = ModelConfig(
     routed_scaling_factor=5.0,
 )
 
+# dots-studio/dots3-note-prev (config.json, model_type dots3_note), one
+# chip's share of the first of eight pipeline stages: layers 0-4 of 46 (the
+# leading dense layer and one whole period: full, sliding x 3), experts 0-63
+# of each layer's 256 (the router keeps 256 and top 8), rows 0-38,015 of the
+# 152,064-row vocabulary; every width as published
+# (benchmarks/configs/dots3-note-prev-w8a8.json has the reckoning).
+DOTS3_NOTE_PREV_5L = ModelConfig(
+    name="dots3-note-prev-5l",
+    vocab_size=38_016,
+    hidden_size=5120,
+    intermediate_size=13_824,
+    num_layers=5,
+    num_heads=128,
+    num_kv_heads=128,
+    head_dim=192,
+    rope_theta=80_000_000.0,
+    rms_norm_eps=1e-5,
+    max_seq_len=524_288,
+    mixer="latent",
+    q_lora_rank=1024,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    swa_num_heads=64,
+    swa_q_lora_rank=1024,
+    swa_kv_lora_rank=1024,
+    swa_qk_nope_head_dim=192,
+    swa_qk_rope_head_dim=64,
+    swa_v_head_dim=128,
+    swa_rope_theta=50_000.0,
+    sliding_window=513,
+    layer_types=("full_attention", "full_attention", "sliding_attention",
+                 "sliding_attention", "sliding_attention"),
+    attn_gate="headwise",
+    lora_rescale=True,
+    index_n_heads=64,
+    index_head_dim=128,
+    index_topk=2048,
+    num_experts=256,
+    num_experts_per_tok=8,
+    experts_held=64,
+    expert_start=0,
+    first_dense_layers=1,
+    moe_intermediate_size=1536,
+    n_shared_experts=1,
+    moe_scoring="sigmoid+bias",
+    norm_topk_prob=True,
+    routed_scaling_factor=1.0,
+)
+
 # A ~1.1B config used for single-chip benchmarks when full 8B weights would not
 # leave headroom for the KV cache on a 16 GB v5e chip with random-init weights.
 LLAMA_1B = ModelConfig(
@@ -555,6 +757,7 @@ LLAMA_1B = ModelConfig(
 PRESETS = {
     c.name: c
     for c in [TINY, TINY_QWEN, TINY_MOE, TINY_LATENT_MOE, TINY_NEMOTRON_H,
+              TINY_DOTS3_NOTE, DOTS3_NOTE_PREV_5L,
               LLAMA3_8B, LLAMA3_70B, MISTRAL_7B, MIXTRAL_8X7B, QWEN2_7B,
               QWEN2_72B, GEMMA2_2B, GEMMA2_9B, LLAMA_1B, KANANA2_30B_A3B_12L,
               NEMOTRON3_SUPER_22L]

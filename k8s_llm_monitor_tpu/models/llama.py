@@ -32,12 +32,13 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from k8s_llm_monitor_tpu.models.config import ModelConfig
-from k8s_llm_monitor_tpu.ops import grouped
+from k8s_llm_monitor_tpu.models.config import LatentGeometry, ModelConfig
+from k8s_llm_monitor_tpu.ops import grouped, sparse
 from k8s_llm_monitor_tpu.ops.attention import (
     blockwise_attention,
     causal_attention,
     gather_pages,
+    index_scores_decode,
     paged_decode_attention,
     paged_decode_attention_quant,
 )
@@ -112,6 +113,8 @@ class KVPages(NamedTuple):
     v_scale: tuple | list = ()
     ssm: tuple | list = ()
     conv: tuple | list = ()
+    idx: tuple | list = ()
+    win: tuple | list = ()
 
     @property
     def num_blocks(self) -> int:
@@ -195,9 +198,20 @@ def init_kv_pages(cfg: ModelConfig, num_blocks: int, block_size: int,
             raise ValueError(
                 f"latent pages are not built for kv_dtype={kv_quant!r}")
         dtype = jnp.dtype(cfg.kv_dtype or cfg.dtype)
-        shape = (num_blocks, block_size, cfg.latent_page_width)
-        return KVPages(k=[jnp.zeros(shape, dtype)
-                          for _ in range(cfg.num_layers)], v=[])
+        paged = [cfg.latent_geometry(i) for i in cfg.layers_with("latent")]
+        extra = {}
+        if any(g.indexed for g in paged):
+            extra["idx"] = [jnp.zeros((num_blocks, block_size, g.index_dim),
+                                      dtype) for g in paged if g.indexed]
+        if cfg.layers_with("window"):
+            ring = 1 + state_lanes * (cfg.window_rows(block_size)
+                                      // block_size)
+            extra["win"] = [
+                jnp.zeros((ring, block_size,
+                           cfg.latent_geometry(i).page_width), dtype)
+                for i in cfg.layers_with("window")]
+        return KVPages(k=[jnp.zeros((num_blocks, block_size, g.page_width),
+                                    dtype) for g in paged], v=[], **extra)
     shape = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim_)
     if kv_quant:
         qdtype, _ = kv_quant_spec(kv_quant)
@@ -269,7 +283,12 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "input_norm": norm_init((H,), dtype),
             "post_norm": norm_init((H,), dtype),
         }
-        if spec.mixer == "latent":
+        if spec.mixer == "latent" and _low_rank_mixer(cfg, i):
+            layer.update(init_latent_mixer(
+                jax.random.fold_in(keys[2 + i], 2), cfg,
+                cfg.latent_geometry(i), dense,
+                lambda key, in_f, out_f: dense(key, in_f, out_f, False)))
+        elif spec.mixer == "latent":
             R, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim)
             layer["q"] = dense(lk[0], H, nH * (dn + dr), False)
@@ -316,6 +335,47 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(keys[1], H, cfg.vocab_size, False)
     return params
+
+
+def _low_rank_mixer(cfg: ModelConfig, i: int) -> bool:
+    """Layer ``i``'s latent mixer is the one ``init_latent_mixer`` builds:
+    low-rank queries, with whatever gate and indexer its geometry has."""
+    g = cfg.latent_geometry(i)
+    if not g.q_lora_rank and (g.gate or g.indexed):
+        raise NotImplementedError(
+            "a gate or an indexer on a latent mixer with direct queries: the "
+            "indexer reads the query latent")
+    return bool(g.q_lora_rank)
+
+
+def init_latent_mixer(key: jax.Array, cfg: ModelConfig, g: LatentGeometry,
+                      dense, wide) -> Params:
+    """The leaves of a latent mixer with low-rank queries (``q_a``, its
+    norm, ``q_b``), a head-wise gate and an indexer where the geometry has
+    them.  ``dense(key, in, out, bias)`` builds a projection in the caller's
+    form (int8 in utils/quantize.py), ``wide(key, in, out)`` one that stays
+    in the activation dtype under every quantisation (``W_kvb``: ``_kv_b``
+    says why); norms are built here."""
+    H, dtype = cfg.hidden_size, jnp.dtype(cfg.dtype)
+    nH, R, Rq = g.num_heads, g.kv_lora_rank, g.q_lora_rank
+    dn, dr, dv = g.qk_nope_head_dim, g.qk_rope_head_dim, g.v_head_dim
+    k = jax.random.split(key, 9)
+    layer = {"q_a": dense(k[0], H, Rq, False),
+             "q_norm": jnp.ones((Rq,), dtype),
+             "q_b": dense(k[1], Rq, nH * (dn + dr), False),
+             "kv_a": dense(k[2], H, R + dr, False),
+             "kv_norm": jnp.ones((R,), dtype),
+             "kv_b": wide(k[3], R, nH * (dn + dv)),
+             "o": dense(k[4], nH * dv, H, False)}
+    if g.gate:
+        layer["attn_gate"] = dense(k[5], H, nH, False)
+    if g.indexed:
+        layer["idx_q"] = dense(k[6], Rq, g.index_heads * g.index_dim, False)
+        layer["idx_k"] = dense(k[7], H, g.index_dim, False)
+        layer["idx_k_norm"] = {"weight": jnp.ones((g.index_dim,), dtype),
+                               "bias": jnp.zeros((g.index_dim,), dtype)}
+        layer["idx_w"] = dense(k[8], H, g.index_heads, False)
+    return layer
 
 
 def mamba_init(key: jax.Array, cfg: ModelConfig) -> Params:
@@ -548,59 +608,130 @@ def _qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
     return q, k, v
 
 
-def _rope_width(cfg: ModelConfig) -> int:
-    """Lanes the rotary embedding turns: the whole head, or the rope part of
-    a latent mixer's score."""
-    return cfg.qk_rope_head_dim if cfg.latent else cfg.head_dim_
+def _rope_kind(cfg: ModelConfig, li: int) -> tuple[int, float]:
+    """(lanes, theta) of the rotation layer ``li`` makes: the whole head at
+    ``rope_theta``, or its latent geometry's rope part at its own theta."""
+    if not cfg.latent:
+        return cfg.head_dim_, cfg.rope_theta
+    g = cfg.latent_geometry(li)
+    return g.qk_rope_head_dim, g.rope_theta
 
 
-def _latent_qkv(layer: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin):
+def _rope_tables(cfg: ModelConfig, positions: jnp.ndarray) -> dict:
+    """(cos, sin) of ``positions`` for every rotation the model makes, by
+    ``_rope_kind`` (one table a model, two where its geometries differ)."""
+    kinds = dict.fromkeys(_rope_kind(cfg, li) for li in range(cfg.num_layers))
+    return {kind: rope_angles(positions, *kind, scaling=cfg.rope_scaling)
+            for kind in kinds}
+
+
+def _rope_of(cfg: ModelConfig, tables: dict, li: int):
+    """Layer ``li``'s (cos, sin) out of ``_rope_tables``."""
+    return tables[_rope_kind(cfg, li)]
+
+
+def _latent_qkv(layer: Params, cfg: ModelConfig, g: LatentGeometry,
+                x: jnp.ndarray, cos, sin):
     """Latent mixer, what is computed per token.  x [B, S, H] ->
     q_nope [B,S,nH,dn], q_rope [B,S,nH,dr] (rotated), c [B,S,R] (the
-    normalised latent) and k_rope [B,S,dr] (one rotated key shared by all
-    heads).  ``c`` and ``k_rope`` are all that is cached."""
+    normalised latent), k_rope [B,S,dr] (one rotated key shared by all
+    heads) and the query latent ``cq`` [B,S,Rq] (None where the query
+    projection is direct).  ``c`` and ``k_rope`` are all that is cached."""
     B, S, _ = x.shape
-    R, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    R, dn = g.kv_lora_rank, g.qk_nope_head_dim
     aq = cfg.act_quant
     with jax.named_scope("qkv"):
-        q = _linear(layer["q"], x, aq).reshape(B, S, cfg.num_heads, -1)
+        cq = None
+        if g.q_lora_rank:
+            cq = rms_norm(_linear(layer["q_a"], x, aq), layer["q_norm"],
+                          cfg.rms_norm_eps, cfg.rmsnorm_unit_offset)
+            if g.q_scale != 1.0:
+                cq = cq * jnp.asarray(g.q_scale, cq.dtype)
+            q = _linear(layer["q_b"], cq, aq).reshape(B, S, g.num_heads, -1)
+        else:
+            q = _linear(layer["q"], x, aq).reshape(B, S, g.num_heads, -1)
         kva = _linear(layer["kv_a"], x, aq)                 # [B, S, R + dr]
         c = rms_norm(kva[..., :R], layer["kv_norm"], cfg.rms_norm_eps,
                      cfg.rmsnorm_unit_offset)
+        if g.kv_scale != 1.0:
+            c = c * jnp.asarray(g.kv_scale, c.dtype)
         q_rope = apply_rope(q[..., dn:], cos, sin)
         k_rope = apply_rope(kva[..., None, R:], cos, sin)[:, :, 0]
-    return q[..., :dn], q_rope, c, k_rope
+    return q[..., :dn], q_rope, c, k_rope, cq
 
 
-def _page_width(cfg: ModelConfig, latent: jnp.ndarray,
+def _index_qk(layer: Params, cfg: ModelConfig, g: LatentGeometry,
+              x: jnp.ndarray, cq: jnp.ndarray, cos, sin):
+    """The indexer, what is computed per token.  x [B, S, H] (the layer's
+    normed input), cq [B, S, Rq] -> its queries qI [B,S,Hi,Di] off the
+    query latent, its one key a token kI [B,S,Di] (a LayerNorm of a
+    projection of x) — both with their first ``qk_rope_head_dim`` lanes
+    rotated — and the heads' weights w [B,S,Hi] float32, with the score's
+    1/sqrt(Hi) and 1/sqrt(Di) folded in.  ``kI`` is all that is cached."""
+    B, S, _ = x.shape
+    Hi, Di, dr = g.index_heads, g.index_dim, g.qk_rope_head_dim
+    aq = cfg.act_quant
+    with jax.named_scope("attn/indexer"):
+        qI = _linear(layer["idx_q"], cq, aq).reshape(B, S, Hi, Di)
+        k32 = _linear(layer["idx_k"], x, aq).astype(jnp.float32)
+        mu = jnp.mean(k32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k32 - mu), axis=-1, keepdims=True)
+        n = layer["idx_k_norm"]
+        kI = ((k32 - mu) * jax.lax.rsqrt(var + cfg.rms_norm_eps)
+              * n["weight"].astype(jnp.float32)
+              + n["bias"].astype(jnp.float32)).astype(x.dtype)
+        qI = jnp.concatenate(
+            [apply_rope(qI[..., :dr], cos, sin), qI[..., dr:]], axis=-1)
+        kI = jnp.concatenate(
+            [apply_rope(kI[..., None, :dr], cos, sin)[:, :, 0], kI[..., dr:]],
+            axis=-1)
+        w = (_linear(layer["idx_w"], x, aq).astype(jnp.float32)
+             * (Hi ** -0.5 * Di ** -0.5))
+    return qI, kI, w
+
+
+def _page_width(g: LatentGeometry, latent: jnp.ndarray,
                 rope: jnp.ndarray) -> jnp.ndarray:
     """``[latent part | rope part | zeros]`` at the latent page's width: the
     layout of a cached row, and of the absorbed query that meets it."""
-    pad = cfg.latent_page_width - latent.shape[-1] - rope.shape[-1]
+    pad = g.page_width - latent.shape[-1] - rope.shape[-1]
     zeros = jnp.zeros((*latent.shape[:-1], pad), latent.dtype)
     return jnp.concatenate([latent, rope, zeros], axis=-1)
 
 
-def _latent_rows(cfg: ModelConfig, c: jnp.ndarray,
+def _latent_rows(g: LatentGeometry, c: jnp.ndarray,
                  k_rope: jnp.ndarray) -> jnp.ndarray:
     """The page row of each token: ``[c | k_rope | zeros]`` [B, S, 1, F]."""
-    return _page_width(cfg, c, k_rope)[:, :, None, :]
+    return _page_width(g, c, k_rope)[:, :, None, :]
 
 
-def _kv_b(layer: Params, cfg: ModelConfig):
+def _kv_b(layer: Params, g: LatentGeometry):
     """``W_kvb`` by head: (W_UK [R, nH, dn], W_UV [R, nH, dv]).  It stays in
     the activation dtype under every quantisation (utils/quantize.py): the
     absorbed form multiplies queries and outputs by it, not the cached
     latent, so an activation-quantised product would differ between the two
     forms."""
-    w = layer["kv_b"]["kernel"].reshape(
-        cfg.kv_lora_rank, cfg.num_heads, -1)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+    w = layer["kv_b"]["kernel"].reshape(g.kv_lora_rank, g.num_heads, -1)
+    return w[..., :g.qk_nope_head_dim], w[..., g.qk_nope_head_dim:]
 
 
-def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
+def window_tables(lanes: jnp.ndarray, pool_blocks: int,
+                  ring_blocks: int) -> jnp.ndarray:
+    """The block table of each row's ring in the window store
+    (``KVPages.win``): lane l's blocks are ``1 + l * ring_blocks ...``; a
+    lane past the pool (an idle row) gets the null block.  [B] -> [B,
+    ring_blocks] int32."""
+    first = 1 + lanes.astype(jnp.int32) * ring_blocks
+    table = first[:, None] + jnp.arange(ring_blocks, dtype=jnp.int32)[None, :]
+    return jnp.where(table < pool_blocks, table, 0)
+
+
+def _latent_attend_expanded(layer: Params, cfg: ModelConfig,
+                            g: LatentGeometry, q_nope, q_rope,
                             c, k_rope, positions, kv_len, attn_fn=None,
-                            kernel=None, view: Optional["RowView"] = None):
+                            kernel=None, view: Optional["RowView"] = None,
+                            index=None, sel_stats: Optional[list] = None,
+                            selection: Optional[list] = None):
     """Expanded form over the batch's own tokens: per-head keys
     ``[k_nope | k_rope]`` and values from ``c W_kvb``.  ``kernel``: the
     Pallas kernel of a fresh prefill (positions are indices,
@@ -608,24 +739,72 @@ def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
     the dense oracle (``causal_attention``); neither: blockwise XLA
     operations.  ``view``: the inputs are a packed stream; keys and values
     are expanded on it (per token) and attention sees its rows
-    (``positions`` are then the rows')."""
+    (``positions`` are then the rows').
+
+    A geometry with a window or an indexer (``index`` = the indexer's
+    (qI, kI, w) of these tokens) restricts the keys a query may see: the
+    band, or the indexer's top ``index_topk`` of the earlier keys
+    (ops/sparse.py).  The kernel takes both as options; without one the
+    mask is built whole ([rows, S, S]: the CPU's path).  ``sel_stats``
+    receives the layer's counts (``_sel_counts``: at admission what the
+    lengths imply, the keep mask stays inside the kernel's wrapper);
+    ``selection`` (rows, not a stream) an indexed layer's (scores [B, S, S]
+    float32, keep [B, S, S] bool) as the path taken computed them."""
     B, S = c.shape[:2]
-    w_uk, w_uv = _kv_b(layer, cfg)
+    w_uk, w_uv = _kv_b(layer, g)
     k_nope = jnp.einsum("bsr,rhd->bshd", c, w_uk)
     v = jnp.einsum("bsr,rhd->bshd", c, w_uv)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (B, S, cfg.num_heads, k_rope.shape[-1]))],
+                                  (B, S, g.num_heads, k_rope.shape[-1]))],
         axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    scale = cfg.qk_head_dim ** -0.5
+    scale = g.qk_head_dim ** -0.5
+    restricted = bool(g.window or g.indexed)
+    if restricted and sel_stats is not None:
+        seen = jnp.where(
+            jnp.arange(S if view is None else view.take.shape[1],
+                       dtype=jnp.int32)[None, :] < kv_len[:, None],
+            positions + 1, 0)
+        sel_stats.append(_sel_counts(g, seen,
+                                     jnp.minimum(seen, g.index_topk)))
+    if restricted and kernel is not None:
+        extra = dict(window=g.window, topk=g.index_topk,
+                     index=None if index is None else tuple(
+                         t[0] if view is not None else t for t in index))
+        if view is not None:
+            return _packed_form(kernel)(
+                q[0], k[0], v[0], view.offset, kv_len, scale=scale,
+                row_len=view.take.shape[1], **extra)[None]
+        if selection is not None and g.indexed:
+            attn, scores, keep = kernel(q, k, v, kv_len, scale=scale,
+                                        probe=True, **extra)
+            selection.append((scores, keep))
+            return attn
+        return kernel(q, k, v, kv_len, scale=scale, **extra)
     if view is not None and kernel is not None:
         # The kernel's blocks straight from the stream, no row views.
         return _packed_form(kernel)(
             q[0], k[0], v[0], view.offset, kv_len, scale=scale,
             row_len=view.take.shape[1])[None]
     q, k, v = (_rows(view, t) for t in (q, k, v))
-    if attn_fn is not None:
+    if restricted:
+        if attn_fn is not None and not g.indexed:
+            attn = attn_fn(q, k, v, q_positions=positions, scale=scale,
+                           window=g.window)
+            return _stream(view, attn)
+        keep = sparse.allowed_keys(
+            positions, jnp.full((q.shape[0],), q.shape[1], jnp.int32)
+            if kv_len is None else kv_len, q.shape[1], g.window)
+        if g.indexed:
+            qI, kI, wI = (_rows(view, t) for t in index)
+            with jax.named_scope("attn/select"):
+                scores = sparse.index_scores(qI, wI, kI)
+                keep = sparse.topk_keep(scores, keep, g.index_topk)
+            if selection is not None:
+                selection.append((scores, keep))
+        attn = sparse.masked_attention(q, k, v, keep, scale=scale)
+    elif attn_fn is not None:
         attn = attn_fn(q, k, v, q_positions=positions, scale=scale)
     elif kernel is not None:
         attn = kernel(q, k, v, kv_len, scale=scale)
@@ -635,21 +814,49 @@ def _latent_attend_expanded(layer: Params, cfg: ModelConfig, q_nope, q_rope,
     return _stream(view, attn)
 
 
-def _latent_absorb(layer: Params, cfg: ModelConfig, q_nope,
+def _sel_counts(g: LatentGeometry, seen: jnp.ndarray,
+                kept: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """float32[3] = (index keys scored, keys selected, window rows read) of
+    one layer.  ``seen``: the keys each query has before it (its position +
+    1, 0 for padding or an idle lane) — what an indexed layer scores, and,
+    capped at ``window``, the rows a window layer's kernel is told to read;
+    ``kept``: the keys each query of an indexed layer attends to (at a
+    decode step the sum of the keep mask that was applied)."""
+    zero = jnp.zeros((), jnp.float32)
+    total = lambda n: jnp.sum(n.astype(jnp.float32))             # noqa: E731
+    if g.indexed:
+        return jnp.stack([total(seen), total(kept), zero])
+    return jnp.stack([zero, zero, total(jnp.minimum(seen, g.window))])
+
+
+def _latent_absorb(layer: Params, g: LatentGeometry, q_nope,
                    q_rope) -> jnp.ndarray:
     """Queries of the absorbed form, against page rows: ``[q_nope W_UK^T |
     q_rope | zeros] / sqrt(dn + dr)`` [B, S, nH, F]."""
-    w_uk, _ = _kv_b(layer, cfg)
+    w_uk, _ = _kv_b(layer, g)
     q_abs = _page_width(
-        cfg, jnp.einsum("bshd,rhd->bshr", q_nope, w_uk), q_rope)
-    return q_abs * jnp.asarray(cfg.qk_head_dim ** -0.5, q_abs.dtype)
+        g, jnp.einsum("bshd,rhd->bshr", q_nope, w_uk), q_rope)
+    return q_abs * jnp.asarray(g.qk_head_dim ** -0.5, q_abs.dtype)
 
 
-def _latent_unabsorb(layer: Params, cfg: ModelConfig,
+def _latent_unabsorb(layer: Params, g: LatentGeometry,
                      o_lat: jnp.ndarray) -> jnp.ndarray:
     """``(P c) W_UV`` by head: [B, S, nH, R] -> [B, S, nH, dv]."""
-    _, w_uv = _kv_b(layer, cfg)
+    _, w_uv = _kv_b(layer, g)
     return jnp.einsum("bshr,rhd->bshd", o_lat, w_uv)
+
+
+def _head_gate(layer: Params, cfg: ModelConfig, g: LatentGeometry,
+               h: jnp.ndarray, attn: jnp.ndarray) -> jnp.ndarray:
+    """The head-wise output gate: head j's output [B, S, nH, dv] times
+    ``sigmoid(W_g h)_j``, one scalar a head from the layer's normed input;
+    ``attn`` as it is where the geometry has no gate."""
+    if not g.gate:
+        return attn
+    with jax.named_scope("attn/gate"):
+        gate = jax.nn.sigmoid(
+            _linear(layer["attn_gate"], h, cfg.act_quant).astype(jnp.float32))
+        return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
 
 
 def _marked(attn_impl, mark: str) -> bool:
@@ -1433,11 +1640,16 @@ def layer_block(
                                     jnp.ones((B, S), bool), last)
         return x + out, jnp.zeros((), jnp.float32)
     if cfg.latent:
-        q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+        g = cfg.latent_geometry(layer_idx)
+        q_nope, q_rope, c, k_rope, cq = _latent_qkv(layer, cfg, g, h, cos,
+                                                    sin)
+        index = (_index_qk(layer, cfg, g, h, cq, cos, sin) if g.indexed
+                 else None)
         with jax.named_scope("attention"), jax.named_scope("latent"):
             attn = _latent_attend_expanded(
-                layer, cfg, q_nope, q_rope, c, k_rope, positions, None,
-                attn_fn=attn_fn)
+                layer, cfg, g, q_nope, q_rope, c, k_rope, positions, None,
+                attn_fn=attn_fn, index=index)
+        attn = _head_gate(layer, cfg, g, h, attn)
     else:
         q, k, v = _qkv(layer, cfg, h, cos, sin)
         with jax.named_scope("attention"):
@@ -1474,10 +1686,10 @@ def forward_full(
     x = _embed_lookup(params, cfg, tokens)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
-                           scaling=cfg.rope_scaling)
+    ropes = _rope_tables(cfg, positions)
     aux_total = jnp.zeros((), jnp.float32)
     for li, layer in enumerate(params["layers"]):
+        cos, sin = _rope_of(cfg, ropes, li)
         x, aux = layer_block(layer, cfg, x, cos, sin, positions,
                              attn_fn=attn_fn, collect_aux=return_aux,
                              layer_idx=li)
@@ -1608,6 +1820,8 @@ def _prefill_impl(
     hidden: Optional[list] = None,
     view: Optional[RowView] = None,
     lanes: Optional[jnp.ndarray] = None,
+    sel_stats: Optional[list] = None,
+    selection: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Shared prefill layer loop.
 
@@ -1641,28 +1855,34 @@ def _prefill_impl(
     ``lanes`` [rows]: the state-pool lane each row's recurrent state is
     written to (a description with recurrent layers; a lane past the pool
     drops the write, as an idle row's must).  The state starts from zero:
-    continuing one (``attend_to_pages``) is not built.
+    continuing one (``attend_to_pages``) is not built.  A description with
+    window layers names its lanes the same way: the last ``window`` rows of
+    each prompt go to its lane's ring of the window store
+    (``KVPages.win``).  ``sel_stats``: a list each window or indexed layer
+    appends its counts to (``_sel_counts``); ``selection``: a list each
+    indexed layer appends its (scores, keep) to (``_latent_attend_expanded``;
+    rows, not a packed stream).
     """
     B, S = tokens.shape
+    if cfg.lane_state and (attend_to_pages or lanes is None):
+        raise ValueError(
+            "recurrent layers and window layers are prefilled whole, from "
+            "nothing, into the lanes the call names: chunked prefill, a "
+            "cached prefix and the verify pass are not built for them")
     if cfg.recurrent:
-        if attend_to_pages or lanes is None:
-            raise ValueError(
-                "recurrent layers are prefilled whole, from a zero state, "
-                "into the lanes the call names: chunked prefill, a cached "
-                "prefix and the verify pass are not built for them")
         # Where each row's last real token lies in the flattened tokens.
         last = jnp.maximum(lengths - 1, 0) + (
             jnp.arange(B, dtype=jnp.int32) * S if view is None
             else view.offset)
         last = jnp.minimum(last, B * S - 1)
-    kv_of = {li: n for n, li in enumerate(cfg.layers_with("kv"))}
+    kv_of = {li: n for n, li in enumerate(cfg.layers_with(
+        "latent" if cfg.latent else "kv"))}
     if cfg.latent and paged_attn_fn is not None \
             and not is_latent_prefill_impl(paged_attn_fn):
         raise ValueError(
             "a latent mixer's prefill takes its own kernel or none "
             f"(ops/attention.py:select_prefill_impl); got {paged_attn_fn!r}")
-    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
-                           scaling=cfg.rope_scaling)
+    ropes = _rope_tables(cfg, positions)
     if view is None:
         row_pos, sc_tables, sc_pos = positions, block_tables, positions
     else:
@@ -1674,6 +1894,18 @@ def _prefill_impl(
             jnp.arange(view.take.shape[1], dtype=jnp.int32), view.take.shape)
         sc_tables = block_tables.reshape(1, R * W)
         sc_pos = view.seg[None] * (W * pages.block_size) + positions
+    if pages.win:
+        # A window layer keeps a prompt's last ``window`` rows: the row of
+        # position p at ring row p % window of the row's lane.
+        Wn, bs = cfg.sliding_window, pages.block_size
+        ring = cfg.window_rows(bs) // bs
+        w_tables = window_tables(lanes, pages.win[0].shape[0], ring)
+        row_len = lengths[:, None] if view is None else lengths[view.seg][None]
+        w_valid = valid & (positions >= row_len - Wn)
+        w_pos = positions % Wn
+        if view is not None:
+            w_tables = w_tables.reshape(1, -1)
+            w_pos = view.seg[None] * (ring * bs) + w_pos
 
     x = _embed_lookup(params, cfg, tokens)
     uo = cfg.rmsnorm_unit_offset
@@ -1681,11 +1913,13 @@ def _prefill_impl(
     new_k, new_v = [], []
     new_ks, new_vs = [], []
     new_ssm, new_conv = [], []
+    new_idx, new_win = [], []
     for li, layer in enumerate(params["layers"]):
         if hidden is not None:
             hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
         spec = cfg.layer_spec(li)
+        cos, sin = _rope_of(cfg, ropes, li)
         if spec.mixer == "mamba2":
             out, state, tail = _mamba2_prefill(layer, cfg, h, positions,
                                                valid, last)
@@ -1700,22 +1934,38 @@ def _prefill_impl(
                 x = x + _mlp(layer, cfg, h, valid, moe_stats)
             continue
         if cfg.latent:
-            q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+            g = cfg.latent_geometry(li)
+            q_nope, q_rope, c, k_rope, cq = _latent_qkv(layer, cfg, g, h,
+                                                        cos, sin)
+            index = None
+            if g.indexed:
+                index = _index_qk(layer, cfg, g, h, cq, cos, sin)
+                new_idx.append(_scatter_pages(
+                    pages.idx[len(new_idx)], index[1][:, :, None, :],
+                    sc_tables, sc_pos, valid))
             with jax.named_scope("attention"), jax.named_scope("latent"):
-                pk = _scatter_pages(pages.k[li], _latent_rows(cfg, c, k_rope),
-                                    sc_tables, sc_pos, valid)
-                new_k.append(pk)
+                if spec.cache == "window":
+                    new_win.append(_scatter_pages(
+                        pages.win[len(new_win)], _latent_rows(g, c, k_rope),
+                        w_tables, w_pos, w_valid))
+                else:
+                    pk = _scatter_pages(
+                        pages.k[kv_of[li]], _latent_rows(g, c, k_rope),
+                        sc_tables, sc_pos, valid)
+                    new_k.append(pk)
                 if attend_to_pages:
                     rows = gather_pages(pk, block_tables)[:, :, None, :]
                     o_lat = blockwise_attention(
-                        _latent_absorb(layer, cfg, q_nope, q_rope), rows,
-                        rows[..., :cfg.kv_lora_rank], q_positions=positions,
+                        _latent_absorb(layer, g, q_nope, q_rope), rows,
+                        rows[..., :g.kv_lora_rank], q_positions=positions,
                         kv_len=kv_len, scale=1.0)
-                    attn = _latent_unabsorb(layer, cfg, o_lat)
+                    attn = _latent_unabsorb(layer, g, o_lat)
                 else:
                     attn = _latent_attend_expanded(
-                        layer, cfg, q_nope, q_rope, c, k_rope, row_pos,
-                        kv_len, kernel=paged_attn_fn, view=view)
+                        layer, cfg, g, q_nope, q_rope, c, k_rope, row_pos,
+                        kv_len, kernel=paged_attn_fn, view=view, index=index,
+                        sel_stats=sel_stats, selection=selection)
+            attn = _head_gate(layer, cfg, g, h, attn)
             o = _attn_out(layer, cfg, attn.reshape(B, S, -1))
             x, _ = _residual_tail(layer, cfg, x, o, valid=valid,
                                   moe_stats=moe_stats)
@@ -1812,7 +2062,9 @@ def _prefill_impl(
                         k_scale=new_ks if quant else (),
                         v_scale=new_vs if quant else (),
                         **(dict(ssm=new_ssm, conv=new_conv)
-                           if cfg.recurrent else {}))
+                           if cfg.recurrent else {}),
+                        **({"idx": new_idx} if pages.idx else {}),
+                        **({"win": new_win} if pages.win else {}))
     if return_all_logits:
         return _unembed(params, cfg, x), out_pages
     last_idx = jnp.maximum(lengths - 1, 0)
@@ -1837,6 +2089,8 @@ def prefill(
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
     lanes: Optional[jnp.ndarray] = None,
+    sel_stats: Optional[list] = None,
+    selection: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """Ingest padded prompts, writing K/V into the paged cache.
 
@@ -1851,6 +2105,8 @@ def prefill(
         pages already hold exactly this call's K/V when attention runs.
       lanes: [B] int32, the state-pool lane of each row (recurrent layers;
         see ``_prefill_impl``).
+      selection: a list each indexed layer appends (scores [B, S, S]
+        float32, keep [B, S, S] bool) to, as this call computed them.
 
     Returns:
       (last_logits [B, V] float32, updated pages)
@@ -1861,7 +2117,8 @@ def prefill(
     return _prefill_impl(params, cfg, tokens, positions, valid, lengths,
                          lengths, pages, block_tables, attend_to_pages=False,
                          paged_attn_fn=attn_impl, moe_stats=moe_stats,
-                         hidden=hidden, lanes=lanes)
+                         hidden=hidden, lanes=lanes, sel_stats=sel_stats,
+                         selection=selection)
 
 
 def prefill_packed(
@@ -1877,6 +2134,7 @@ def prefill_packed(
     attn_impl=None,
     moe_stats: Optional[list] = None,
     lanes: Optional[jnp.ndarray] = None,
+    sel_stats: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """``prefill`` of prompts laid end to end in one token stream: what is
     computed per token is computed for the stream's ``T`` positions, not for
@@ -1911,7 +2169,8 @@ def prefill_packed(
     return _prefill_impl(params, cfg, tokens[None], positions[None],
                          valid[None], lengths, lengths, pages, block_tables,
                          attend_to_pages=False, paged_attn_fn=attn_impl,
-                         moe_stats=moe_stats, view=view, lanes=lanes)
+                         moe_stats=moe_stats, view=view, lanes=lanes,
+                         sel_stats=sel_stats)
 
 
 def prefill_chunk(
@@ -2017,6 +2276,9 @@ def decode_step(
     hidden: Optional[list] = None,
     lanes: Optional[jnp.ndarray] = None,
     ssm_update=ssm_decode_update_xla,
+    sel_stats: Optional[list] = None,
+    index_scores=index_scores_decode,
+    selection: Optional[list] = None,
 ) -> tuple[jnp.ndarray, KVPages]:
     """One decode step for a batch of slots.
 
@@ -2026,10 +2288,20 @@ def decode_step(
         0 means the slot is inactive (its writes go to the null block).
       pages / block_tables: paged cache state.
       attn_impl: paged attention implementation (XLA fallback or Pallas).
-      lanes: [B] int32, the state-pool lane of each slot (recurrent layers);
-        None = slot b is lane b, the pool has B lanes.
+      lanes: [B] int32, the state-pool lane of each slot (recurrent layers)
+        and its ring of the window store (window layers); None = slot b is
+        lane b, the pool has B lanes.
+      sel_stats: a list each window or indexed layer appends its counts to
+        (``_sel_counts``; the keys selected are the sum of the keep mask
+        the attention kernel is handed).
       ssm_update: the recurrent layers' one-step state update
         (ops/ssm.py:ssm_decode_update, or its XLA form).
+      index_scores: the indexer's scores against a lane's index-key pages
+        (ops/attention.py:select_index_scores_impl: the Pallas kernel, or
+        its XLA form).
+      selection: a list each indexed layer appends (scores [B, T] float32,
+        keep [B, T] bool) to, as this step computed them
+        (``InferenceEngine.score_logits``).
 
     Returns:
       (logits [B, V] float32, updated pages)
@@ -2037,13 +2309,14 @@ def decode_step(
     B = tokens.shape[0]
     positions = context_lens[:, None]  # [B, 1]
     active = (context_lens > 0)[:, None]
-    cos, sin = rope_angles(positions, _rope_width(cfg), cfg.rope_theta,
-                           scaling=cfg.rope_scaling)
+    ropes = _rope_tables(cfg, positions)
     if not cfg.use_rope:
         # The fused kernels rotate inside, by the tables they are handed:
         # ones and zeros make the rotation the identity.
-        cos, sin = jnp.ones_like(cos), jnp.zeros_like(sin)
-    kv_of = {li: n for n, li in enumerate(cfg.layers_with("kv"))}
+        ropes = {kind: (jnp.ones_like(cos), jnp.zeros_like(sin))
+                 for kind, (cos, sin) in ropes.items()}
+    kv_of = {li: n for n, li in enumerate(cfg.layers_with(
+        "latent" if cfg.latent else "kv"))}
     quant = pages.quantized
     if cfg.latent and not is_latent_decode_impl(attn_impl):
         raise ValueError(
@@ -2061,11 +2334,22 @@ def decode_step(
     new_k, new_v = [], []
     new_ks, new_vs = [], []
     new_ssm, new_conv = [], []
+    new_idx, new_win = [], []
+    if pages.win:
+        # A window layer's rows: the ring of the slot's lane, the row of
+        # position p at ring row p % window — so the rows a query may see
+        # are the ring's first min(context, window).
+        Wn, bs = cfg.sliding_window, pages.block_size
+        w_tables = window_tables(
+            jnp.arange(B, dtype=jnp.int32) if lanes is None else lanes,
+            pages.win[0].shape[0], cfg.window_rows(bs) // bs)
+        w_lens = jnp.where(active[:, 0], jnp.minimum(new_lens, Wn), 0)
     for li, layer in enumerate(params["layers"]):
         if hidden is not None:      # see _prefill_impl
             hidden.append(x)
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
         spec = cfg.layer_spec(li)
+        cos, sin = _rope_of(cfg, ropes, li)
         if spec.mixer == "mamba2":
             n = len(new_ssm)
             out, ssm, conv = _mamba2_decode(
@@ -2082,15 +2366,55 @@ def decode_step(
         if cfg.latent:
             # Absorbed form: append this token's row, then every head reads
             # the lane's rows once (the kernel, or its XLA reference).
-            q_nope, q_rope, c, k_rope = _latent_qkv(layer, cfg, h, cos, sin)
+            g = cfg.latent_geometry(li)
+            q_nope, q_rope, c, k_rope, cq = _latent_qkv(layer, cfg, g, h,
+                                                        cos, sin)
+            how = {}
+            if g.indexed:
+                # The selection: this token's index key joins the lane's,
+                # the indexer scores every cached one, and attention is told
+                # which ``index_topk`` of them to keep.
+                qI, kI, wI = _index_qk(layer, cfg, g, h, cq, cos, sin)
+                pidx = _scatter_pages(
+                    pages.idx[len(new_idx)], kI[:, :, None, :], block_tables,
+                    positions, active)
+                new_idx.append(pidx)
+                with jax.named_scope("attn/select"):
+                    scores = index_scores(qI, wI, pidx, block_tables,
+                                          new_lens)
+                    seen = (jnp.arange(scores.shape[1], dtype=jnp.int32)[None]
+                            < new_lens[:, None])
+                    keep = sparse.topk_keep(scores, seen, g.index_topk)
+                    how = dict(keep=keep,
+                               name="sparse_latent_decode_attention")
+                if selection is not None:
+                    selection.append((scores, keep))
             with jax.named_scope("attention"), jax.named_scope("latent"):
-                pk = _scatter_pages(pages.k[li], _latent_rows(cfg, c, k_rope),
-                                    block_tables, positions, active)
-                o_lat = attn_impl(
-                    _latent_absorb(layer, cfg, q_nope, q_rope), pk,
-                    block_tables, new_lens, v_width=cfg.kv_lora_rank)
-                attn = _latent_unabsorb(layer, cfg, o_lat)
-            new_k.append(pk)
+                if spec.cache == "window":
+                    pw = _scatter_pages(
+                        pages.win[len(new_win)], _latent_rows(g, c, k_rope),
+                        w_tables, positions % Wn, active)
+                    new_win.append(pw)
+                    o_lat = attn_impl(
+                        _latent_absorb(layer, g, q_nope, q_rope), pw,
+                        w_tables, w_lens, v_width=g.kv_lora_rank,
+                        name="window_latent_decode_attention",
+                        burst=w_tables.shape[1])
+                else:
+                    pk = _scatter_pages(
+                        pages.k[kv_of[li]], _latent_rows(g, c, k_rope),
+                        block_tables, positions, active)
+                    new_k.append(pk)
+                    o_lat = attn_impl(
+                        _latent_absorb(layer, g, q_nope, q_rope), pk,
+                        block_tables, new_lens, v_width=g.kv_lora_rank,
+                        **how)
+                attn = _latent_unabsorb(layer, g, o_lat)
+            if sel_stats is not None and (g.window or g.indexed):
+                sel_stats.append(_sel_counts(
+                    g, jnp.where(active[:, 0], new_lens, 0),
+                    jnp.sum(keep & active, axis=-1) if g.indexed else None))
+            attn = _head_gate(layer, cfg, g, h, attn)
             o = _attn_out(layer, cfg, attn.reshape(B, 1, -1))
             x, _ = _residual_tail(layer, cfg, x, o, valid=active,
                                   moe_stats=moe_stats)
@@ -2159,4 +2483,6 @@ def decode_step(
                            k_scale=new_ks if quant else (),
                            v_scale=new_vs if quant else (),
                            **(dict(ssm=new_ssm, conv=new_conv)
-                              if cfg.recurrent else {}))
+                              if cfg.recurrent else {}),
+                           **({"idx": new_idx} if pages.idx else {}),
+                           **({"win": new_win} if pages.win else {}))

@@ -176,7 +176,7 @@ def _engine_metrics(w: _Writer, engine) -> None:
     w.metric("engine_kv_blocks_total", "gauge",
              "Configured KV cache blocks",
              [("", engine.ecfg.num_blocks)])
-    if getattr(engine, "_recurrent", False):
+    if getattr(engine, "_recurrent", False) and engine.cfg.recurrent:
         # The per-lane state pool of a description with recurrent layers,
         # beside the pages (the ``state_*`` attributes of ``engine.call``).
         lane = engine.cfg.state_lane_bytes(
@@ -189,6 +189,15 @@ def _engine_metrics(w: _Writer, engine) -> None:
         w.metric("engine_state_lane_bytes", "gauge",
                  "Bytes one lane of the recurrent-state pool holds",
                  [("", lane)])
+    if getattr(engine, "_recurrent", False) and engine.pages.win:
+        # The window store of a description with window layers: one ring a
+        # decode lane a window layer, whatever the lanes' contexts hold.
+        lane = engine.cfg.window_lane_bytes(
+            engine.ecfg.block_size, engine.pages.win[0].dtype.itemsize)
+        w.metric("engine_window_store_bytes", "gauge",
+                 "Bytes of the window store: every decode lane's ring of its "
+                 "last sliding_window rows over all window layers",
+                 [("", lane * engine.ecfg.max_slots)])
     w.metric("engine_prefills_total", "counter",
              "Prompts ingested via prefill",
              [("", engine.prefills)])
@@ -394,6 +403,32 @@ def _loop_metrics(w: _Writer, engine) -> None:
                      "experts or not (engine_moe_assignments_total over it: "
                      "the share of the routed work this chip holds)",
                      [("", moe["assignments_all"])])
+    if getattr(engine, "_sel_counted", False):
+        # A description with an indexer or window layers: its calls bring
+        # these back with their result (models/llama.py:_sel_counts).
+        sel = engine.sel_totals
+        w.metric("engine_index_tokens_total", "counter",
+                 "Index keys the indexers scored: every cached token of a "
+                 "live lane, summed over indexed layers and steps",
+                 [("", sel["index_tokens"])])
+        w.metric("engine_sel_tokens_total", "counter",
+                 "Keys the selection kept for attention (at a decode step the "
+                 "sum of the keep mask applied, summed over indexed layers "
+                 "and steps; over engine_index_tokens_total: the share of "
+                 "its context a query attends to)",
+                 [("", sel["sel_tokens"])])
+        w.metric("engine_window_tokens_total", "counter",
+                 "Rows the window layers' kernel was told to read "
+                 "(min(context, window) a query, summed over window layers "
+                 "and steps)",
+                 [("", sel["window_tokens"])])
+        w.metric("engine_attn_select_calls_total", "counter",
+                 "Calls of a description with an indexer by the form of "
+                 "their selected attention (ops/sparse.py): mask = every "
+                 "page streamed, unselected keys dropped before the "
+                 "softmax, the one form there is",
+                 [(f'{{form="{k}"}}', n)
+                  for k, n in sorted(engine.attn_select_calls.items())])
 
 
 def _latency_histograms(w: _Writer, engine) -> None:

@@ -26,8 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from k8s_llm_monitor_tpu.ops import sparse
 from k8s_llm_monitor_tpu.ops.pallas_attention import (
     flash_prefill_attention,
+    index_scores_decode_pallas,
     latent_decode_attention_pallas,
     latent_prefill_attention_pallas,
     paged_decode_attention_fused,
@@ -329,22 +331,45 @@ def latent_decode_attention(
     lengths: jnp.ndarray,
     *,
     v_width: int,
+    keep: jnp.ndarray | None = None,
+    name: str = "",
+    burst: int = 0,
 ) -> jnp.ndarray:
     """Single-token decode over a latent pool, absorbed form — XLA reference
     (the CPU path, and the oracle of the Pallas kernel).
 
     q: [B, 1, H, F] absorbed and scaled queries; pages: [num_blocks, bs, F]
     rows ``[latent | rotated key | zeros]``; the value of a row is its first
-    ``v_width`` lanes.  Returns [B, 1, H, v_width] (``P c``, before W_UV).
+    ``v_width`` lanes.  ``keep`` [B, T] bool: the selected keys of each lane
+    (the mask form of selected attention: every row is read, the unselected
+    are dropped before the softmax); ``name`` and ``burst`` are the kernel's,
+    unused here.
+    Returns [B, 1, H, v_width] (``P c``, before W_UV).
     """
+    del name, burst
     rows = gather_pages(pages, block_table).astype(jnp.float32)   # [B, T, F]
     logits = jnp.einsum("bshf,btf->bhst", q.astype(jnp.float32), rows)
     seen = (jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :]
             < lengths[:, None])
+    if keep is not None:
+        seen = seen & keep[:, :rows.shape[1]]
     logits = jnp.where(seen[:, None, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhst,btr->bshr", probs, rows[..., :v_width])
     return out.astype(q.dtype)
+
+
+def index_scores_decode(q: jnp.ndarray, w: jnp.ndarray, pages: jnp.ndarray,
+                        block_table: jnp.ndarray,
+                        lengths: jnp.ndarray) -> jnp.ndarray:
+    """The indexer's scores of one decode step against every cached index
+    key — XLA reference (the CPU path, and the oracle of the Pallas kernel).
+    q [B, 1, Hi, Di], w [B, 1, Hi] float32, pages [num_blocks, bs, Di] (the
+    index-key pages), block_table [B, NB] -> [B, NB * bs] float32; what lies
+    at or past a lane's ``lengths`` is meaningless (the selection masks
+    it)."""
+    del lengths
+    return sparse.index_scores(q, w, gather_pages(pages, block_table))[:, 0]
 
 
 latent_decode_attention.latent = True
@@ -510,6 +535,22 @@ def select_attn_impl(platform: str | None = None, cfg=None, mesh=None):
             cfg.num_kv_heads * cfg.head_dim_)
         return paged_decode_attention
     return paged_decode_attention_pallas
+
+
+def select_index_scores_impl(platform: str | None = None,
+                             mode: str = "auto"):
+    """The indexer's decode-step scores for the backend and
+    ``EngineConfig.decode_path``, as ``select_decode_impl`` picks the latent
+    attention beside it: the Pallas kernel over the index-key pages on a TPU
+    (through the interpreter elsewhere when ``mode`` asks for it), the XLA
+    form otherwise."""
+    if platform is None:
+        platform = jax.default_backend()
+    if mode == "gather" or (mode == "auto" and platform != "tpu"):
+        return index_scores_decode
+    if platform != "tpu":
+        return functools.partial(index_scores_decode_pallas, interpret=True)
+    return index_scores_decode_pallas
 
 
 def select_decode_impl(platform: str | None = None, cfg=None, mesh=None,

@@ -328,26 +328,38 @@ _LATENT_WINDOW = 32
 
 def _latent_decode_kernel(
     R,                     # static: value width (the latent part of a row)
+    burst,                 # static: pages a burst (_LATENT_WINDOW)
+    masked,                # static: a keep_ref follows q_ref
     # scalar prefetch
     tables_ref,            # [B, NB] int32 block ids
     lens_ref,              # [B] int32 valid cached tokens (new one included)
     # inputs
     q_ref,                 # [TB, H, F] absorbed queries, scaled (VMEM)
-    kv_hbm,                # [num_blocks, bs, F] latent pages (ANY/HBM)
+    *refs,                 # (keep_ref [TB, 1, bursts * W * bs] int32,) then:
+    # kv_hbm                 [num_blocks, bs, F] latent pages (ANY/HBM)
     # out
-    o_ref,                 # [TB, H, R]
+    # o_ref                  [TB, H, R]
 ):
     """One program handles TB lanes.  Every head of a lane reads the same
     rows ``[latent | rotated key | zeros]``: the score is one
     ``[H, F] x [F, keys]`` product against the whole row and the value is
     the row's first R lanes, so a page is streamed once for all heads.
     Operands stay in the pool's dtype (bf16 on the chip); products
-    accumulate in float32."""
+    accumulate in float32.
+
+    ``masked`` (selected attention, mask form): every page of the lane is
+    streamed all the same, and a key whose ``keep`` is 0 is dropped before
+    the softmax — its weight is set to zero outright, so a burst in which
+    nothing is kept adds nothing whatever the running maximum is."""
+    if masked:
+        keep_ref, kv_hbm, o_ref = refs
+    else:
+        kv_hbm, o_ref = refs
     TB, H, F = q_ref.shape
     b0 = pl.program_id(0) * TB
     bs = kv_hbm.shape[1]
     NB = tables_ref.shape[1]
-    W = min(_LATENT_WINDOW, NB)
+    W = min(burst, NB)
 
     def scoped(buf, sem):
         # buf: [2, W*bs, F] double-buffered slab; sem: [2, W].
@@ -391,10 +403,18 @@ def _latent_decode_kernel(
                 s = jax.lax.dot_general(
                     q, rows, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)        # [H, W*bs]
-                s = jnp.where(pos < length, s, NEG_INF)
+                if masked:
+                    at = pl.multiple_of(w * (W * bs), W * bs)
+                    seen = (pos < length) & (
+                        keep_ref[t, :, pl.ds(at, W * bs)] != 0)
+                    s = jnp.where(seen, s, NEG_INF)
+                else:
+                    s = jnp.where(pos < length, s, NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new)
+                if masked:
+                    p = jnp.where(seen, p, 0.0)
                 l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
                 pv = jax.lax.dot_general(
                     p.astype(rows.dtype), rows[:, :R],
@@ -406,6 +426,8 @@ def _latent_decode_kernel(
             l0 = jnp.zeros((H, 1), jnp.float32)
             acc0 = jnp.zeros((H, R), jnp.float32)
             _, l, acc = jax.lax.fori_loop(0, n_windows, body, (m0, l0, acc0))
+            if masked:      # an idle lane keeps nothing
+                l = jnp.where(l == 0.0, 1.0, l)
             o_ref[t] = (acc / l).astype(o_ref.dtype)
 
     pl.run_scoped(
@@ -415,7 +437,8 @@ def _latent_decode_kernel(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("v_width", "interpret"))
+@functools.partial(jax.jit, static_argnames=("v_width", "interpret", "name",
+                                             "burst"))
 def latent_decode_attention_pallas(
     q: jnp.ndarray,
     pages: jnp.ndarray,
@@ -424,6 +447,9 @@ def latent_decode_attention_pallas(
     *,
     v_width: int,
     interpret: bool = False,
+    keep: jnp.ndarray | None = None,
+    name: str = "latent_decode_attention",
+    burst: int = _LATENT_WINDOW,
 ) -> jnp.ndarray:
     """Single-token decode attention over a latent pool, absorbed form
     (drop-in for ops/attention.py:latent_decode_attention).
@@ -437,6 +463,13 @@ def latent_decode_attention_pallas(
       lengths: [B] int32 valid cached tokens, the new token's row included
         (0 = inactive lane).
       v_width: the latent width R; the value of a row is its first R lanes.
+      keep: [B, >= lengths] bool — the selected keys of each lane (the mask
+        form of selected attention: every page is streamed, a key that is
+        not kept is dropped before the softmax); None = every key.
+      name: the kernel's name in a device trace (a full layer's selected
+        attention and a window layer's ring have their own: the benchmark
+        prices them apart).
+      burst: pages fetched together (a window layer's ring is one burst).
 
     Returns:
       [B, 1, H, v_width] in q.dtype — per-head ``P c``, before ``W_UV``.
@@ -446,27 +479,163 @@ def latent_decode_attention_pallas(
     assert pages.shape[2] == F and v_width <= F, (pages.shape, F, v_width)
     TB = next(tb for tb in (8, 4, 2, 1)
               if B % tb == 0 and (B // tb >= 2 or B == 1))
+    in_specs = [pl.BlockSpec((TB, H, F), lambda p, tbl, ln: (p, 0, 0))]
+    operands = [q.reshape(B, H, F).astype(pages.dtype)]
+    if keep is not None:
+        # Whole bursts of keys a lane: the kernel slices a burst's flags.
+        span = min(burst, block_table.shape[1]) * pages.shape[1]
+        width = -(-block_table.shape[1] * pages.shape[1] // span) * span
+        flags = keep[:, :width].astype(jnp.int32)
+        flags = jnp.pad(flags, ((0, 0), (0, width - flags.shape[1])))
+        in_specs.append(pl.BlockSpec((TB, 1, width),
+                                     lambda p, tbl, ln: (p, 0, 0)))
+        operands.append(flags[:, None, :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B // TB,),
-        in_specs=[
-            pl.BlockSpec((TB, H, F), lambda p, tbl, ln: (p, 0, 0)),
+        in_specs=in_specs + [
             pl.BlockSpec(memory_space=pl.ANY),   # pages stay in HBM
         ],
         out_specs=pl.BlockSpec((TB, H, v_width),
                                lambda p, tbl, ln: (p, 0, 0)),
     )
     out = pl.pallas_call(
-        functools.partial(_latent_decode_kernel, v_width),
+        functools.partial(_latent_decode_kernel, v_width, burst,
+                          keep is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-        name="latent_decode_attention",
-    )(block_table, lengths.astype(jnp.int32), q.reshape(B, H, F).astype(
-        pages.dtype), pages)
+        name=name,
+    )(block_table, lengths.astype(jnp.int32), *operands, pages)
     return out[:, None]
+
+
+def _index_scores_decode_kernel(
+    burst,                 # static: pages a burst
+    # scalar prefetch
+    tables_ref,            # [B, NB] int32 block ids
+    lens_ref,              # [B] int32 valid cached tokens
+    # inputs
+    q_ref,                 # [TB, Hi, Di] the indexer's queries (VMEM)
+    w_ref,                 # [TB, Hi, 1] float32 the heads' weights
+    idx_hbm,               # [num_blocks, bs, Di] index-key pages (ANY/HBM)
+    # out
+    o_ref,                 # [TB, 1, bursts * W * bs] float32 scores
+):
+    """One program handles TB lanes: a lane's index keys are streamed once,
+    a burst of pages at a time, and scored against all of the indexer's
+    heads — ``sum_j w_j relu(q_j . key)`` — as one ``[Hi, Di] x [Di, keys]``
+    product, a relu, and a weighted sum down the heads.  What lies past a
+    lane's last burst is left zero (the selection masks it)."""
+    TB, Hi, Di = q_ref.shape
+    b0 = pl.program_id(0) * TB
+    bs = idx_hbm.shape[1]
+    NB = tables_ref.shape[1]
+    W = min(burst, NB)
+
+    def scoped(buf, sem):
+        def copies(slot, b, w):
+            for i in range(W):
+                j = jnp.minimum(w * W + i, NB - 1)
+                yield pltpu.make_async_copy(
+                    idx_hbm.at[tables_ref[b, j]],
+                    buf.at[slot, pl.ds(i * bs, bs)], sem.at[slot, i])
+
+        for t in range(TB):
+            b = b0 + t
+            length = jnp.maximum(lens_ref[b], 1)
+            n_bursts = ((length + bs - 1) // bs + W - 1) // W
+            for c in copies(0, b, 0):
+                c.start()
+            q, wt = q_ref[t], w_ref[t]                 # [Hi, Di], [Hi, 1]
+            o_ref[t] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+            def body(w, carry, b=b, n_bursts=n_bursts, q=q, wt=wt, t=t):
+                slot = jax.lax.rem(w, 2)
+
+                @pl.when(w + 1 < n_bursts)
+                def _prefetch():
+                    for c in copies(1 - slot, b, w + 1):
+                        c.start()
+
+                for c in copies(slot, b, w):
+                    c.wait()
+                s = jax.lax.dot_general(
+                    q, buf[slot], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)        # [Hi, W*bs]
+                at = pl.multiple_of(w * (W * bs), W * bs)
+                o_ref[t, :, pl.ds(at, W * bs)] = jnp.sum(
+                    jnp.maximum(s, 0.0) * wt, axis=0, keepdims=True)
+                return carry
+
+            jax.lax.fori_loop(0, n_bursts, body, 0)
+
+    pl.run_scoped(
+        scoped,
+        buf=pltpu.VMEM((2, W * bs, Di), idx_hbm.dtype),
+        sem=pltpu.SemaphoreType.DMA((2, W)),
+    )
+
+
+# Pages per burst of the index-score kernel: an index key is a fifth of a
+# latent row, so a burst takes twice the pages.
+_INDEX_BURST = 64
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def index_scores_decode_pallas(
+    q: jnp.ndarray,
+    w: jnp.ndarray,
+    pages: jnp.ndarray,
+    block_table: jnp.ndarray,
+    lengths: jnp.ndarray,
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The indexer's scores of one decode step against every cached index
+    key (drop-in for ops/attention.py:index_scores_decode).
+
+    Args:
+      q: [B, 1, Hi, Di] the indexer's queries, rotated.
+      w: [B, 1, Hi] float32 the heads' weights, scaled.
+      pages: [num_blocks, bs, Di] index-key pages.
+      block_table: [B, NB] int32; lengths: [B] int32 cached tokens.
+
+    Returns:
+      [B, >= NB * bs] float32; what lies at or past a lane's ``lengths`` is
+      meaningless (the selection masks it).
+    """
+    B, S, Hi, Di = q.shape
+    assert S == 1, f"decode kernel expects one query token, got {S}"
+    NB, bs = block_table.shape[1], pages.shape[1]
+    span = min(_INDEX_BURST, NB) * bs
+    width = -(-NB * bs // span) * span
+    TB = next(tb for tb in (8, 4, 2, 1)
+              if B % tb == 0 and (B // tb >= 2 or B == 1))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B // TB,),
+        in_specs=[
+            pl.BlockSpec((TB, Hi, Di), lambda p, tbl, ln: (p, 0, 0)),
+            pl.BlockSpec((TB, Hi, 1), lambda p, tbl, ln: (p, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),   # pages stay in HBM
+        ],
+        out_specs=pl.BlockSpec((TB, 1, width), lambda p, tbl, ln: (p, 0, 0)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_scores_decode_kernel, _INDEX_BURST),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="sparse_latent_decode_index_scores",
+    )(block_table, lengths.astype(jnp.int32),
+      q.reshape(B, Hi, Di).astype(pages.dtype),
+      w.reshape(B, Hi, 1).astype(jnp.float32), pages)
+    return out[:, 0]
 
 
 latent_decode_attention_pallas.latent = True
@@ -474,6 +643,8 @@ latent_decode_attention_pallas.latent = True
 
 def _latent_prefill_kernel(
     stream,                # static: blocks of a packed stream (below)
+    window,                # static: keys a query sees (0 = all before it)
+    masked,                # static: a keep_ref follows v_ref
     # scalar prefetch
     lens_ref,              # [B] int32 valid tokens of each row
     *refs,                 # (tile_row_ref, tile_t_ref, first_ref,) then:
@@ -501,10 +672,21 @@ def _latent_prefill_kernel(
     to end, the grid is (H, NT, key blocks of the longest row), and two
     prefetched arrays say which sequence query block i' is of and which of
     that sequence's blocks it is (a third, where each sequence's blocks
-    start, is the index maps').  The block's arithmetic is the same."""
+    start, is the index maps').  The block's arithmetic is the same.
+
+    ``window`` (stream form): a query sees the ``window`` positions that end
+    with its own; the key axis of the grid is then the few blocks a query
+    block's band crosses, counted from the first of them, and a block wholly
+    before the band is as dead as one above the diagonal.  ``masked``
+    (stream form): ``keep_ref`` [bq, bk] int8 says which keys each query
+    sees (selected attention, the mask form; it holds the diagonal and the
+    length already) — a key that is not kept gets no weight whatever the
+    running maximum is."""
     if stream:
-        (tile_row_ref, tile_t_ref, _, q_ref, k_ref, v_ref, o_ref,
-         m_scr, l_scr, acc_scr) = refs
+        tile_row_ref, tile_t_ref, _, q_ref, k_ref, v_ref, *refs = refs
+        if masked:
+            keep_ref, *refs = refs
+        o_ref, m_scr, l_scr, acc_scr = refs
         b, i = (tile_row_ref[pl.program_id(1)], tile_t_ref[pl.program_id(1)])
         j, last_j = pl.program_id(2), pl.num_programs(2) - 1
         blk = lambda ref: ref[0]                               # noqa: E731
@@ -516,38 +698,55 @@ def _latent_prefill_kernel(
     bq, bk = q_ref.shape[-2], k_ref.shape[-2]
     Dv = v_ref.shape[-1]
     length = lens_ref[b]
+    jrel = j
+    if window:      # the key axis counts from the band's first block
+        j = j + jnp.maximum(i * bq - (window - 1), 0) // bk
     live = (i * bq < length) & (j * bk < length) & (j * bk <= i * bq + bq - 1)
     whole = (j * bk + bk - 1 <= i * bq) & (j * bk + bk <= length)
+    if window:
+        live = live & (j * bk + bk - 1 > i * bq - window)
+        whole = whole & (i * bq + bq - 1 - j * bk < window)
 
-    @pl.when(j == 0)
+    @pl.when(jrel == 0)
     def _init():
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def block(masked: bool):
+    def block(cut: bool):
         k, v = blk(k_ref), blk(v_ref)
         s = jax.lax.dot_general(
             blk(q_ref), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                 # [bq, bk]
         if masked:
+            kept = keep_ref[...].astype(jnp.int32) != 0
+            s = jnp.where(kept, s, NEG_INF)
+        elif cut:
             rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where((cols <= rows) & (cols < length), s, NEG_INF)
+            seen = (cols <= rows) & (cols < length)
+            if window:
+                seen = seen & (rows - cols < window)
+            s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[...]                                     # [bq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - _lanes(m_new, bk))
+        if masked:
+            p = jnp.where(kept, p, 0.0)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
         acc_scr[...] = _lanes(alpha, Dv) * acc_scr[...] + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
 
-    pl.when(live & whole)(lambda: block(False))
-    pl.when(live & jnp.logical_not(whole))(lambda: block(True))
+    if masked:
+        pl.when(live)(lambda: block(False))
+    else:
+        pl.when(live & whole)(lambda: block(False))
+        pl.when(live & jnp.logical_not(whole))(lambda: block(True))
 
-    @pl.when(j == last_j)
+    @pl.when(jrel == last_j)
     def _store():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)          # rows past the length
@@ -567,7 +766,8 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block", "interpret",
+                                             "window", "topk", "probe"))
 def latent_prefill_attention_pallas(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -577,6 +777,10 @@ def latent_prefill_attention_pallas(
     scale: float,
     block: int = 512,
     interpret: bool = False,
+    window: int = 0,
+    topk: int = 0,
+    index=None,
+    probe: bool = False,
 ) -> jnp.ndarray:
     """Causal attention over a fresh prefill batch's own tokens, the
     expanded form of a latent mixer: per-head keys wider than the values.
@@ -590,12 +794,34 @@ def latent_prefill_attention_pallas(
         past it are garbage the caller masks, as everywhere.
       scale: multiplies the scores (folded into q here).
 
+      window, topk, index: a geometry that restricts the keys a query sees
+        (``latent_prefill_attention_packed`` says how); the rows are then
+        laid end to end and take the stream form.
+      probe: with ``index``, also return what the selection's kernels
+        computed (a comparison's, never a served program's).
+
     Returns:
       [B, S, H, Dv] in q.dtype.  No [S, S] score tensor leaves VMEM; key
-      blocks above the diagonal or past a row's length are skipped.
+      blocks above the diagonal or past a row's length are skipped.  With
+      ``probe``: (that, scores [B, S, S] float32, keep [B, S, S] bool).
     """
     B, S0, H, Dk = q.shape
     Dv = v.shape[-1]
+    if window or index is not None:
+        flat = lambda x: x.reshape(B * S0, *x.shape[2:])       # noqa: E731
+        out = latent_prefill_attention_packed(
+            flat(q), flat(k), flat(v),
+            jnp.arange(B, dtype=jnp.int32) * S0, lengths, scale=scale,
+            row_len=S0, block=block, interpret=interpret, window=window,
+            topk=topk,
+            index=None if index is None else tuple(map(flat, index)),
+            probe=probe)
+        if probe and index is not None:
+            out, scores, keep = out
+            return (out.reshape(B, S0, H, Dv),
+                    scores[:, :S0].reshape(B, S0, S0),
+                    keep[:, :S0].reshape(B, S0, S0) != 0)
+        return out.reshape(B, S0, H, Dv)
     bq = bk = min(block, S0)
     pad, tail = -Dk % 128, -S0 % bq       # whole lane tiles, whole blocks
     q = q * jnp.asarray(scale, q.dtype)
@@ -629,7 +855,7 @@ def latent_prefill_attention_pallas(
                         pltpu.VMEM((bq, Dv), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_prefill_kernel, False),
+        functools.partial(_latent_prefill_kernel, False, 0, False),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -670,8 +896,132 @@ def _stream_tiles(offset, lengths, T: int, tile: int):
     return NT, tile_row, tile_t, first, src, back
 
 
+def _index_scores_prefill_kernel(
+    # scalar prefetch
+    lens_ref,              # [R] int32 valid tokens of each segment
+    tile_row_ref,          # [NT] the segment query tile i is of
+    tile_t_ref,            # [NT] which of its segment's tiles it is
+    _first_ref,            # [R] (the index maps')
+    # inputs (one query tile, one key tile, one of the indexer's heads)
+    q_ref,                 # [1, bq, Di]
+    k_ref,                 # [bk, Di]
+    w_ref,                 # [1, bq, 1] float32 that head's weight a query
+    # out, kept across the heads of grid axis 2
+    o_ref,                 # [bq, bk] float32
+):
+    """The indexer's scores of one query tile against one key tile of the
+    SAME segment, ``sum_j w_j relu(q_j . key)``, one head a grid step.  A
+    tile pair above the diagonal or past the segment's length stays zero
+    (and fetches nothing new: the index map clamps it)."""
+    i, j, h = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, t = tile_row_ref[i], tile_t_ref[i]
+    bq, bk = q_ref.shape[1], k_ref.shape[0]
+    length = lens_ref[b]
+    live = (t * bq < length) & (j * bk < length) & (j * bk <= t * bq + bq - 1)
+
+    @pl.when(h == 0)
+    def _init():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(live)
+    def _score():
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [bq, bk]
+        o_ref[...] += jnp.maximum(s, 0.0) * w_ref[0]
+
+
+def _kth_largest_kernel(k, keys_ref, thr_ref):
+    """The k-th largest int32 key of each row of the block, built bit by bit
+    from the top (ops/sparse.py:kth_largest): the block stays in VMEM for
+    all 32 counting passes.  keys_ref [rows, T] int32; thr_ref [rows, 128]
+    int32, lane-replicated."""
+    keys = keys_ref[...]
+    top = jnp.int32(-2 ** 31)
+
+    def bit(i, thr):                                   # thr [rows, 1]
+        cand = thr | jnp.left_shift(jnp.int32(1), jnp.int32(31) - i)
+        count = jnp.sum((keys >= (cand ^ top)).astype(jnp.int32), axis=1,
+                        keepdims=True)
+        return jnp.where(count >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros((keys.shape[0], 1), jnp.int32))
+    thr_ref[...] = jnp.broadcast_to(thr ^ top, thr_ref.shape)
+
+
+def kth_largest_pallas(keys: jnp.ndarray, k: int, *, rows: int = 64,
+                       interpret: bool = False) -> jnp.ndarray:
+    """``ops/sparse.py:kth_largest`` of int32 ``keys`` [N, T] (N a multiple
+    of ``rows``): each row is read from HBM once, not 32 times."""
+    N, T = keys.shape
+    out = pl.pallas_call(
+        functools.partial(_kth_largest_kernel, k),
+        grid=(N // rows,),
+        in_specs=[pl.BlockSpec((rows, T), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((rows, 128), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, 128), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="sparse_latent_prefill_select",
+    )(keys)
+    return out[:, 0]
+
+
+def _selected_keys(index, lengths, tiles, *, topk: int, bq: int, nk: int,
+                   interpret: bool) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(int8, float32) [NT * bq, nk * bq]: which keys of its own segment
+    each query of the tile-aligned stream sees, and the scores that say so — the indexer's ``topk`` best of those no
+    later than it (ops/sparse.py:topk_keep; all of them when there are no
+    more).  The scores and each query's ``topk``-th score are kernels';
+    what is kept of the keys at that score is XLA."""
+    from k8s_llm_monitor_tpu.ops import sparse
+
+    NT, tile_row, tile_t, first, src, _ = tiles
+    qI, kI, wI = index                  # [T, Hi, Di], [T, Di], [T, Hi]
+    Hi, Di = qI.shape[1:]
+
+    def kv_map(i, j, h, lens, rows, ts, firsts):
+        b, t = rows[i], ts[i]
+        last = jnp.minimum((t * bq + bq - 1) // bq,
+                           jnp.maximum(lens[b] - 1, 0) // bq)
+        return (firsts[b] + jnp.minimum(j, last), 0)
+
+    scores = pl.pallas_call(
+        _index_scores_prefill_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(NT, nk, Hi),
+            in_specs=[
+                pl.BlockSpec((1, bq, Di), lambda i, j, h, *_: (h, i, 0)),
+                pl.BlockSpec((bq, Di), kv_map),
+                pl.BlockSpec((1, bq, 1), lambda i, j, h, *_: (h, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((bq, bq), lambda i, j, h, *_: (i, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((NT * bq, nk * bq), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="sparse_latent_prefill_index_scores",
+    )(lengths, tile_row, tile_t, first,
+      qI[src].transpose(1, 0, 2), kI[src].astype(qI.dtype),
+      wI[src].astype(jnp.float32).T[..., None])
+    pos = (tile_t[:, None] * bq
+           + jnp.arange(bq, dtype=jnp.int32)[None, :]).reshape(NT * bq)
+    row_len = jnp.repeat(lengths[tile_row], bq)
+    cols = jnp.arange(nk * bq, dtype=jnp.int32)[None, :]
+    allowed = (cols <= pos[:, None]) & (cols < row_len[:, None])
+    keys = sparse.ordered_keys(scores, allowed)
+    thr = kth_largest_pallas(keys, topk, rows=min(64, bq),
+                             interpret=interpret)
+    return sparse.keep_from_threshold(keys, thr, topk).astype(jnp.int8), scores
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "row_len", "block", "interpret"))
+                   static_argnames=("scale", "row_len", "block", "interpret",
+                                    "window", "topk", "probe"))
 def latent_prefill_attention_packed(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -683,6 +1033,10 @@ def latent_prefill_attention_packed(
     row_len: int,
     block: int = 512,
     interpret: bool = False,
+    window: int = 0,
+    topk: int = 0,
+    index=None,
+    probe: bool = False,
 ) -> jnp.ndarray:
     """``latent_prefill_attention_pallas`` of a fresh call's packed stream
     (models/llama.py:prefill_packed): the same kernel over the same blocks,
@@ -697,9 +1051,24 @@ def latent_prefill_attention_packed(
       offset, lengths: [R] int32 (0 = idle row).
       row_len: the longest a segment can be (static): sets the block and
         the key-block axis of the grid.
+      window: a query sees the ``window`` positions that end with its own
+        (0 = every earlier one): the key axis of the grid shrinks to the
+        blocks a query block's band crosses, and blocks wholly before the
+        band are dead tiles like those above the diagonal.  The kernel is
+        then ``window_latent_prefill_attention`` in a device trace.
+      index, topk: the indexer's (queries [T, Hi, Di], keys [T, Di], head
+        weights [T, Hi]) of the stream's tokens — selected attention, the
+        mask form: the indexer's scores come from a kernel of their own
+        (``sparse_latent_prefill_index_scores``), the ``topk`` best earlier
+        keys of each query are found without a sort (ops/sparse.py), and the
+        attention kernel (``sparse_latent_prefill_attention``) visits every
+        causal block and drops what was not selected before the softmax.
+      probe: with ``index``, also return each stream token's scores and
+        keep mask over its own segment's positions.
 
     Returns:
-      [T, H, Dv] in q.dtype; rows of no segment are garbage.
+      [T, H, Dv] in q.dtype; rows of no segment are garbage.  With
+      ``probe``: (that, scores [T, >= row_len] float32, keep int8 likewise).
     """
     T, H, Dk = q.shape
     Dv = v.shape[-1]
@@ -708,6 +1077,11 @@ def latent_prefill_attention_packed(
     offset, lengths = offset.astype(jnp.int32), lengths.astype(jnp.int32)
     NT, tile_row, tile_t, first, src, back = _stream_tiles(
         offset, lengths, T, bq)
+    keep = scores = None
+    if index is not None:
+        keep, scores = _selected_keys(
+            index, lengths, (NT, tile_row, tile_t, first, src, back),
+            topk=topk, bq=bq, nk=-(-row_len // bk), interpret=interpret)
     q = q * jnp.asarray(scale, q.dtype)
     if pad:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
@@ -718,37 +1092,59 @@ def latent_prefill_attention_packed(
     def q_map(h, i, j, lens, rows, ts, firsts):
         return (h, i, 0)
 
-    def kv_map(h, i, j, lens, rows, ts, firsts):
+    def key_block(i, j, lens, rows, ts):
         # As in the row form: blocks that will do nothing repeat the last
-        # one that does.
+        # one that does.  Under a window the axis counts from the band's
+        # first block.
         b, t = rows[i], ts[i]
         last = jnp.minimum((t * bq + bq - 1) // bk,
                            jnp.maximum(lens[b] - 1, 0) // bk)
-        return (h, firsts[b] + jnp.minimum(j, last), 0)
+        if window:
+            j = j + jnp.maximum(t * bq - (window - 1), 0) // bk
+        return b, jnp.minimum(j, last)
 
+    def kv_map(h, i, j, lens, rows, ts, firsts):
+        b, j = key_block(i, j, lens, rows, ts)
+        return (h, firsts[b] + j, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, bq, Dk + pad), q_map),
+        pl.BlockSpec((1, bk, Dk + pad), kv_map),
+        pl.BlockSpec((1, bk, Dv), kv_map),
+    ]
+    operands = [q, k, v]
+    if keep is not None:
+        in_specs.append(pl.BlockSpec(
+            (bq, bk), lambda h, i, j, lens, rows, ts, firsts:
+            (i, key_block(i, j, lens, rows, ts)[1])))
+        operands.append(keep)
+    if window:      # the blocks a query block's band crosses
+        nk = min(nk, -(-(window - 1) // bk) + 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(H, NT, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, Dk + pad), q_map),
-            pl.BlockSpec((1, bk, Dk + pad), kv_map),
-            pl.BlockSpec((1, bk, Dv), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bq, Dv), q_map),
         scratch_shapes=[pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, 128), jnp.float32),
                         pltpu.VMEM((bq, Dv), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_prefill_kernel, True),
+        functools.partial(_latent_prefill_kernel, True, window,
+                          keep is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((H, NT * bq, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="latent_prefill_attention",
-    )(lengths, tile_row, tile_t, first, q, k, v)
-    return out.transpose(1, 0, 2)[back]
+        name=("window_latent_prefill_attention" if window
+              else "sparse_latent_prefill_attention" if keep is not None
+              else "latent_prefill_attention"),
+    )(lengths, tile_row, tile_t, first, *operands)
+    out = out.transpose(1, 0, 2)[back]
+    if probe and keep is not None:
+        return out, scores[back], keep[back]
+    return out
 
 
 latent_prefill_attention_pallas.packed = latent_prefill_attention_packed

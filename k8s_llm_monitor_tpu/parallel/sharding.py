@@ -154,11 +154,13 @@ def partition_rules(
     return (
         (r"(^|/)embed/(weight|weight_q)$", layout.embedding()),
         (r"(^|/)embed/scale$", layout.embedding_scale()),
-        # Latent mixer (kv_a, kv_b, kv_norm) and the router with its
+        # Latent mixer (kv_a, kv_b, kv_norm; with low-rank queries q_a and
+        # q_b, a head-wise gate, an indexer) and the router with its
         # selection bias: replicated.  The engine refuses a mesh for a
         # latent pool (no kv-head axis to shard); these rules only keep
         # eval_shape-level checks of every preset meaningful.
-        (r"(^|/)(kv_a|kv_b|router)/", layout.replicated()),
+        (r"(^|/)(kv_a|kv_b|router|q_a|q_b|attn_gate|idx_q|idx_k|idx_w"
+         r"|idx_k_norm)/", layout.replicated()),
         (r"(^|/)(gate_e|up_e|down_e)/scale$", layout.expert_scale()),
         (r"(^|/)(gate_e|up_e|down_e)/", layout.expert_kernel()),
         (r"(^|/)(q|k|v|gate|up|lm_head)/(kernel|kernel_q)$",

@@ -470,7 +470,13 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     "moe_product_form",
                     # ... that holds a share of its experts (the four above
                     # are then over the experts held).
-                    "moe_assignments_all"),
+                    "moe_assignments_all",
+                    # Programs of a description with an indexer or window
+                    # layers (SEL_COUNTS), and the form of their selected
+                    # attention (ops/sparse.py: the mask form is the one
+                    # there is).
+                    "index_tokens", "sel_tokens", "window_tokens",
+                    "attn_select_form"),
     "xla.compile": ("seconds", "program"),
 }
 
@@ -487,29 +493,49 @@ MOE_COUNTS = ("moe_assignments", "moe_expert_layer_steps_hit", "moe_max_rows",
 MOE_SHARE_COUNTS = MOE_COUNTS + ("moe_assignments_all",)
 
 
-def _with_counts(outs: tuple, stats: Optional[list]) -> tuple:
-    """A program's outputs, with the call's routing counts last when the
-    model routes (``stats``: what each expert layer appended, or None — a
-    dense model's programs return exactly what they always did)."""
-    if stats is None:
-        return outs
-    return (*outs, jnp.sum(jnp.stack(stats), axis=0))
+# What a program of a description with an indexer or window layers returns,
+# summed on the device over those layers and the call's steps
+# (models/llama.py:_sel_counts): index keys scored (every cached token of a
+# live lane, an indexed layer), keys the selection kept (at a decode step the
+# sum of the keep mask the attention kernel was handed; at admission
+# min(position + 1, index_topk) a query), rows the window layers' kernel was
+# told to read (min(context, window) a lane).
+SEL_COUNTS = ("index_tokens", "sel_tokens", "window_tokens")
 
 
-class _RoutedProgram:
-    """A jitted program of a routed model.  Its last output is the call's
-    routing counts: they are set aside for the ``engine.call`` span the
-    dispatch is about to build (``InferenceEngine._take_moe_counts``), and
-    the caller gets the outputs every program of its family returns."""
+def _sum_counts(stats: Optional[list], sel: Optional[list]) -> tuple:
+    """One call's (or step's) device counts, a vector a group: the expert
+    layers' summed (``stats``), then the window and indexed layers'
+    (``sel``); a group the description has not (None) is left out."""
+    return tuple(jnp.sum(jnp.stack(parts), axis=0)
+                 for parts in (stats, sel) if parts is not None)
+
+
+def _with_counts(outs: tuple, stats: Optional[list],
+                 sel: Optional[list] = None) -> tuple:
+    """A program's outputs, with the call's device counts last, an output a
+    group (``_sum_counts``; a dense model's programs return exactly what
+    they always did)."""
+    return (*outs, *_sum_counts(stats, sel))
+
+
+class _CountedProgram:
+    """A jitted program of a description that counts on the device (expert
+    layers, window or indexed layers).  Its last outputs are the call's
+    counts, one a group (``_sum_counts``): they are set aside for the
+    ``engine.call`` span the dispatch is about to build
+    (``InferenceEngine._take_moe_counts``), and the caller gets the outputs
+    every program of its family returns."""
 
     def __init__(self, jitted, engine: "InferenceEngine") -> None:
         self._jitted = jitted
         self._engine = engine
 
     def __call__(self, *args):
-        *outs, counts = self._jitted(*args)
-        self._engine._moe_counts = counts
-        return tuple(outs)
+        outs = self._jitted(*args)
+        groups = self._engine._count_names
+        self._engine._moe_counts = dict(zip(groups, outs[-len(groups):]))
+        return tuple(outs[:-len(groups)])
 
     def __getattr__(self, name):      # lower, _cache_size, ...
         return getattr(self._jitted, name)
@@ -761,7 +787,21 @@ class InferenceEngine:
         # host tier, KVX1), no roll-back (verify), no continuation (chunked
         # prefill) and no mesh layout.
         self._unbuilt = self._unbuilt_reason(cfg)
-        self._recurrent = cfg.recurrent
+        # Something is kept a decode lane (recurrent state, a window-bounded
+        # store): prompts are admitted whole into the lanes a call names.
+        self._recurrent = cfg.lane_state
+        # Such a description's admission round is ONE packed call, of at
+        # most the rung that holds two sequences of the largest bucket (which
+        # covers a sequence here: nothing chunks) — the least bound under
+        # which any two prompts still share a call.  The prompt that would
+        # pass it leads the next step's round.  That bounds the largest
+        # program's temporaries where prompts are thousands of tokens, and
+        # evens out what falls between two decode calls of a running lane.
+        self._round_tokens = (self._token_rung(2 * ec.prefill_buckets[-1])
+                              if self._recurrent else 0)
+        # The form of selected attention (ops/sparse.py): the mask form is
+        # the one there is.
+        self._select_form = "mask" if cfg.latent and cfg.index_topk else ""
         if self._unbuilt:
             cap = min(ec.max_blocks_per_seq, ec.num_blocks - 1) * ec.block_size
             for what, asked in (
@@ -904,6 +944,10 @@ class InferenceEngine:
         # the pool in place on a TPU, its XLA form elsewhere.
         from k8s_llm_monitor_tpu.ops.ssm import select_ssm_update
         self._ssm_update = select_ssm_update()
+        # The indexer's decode-step scores over the index-key pages, picked
+        # as the latent attention beside them is.
+        from k8s_llm_monitor_tpu.ops.attention import select_index_scores_impl
+        self._index_scores = select_index_scores_impl(mode=ec.decode_path)
         # "fused" | "pallas" | "gather" — surfaced in /metrics.
         if self.kv_quant and llama.is_fused_quant_decode_impl(attn_impl):
             self.decode_path = "fused"
@@ -975,12 +1019,23 @@ class InferenceEngine:
         # their last output (MOE_COUNTS); a dense model's return what they
         # always did: ``_stats()`` is None and ``_with_counts`` adds nothing.
         self._routed = cfg.expert_layers > 0
-        self._moe_names = MOE_SHARE_COUNTS if cfg.expert_share else MOE_COUNTS
+        # Window and indexed layers count on the device too (SEL_COUNTS), a
+        # group of the same output beside the routing counts.
+        self._sel_counted = bool(cfg.latent and (cfg.index_topk
+                                                 or cfg.sliding_window))
+        self._count_names = {
+            **({"moe": MOE_SHARE_COUNTS if cfg.expert_share else MOE_COUNTS}
+               if self._routed else {}),
+            **({"sel": SEL_COUNTS} if self._sel_counted else {})}
         self._moe_counts = None
         routed = self._routed
+        sel_counted = self._sel_counted
 
         def _stats() -> Optional[list]:
             return [] if routed else None
+
+        def _sel() -> Optional[list]:
+            return [] if sel_counted else None
 
         # A fresh admission call (no lane shares a cached prefix) takes the
         # packed form on one chip: ``tokens [T]`` is the call's prompts end
@@ -995,10 +1050,13 @@ class InferenceEngine:
 
         recurrent = self._recurrent
 
-        def _fresh_prefill(params, tokens, seg, pages, tables, stats):
+        def _fresh_prefill(params, tokens, seg, pages, tables, stats,
+                           sel=None):
             # A description with recurrent layers: ``seg`` ends with the
             # state-pool lane of each row (its slot; max_slots = none).
             kw = {"lanes": seg[-1]} if recurrent else {}
+            if sel is not None:
+                kw["sel_stats"] = sel
             seg = seg[:-1] if recurrent else seg
             if packed:
                 return llama.prefill_packed(
@@ -1012,22 +1070,22 @@ class InferenceEngine:
 
         def _prefill_sample_fn(params, tokens, seg, pages, tables,
                                temp, topk, topp, rng):
-            stats = _stats()
+            stats, sel = _stats(), _sel()
             logits, pages = _fresh_prefill(params, tokens, seg, pages,
-                                           tables, stats)
+                                           tables, stats, sel)
             first = sample_tokens(
                 rng, logits, temperature=temp, top_k=topk, top_p=topp
             )
-            return _with_counts((first, pages), stats)
+            return _with_counts((first, pages), stats, sel)
 
         def _prefill_greedy_fn(params, tokens, seg, pages, tables):
             # Sort-free fast path for all-greedy admission rounds: skips the
             # [P, V] argsort nucleus filtering needs (V is 128k on the 8B
             # target — the sort costs more than the unembed).
-            stats = _stats()
+            stats, sel = _stats(), _sel()
             logits, pages = _fresh_prefill(params, tokens, seg, pages,
-                                           tables, stats)
-            return _with_counts((greedy_tokens(logits), pages), stats)
+                                           tables, stats, sel)
+            return _with_counts((greedy_tokens(logits), pages), stats, sel)
 
         def _prefill_chunk_sample_fn(params, tokens, start, lengths, pages,
                                      tables, temp, topk, topp, rng):
@@ -1059,15 +1117,16 @@ class InferenceEngine:
             # each lane's FSM state (0 = FREE lane, unmasked) BEFORE the
             # shared sampler — greedy lanes take the argmax of the masked
             # logits inside sample_tokens, so constrained-greedy is exact.
-            stats = _stats()
+            stats, sel = _stats(), _sel()
             logits, pages = _fresh_prefill(params, tokens, seg, pages,
-                                           tables, stats)
+                                           tables, stats, sel)
             masked = fsm_mask_logits(logits, fstate, ftrans)
             first = sample_tokens(
                 rng, masked, temperature=temp, top_k=topk, top_p=topp
             )
             return _with_counts(
-                (first, fsm_advance(fstate, ftrans, first), pages), stats)
+                (first, fsm_advance(fstate, ftrans, first), pages), stats,
+                sel)
 
         def _prefill_chunk_sample_fsm_fn(params, tokens, start, lengths,
                                          pages, tables, fstate, ftrans,
@@ -1224,6 +1283,11 @@ class InferenceEngine:
         # tokens.
         self.moe_product_calls = {"stream": 0, "compiler": 0}
         self._moe_form_of: dict[int, str] = {}
+        # Window and indexed layers' counts, summed as results are applied
+        # (SEL_COUNTS; exporter counters), and the calls of a description
+        # with an indexer by the form of their selected attention.
+        self.sel_totals = dict.fromkeys(SEL_COUNTS, 0)
+        self.attn_select_calls = {"mask": 0}
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
         # class, with exemplar trace ids, observed on the step thread only.
         # The exporter renders these as real Prometheus histograms.
@@ -1267,6 +1331,10 @@ class InferenceEngine:
             parts.append("recurrent state (a per-lane state pool)")
         if cfg.latent:
             parts.append("a latent (compressed-KV) pool")
+        if cfg.layers_with("window"):
+            parts.append("a window-bounded store (a ring a decode lane)")
+        if cfg.latent and cfg.index_topk:
+            parts.append("index-key pages (selected attention)")
         if any(cfg.layer_spec(i).mlp == "shared+routed"
                for i in range(cfg.num_layers)):
             parts.append("shared + routed expert layers")
@@ -1280,19 +1348,19 @@ class InferenceEngine:
                              f"{self._unbuilt} (ROADMAP Queue 2)")
 
     def _program(self, fn, donate: tuple):
-        """``jax.jit(fn)`` with ``donate`` donated; for a routed model the
-        program's last output (the routing counts) is set aside for the
-        call's span (:class:`_RoutedProgram`)."""
+        """``jax.jit(fn)`` with ``donate`` donated; for a description that
+        counts on the device the program's last output (the counts by
+        group) is set aside for the call's span (:class:`_CountedProgram`)."""
         jitted = jax.jit(fn, donate_argnums=donate)
-        return _RoutedProgram(jitted, self) if self._routed else jitted
+        return _CountedProgram(jitted, self) if self._count_names else jitted
 
     def _take_moe_counts(self):
-        """The routing counts of the program call just made (a device
-        array), or None for a dense model."""
+        """The device counts of the program call just made (device arrays
+        by group, ``_sum_counts``), or None for a description with none."""
         counts, self._moe_counts = self._moe_counts, None
-        if counts is not None:
+        for arr in (counts or {}).values():
             try:
-                counts.copy_to_host_async()
+                arr.copy_to_host_async()
             except AttributeError:
                 pass
         return counts
@@ -1538,7 +1606,8 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     def score_logits(self, prompt_ids: list[int], n_decode: int = 0, *,
-                     tenant: str = DEFAULT_TENANT, hidden: bool = False):
+                     tenant: str = DEFAULT_TENANT, hidden: bool = False,
+                     selection: bool = False):
         """The model's logits as the engine computes them, instead of
         sampled tokens: float32 ``[1 + n_decode, vocab]`` — the row of the
         last prompt position, then one row per decode step, each step fed
@@ -1566,6 +1635,14 @@ class InferenceEngine:
         (``benchmarks/references``) is compared layer by layer with these —
         where whole-model logits of random weights are chaotic in the activation
         precision, one layer is not.
+
+        ``selection=True`` (with ``hidden``; a description with an indexer)
+        returns ``(rows, states, chosen)``: ``chosen[layer]`` = (scores
+        float32, keep bool), both ``[S, S]`` over ``S = len(prompt_ids) +
+        n_decode`` positions — the indexer's scores and the keys kept, as
+        these programs' kernels computed them and as attention was handed
+        them (row t: the prefill's for a prompt position, the decode step's
+        after it; what lies past t is meaningless).
         """
         self._reconcile_all()
         if self.has_work:
@@ -1577,6 +1654,11 @@ class InferenceEngine:
                 f"prompt of {L} tokens + {n_decode} steps does not fit a "
                 f"sequence's {self.capacity_tokens} cached tokens")
         cfg = self.cfg
+        if selection and not (hidden and self._select_form
+                              and L <= self.ecfg.prefill_buckets[-1]):
+            raise ValueError(
+                "selection=True goes with hidden=True, a description with an "
+                "indexer and a prompt the largest prefill bucket covers")
         shared, start = ([], 0)
         if self.prefix_cache is not None and not hidden:
             shared, start = self.prefix_cache.lookup(ids, tenant=tenant)
@@ -1597,30 +1679,41 @@ class InferenceEngine:
                      if self._recurrent else {})
 
             def scored(fn, **kw):
-                # (logits, pages, states [layers + 1, B, S, H] or None)
-                def run(p, *args, want_hidden):
+                # (logits, pages, states [layers + 1, B, S, H] or None, each
+                # indexed layer's (scores, keep) or None)
+                def run(p, *args, want_hidden, want_selection=False):
                     states = [] if want_hidden else None
-                    logits, pages = fn(p, cfg, *args, hidden=states, **kw)
+                    chosen = [] if want_selection else None
+                    extra = {"selection": chosen} if want_selection else {}
+                    logits, pages = fn(p, cfg, *args, hidden=states, **kw,
+                                       **extra)
                     return logits, pages, (
                         None if states is None
-                        else jnp.stack(states).astype(jnp.float32))
+                        else jnp.stack(states).astype(jnp.float32)), chosen
                 return run
+
+            static = ("want_hidden", "want_selection")
 
             progs["prefill"] = jax.jit(
                 scored(llama.prefill, attn_impl=attn, **lane0),
-                donate_argnums=(3,), static_argnames=("want_hidden",))
+                donate_argnums=(3,), static_argnames=static)
             progs["chunk"] = jax.jit(
                 scored(llama.prefill_chunk, attn_impl=attn),
-                donate_argnums=(4,), static_argnames=("want_hidden",))
+                donate_argnums=(4,), static_argnames=static)
             progs["decode"] = jax.jit(
                 scored(llama.decode_step, attn_impl=dec,
-                       ssm_update=self._ssm_update, **lane0),
-                donate_argnums=(3,), static_argnames=("want_hidden",))
+                       ssm_update=self._ssm_update,
+                       index_scores=self._index_scores, **lane0),
+                donate_argnums=(3,), static_argnames=static)
         table = np.zeros((1, self.ecfg.max_blocks_per_seq), np.int32)
         table[0, :len(blocks)] = blocks
         table = jnp.asarray(table)
         top = self.ecfg.prefill_buckets[-1]
         rows, states = [], []
+        S = L + n_decode
+        chosen = ([(np.zeros((S, S), np.float32), np.zeros((S, S), bool))
+                   for li in range(cfg.num_layers)
+                   if cfg.latent_geometry(li).indexed] if selection else [])
         self.in_program_call = True
         try:
             pos, logits = start, None
@@ -1629,12 +1722,15 @@ class InferenceEngine:
                 toks = np.zeros((1, self._bucket(n)), np.int32)
                 toks[0, :n] = ids[pos:pos + n]
                 if pos == 0:
-                    logits, self.pages, st = progs["prefill"](
+                    logits, self.pages, st, ch = progs["prefill"](
                         self.params, self._tokens_to_device(toks),
                         jnp.asarray([n], jnp.int32), self.pages, table,
-                        want_hidden=hidden)
+                        want_hidden=hidden, want_selection=selection)
+                    for (scores, keep), got in zip(chosen, ch or ()):
+                        scores[:n, :n] = np.asarray(got[0][0, :n, :n])
+                        keep[:n, :n] = np.asarray(got[1][0, :n, :n])
                 else:
-                    logits, self.pages, st = progs["chunk"](
+                    logits, self.pages, st, _ = progs["chunk"](
                         self.params, self._tokens_to_device(toks),
                         jnp.asarray([pos], jnp.int32),
                         jnp.asarray([n], jnp.int32), self.pages, table,
@@ -1645,16 +1741,25 @@ class InferenceEngine:
             rows.append(np.asarray(logits[0], np.float32))
             for i in range(n_decode):
                 tok = jnp.asarray([int(np.argmax(rows[-1]))], jnp.int32)
-                logits, self.pages, st = progs["decode"](
+                logits, self.pages, st, ch = progs["decode"](
                     self.params, tok, jnp.asarray([L + i], jnp.int32),
-                    self.pages, table, want_hidden=hidden)
+                    self.pages, table, want_hidden=hidden,
+                    want_selection=selection)
                 rows.append(np.asarray(logits[0], np.float32))
                 if hidden:
                     states.append(np.asarray(st[:, 0]))
+                for (scores, keep), got in zip(chosen, ch or ()):
+                    scores[L + i] = np.asarray(got[0][0, :S])
+                    keep[L + i] = np.asarray(got[1][0, :S])
         finally:
             self.in_program_call = False
             self.last_program_call = time.monotonic()
             self.allocator.free(blocks)
+        if selection:
+            layers = [li for li in range(cfg.num_layers)
+                      if cfg.latent_geometry(li).indexed]
+            return (np.stack(rows), np.concatenate(states, axis=1),
+                    dict(zip(layers, chosen)))
         if hidden:
             return np.stack(rows), np.concatenate(states, axis=1)
         return np.stack(rows)
@@ -1741,7 +1846,7 @@ class InferenceEngine:
             # page kind's own figure, beside the block counts it scales.
             census["kv_token_bytes"] = self.cfg.kv_token_bytes(
                 self.pages.k[0].dtype.itemsize)
-        if self._recurrent:
+        if self.cfg.recurrent:
             # The state pool beside the pages: a lane costs the same
             # whatever its context holds, and every lane is resident.
             lane = self.cfg.state_lane_bytes(self.pages.conv[0].dtype.itemsize)
@@ -1782,6 +1887,9 @@ class InferenceEngine:
                     self.cfg, self.params, tokens)
             self.moe_product_calls[form] += 1
             counts["moe_product_form"] = form
+        if self._select_form:
+            self.attn_select_calls[self._select_form] += 1
+            counts["attn_select_form"] = self._select_form
         attrs = {"kind": kind, "program": program,
                  "call_id": self._next_call_id,
                  "device_empty": int(device_empty), **counts}
@@ -2602,6 +2710,10 @@ class InferenceEngine:
                          f"{time.monotonic() - req.submit_time:.2f}s in queue")
                 continue
             L = len(req.prompt_ids)
+            if (self._round_tokens and batch
+                    and sum(len(r.prompt_ids) for _, r, _, _ in batch) + L
+                    > self._round_tokens):
+                break       # this prompt leads the next step's round
             if L + 1 > self.capacity_tokens:
                 # Defensive: submit() caps requests, so this only catches
                 # internal misuse; fail loudly instead of livelocking.
@@ -3176,12 +3288,14 @@ class InferenceEngine:
         overlap_step = self._overlap_step
 
         routed = self._routed
-        # Routing counts ride in the scan's carry: () for a dense model (no
-        # leaf, the program is what it always was), float32[4] for a routed
-        # one, summed over the steps.
-        cnt0 = (jnp.zeros((len(self._moe_names),), jnp.float32)
-                if routed else ())
+        # The device counts ride in the scan's carry, a float32 vector a
+        # group (``_sum_counts``), summed over the steps: no leaf for a dense
+        # model (the program is what it always was).
+        cnt0 = tuple(jnp.zeros((len(names),), jnp.float32)
+                     for names in self._count_names.values())
         ssm_update = self._ssm_update
+        index_scores = self._index_scores
+        sel_counted = self._sel_counted
 
         def _step_core(params, tokens, ctx, act, pages, tables, cnt):
             ctx_eff = jnp.where(act, ctx, 0)
@@ -3193,17 +3307,20 @@ class InferenceEngine:
                     params, tokens, ctx_eff, pages, tables)
             else:
                 stats = [] if routed else None
+                sel = [] if sel_counted else None
                 logits, pages = llama.decode_step(
                     params, cfg, tokens, ctx_eff, pages, tables,
                     attn_impl=attn_impl, moe_stats=stats,
                     ssm_update=ssm_update,
+                    **({"sel_stats": sel, "index_scores": index_scores}
+                       if sel_counted else {}),
                 )
-                if routed:
-                    cnt = cnt + jnp.sum(jnp.stack(stats), axis=0)
+                cnt = tuple(a + b for a, b in
+                            zip(cnt, _sum_counts(stats, sel)))
             return logits, pages, cnt
 
         def _outs(outs: tuple, cnt) -> tuple:
-            return (*outs, cnt) if routed else outs
+            return (*outs, *cnt)
 
         if sampled and constrained:
             def fn(params, tok_state, fsm_state, ctx, remaining, pages,
@@ -3849,21 +3966,28 @@ class InferenceEngine:
         if call.moe_counts is not None:
             # Outputs of the same program as ``arr``: on the host already,
             # or a moment behind it.
-            counts = dict(zip(self._moe_names,
-                              (float(c) for c in np.asarray(call.moe_counts))))
-            slots = counts["moe_expert_layer_steps"]
-            # The mean rows of an expert, summed as the fullest expert's
-            # are: each layer and step adds its assignments / experts.
-            counts["moe_mean_rows"] = (counts["moe_assignments"]
-                                       / self.cfg.experts_held_)
+            counts = {name: float(c)
+                      for group, got in call.moe_counts.items()
+                      for name, c in zip(self._count_names[group],
+                                         np.asarray(got))}
+            if self._routed:
+                slots = counts["moe_expert_layer_steps"]
+                # The mean rows of an expert, summed as the fullest expert's
+                # are: each layer and step adds its assignments / experts.
+                counts["moe_mean_rows"] = (counts["moe_assignments"]
+                                           / self.cfg.experts_held_)
+                if "moe_assignments_all" in counts:
+                    self.moe_totals["assignments_all"] += int(
+                        counts["moe_assignments_all"])
+                self.moe_totals["assignments"] += int(
+                    counts["moe_assignments"])
+                self.moe_totals["experts_hit"] += int(
+                    counts["moe_expert_layer_steps_hit"])
+                self.moe_totals["expert_slots"] += int(slots)
+            for name in SEL_COUNTS:
+                if name in counts:
+                    self.sel_totals[name] += int(counts[name])
             attrs.update(counts)
-            if "moe_assignments_all" in counts:
-                self.moe_totals["assignments_all"] += int(
-                    counts["moe_assignments_all"])
-            self.moe_totals["assignments"] += int(counts["moe_assignments"])
-            self.moe_totals["experts_hit"] += int(
-                counts["moe_expert_layer_steps_hit"])
-            self.moe_totals["expert_slots"] += int(slots)
         if self._loop_sampled:
             # enqueue -> result on the host: includes the time queued
             # behind earlier calls; how long the device ran it is the

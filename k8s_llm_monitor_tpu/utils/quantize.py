@@ -112,14 +112,17 @@ def quantize_params(params: Params) -> Params:
             if name in layer:
                 ql[name] = layer[name]
         if "kv_a" in layer:
-            # Latent mixer: q, kv_a and o quantize like any projection; the
-            # latent's norm and W_kvb stay wide (models/llama.py:_kv_b: the
+            # Latent mixer: q (or q_a and q_b), kv_a, o, the gate and the
+            # indexer's projections quantize like any projection; the
+            # norms and W_kvb stay wide (models/llama.py:_kv_b: the
             # absorbed form multiplies queries and outputs by W_kvb, so it
             # has no per-token activation to quantize against).
-            for name in ("q", "kv_a", "o"):
-                ql[name] = quantize_linear(layer[name])
-            ql["kv_norm"] = layer["kv_norm"]
-            ql["kv_b"] = layer["kv_b"]
+            for name in _LATENT_LINEARS:
+                if name in layer:
+                    ql[name] = quantize_linear(layer[name])
+            for name in ("kv_norm", "kv_b", "q_norm", "idx_k_norm"):
+                if name in layer:
+                    ql[name] = layer[name]
         else:
             for name in ("q", "k", "v", "o"):
                 ql[name] = quantize_linear(layer[name])
@@ -146,6 +149,10 @@ def quantize_params(params: Params) -> Params:
         out["lm_head"] = quantize_linear(params["lm_head"])
     return out
 
+
+# A latent mixer's projections that take int8 kernels.
+_LATENT_LINEARS = ("q", "q_a", "q_b", "kv_a", "o", "attn_gate", "idx_q",
+                   "idx_k", "idx_w")
 
 # What a one-sub-block layer (``ModelConfig.layer_pattern``) keeps wide: the
 # norms, the router, and a Mamba-2 mixer's convolution and per-head vectors
@@ -237,7 +244,14 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig) -> Params:
             continue
         layer: Params = {"input_norm": jnp.ones((H,), dtype),
                          "post_norm": jnp.ones((H,), dtype)}
-        if spec.mixer == "latent":
+        if spec.mixer == "latent" and cfg.latent_geometry(i).q_lora_rank:
+            from k8s_llm_monitor_tpu.models.llama import init_latent_mixer
+
+            layer.update(init_latent_mixer(
+                jax.random.fold_in(keys[2 + i], 2), cfg,
+                cfg.latent_geometry(i), qdense,
+                lambda key, in_f, out_f: wide(key, in_f, out_f, dtype)))
+        elif spec.mixer == "latent":
             R, dn, dr = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim)
             layer["q"] = qdense(lk[0], H, nH * (dn + dr), False)

@@ -512,3 +512,78 @@ def test_grouped_stream_kernel_compiles_at_the_cells_decode_shapes(
     assert "grouped_rows_product" in compiled.as_text()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- PR 33: the dots3_note cell's kernels --------------------------------------
+
+DCFG = PRESETS["dots3-note-prev-5l"]
+D_LANES, D_TABLE = 64, 528               # the cell's lanes and table width
+D_BLOCKS = D_LANES * D_TABLE + 1
+
+
+@pytest.mark.parametrize("kernel", ["selected", "window", "index_scores"])
+def test_sparse_decode_kernels_compile_at_the_served_cell(one_chip, kernel):
+    """``dots3-note-prev.casefile-loops``'s decode call: the latent decode
+    kernel at 128 heads over 640-lane rows with a keep mask a lane (selected
+    attention, the mask form), at 64 heads over the window store's 1,152-lane
+    rows as one burst of a lane's 33-block ring, and the index-score kernel
+    over 128-lane index-key pages — each under the name the benchmark reads."""
+    S = one_chip
+    full, sliding = DCFG.latent_geometry(1), DCFG.latent_geometry(2)
+    assert (full.page_width, sliding.page_width, full.index_dim) == (640, 1152, 128)
+    if kernel == "selected":
+        text = _compile(
+            lambda q, p, t, n, keep: pa.latent_decode_attention_pallas(
+                q, p, t, n, v_width=full.kv_lora_rank, keep=keep,
+                name="sparse_latent_decode_attention"),
+            S((D_LANES, 1, full.num_heads, 640), BF16),
+            S((D_BLOCKS, BS, 640), BF16), S((D_LANES, D_TABLE), I32),
+            S((D_LANES,), I32), S((D_LANES, D_TABLE * BS), jnp.bool_))
+        assert "sparse_latent_decode_attention" in text
+    elif kernel == "window":
+        ring = DCFG.window_rows(BS) // BS
+        assert ring == 33
+        text = _compile(
+            functools.partial(pa.latent_decode_attention_pallas,
+                              v_width=sliding.kv_lora_rank, burst=ring,
+                              name="window_latent_decode_attention"),
+            S((D_LANES, 1, sliding.num_heads, 1152), BF16),
+            S((1 + D_LANES * ring, BS, 1152), BF16), S((D_LANES, ring), I32),
+            S((D_LANES,), I32))
+        assert "window_latent_decode_attention" in text
+    else:
+        text = _compile(
+            pa.index_scores_decode_pallas,
+            S((D_LANES, 1, full.index_heads, 128), BF16),
+            S((D_LANES, 1, full.index_heads), F32),
+            S((D_BLOCKS, BS, 128), BF16), S((D_LANES, D_TABLE), I32),
+            S((D_LANES,), I32))
+        assert "sparse_latent_decode_index_scores" in text
+
+
+@pytest.mark.parametrize("geometry", ["selected", "window"])
+def test_sparse_prefill_kernels_compile_at_the_served_cell(one_chip, geometry):
+    """The cell's largest admission call (18,432 tokens, 8 rows of up to
+    9,216): the packed prefill kernel under a band of 513 (64 heads, keys of
+    192 + 64), and under the selection (128 heads, keys of 128 + 64) with
+    the index-score kernel and the counting selection in front of it."""
+    S = one_chip
+    T, R, top = 18_432, 8, 9216
+    g = DCFG.latent_geometry(1 if geometry == "selected" else 2)
+    args = [S((T, g.num_heads, g.qk_head_dim), BF16),
+            S((T, g.num_heads, g.qk_head_dim), BF16),
+            S((T, g.num_heads, g.v_head_dim), BF16), S((R,), I32), S((R,), I32)]
+    kw = dict(scale=g.qk_head_dim ** -0.5, row_len=top)
+    if geometry == "window":
+        text = _compile(functools.partial(
+            pa.latent_prefill_attention_packed, window=g.window, **kw), *args)
+        assert "window_latent_prefill_attention" in text
+        return
+    text = _compile(
+        lambda q, k, v, o, n, qi, ki, wi: pa.latent_prefill_attention_packed(
+            q, k, v, o, n, topk=g.index_topk, index=(qi, ki, wi), **kw),
+        *args, S((T, g.index_heads, g.index_dim), BF16),
+        S((T, g.index_dim), BF16), S((T, g.index_heads), F32))
+    assert "sparse_latent_prefill_index_scores" in text
+    assert "sparse_latent_prefill_attention" in text
+    assert " sort(" not in text      # the 2,048th score is counted, not sorted

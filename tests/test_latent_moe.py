@@ -190,16 +190,17 @@ def test_absorbed_equals_expanded(params):
     S = c.shape[1]
     pos = jnp.arange(S)[None]
     lens = jnp.asarray([S])
+    g = CFG.latent_geometry(1)
     expanded = llama._latent_attend_expanded(
-        layer, CFG, q_nope, q_rope, c, k_rope, pos, lens)
+        layer, CFG, g, q_nope, q_rope, c, k_rope, pos, lens)
     dense = llama._latent_attend_expanded(
-        layer, CFG, q_nope, q_rope, c, k_rope, pos, lens,
+        layer, CFG, g, q_nope, q_rope, c, k_rope, pos, lens,
         attn_fn=ops.causal_attention)
-    rows = llama._latent_rows(CFG, c, k_rope)
+    rows = llama._latent_rows(g, c, k_rope)
     o_lat = ops.blockwise_attention(
-        llama._latent_absorb(layer, CFG, q_nope, q_rope), rows,
+        llama._latent_absorb(layer, g, q_nope, q_rope), rows,
         rows[..., :CFG.kv_lora_rank], q_positions=pos, kv_len=lens, scale=1.0)
-    absorbed = llama._latent_unabsorb(layer, CFG, o_lat)
+    absorbed = llama._latent_unabsorb(layer, g, o_lat)
     np.testing.assert_allclose(expanded, dense, atol=2e-5, rtol=0)
     np.testing.assert_allclose(absorbed, dense, atol=2e-5, rtol=0)
 
