@@ -408,15 +408,23 @@ def sparse_kernel_checks(cfg, seed: int, lanes: int,
 
 
 def grouped_kernel_checks(cfg, seed: int, lanes: int,
-                          rows_per_expert=(1, 3, 8, 16, 32, 64, 128)) -> None:
+                          rows_per_expert=(1, 3, 8, 16, 32, 64, 128, 256, 512,
+                                           768, 1024),
+                          tile_rows=(None,)) -> list[dict]:
     """The expert layer's grouped product at ``cfg``'s widths (a routed
-    description): ``ops/grouped.py``'s stream kernel against the compiler's
-    ``ragged_dot``, bit-equal on every row a group covers — at a decode
-    step's shape (``lanes`` x experts per token sorted rows, of which a
-    chip's held share are live) and at ``rows_per_expert`` rows an expert —
-    and both forms' device time (the sweep ``STREAM_MAX_ROWS_PER_GROUP``
-    cites; on the CPU the kernel runs interpreted and the times say
-    nothing)."""
+    description), in the three forms of ``ops/grouped.py``: the stream
+    kernel's int32 bit-equal to the compiler's ``ragged_dot`` on every row a
+    group covers, the tiles kernel's dequantised result bit-equal to
+    ``ragged_dot`` followed by the dequantisation ``_expert_rows`` writes —
+    at a decode step's shape (``lanes`` x experts per token sorted rows, of
+    which a chip's held share are live), at ``rows_per_expert`` rows an
+    expert, and at an admission window of 16,384 rows whose live rows lie on
+    half of the experts (what a window of the share layer sees) — and each
+    form's device time *with its dequantisation* (the sweep ``product_form``'s
+    bounds cite; on the CPU the kernels run interpreted and the times say
+    nothing).  The stream form is timed up to 128 rows an expert; the tiles
+    form at each of ``tile_rows`` (None: ``grouped.TILES_ROWS``).  Returns
+    the lines' numbers."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -427,54 +435,107 @@ def grouped_kernel_checks(cfg, seed: int, lanes: int,
     narrow, wide = cfg.moe_latent_size or cfg.hidden_size, cfg.expert_width
     on_tpu = jax.default_backend() == "tpu"
     rng = np.random.default_rng(seed)
-    stream = functools.partial(grouped.grouped_rows_product,
-                               interpret=not on_tpu)
+    interpret = not on_tpu
     reps = 16 if on_tpu else 1
+    BF16, F32 = jnp.bfloat16, jnp.float32
 
-    def seconds(fn, rows, kernels, sizes) -> float:
+    def dequantised(product):
+        """``_expert_rows``'s W8A8 branch around an int32 product."""
+        def fn(rows, kernels, sizes, row_scale, scale, rows_e):
+            y32 = product(rows, kernels, sizes)
+            return (y32.astype(F32) * row_scale * scale[rows_e]).astype(BF16)
+        return fn
+
+    def tiles(tm):
+        def fn(rows, kernels, sizes, row_scale, scale, rows_e):
+            return grouped.grouped_tiles_product(
+                rows, kernels, sizes, row_scale, scale, dtype=BF16,
+                tile_rows=tm, interpret=interpret)
+        return fn
+
+    stream = dequantised(functools.partial(grouped.grouped_rows_product,
+                                           interpret=interpret))
+    compiler = dequantised(grouped.grouped_rows_product_xla)
+
+    def seconds(fn, rows, *rest) -> float:
         """Device time of one product: ``reps`` of them in one program, on
-        rows that differ, the fastest of three runs."""
-        def many(rows, kernels, sizes):
-            def one(acc, i):
-                y = fn(rows ^ i.astype(jnp.int8), kernels, sizes)
-                return acc + y[0, 0], None
-            return jax.lax.scan(one, jnp.zeros((), jnp.int32),
-                                jnp.arange(reps))[0]
+        rows that differ, each whole result the loop's carry (so no part of
+        a product can be left out), the fastest of three runs."""
+        def many(rows, *rest):
+            def one(y, i):
+                return fn(rows ^ i.astype(jnp.int8), *rest), None
+            y0 = jnp.zeros((rows.shape[0], rest[0].shape[2]), BF16)
+            return jax.lax.scan(one, y0, jnp.arange(reps))[0]
         run = jax.jit(many)
-        run(rows, kernels, sizes).block_until_ready()
+        run(rows, *rest).block_until_ready()
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
-            run(rows, kernels, sizes).block_until_ready()
+            run(rows, *rest).block_until_ready()
             best = min(best, time.perf_counter() - t0)
         return best / reps
 
-    def case(name, M, live, K, N, kernels):
-        sizes = rng.multinomial(live, np.ones(G) / G).astype(np.int32)
-        rows = jnp.asarray(rng.integers(-127, 128, (M, K), dtype=np.int8))
-        want = jax.jit(grouped.grouped_rows_product_xla)(rows, kernels, sizes)
-        got = jax.jit(stream)(rows, kernels, sizes)
-        assert np.array_equal(np.asarray(got)[:live],
-                              np.asarray(want)[:live]), (
-            f"{name}: the stream kernel differs from ragged_dot")
-        hit = int((sizes > 0).sum())
-        t_s = seconds(stream, rows, kernels, jnp.asarray(sizes))
-        t_c = seconds(grouped.grouped_rows_product_xla, rows, kernels,
-                      jnp.asarray(sizes))
-        say(f"grouped product {name}: [{M}, {K}] x [{G}, {K}, {N}], "
-            f"{live} live rows on {hit} experts, bit-equal; stream "
-            f"{t_s * 1e6:.1f} us ({t_s * 1e6 / max(hit, 1):.2f} us a hit "
-            f"expert, {hit * K * N / 819e9 / t_s * 100:.1f}% of its kernels' "
-            f"bytes at 819 GB/s), compiler {t_c * 1e6:.1f} us; "
-            f"form={grouped.product_form(M, G, K, N, jnp.int8)}")
+    lines = []
 
+    def case(name, M, sizes, K, N, kernels, scale):
+        live, hit = int(sizes.sum()), int((sizes > 0).sum())
+        rows = jnp.asarray(rng.integers(-127, 128, (M, K), dtype=np.int8))
+        row_scale = jnp.asarray(rng.random((M, 1), dtype=np.float32) / 127)
+        rows_e = jnp.asarray(np.minimum(
+            np.repeat(np.arange(G + 1), [*sizes, M - live]), G - 1), jnp.int32)
+        args = (rows, kernels, jnp.asarray(sizes), row_scale, scale, rows_e)
+        want32 = jax.jit(grouped.grouped_rows_product_xla)(*args[:3])
+        got32 = jax.jit(functools.partial(grouped.grouped_rows_product,
+                                          interpret=interpret))(*args[:3])
+        assert np.array_equal(np.asarray(got32)[:live],
+                              np.asarray(want32)[:live]), (
+            f"{name}: the stream kernel differs from ragged_dot")
+        del want32, got32
+        want = np.asarray(jax.jit(compiler)(*args))[:live]
+        line = {"case": name, "m": M, "g": G, "k": K, "n": N, "live": live,
+                "hit": hit, "form": grouped.product_form(M, G, K, N, jnp.int8),
+                "tiles_us": {}}
+        for tm in tile_rows:
+            got = np.asarray(jax.jit(tiles(tm))(*args))[:live]
+            assert np.array_equal(got.view(np.uint16), want.view(np.uint16)), (
+                f"{name}: the tiles kernel (row tile {tm}) differs from "
+                f"ragged_dot and its dequantisation")
+            tm = tm or grouped.TILES_ROWS
+            line["tiles_us"][tm] = seconds(tiles(tm), *args) * 1e6
+        if live <= 128 * G:
+            line["stream_us"] = seconds(stream, *args) * 1e6
+        line["compiler_us"] = seconds(compiler, *args) * 1e6
+        best = min(line["tiles_us"].values())
+        lines.append(line)
+        say(f"grouped product {name}: [{M}, {K}] x [{G}, {K}, {N}], "
+            f"{live} live rows on {hit} experts, bit-equal; us with the "
+            f"dequantisation: stream "
+            f"{line.get('stream_us', float('nan')):.1f}, tiles "
+            + ", ".join(f"{t:.1f} (tile {tm})"
+                        for tm, t in line["tiles_us"].items())
+            + f" = {2 * live * K * N / 393e12 / best * 1e8:.1f}% of the int8 "
+            f"peak, {hit * K * N / 819e9 / best * 1e8:.1f}% of its kernels' "
+            f"bytes at 819 GB/s, compiler {line['compiler_us']:.1f}; "
+            f"form={line['form']}")
+
+    even = lambda live: rng.multinomial(  # noqa: E731
+        live, np.ones(G) / G).astype(np.int32)
     for K, N in ((narrow, wide), (wide, narrow)):
         kernels = jnp.asarray(rng.integers(-127, 128, (G, K, N),
                                            dtype=np.int8))
+        scale = jnp.asarray(rng.random((G, N), dtype=np.float32) / 127)
         M = lanes * cfg.num_experts_per_tok
-        case("decode", M, M * G // cfg.num_experts, K, N, kernels)
+        case("decode", M, even(M * G // cfg.num_experts), K, N, kernels, scale)
         for r in rows_per_expert:
-            case(f"{r} rows an expert", r * G, r * G, K, N, kernels)
+            case(f"{r} rows an expert", r * G, even(r * G), K, N, kernels,
+                 scale)
+        if max(rows_per_expert) >= 128:
+            half = np.zeros(G, np.int32)
+            half[G // 4:G // 4 + G // 2] = rng.multinomial(
+                16_384, np.ones(G // 2) * 2 / G)
+            case("a window on half the experts", 16_384, half, K, N, kernels,
+                 scale)
+    return lines
 
 
 # ---------------------------------------------------------------------------

@@ -1147,12 +1147,18 @@ def _expert_rows(p: Params, rows: jnp.ndarray, row_scale, rows_e: jnp.ndarray,
     contract: both scales factor out of the contraction), and the int8 x
     int8 -> int32 product takes the form its shape asks for
     (``ops/grouped.py``: at a decode step's few rows an expert a kernel that
-    streams each hit expert's kernel once, else ``jax.lax.ragged_dot``, on
-    TPU the compiler's own grouped-product kernel); else ``rows`` are wide,
-    ``row_scale`` is None and the product is ``ragged_dot``."""
+    streams each hit expert's kernel once; at an admission call's many a
+    row-tiled kernel that dequantises as well, the same expression in its
+    epilogue; else ``jax.lax.ragged_dot``, on TPU the compiler's own
+    grouped-product kernel); else ``rows`` are wide, ``row_scale`` is None
+    and the product is ``ragged_dot``."""
     if row_scale is not None:
-        product = grouped.select_grouped_product(
-            rows.shape[0], *p["kernel_q"].shape, rows.dtype)
+        shape = (rows.shape[0], *p["kernel_q"].shape, rows.dtype)
+        if grouped.product_form(*shape) == "tiles":
+            return grouped.grouped_tiles_product(
+                rows, p["kernel_q"], group_sizes, row_scale, p["scale"],
+                dtype=jnp.dtype(dtype))
+        product = grouped.select_grouped_product(*shape)
         y32 = product(rows, p["kernel_q"], group_sizes)
         return (y32.astype(jnp.float32) * row_scale
                 * p["scale"][rows_e]).astype(dtype)
@@ -1163,10 +1169,10 @@ def _expert_rows(p: Params, rows: jnp.ndarray, row_scale, rows_e: jnp.ndarray,
 
 
 def expert_product_form(cfg: ModelConfig, params: Params, tokens: int) -> str:
-    """``"stream"`` or ``"compiler"``: the form the serving expert layers'
-    products take in a program of ``tokens`` tokens (a decode step's lanes,
-    an admission call's stream) — ``_expert_rows``'s choice, from the same
-    predicate on the same shapes."""
+    """``"stream"``, ``"tiles"`` or ``"compiler"``: the form the serving
+    expert layers' products take in a program of ``tokens`` tokens (a decode
+    step's lanes, an admission call's stream) — ``_expert_rows``'s choice,
+    from the same predicate on the same shapes."""
     p = next(layer for layer in params["layers"] if "router" in layer)["up_e"]
     if not (cfg.act_quant and "kernel_q" in p):
         return "compiler"

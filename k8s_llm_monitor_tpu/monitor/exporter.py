@@ -391,8 +391,9 @@ def _loop_metrics(w: _Writer, engine) -> None:
                  "Calls of a routed model by the form their program gave the "
                  "expert layers' grouped products (ops/grouped.py): stream = "
                  "the kernel that fetches each hit expert's kernel once (a "
-                 "decode step's few rows an expert), compiler = "
-                 "jax.lax.ragged_dot",
+                 "decode step's few rows an expert), tiles = the row-tiled "
+                 "kernel that dequantises in its epilogue (an admission "
+                 "call's many), compiler = jax.lax.ragged_dot",
                  [(f'{{form="{k}"}}', n)
                   for k, n in sorted(engine.moe_product_calls.items())])
         if "assignments_all" in moe:

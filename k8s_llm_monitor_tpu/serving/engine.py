@@ -465,8 +465,8 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     "moe_assignments", "moe_expert_layer_steps_hit",
                     "moe_expert_layer_steps", "moe_max_rows",
                     "moe_mean_rows",
-                    # "stream" | "compiler": the form the call's program
-                    # gave its expert products (ops/grouped.py).
+                    # "stream" | "tiles" | "compiler": the form the call's
+                    # program gave its expert products (ops/grouped.py).
                     "moe_product_form",
                     # ... that holds a share of its experts (the four above
                     # are then over the experts held).
@@ -1281,7 +1281,7 @@ class InferenceEngine:
         # took (ops/grouped.py:product_form on the call's shape, as
         # models/llama.py:_expert_rows asks it), and that form by the call's
         # tokens.
-        self.moe_product_calls = {"stream": 0, "compiler": 0}
+        self.moe_product_calls = {"stream": 0, "tiles": 0, "compiler": 0}
         self._moe_form_of: dict[int, str] = {}
         # Window and indexed layers' counts, summed as results are applied
         # (SEL_COUNTS; exporter counters), and the calls of a description

@@ -387,14 +387,17 @@ ROW_TEMPS = {"qwen2-7b-w8a8": 708_665_856,
     ("qwen2-7b-w8a8", 1024, 2), ("qwen2-7b-w8a8", 8192, None),
     ("kanana2-30b-a3b-w8a8", 16384, None)],
     ids=["qwen2-t1024-2-layers", "qwen2-t8192", "kanana-t16384"])
-def test_packed_prefill_program_compiles(one_chip, config, tokens, layers):
+def test_packed_prefill_program_compiles(one_chip, monkeypatch, config,
+                                         tokens, layers):
     """The sampled fresh-prefill program in its packed form
     (serving/engine.py:_prefill_sample_fn), whole, at the cells' engines
     (benchmarks/configs) and their smallest and largest rungs: the chip's
     compiler takes it, its kernels are in it, and its temporaries leave the
     pool rule (PERF.md section 4: pool <= bytes_limit - weights -
     temporaries - 1 GiB) standing — within a twentieth of the row program's
-    it replaces."""
+    it replaces.  The expert products take the form the chip's program
+    takes (the predicate asks the backend, which is the CPU here: told
+    "tpu"), so a routed program holds the tiles kernel and no ``ragged-dot``."""
     import dataclasses
     import json
     import pathlib
@@ -403,6 +406,12 @@ def test_packed_prefill_program_compiles(one_chip, config, tokens, layers):
     from k8s_llm_monitor_tpu.serving.engine import EngineConfig
     from k8s_llm_monitor_tpu.utils.quantize import init_params_quantized
 
+    from k8s_llm_monitor_tpu.ops import grouped
+
+    form = grouped.product_form
+    monkeypatch.setattr(
+        grouped, "product_form",
+        lambda m, g, k, n, dtype, platform=None: form(m, g, k, n, dtype, "tpu"))
     S = one_chip
     cell = json.loads((pathlib.Path(__file__).parents[1] / "benchmarks"
                        / "configs" / f"{config}.json").read_text())
@@ -431,7 +440,10 @@ def test_packed_prefill_program_compiles(one_chip, config, tokens, layers):
         params, S((tokens,), I32), (S((R,), I32), S((R,), I32)), pages,
         S((R, W), I32), S((R,), F32), S((R,), I32), S((R,), F32),
         S((2,), jnp.uint32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= cfg.num_layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= cfg.num_layers
+    assert ("grouped_tiles_product" in text) == bool(cfg.expert_layers)
+    assert "ragged-dot" not in text
     temps = compiled.memory_analysis().temp_size_in_bytes
     if not layers:
         rule = cell["assumed"]["pool_reckoning"]
@@ -587,3 +599,38 @@ def test_sparse_prefill_kernels_compile_at_the_served_cell(one_chip, geometry):
     assert "sparse_latent_prefill_index_scores" in text
     assert "sparse_latent_prefill_attention" in text
     assert " sort(" not in text      # the 2,048th score is counted, not sorted
+
+
+# -- PR 34: admission's expert products ----------------------------------------
+
+
+@pytest.mark.parametrize("product", ["in", "out"])
+@pytest.mark.parametrize("cfg,tokens", [(KCFG, 16_384), (NCFG, 12_288),
+                                        (DCFG, 18_432)],
+                         ids=["kanana", "nemotron", "dots3"])
+def test_grouped_tiles_kernel_compiles_at_the_cells_admission_shapes(
+        one_chip, cfg, tokens, product):
+    """``ops/grouped.py``'s tiles kernel on the sorted rows of each routed
+    cell's largest admission call (kanana every assignment: 98,304 rows; the
+    share layers a window of 16,384) against the int8 kernels a chip holds,
+    dequantised to bf16 in its epilogue: the predicate takes it there, the
+    chip's compiler takes its row tile and the column tile ``column_tile``
+    picks (dots3's [5120, 1536] kernel in two column tiles), and no int32 or
+    gathered-scale temporary is left beside it."""
+    from k8s_llm_monitor_tpu.models import llama
+    from k8s_llm_monitor_tpu.ops import grouped
+
+    S = one_chip
+    M, G = tokens * cfg.num_experts_per_tok, cfg.experts_held_
+    if cfg.expert_share:
+        M = min(M, llama._EXPERT_WINDOW_ROWS)
+    narrow, wide = cfg.moe_latent_size or cfg.hidden_size, cfg.expert_width
+    K, N = (narrow, wide) if product == "in" else (wide, narrow)
+    assert grouped.product_form(M, G, K, N, jnp.int8, platform="tpu") == "tiles"
+    compiled = jax.jit(functools.partial(
+        grouped.grouped_tiles_product, dtype=jnp.dtype(BF16))).lower(
+        S((M, K), jnp.int8), S((G, K, N), jnp.int8), S((G,), I32),
+        S((M, 1), F32), S((G, N), F32)).compile()
+    assert "grouped_tiles_product" in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -1,7 +1,9 @@
 """The expert layer's grouped product (ops/grouped.py): the stream kernel, in
 the interpreter, is ``jax.lax.ragged_dot`` bit for bit on every row a group
-covers, and the predicate sends a decode step's shapes to it and an admission
-call's to the compiler's product.
+covers; the tiles kernel is ``ragged_dot`` and the dequantisation that follows
+it bit for bit; and the predicate sends a decode step's shapes to the first,
+an admission call's to the second and everything else to the compiler's
+product.
 """
 
 import json
@@ -18,6 +20,7 @@ from k8s_llm_monitor_tpu.ops import grouped
 
 NEMOTRON = PRESETS["nemotron3-super-120b-a12b-22l"]
 KANANA = PRESETS["kanana-2-30b-a3b-12l"]
+DOTS3 = PRESETS["dots3-note-prev-5l"]
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
@@ -66,6 +69,84 @@ def test_stream_kernel_is_ragged_dot_bit_for_bit(name):
     assert np.array_equal(np.asarray(got)[:live], np.asarray(want)[:live])
 
 
+# name -> (M, K, N, group sizes, row tile or None for ``TILES_ROWS``): the
+# cells' admission shapes cut in rows, groups and widths (nemotron: a window
+# of which ~5/8 are live, 128 rows an expert; kanana: 768 rows an expert;
+# dots3: a window whose live rows lie on half the experts, 256 rows an
+# expert), two at full width (dots3's [5120, 1536] splits its columns), and
+# what a schedule can get wrong, at a row tile of 128 and of 256.
+def _tiles_cases():
+    rng = np.random.default_rng(34)
+    uneven = lambda live, groups: rng.multinomial(  # noqa: E731
+        live, rng.dirichlet(np.ones(groups) * 2))
+    half = np.zeros(8, np.int64)
+    half[2:6] = uneven(2048, 4)
+    return {
+        "nemotron-admit-up": (2048, 256, 384, uneven(1280, 16), None),
+        "nemotron-admit-down": (2048, 384, 256, uneven(1280, 16), None),
+        "kanana-admit-up": (3072, 256, 128, uneven(3072, 4), None),
+        "kanana-admit-down": (3072, 128, 256, uneven(3072, 4), None),
+        "dots3-admit-up": (2048, 640, 256, half, None),
+        "dots3-admit-down": (2048, 256, 640, half, None),
+        "kanana-admit-up-full-width": (640, 2048, 768, [200, 0, 317, 123], None),
+        "dots3-admit-up-full-width": (256, 5120, 1536, [90, 166], None),
+        "empty-groups": (384, 128, 128, [0, 140, 0, 0, 170, 0, 30, 0], 128),
+        "a-tile-shared-by-three-groups": (256, 128, 256, [100, 9, 7, 140], 128),
+        "a-group-over-three-tiles": (512, 128, 128, [60, 300, 152], 128),
+        "rows-behind-the-last-group": (1024, 128, 128, [3, 400, 0, 70], 256),
+        "rows-not-a-multiple-of-the-tile": (300, 64, 96, [1, 2, 130, 0, 160], 128),
+        "no-row-at-all": (256, 128, 128, [0, 0, 0], None),
+        "one-group": (256, 128, 128, [200], None),
+    }
+
+
+TILES_CASES = _tiles_cases()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("name", list(TILES_CASES))
+def test_tiles_kernel_is_ragged_dot_and_its_dequantisation_bit_for_bit(
+        name, dtype):
+    """The tiles form against what it replaces: ``_expert_rows`` itself,
+    which on the CPU is ``ragged_dot`` and the XLA dequantisation."""
+    from k8s_llm_monitor_tpu.models import llama
+
+    M, K, N, sizes, tile_rows = TILES_CASES[name]
+    rng = np.random.default_rng(len(name))
+    G, live = len(sizes), int(np.sum(sizes))
+    rows = jnp.asarray(rng.integers(-127, 128, (M, K), dtype=np.int8))
+    p = {"kernel_q": jnp.asarray(rng.integers(-127, 128, (G, K, N),
+                                              dtype=np.int8)),
+         "scale": jnp.asarray(rng.random((G, N), dtype=np.float32) / 127)}
+    row_scale = jnp.asarray(rng.random((M, 1), dtype=np.float32) * 3)
+    rows_e = jnp.asarray(np.minimum(
+        np.repeat(np.arange(G + 1), [*sizes, M - live]), G - 1), jnp.int32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = llama._expert_rows(p, rows, row_scale, rows_e, sizes,
+                              jnp.dtype(dtype))
+    got = grouped.grouped_tiles_product(
+        rows, p["kernel_q"], sizes, row_scale, p["scale"],
+        dtype=jnp.dtype(dtype), tile_rows=tile_rows, interpret=True)
+    assert got.shape == (M, N) and got.dtype == want.dtype == jnp.dtype(dtype)
+    # Rows behind the last group may hold anything: they are not compared.
+    bits = {"bfloat16": np.uint16, "float32": np.uint32}[dtype]
+    assert np.array_equal(np.asarray(got)[:live].view(bits),
+                          np.asarray(want)[:live].view(bits))
+
+
+@pytest.mark.parametrize("kernel,tile", [
+    # [k, n] of an expert's kernel: nemotron's, dots3's in both orientations
+    # (two column tiles), kanana's in both.
+    ((1024, 2688), 2688), ((5120, 1536), 768), ((1536, 5120), 2560),
+    ((2048, 768), 768), ((768, 2048), 2048)])
+def test_the_column_tile_bounds_a_kernel_block(kernel, tile):
+    k, n = kernel
+    tn = grouped.column_tile(k, n)
+    assert tn == tile and n % tn == 0 and tn % 128 == 0
+    assert k * tn <= grouped.TILE_KERNEL_BYTES
+    assert grouped.TILES_ROWS % 32 == 0
+
+
 def test_the_schedule_visits_each_hit_group_once_a_tile_and_no_other():
     sizes = jnp.asarray([0, 5, 0, 40, 0, 3, 0, 0], jnp.int32)
     group, tile, offsets, used = grouped._visits(sizes, tiles=3, tm=32)
@@ -87,15 +168,20 @@ def _product_shapes(cfg):
 
 
 @pytest.mark.parametrize("cfg,tokens,form", [
-    (NEMOTRON, 64, "stream"), (KANANA, 64, "stream"),
+    (NEMOTRON, 64, "stream"), (KANANA, 64, "stream"), (DOTS3, 64, "stream"),
     # Admission: the smallest and the largest call of each cell (rungs
-    # 384-12,288 and 1,024-16,384 tokens; a window of the share-aware layer
-    # holds at most 16,384 sorted rows).
-    (NEMOTRON, 384, "compiler"), (NEMOTRON, 12_288, "compiler"),
-    (KANANA, 1_024, "compiler"), (KANANA, 16_384, "compiler")],
-    ids=["nemotron-decode", "kanana-decode", "nemotron-admit-384",
-         "nemotron-admit-12288", "kanana-admit-1024", "kanana-admit-16384"])
+    # 384-12,288, 1,024-16,384 and 2,304-18,432 tokens; a window of the
+    # share-aware layer holds at most 16,384 sorted rows).
+    (NEMOTRON, 384, "tiles"), (NEMOTRON, 12_288, "tiles"),
+    (KANANA, 1_024, "tiles"), (KANANA, 16_384, "tiles"),
+    (DOTS3, 2_304, "tiles"), (DOTS3, 18_432, "tiles")],
+    ids=["nemotron-decode", "kanana-decode", "dots3-decode",
+         "nemotron-admit-384", "nemotron-admit-12288", "kanana-admit-1024",
+         "kanana-admit-16384", "dots3-admit-2304", "dots3-admit-18432"])
 def test_the_predicate_streams_decode_and_leaves_admission(cfg, tokens, form):
+    """Three forms by the call's static shapes: every decode program of the
+    three routed cells streams, every admission program takes the tiles
+    form, and off the TPU both are the compiler's."""
     from k8s_llm_monitor_tpu.models import llama
 
     rows = tokens * cfg.num_experts_per_tok
@@ -104,17 +190,25 @@ def test_the_predicate_streams_decode_and_leaves_admission(cfg, tokens, form):
     for K, N in _product_shapes(cfg):
         assert grouped.product_form(rows, cfg.experts_held_, K, N, jnp.int8,
                                     platform="tpu") == form
+        assert grouped.product_form(rows, cfg.experts_held_, K, N, jnp.int8,
+                                    platform="cpu") == "compiler"
 
 
-@pytest.mark.parametrize("why,args", [
-    ("off the TPU", dict(platform="cpu")),
-    ("wide operands", dict(dtype=jnp.bfloat16)),
-    ("a width that is no multiple of 128 lanes", dict(n=96)),
-    ("many rows an expert", dict(m=128 * 128))])
-def test_the_predicate_keeps_the_compiler_form(why, args):
+@pytest.mark.parametrize("why,args,form", [
+    ("off the TPU", dict(platform="cpu"), "compiler"),
+    ("wide operands", dict(dtype=jnp.bfloat16), "compiler"),
+    ("a width that is no multiple of 128 lanes", dict(n=96), "compiler"),
+    ("many rows an expert", dict(m=128 * 128), "tiles"),
+    ("many rows an expert, off the TPU", dict(m=128 * 128, platform="cpu"),
+     "compiler"),
+    ("many rows an expert, wide operands",
+     dict(m=128 * 128, dtype=jnp.bfloat16), "compiler")])
+def test_the_predicate_keeps_the_compiler_form(why, args, form):
     call = dict(m=384, g=128, k=2048, n=768, dtype=jnp.int8, platform="tpu")
     assert grouped.product_form(**call) == "stream"
-    assert grouped.product_form(**{**call, **args}) == "compiler", why
+    assert grouped.product_form(**{**call, **args}) == form, why
+    # The int32 product of a call that does not stream is the compiler's
+    # (the tiles form is no int32 product: _expert_rows asks the form first).
     assert (grouped.select_grouped_product(**{**call, **args})
             is grouped.grouped_rows_product_xla)
     assert (grouped.select_grouped_product(**call)

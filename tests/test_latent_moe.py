@@ -386,6 +386,48 @@ def test_routed_layer_w8a8_stream_form_is_the_compiler_form_exactly(
     np.testing.assert_array_equal(counts, want_counts)
 
 
+def _force_tiles(monkeypatch, taken=None):
+    """Steer every W8A8 expert product to the tiles kernel, interpreted (the
+    predicate picks it on a TPU at an admission call's rows)."""
+    import functools
+
+    from k8s_llm_monitor_tpu.ops import grouped
+
+    kernel = grouped.grouped_tiles_product
+
+    def form(m, g, k, n, dtype, platform=None):
+        if taken is not None:
+            taken.append((m, g, k, n, jnp.dtype(dtype)))
+        return "tiles"
+
+    monkeypatch.setattr(grouped, "product_form", form)
+    monkeypatch.setattr(grouped, "grouped_tiles_product",
+                        functools.partial(kernel, interpret=True))
+
+
+@pytest.mark.parametrize("case", ["all-real", "padded"])
+def test_routed_layer_w8a8_tiles_form_is_the_compiler_form_exactly(
+        params, monkeypatch, case):
+    """The expert products through the tiles kernel (ops/grouped.py, in the
+    interpreter), which dequantises too — the down product with the router's
+    weight on its row scales: the same layer output bit for bit."""
+    cfg = dataclasses.replace(CFG, act_quant=True)
+    layer = quantize_params(params)["layers"][1]
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((3, 50, 64)) * 0.5,
+                    jnp.float32)
+    valid = (jnp.arange(150).reshape(3, 50) % 7 != 1) if case == "padded" else None
+    want, want_counts = llama._moe_mlp_routed(layer, cfg, x, valid)
+    taken = []
+    _force_tiles(monkeypatch, taken)
+    got, counts = llama._moe_mlp_routed(layer, cfg, x, valid)
+    E, top, H, I = (cfg.num_experts, cfg.num_experts_per_tok, cfg.hidden_size,
+                    cfg.expert_width)
+    assert taken == [(150 * top, E, H, I, jnp.int8)] * 2 + [
+        (150 * top, E, I, H, jnp.int8)]
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(counts, want_counts)
+
+
 # -- (d) the routing counts of a call ----------------------------------------
 
 
@@ -475,7 +517,8 @@ def test_the_calls_say_which_form_their_products_took(params, monkeypatch):
 
     eng, want, calls = run()
     assert {c["moe_product_form"] for c in calls} == {"compiler"}
-    assert eng.moe_product_calls == {"stream": 0, "compiler": len(calls)}
+    assert eng.moe_product_calls == {"stream": 0, "tiles": 0,
+                                     "compiler": len(calls)}
 
     decode_rows = ENGINE["max_slots"] * cfg.num_experts_per_tok
 
@@ -495,12 +538,31 @@ def test_the_calls_say_which_form_their_products_took(params, monkeypatch):
     assert {c["kind"]: c["moe_product_form"] for c in calls} == {
         "admit": "compiler", "decode": "stream"}
     decodes = sum(c["kind"] == "decode" for c in calls)
-    assert eng.moe_product_calls == {"stream": decodes,
+    assert eng.moe_product_calls == {"stream": decodes, "tiles": 0,
                                      "compiler": len(calls) - decodes}
     w = exporter._Writer()
     exporter._loop_metrics(w, eng)
     assert (f'k8s_llm_monitor_engine_moe_product_calls_total{{form="stream"}} '
             f"{decodes}") in "\n".join(w.lines)
+
+    # The chip's choice: admission through the tiles kernel, decode through
+    # the stream kernel.
+    tiles = functools.partial(grouped.grouped_tiles_product, interpret=True)
+    monkeypatch.setattr(
+        grouped, "product_form", lambda m, g, k, n, dtype, platform=None: (
+            "stream" if m <= decode_rows else "tiles"))
+    monkeypatch.setattr(grouped, "grouped_tiles_product", tiles)
+    eng, got, calls = run()
+    assert got == want
+    assert {c["kind"]: c["moe_product_form"] for c in calls} == {
+        "admit": "tiles", "decode": "stream"}
+    assert eng.moe_product_calls == {"stream": decodes,
+                                     "tiles": len(calls) - decodes,
+                                     "compiler": 0}
+    w = exporter._Writer()
+    exporter._loop_metrics(w, eng)
+    assert (f'k8s_llm_monitor_engine_moe_product_calls_total{{form="tiles"}} '
+            f"{len(calls) - decodes}") in "\n".join(w.lines)
 
 
 def test_a_dense_models_programs_return_what_they_always_did():

@@ -406,6 +406,43 @@ def test_share_layer_w8a8_stream_form_is_the_compiler_form_exactly(
     np.testing.assert_array_equal(counts, want_counts)
 
 
+@pytest.mark.parametrize("window", [16_384, 64], ids=["one-window", "windows"])
+def test_share_layer_w8a8_tiles_form_is_the_compiler_form_exactly(
+        params, monkeypatch, window):
+    """The held experts' products through the tiles kernel (ops/grouped.py,
+    in the interpreter; the predicate picks it on a TPU at an admission
+    call's rows), which dequantises too, whole and in windows whose group
+    sizes are clipped: the same layer output bit for bit."""
+    import functools
+
+    from k8s_llm_monitor_tpu.ops import grouped
+
+    cfg = dataclasses.replace(CFG, act_quant=True)
+    layer = quantize_params(params)["layers"][3]
+    x = jnp.asarray(np.random.default_rng(12).standard_normal((2, 60, 64)),
+                    jnp.float32)
+    valid = jnp.asarray(np.random.default_rng(12).random((2, 60)) < 0.8)
+    monkeypatch.setattr(llama, "_EXPERT_WINDOW_ROWS", window)
+    want, want_counts = llama._moe_mlp_share(layer, cfg, x, valid)
+    taken = []
+
+    def form(m, g, k, n, dtype, platform=None):
+        taken.append((m, g, k, n, jnp.dtype(dtype)))
+        return "tiles"
+
+    monkeypatch.setattr(grouped, "product_form", form)
+    monkeypatch.setattr(grouped, "grouped_tiles_product", functools.partial(
+        grouped.grouped_tiles_product, interpret=True))
+    got, counts = llama._moe_mlp_share(layer, cfg, x, valid)
+    rows = min(120 * cfg.num_experts_per_tok, window)
+    L, I = cfg.moe_latent_size, cfg.expert_width
+    assert taken == [(rows, cfg.experts_held_, L, I, jnp.int8),
+                     (rows, cfg.experts_held_, I, L, jnp.int8)]
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(counts, want_counts)
+    assert counts[0] > 2 * 64   # the windowed case takes three windows
+
+
 @pytest.mark.parametrize("form", ["w8a8", "weight_only"])
 def test_the_quantised_forms_against_the_reference(params, form):
     """int8 kernels, with and without activation rounding: the reference
