@@ -178,7 +178,7 @@ def _engine_metrics(w: _Writer, engine) -> None:
              [("", engine.ecfg.num_blocks)])
     if getattr(engine, "_recurrent", False) and engine.cfg.recurrent:
         # The per-lane state pool of a description with recurrent layers,
-        # beside the pages (the ``state_*`` attributes of ``engine.call``).
+        # beside the pages: its bytes never change, so no span carries them.
         lane = engine.cfg.state_lane_bytes(
             engine.pages.conv[0].dtype.itemsize)
         w.metric("engine_state_pool_bytes", "gauge",
@@ -345,6 +345,13 @@ def _loop_metrics(w: _Writer, engine) -> None:
              "Device calls dispatched, by kind (admit, chunk, decode, spec)",
              [(f'{{kind="{k}"}}', n)
               for k, n in sorted(engine.calls_by_kind.items())])
+    w.metric("engine_device_seconds_total", "counter",
+             "Device seconds of reconciled calls, by kind: each call's time "
+             "at the head of the in-order device queue, from the later of "
+             "its dispatch and the previous call's finish to its own, as "
+             "the step thread first saw them",
+             [(f'{{kind="{k}"}}', round(sec, 6))
+              for k, sec in sorted(engine.device_seconds.items())])
     w.metric("engine_decode_slot_steps_total", "counter",
              "Decode lane-steps computed: max_slots x steps of every "
              "decode or spec call, live lane or not",

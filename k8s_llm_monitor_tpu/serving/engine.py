@@ -407,10 +407,12 @@ class _Inflight:
     lanes: list[tuple]
     # chunk: every slot touched by the call (inflight_chunks decrement).
     touched: list = dataclasses.field(default_factory=list)
-    # Enqueue timestamp (monotonic) — the per-lane phase spans and
-    # ``engine.call`` cover enqueue -> result on the host, which includes
-    # the time queued behind earlier calls; host-side bookkeeping only.
+    # Dispatch (monotonic, taken just before the program is entered): the
+    # per-lane phase spans and ``engine.call`` begin here.
     t0: float = 0.0
+    # The first moment the step thread saw the result ready (_call_ready,
+    # or the return of the blocking fetch); ``engine.call`` ends here.
+    t_ready: Optional[float] = None
     # Attributes of the call's ``engine.call`` span, counted where the call
     # is built (InferenceEngine._call_attrs); ``emitted`` joins at reconcile.
     span_attrs: dict = dataclasses.field(default_factory=dict)
@@ -455,8 +457,11 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     "kv_blocks", "kv_live_blocks", "kv_cached_blocks",
                     "kv_token_bytes",
                     # A description with recurrent layers (the state pool).
-                    "state_pool_bytes", "state_live_lanes",
-                    "state_lane_bytes",
+                    "state_live_lanes",
+                    # The call's seconds at the head of the in-order device
+                    # queue, and whether the step thread blocked on it
+                    # (InferenceEngine._reconcile_one).
+                    "device_s", "waited",
                     "sampler_filter", "steps", "lanes", "slots", "emitted",
                     "ctx_tokens",
                     "bucket", "rows", "prompts", "real_tokens",
@@ -1260,6 +1265,11 @@ class InferenceEngine:
         # of every decode call: all lanes compute every step, so
         # decode_tokens / decode_slot_steps is the share that was of use.
         self.calls_by_kind: dict[str, int] = {}
+        # Device seconds by kind, summed as calls are reconciled
+        # (_reconcile_one: each call's time at the head of the in-order
+        # device queue), and the ready stamp of the last call reconciled.
+        self.device_seconds: dict[str, float] = {}
+        self._last_ready = 0.0
         self.decode_slot_steps = 0
         self.decode_tokens = 0
         self.prefill_tokens = {"real": 0, "padded": 0, "cached": 0}
@@ -1847,13 +1857,10 @@ class InferenceEngine:
             census["kv_token_bytes"] = self.cfg.kv_token_bytes(
                 self.pages.k[0].dtype.itemsize)
         if self.cfg.recurrent:
-            # The state pool beside the pages: a lane costs the same
-            # whatever its context holds, and every lane is resident.
-            lane = self.cfg.state_lane_bytes(self.pages.conv[0].dtype.itemsize)
-            census.update(
-                state_pool_bytes=lane * self.ecfg.max_slots,
-                state_live_lanes=sum(s is not None for s in self._slots),
-                state_lane_bytes=lane)
+            # Lanes of the state pool a live slot owns (the pool's bytes
+            # never change: gauges engine_state_pool_bytes / _lane_bytes).
+            census["state_live_lanes"] = sum(s is not None
+                                             for s in self._slots)
         return census
 
     def _call_attrs(self, kind: str, program: str, device_empty: bool,
@@ -1899,12 +1906,19 @@ class InferenceEngine:
 
     @staticmethod
     def _call_ready(call: _Inflight) -> bool:
-        """True when reconciling ``call`` would not block on the device."""
+        """True when reconciling ``call`` would not block on the device.
+        The first True stamps ``call.t_ready``: every caller checks the
+        calls oldest first, so the stamps keep the device's order."""
+        if call.t_ready is not None:
+            return True
         arrs = call.arr if isinstance(call.arr, tuple) else (call.arr,)
         try:
-            return all(a.is_ready() for a in arrs)
+            ready = all(a.is_ready() for a in arrs)
         except AttributeError:  # non-jax payloads (tests with stub arrays)
-            return True
+            ready = True
+        if ready:
+            call.t_ready = time.monotonic()
+        return ready
 
     def _reconcile_all(self) -> None:
         while self._inflight:
@@ -2970,6 +2984,7 @@ class InferenceEngine:
         try:
             self._faults.maybe_raise("prefill_dispatch")
             self.in_program_call = True
+            t_dispatch = time.monotonic()
             if not any_shared:
                 # (offset, lengths) of a packed stream; (lengths,) of rows.
                 seg = ((jnp.asarray(start), jnp.asarray(lengths)) if packed
@@ -3043,7 +3058,7 @@ class InferenceEngine:
                     "real_tokens": sum(suffix), "padded_tokens": padded,
                     "cached_tokens": cached, "shared": int(any_shared),
                     "packed": int(packed)},
-            program=program, device_empty=device_empty)
+            program=program, device_empty=device_empty, t0=t_dispatch)
         return None
 
     def _dispatch_prefill_chunks(self) -> bool:
@@ -3143,6 +3158,7 @@ class InferenceEngine:
         try:
             self._faults.maybe_raise("prefill_dispatch")
             self.in_program_call = True
+            t_dispatch = time.monotonic()
             if final_constrained:
                 # Only final lanes sample, so only they consult the FSM;
                 # non-final lanes stay at state 0 and drop their token (and
@@ -3196,7 +3212,7 @@ class InferenceEngine:
         for s in touched:
             s.cached_uncounted = 0
         self._queue_inflight(
-            "chunk", first, idx, lanes, touched, fsm_next=fnext,
+            "chunk", first, idx, lanes, touched, fsm_next=fnext, t0=t_dispatch,
             span_attrs=self._call_attrs(
                 "chunk", program, device_empty,
                 sampler_filter=(
@@ -3208,9 +3224,11 @@ class InferenceEngine:
         return True
 
     def _queue_inflight(self, kind: str, first, idx, lanes,
-                        touched=(), fsm_next=None, span_attrs=None) -> None:
+                        touched=(), fsm_next=None, span_attrs=None, *,
+                        t0: float) -> None:
         """Shared dispatch tail: place sampled tokens into the device token
-        buffer, start the async host copy, and queue the reconcile entry."""
+        buffer, start the async host copy, and queue the reconcile entry
+        (``t0``: the call's dispatch)."""
         self._tok_state = self._place_tokens(
             self._tok_state, first, jnp.asarray(idx))
         if self._fsm_trans is not None:
@@ -3231,13 +3249,13 @@ class InferenceEngine:
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=first,
             lanes=list(lanes), touched=list(touched),
-            t0=time.monotonic(), span_attrs=span_attrs or {},
+            t0=t0, span_attrs=span_attrs or {},
             moe_counts=self._take_moe_counts()))
         self._next_call_id += 1
 
     def _finish_admit_dispatch(self, first, batch, idx, fsm_next=None, *,
                                counts: dict, program: str,
-                               device_empty: bool) -> None:
+                               device_empty: bool, t0: float) -> None:
         """Admission tail: occupy slots, then queue via the shared path."""
         lanes = []
         for slot_idx, req, blocks in batch:
@@ -3248,7 +3266,7 @@ class InferenceEngine:
         self.prefills += len(batch)
         self._write_hist(lanes)
         self._queue_inflight(
-            "admit", first, idx, lanes, fsm_next=fsm_next,
+            "admit", first, idx, lanes, fsm_next=fsm_next, t0=t0,
             span_attrs=self._call_attrs("admit", program, device_empty,
                                         **counts))
 
@@ -3693,6 +3711,7 @@ class InferenceEngine:
         try:
             self._faults.maybe_raise("decode_dispatch")
             self.in_program_call = True
+            t_dispatch = time.monotonic()
             payload, kind, program = self._dispatch_decode_call(
                 spec and not constrained, all_greedy, lanes, K, ctx,
                 steps_arr, table, temp, topk, topp, eos,
@@ -3721,7 +3740,7 @@ class InferenceEngine:
             payload = _StuckPayload(payload)
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=payload, lanes=meta,
-            t0=time.monotonic(),
+            t0=t_dispatch,
             span_attrs=self._call_attrs(
                 kind, program, device_empty,
                 sampler_filter=(None if all_greedy and not constrained
@@ -3837,7 +3856,11 @@ class InferenceEngine:
                     return
                 if self._faults.should_fire("slow_host_callback"):
                     time.sleep(self._faults.delay_s("slow_host_callback"))
+                waited = not self._call_ready(call)
                 arr = self._fetch_call(call)
+                if waited:      # the fetch's return is the first sighting
+                    call.t_ready = time.monotonic()
+                self._count_device_time(call, waited)
             with self._phase("engine.step.apply"):
                 self._apply_call(call, arr)
         except Exception as exc:
@@ -3858,6 +3881,20 @@ class InferenceEngine:
                 else:
                     still.append((after_id, blocks))
             self._deferred_frees = still
+
+    def _count_device_time(self, call: _Inflight, waited: bool) -> None:
+        """The call's seconds at the head of the device queue: the device
+        runs calls in the order they were enqueued, so a call holds the
+        head from the moment the one before it finished — or from its own
+        dispatch, if that came later — until it finishes.  Both ends are
+        the step thread's first sightings (``t_ready``), so a call that
+        finished unseen lends the time until it was seen to the next one;
+        with ``waited`` the end is the blocking fetch's return."""
+        device_s = call.t_ready - max(self._last_ready, call.t0)
+        self._last_ready = call.t_ready
+        self.device_seconds[call.kind] = (
+            self.device_seconds.get(call.kind, 0.0) + device_s)
+        call.span_attrs.update(device_s=device_s, waited=int(waited))
 
     def _await_call(self, call: _Inflight) -> bool:
         """Watchdog: poll readiness instead of blocking in np.asarray — a
@@ -3989,11 +4026,10 @@ class InferenceEngine:
                     self.sel_totals[name] += int(counts[name])
             attrs.update(counts)
         if self._loop_sampled:
-            # enqueue -> result on the host: includes the time queued
-            # behind earlier calls; how long the device ran it is the
-            # device trace's to say.
-            self._tracer.record("engine.call", call.t0, now, self._maint_ctx,
-                                attrs=attrs)
+            # dispatch -> first seen ready: includes the time queued
+            # behind earlier calls; ``device_s`` is the call's own share.
+            self._tracer.record("engine.call", call.t0, call.t_ready,
+                                self._maint_ctx, attrs=attrs)
 
     def _observe_ttft(self, ttft_s: float,
                       slo_class: str = DEFAULT_CLASS,

@@ -497,8 +497,8 @@ def test_the_counts_and_the_census(params):
         assert set(c) <= set(SPAN_CATALOG["engine.call"]), set(c) - set(
             SPAN_CATALOG["engine.call"])
         assert set(MOE_SHARE_COUNTS) <= set(c)
-        assert c["state_lane_bytes"] == lane
-        assert c["state_pool_bytes"] == lane * ENGINE["max_slots"]
+        # The pool's bytes never change: the gauges below carry them.
+        assert "state_lane_bytes" not in c and "state_pool_bytes" not in c
         assert 0 < c["state_live_lanes"] <= 2
         assert c["moe_assignments"] <= c["moe_assignments_all"]
         if c["kind"] == "admit":

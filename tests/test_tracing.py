@@ -11,6 +11,7 @@ engines).  Acceptance tests boot real in-process fleets and are marked
 """
 
 import json
+import re
 import time
 import urllib.request
 
@@ -857,6 +858,39 @@ def _loop_case_the_exporter_counts_what_the_spans_carry(rec):
     stepped = sum(s["duration_s"] for s in rec["by_name"]("engine.step"))
     counted = eng.loop_seconds["engine.step.wait_device"]
     assert 0 < waited - 1e-6 <= counted <= stepped + 1e-6
+
+
+def _loop_case_device_seconds_add_up_to_the_counter(rec):
+    eng, calls = rec["engine"], rec["by_name"]("engine.call")
+    summed: dict[str, float] = {}
+    for c in calls:
+        a = c["attrs"]
+        assert a["waited"] in (0, 1)
+        # At the head of the queue for no longer than dispatch -> ready.
+        assert 0 <= a["device_s"] <= c["duration_s"] + 1e-9
+        summed[a["kind"]] = summed.get(a["kind"], 0.0) + a["device_s"]
+    assert set(summed) == set(eng.device_seconds) == set(eng.calls_by_kind)
+    for kind, seconds in summed.items():
+        assert eng.device_seconds[kind] == pytest.approx(seconds, abs=1e-9)
+    # The queue is in order: the calls' head times never overlap.
+    by_id = sorted(calls, key=lambda c: c["attrs"]["call_id"])
+    for before, after in zip(by_id, by_id[1:]):
+        ready = before["start_mono"] + before["duration_s"]
+        start = after["start_mono"] + after["duration_s"] - after["attrs"][
+            "device_s"]
+        assert start >= ready - 1e-9
+
+
+def _loop_case_the_exporter_renders_device_seconds_by_kind(rec):
+    text, eng = rec["exposition"], rec["engine"]
+    assert lint_exposition(text) == []
+    assert ("# TYPE k8s_llm_monitor_engine_device_seconds_total counter"
+            in text)
+    rendered = {m.group(1): float(m.group(2)) for m in re.finditer(
+        r'engine_device_seconds_total\{kind="(\w+)"\} (\S+)', text)}
+    assert set(rendered) == {"admit", "decode"}
+    for kind, seconds in rendered.items():   # taken before the service stopped
+        assert 0 < seconds <= eng.device_seconds[kind] + 1e-6
 
 
 def _loop_case_the_exporter_prints_the_counters_not_the_gauges(rec):
