@@ -338,6 +338,98 @@ def state_kernel_checks(cfg, seed: int, lanes: int,
             f"{(time.perf_counter() - t0) / 5 * 1e3:.3f} ms")
 
 
+def mamba1_kernel_checks(cfg, seed: int, lanes: int,
+                         tokens: int = 4096) -> None:
+    """The Mamba-1 kernels at ``cfg``'s widths (a description with Mamba-1
+    layers): ``mamba1_decode_update`` on a pool of ``lanes`` lanes in place
+    against its XLA form, with idle rows (step 0: the state must come back
+    bit-equal) and a lane order that is not the identity; and
+    ``mamba1_scan_kernel`` against ``mamba1_scan`` on a packed stream of
+    ``tokens`` tokens (segments starting mid-chunk, padding between and
+    behind them, two wanted states in one chunk), with both forms' times."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from k8s_llm_monitor_tpu.ops import ssm
+
+    C, N = cfg.mamba_inner, cfg.ssm_state_size
+    interpret = jax.default_backend() != "tpu"
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape, np.float32))
+
+    A = -jnp.exp(jnp.asarray(np.log(np.arange(1, N + 1))[:, None]
+                             + 0.1 * rng.standard_normal((N, C)), jnp.float32))
+    pool = normal(lanes, N, C)
+    order = jnp.asarray(rng.permutation(lanes).astype(np.int32))
+    idle = jnp.asarray(rng.random(lanes) < 0.25)
+    dt = jnp.where(idle[:, None], 0.0, jax.nn.softplus(normal(lanes, C) - 4.0))
+    dtu = dt * normal(lanes, C)
+    Bm, Cm = normal(lanes, N), normal(lanes, N)
+    want_y, want_pool = jax.jit(ssm.mamba1_decode_update_xla)(
+        pool, order, dt, dtu, A, Bm, Cm)
+    update = jax.jit(functools.partial(ssm.mamba1_decode_update,
+                                       interpret=interpret),
+                     donate_argnums=(0,))
+    got_y, got_pool = update(pool + 0.0, order, dt, dtu, A, Bm, Cm)
+    _close(np, "mamba1_decode_update: y", got_y, want_y,
+           rows=~np.asarray(idle))
+    _close(np, "mamba1_decode_update: the pool", got_pool, want_pool)
+    kept = np.asarray(order)[np.asarray(idle)]
+    assert np.array_equal(np.asarray(got_pool)[kept],
+                          np.asarray(pool)[kept]), (
+        "mamba1_decode_update: an idle row's state changed")
+    fresh = normal(lanes, N, C)
+    fresh = jax.block_until_ready(update(fresh, order, dt, dtu, A, Bm, Cm)[1])
+    t0 = time.perf_counter()
+    for _ in range(5):
+        fresh = update(fresh, order, dt, dtu, A, Bm, Cm)[1]
+    jax.block_until_ready(fresh)
+    say(f"  mamba1_decode_update kernel at {lanes} lanes: "
+        f"{(time.perf_counter() - t0) / 5 * 1e3:.3f} ms")
+
+    # The admission scan: four segments of a packed stream, the first two
+    # ending in one chunk, padding (dt 0) between the third and the fourth
+    # and behind the last.
+    dtype = jnp.float32 if interpret else jnp.bfloat16
+    a, b, c = tokens // 3 + 5, tokens // 3 + 70, 2 * tokens // 3 + 33
+    segments = [(0, a), (a, b), (b, c),
+                (c + tokens // 12, tokens - tokens // 60)]
+    first = np.zeros((1, tokens), bool)
+    real = np.zeros(tokens, bool)
+    for start, end in segments:
+        first[0, start] = True
+        real[start:end] = True
+    dts = jnp.where(jnp.asarray(real)[None, :, None],
+                    jax.nn.softplus(normal(1, tokens, C) - 4.0), 0.0)
+    scan = (normal(1, tokens, C).astype(dtype), dts, A, normal(1, tokens, N),
+            normal(1, tokens, N), jnp.asarray(first),
+            jnp.asarray([end - 1 for _, end in segments], jnp.int32))
+    forms = {"xla": jax.jit(ssm.mamba1_scan),
+             "kernel": jax.jit(functools.partial(ssm.mamba1_scan_kernel,
+                                                 interpret=interpret))}
+    out = {name: f(*scan) for name, f in forms.items()}
+    rows = np.asarray(real)
+    for part, got, want in (("y", out["kernel"][0][0][rows],
+                             out["xla"][0][0][rows]),
+                            ("states", out["kernel"][1], out["xla"][1])):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        ok = np.isfinite(got).all() and err <= 2.0 ** -6
+        say(f"  kernel mamba1_scan_kernel at T={tokens}: {part}: "
+            f"max|err| / max|want| = {err:.3e} {'ok' if ok else 'MISMATCH'}")
+        assert ok, f"mamba1_scan_kernel at T={tokens}: {part} ({err})"
+    for name, f in forms.items():
+        jax.block_until_ready(f(*scan))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            jax.block_until_ready(f(*scan))
+        say(f"  mamba1_scan {name} form at T={tokens}: "
+            f"{(time.perf_counter() - t0) / 5 * 1e3:.3f} ms")
+
+
 def sparse_kernel_checks(cfg, seed: int, lanes: int,
                          context: int = 1536, rows: int = 1024) -> None:
     """The selected-attention and window kernels at ``cfg``'s widths (a
@@ -773,6 +865,12 @@ def one_chip(seed: int, model: str = MODEL, max_tokens: int = 9,
         # The selected-attention and window kernels of the description with
         # two latent geometries.
         sparse_kernel_checks(PRESETS["dots3-note-prev-5l"], seed, 16)
+        # The jamba description's Mamba-1 kernels at its 256 lanes, and the
+        # attention kernels at its head shape (20 query heads on one kv
+        # head).
+        jamba = PRESETS["jamba2-3b"]
+        mamba1_kernel_checks(jamba, seed, 256)
+        kernel_checks(jamba, seed, tcfg.kv_blocks, 16, 64, tcfg.max_batch)
         say(f"phase kernels: ok in {time.monotonic() - t0:.1f} s "
             "(information)")
 

@@ -20,8 +20,9 @@ class LayerSpec:
     mixer: ``"full"`` (per-head K and V, GQA) | ``"latent"`` (DeepSeek-V3
       multi-head latent attention: one compressed latent and one rotated key
       per token) | ``"mamba2"`` (a state-space mixer: no cached tokens, one
-      recurrent state a lane) | ``"none"`` (the layer is a feed-forward
-      alone).
+      recurrent state a lane) | ``"mamba1"`` (Mamba-1's selective scan: a
+      decay per channel and state; no cached tokens, one recurrent state a
+      lane) | ``"none"`` (the layer is a feed-forward alone).
     mlp: ``"dense"`` | ``"routed"`` (top-k experts) | ``"shared+routed"`` |
       ``"none"`` (the layer is a mixer alone).
     cache: ``"kv"`` (two page arrays, ``kv_heads * head_dim`` lanes) |
@@ -236,6 +237,16 @@ class ModelConfig:
     mamba_n_groups: int = 1
     conv_kernel: int = 4
     ssm_chunk_size: int = 128
+    # --- a mixer and an MLP a layer, attention every so many (jamba) -------
+    # > 0: layer i is attention where ``i % attn_layer_period ==
+    # attn_layer_offset`` and a Mamba-1 mixer elsewhere, each followed by the
+    # layer's MLP.  A Mamba-1 mixer has ``mamba_expand x hidden`` channels, a
+    # state of ``ssm_state_size`` a channel, a step size of rank
+    # ``mamba_dt_rank`` and a convolution of ``conv_kernel`` with a bias.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
 
     def layer_spec(self, i: int) -> LayerSpec:
         """Mixer, MLP and cache kind of layer ``i``."""
@@ -243,6 +254,10 @@ class ModelConfig:
             mlp = "shared+routed" if self.n_shared_experts > 0 else "routed"
         else:
             mlp = "dense"
+        if self.attn_layer_period:
+            if i % self.attn_layer_period == self.attn_layer_offset:
+                return LayerSpec(mixer="full", mlp=mlp, cache="kv")
+            return LayerSpec(mixer="mamba1", mlp=mlp, cache="state")
         if self.layer_pattern is not None:
             kind = self.layer_pattern[i]
             if kind == "M":
@@ -300,6 +315,13 @@ class ModelConfig:
         return bool(self.layers_with("state"))
 
     @property
+    def state_mixer(self) -> str:
+        """The recurrent layers' mixer, ``"mamba2"`` or ``"mamba1"`` (one
+        kind a description); "" without recurrent layers."""
+        layers = self.layers_with("state")
+        return self.layer_spec(layers[0]).mixer if layers else ""
+
+    @property
     def lane_state(self) -> bool:
         """Some layer keeps something a decode lane (not a block): recurrent
         state, or a window-bounded store.  Such a description is admitted
@@ -345,12 +367,18 @@ class ModelConfig:
 
     @property
     def mamba_inner(self) -> int:
-        """Channels of a Mamba-2 mixer's ``x`` and ``z``."""
-        return self.mamba_num_heads * self.mamba_head_dim
+        """Channels of a Mamba mixer's ``x`` and ``z``: heads x head width
+        (Mamba-2), ``mamba_expand`` x the hidden size (Mamba-1)."""
+        if self.mamba_num_heads:
+            return self.mamba_num_heads * self.mamba_head_dim
+        return self.mamba_expand * self.hidden_size
 
     @property
     def mamba_conv_dim(self) -> int:
-        """Channels the short convolution runs over: ``[x | B | C]``."""
+        """Channels the short convolution runs over: ``[x | B | C]``
+        (Mamba-2), ``x`` alone (Mamba-1)."""
+        if not self.mamba_num_heads:
+            return self.mamba_inner
         return (self.mamba_inner
                 + 2 * self.mamba_n_groups * self.ssm_state_size)
 
@@ -469,6 +497,18 @@ TINY_NEMOTRON_H = ModelConfig(
     moe_shared_intermediate_size=40, mlp_gated=False, mlp_activation="relu2",
     moe_scoring="sigmoid+bias", norm_topk_prob=True,
     routed_scaling_factor=5.0)
+
+# The jamba block at test size: four layers, the third attention by
+# offset / period, each mixer followed by a dense MLP; Mamba-1 with the
+# published state size (16) and a step-size rank below the hidden size; MQA
+# with a query-head count that is no multiple of 8 over one key/value head,
+# as the served model's 20:1.  vocab >= 259 as above.
+TINY_JAMBA = ModelConfig(
+    name="tiny-jamba", vocab_size=320, hidden_size=64,
+    intermediate_size=96, num_layers=4, num_heads=5, num_kv_heads=1,
+    head_dim=16, rope_theta=10_000.0, rms_norm_eps=1e-6, use_rope=False,
+    tie_embeddings=True, attn_layer_period=4, attn_layer_offset=2,
+    mamba_expand=2, mamba_dt_rank=8, ssm_state_size=16, conv_kernel=4)
 
 # The dots3_note block at test size: both latent geometries (two full layers
 # with low-rank queries and an indexer whose top-k is smaller than a test
@@ -739,6 +779,33 @@ DOTS3_NOTE_PREV_5L = ModelConfig(
     routed_scaling_factor=1.0,
 )
 
+# ai21labs/AI21-Jamba2-3B (config.json, model_type jamba), whole: 28 layers,
+# attention in layers 7 and 21 (attn_layer_offset 7, attn_layer_period 14),
+# Mamba-1 in the other 26, a dense MLP in every layer (num_experts 1), tied
+# embeddings (benchmarks/configs/jamba2-3b-w8a8.json has the reckoning).
+# ``rope_theta`` is the file's key, and nothing rotates (``use_rope``).
+JAMBA2_3B = ModelConfig(
+    name="jamba2-3b",
+    vocab_size=65_536,
+    hidden_size=2560,
+    intermediate_size=8192,
+    num_layers=28,
+    num_heads=20,
+    num_kv_heads=1,
+    head_dim=128,
+    rope_theta=10_000.0,
+    rms_norm_eps=1e-6,
+    max_seq_len=262_144,
+    tie_embeddings=True,
+    use_rope=False,
+    attn_layer_period=14,
+    attn_layer_offset=7,
+    mamba_expand=2,
+    mamba_dt_rank=160,
+    ssm_state_size=16,
+    conv_kernel=4,
+)
+
 # A ~1.1B config used for single-chip benchmarks when full 8B weights would not
 # leave headroom for the KV cache on a 16 GB v5e chip with random-init weights.
 LLAMA_1B = ModelConfig(
@@ -757,7 +824,7 @@ LLAMA_1B = ModelConfig(
 PRESETS = {
     c.name: c
     for c in [TINY, TINY_QWEN, TINY_MOE, TINY_LATENT_MOE, TINY_NEMOTRON_H,
-              TINY_DOTS3_NOTE, DOTS3_NOTE_PREV_5L,
+              TINY_DOTS3_NOTE, TINY_JAMBA, DOTS3_NOTE_PREV_5L, JAMBA2_3B,
               LLAMA3_8B, LLAMA3_70B, MISTRAL_7B, MIXTRAL_8X7B, QWEN2_7B,
               QWEN2_72B, GEMMA2_2B, GEMMA2_9B, LLAMA_1B, KANANA2_30B_A3B_12L,
               NEMOTRON3_SUPER_22L]

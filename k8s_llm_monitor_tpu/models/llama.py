@@ -47,7 +47,6 @@ from k8s_llm_monitor_tpu.ops.rope import apply_rope, rope_angles
 from k8s_llm_monitor_tpu.ops.ssm import (
     pack_state,
     ssm_chunk_scan,
-    ssm_decode_update_xla,
     state_pack,
 )
 
@@ -92,9 +91,10 @@ class KVPages(NamedTuple):
     in layer order (``cfg.layers_with("kv")``) — a layer without cached
     tokens has no pages.  ``ssm`` / ``conv`` are the **state pool**, one
     entry for each layer of kind ``"state"``: a Mamba-2 mixer's recurrent
-    state ``[lanes, H / pack, N, pack * P]`` float32 (ops/ssm.py has the
-    layout) and the last ``conv_kernel - 1`` un-convolved rows of its short
-    convolution ``[lanes, conv_kernel - 1, channels]``.  It is indexed by
+    state ``[lanes, H / pack, N, pack * P]`` float32, a Mamba-1 mixer's
+    ``[lanes, N, channels]`` float32 (ops/ssm.py has both layouts), and the
+    last ``conv_kernel - 1`` un-convolved rows of its short convolution
+    ``[lanes, conv_kernel - 1, channels]``.  It is indexed by
     decode lane, not by block: a lane's state costs the same whatever its
     context holds, is overwritten whole when a fresh prompt is admitted to
     the lane, and is updated in place by the programs that are given the
@@ -185,11 +185,15 @@ def init_kv_pages(cfg: ModelConfig, num_blocks: int, block_size: int,
                 f"a pool beside recurrent state is not built for "
                 f"kv_dtype={kv_quant!r}")
         Hm, P, N = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size
-        pack = state_pack(Hm, cfg.mamba_n_groups, P)
+        if cfg.state_mixer == "mamba1":
+            row = (N, cfg.mamba_inner)
+        else:
+            pack = state_pack(Hm, cfg.mamba_n_groups, P)
+            row = (Hm // pack, N, pack * P)
         n = len(cfg.layers_with("state"))
         state = dict(
-            ssm=[jnp.zeros((state_lanes, Hm // pack, N, pack * P),
-                           jnp.float32) for _ in range(n)],
+            ssm=[jnp.zeros((state_lanes, *row), jnp.float32)
+                 for _ in range(n)],
             conv=[jnp.zeros((state_lanes, cfg.conv_kernel - 1,
                              cfg.mamba_conv_dim), jnp.dtype(cfg.dtype))
                   for _ in range(n)])
@@ -283,7 +287,10 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
             "input_norm": norm_init((H,), dtype),
             "post_norm": norm_init((H,), dtype),
         }
-        if spec.mixer == "latent" and _low_rank_mixer(cfg, i):
+        if spec.mixer == "mamba1":
+            layer.update(init_mamba1_mixer(
+                jax.random.fold_in(keys[2 + i], 3), cfg, dense))
+        elif spec.mixer == "latent" and _low_rank_mixer(cfg, i):
             layer.update(init_latent_mixer(
                 jax.random.fold_in(keys[2 + i], 2), cfg,
                 cfg.latent_geometry(i), dense,
@@ -399,6 +406,42 @@ def mamba_init(key: jax.Array, cfg: ModelConfig) -> Params:
                                             1.0, 16.0)),
         "D": jnp.ones((Hm,), jnp.float32),
         "ssm_norm": jnp.ones((cfg.mamba_inner,), jnp.dtype(cfg.dtype)),
+    }
+
+
+def init_mamba1_mixer(key: jax.Array, cfg: ModelConfig, dense) -> Params:
+    """A Mamba-1 mixer (jamba's): ``in_proj`` (to ``[u | z]``), ``x_proj``
+    (to the step input, ``B`` and ``C``), ``dt_proj`` and ``out_proj``, built
+    by ``dense(key, in, out, bias)`` in the caller's form (int8 in
+    utils/quantize.py), and what stays wide under every quantisation,
+    seeded the family's usual way (a trained one is not reachable): the
+    depthwise convolution (float32, uniform in +-conv_kernel**-0.5) and its
+    bias (zeros), ``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform
+    in 0.001-0.1, ``A_log`` = log(1 ... N) for every channel (S4D-real) plus a
+    normal of std 0.1, ``D`` ones, the three RMSNorms of the step input,
+    ``B`` and ``C`` ones.  ``A_log`` is laid ``[N, channels]``, as the state
+    pool is (the published ``[channels, N]`` transposed)."""
+    C, N, Rk, Kc = (cfg.mamba_inner, cfg.ssm_state_size, cfg.mamba_dt_rank,
+                    cfg.conv_kernel)
+    H, dtype = cfg.hidden_size, jnp.dtype(cfg.dtype)
+    k = jax.random.split(key, 8)
+    dt = jnp.exp(jax.random.uniform(k[4], (C,), jnp.float32)
+                 * (jnp.log(0.1) - jnp.log(0.001)) + jnp.log(0.001))
+    return {
+        "in_proj": dense(k[0], H, 2 * C, False),
+        "x_proj": dense(k[1], C, Rk + 2 * N, False),
+        "dt_proj": dense(k[2], Rk, C, False),
+        "out_proj": dense(k[3], C, H, False),
+        "conv": {"kernel": jax.random.uniform(k[5], (Kc, C), jnp.float32,
+                                              -(Kc ** -0.5), Kc ** -0.5),
+                 "bias": jnp.zeros((C,), jnp.float32)},
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),     # softplus's inverse
+        "A_log": (jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None]
+                  + 0.1 * jax.random.normal(k[6], (N, C), jnp.float32)),
+        "D": jnp.ones((C,), jnp.float32),
+        "dt_norm": jnp.ones((Rk,), dtype),
+        "B_norm": jnp.ones((N,), dtype),
+        "C_norm": jnp.ones((N,), dtype),
     }
 
 
@@ -1424,6 +1467,34 @@ def _mamba_out(layer: Params, cfg: ModelConfig, y: jnp.ndarray,
     return _linear(layer["out_proj"], g.astype(z.dtype), cfg.act_quant)
 
 
+def _causal_conv(layer: Params, x: jnp.ndarray,
+                 positions: jnp.ndarray) -> jnp.ndarray:
+    """A Mamba mixer's depthwise convolution of ``x`` [B, S, C] over each
+    sequence's last ``conv_kernel`` tokens, plus its bias, float32: a source
+    before the sequence's start (``positions``) is zero."""
+    S, Kc = x.shape[1], layer["conv"]["kernel"].shape[0]
+    src = jnp.pad(x, ((0, 0), (Kc - 1, 0), (0, 0)))
+    w = layer["conv"]["kernel"]
+    conv = layer["conv"]["bias"]
+    for k in range(Kc):
+        reach = (positions >= Kc - 1 - k)[..., None]
+        conv = conv + w[k] * jnp.where(
+            reach, src[:, k:k + S].astype(jnp.float32), 0.0)
+    return conv
+
+
+def _conv_tail(x: jnp.ndarray, positions: jnp.ndarray, last: jnp.ndarray,
+               Kc: int) -> jnp.ndarray:
+    """The un-convolved rows of ``x`` [B, S, C] ending at each flat index
+    ``last`` [R]: [R, Kc - 1, C], zeros before the sequence's start."""
+    Bt, S = positions.shape
+    back = jnp.arange(Kc - 1, dtype=jnp.int32) - (Kc - 2)   # -2, -1, 0
+    rows = x.reshape(Bt * S, -1)[
+        jnp.maximum(last[:, None] + back[None, :], 0)]
+    seen = positions.reshape(-1)[last][:, None] + back[None, :] >= 0
+    return jnp.where(seen[..., None], rows, jnp.zeros((), rows.dtype))
+
+
 def _mamba2_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
                     positions: jnp.ndarray, valid: jnp.ndarray,
                     last: jnp.ndarray):
@@ -1435,19 +1506,9 @@ def _mamba2_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
     token.  Returns (out [B, S, hidden], the state after each ``last`` in
     the pool's layout [R, H / pack, N, pack * P] float32, the un-convolved
     ``xBC`` rows ending there [R, conv_kernel - 1, channels])."""
-    Bt, S = positions.shape
-    Kc = cfg.conv_kernel
     with jax.named_scope("mixer"), jax.named_scope("mamba2"):
         z, xbc, dt = _mamba_in(layer, cfg, h)
-        # Causal depthwise convolution: a source before the sequence's start
-        # is zero.
-        src = jnp.pad(xbc, ((0, 0), (Kc - 1, 0), (0, 0)))
-        w = layer["conv"]["kernel"]
-        conv = layer["conv"]["bias"]
-        for k in range(Kc):
-            reach = (positions >= Kc - 1 - k)[..., None]
-            conv = conv + w[k] * jnp.where(
-                reach, src[:, k:k + S].astype(jnp.float32), 0.0)
+        conv = _causal_conv(layer, xbc, positions)
         # The scan's products take the activation dtype (float32 sums).
         x, Bm, Cm = _mamba_heads(cfg, jax.nn.silu(conv).astype(h.dtype))
         scan = (x, jnp.where(valid[..., None], dt, 0.0),
@@ -1460,11 +1521,7 @@ def _mamba2_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
             states = pack_state(states, state_pack(
                 cfg.mamba_num_heads, cfg.mamba_n_groups, cfg.mamba_head_dim))
         out = _mamba_out(layer, cfg, y, x, z)
-        back = jnp.arange(Kc - 1, dtype=jnp.int32) - (Kc - 2)   # -2, -1, 0
-        rows = xbc.reshape(Bt * S, -1)[
-            jnp.maximum(last[:, None] + back[None, :], 0)]
-        seen = positions.reshape(-1)[last][:, None] + back[None, :] >= 0
-        tail = jnp.where(seen[..., None], rows, jnp.zeros((), rows.dtype))
+        tail = _conv_tail(xbc, positions, last, cfg.conv_kernel)
         # The tail is an output of the program alone: left to itself the
         # compiler gathers it last and keeps every layer's whole ``xBC``
         # alive until then (160 MB a layer at 8,192 tokens).
@@ -1473,10 +1530,13 @@ def _mamba2_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
 
 
 def scan_form(cfg: ModelConfig, rows: int) -> str:
-    """``"kernel"`` or ``"xla"``: the form the Mamba-2 layers' admission
+    """``"kernel"`` or ``"xla"``: the form the recurrent layers' admission
     scan takes in a program of ``rows`` rows (the final states it wants) —
-    ``_mamba2_prefill``'s choice, from ``ops/ssm.py:scan_form`` on the
-    description's widths."""
+    ``_mamba2_prefill``'s and ``_mamba1_prefill``'s choice, from
+    ``ops/ssm.py:scan_form`` / ``mamba1_scan_form`` on the description's
+    widths."""
+    if cfg.state_mixer == "mamba1":
+        return ssm.mamba1_scan_form(cfg.mamba_inner, cfg.ssm_state_size, rows)
     return ssm.scan_form(cfg.mamba_num_heads, cfg.mamba_head_dim,
                          cfg.ssm_state_size, cfg.mamba_n_groups,
                          cfg.ssm_chunk_size, rows)
@@ -1508,6 +1568,88 @@ def _mamba2_decode(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
         tail = jnp.where(active[:, None, None], seen[:, 1:], tail)
         conv = tail if lanes is None else conv.at[lanes].set(tail, mode="drop")
     return out, ssm, conv
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1 mixer
+# ---------------------------------------------------------------------------
+
+
+def _mamba1_ssm_in(layer: Params, cfg: ModelConfig, u: jnp.ndarray):
+    """From the convolved ``u`` [..., C]: ``[d | B | C] = x_proj(u)``, each
+    through its RMSNorm (jamba's), and ``dt = softplus(dt_proj(d) +
+    dt_bias)``.  Returns (dt float32 [..., C], B, C float32 [..., N])."""
+    Rk, N, eps = cfg.mamba_dt_rank, cfg.ssm_state_size, cfg.rms_norm_eps
+    dbc = _linear(layer["x_proj"], u, cfg.act_quant)
+    d = rms_norm(dbc[..., :Rk], layer["dt_norm"], eps)
+    Bm = rms_norm(dbc[..., Rk:Rk + N], layer["B_norm"], eps)
+    Cm = rms_norm(dbc[..., Rk + N:], layer["C_norm"], eps)
+    dt = jax.nn.softplus(_linear(layer["dt_proj"], d, cfg.act_quant)
+                         .astype(jnp.float32) + layer["dt_bias"])
+    return dt, Bm.astype(jnp.float32), Cm.astype(jnp.float32)
+
+
+def _mamba1_out(layer: Params, cfg: ModelConfig, y: jnp.ndarray,
+                u: jnp.ndarray, z: jnp.ndarray) -> jnp.ndarray:
+    """``out_proj((y + D u) * silu(z))``; the arithmetic is float32."""
+    g = ((y.astype(jnp.float32) + layer["D"] * u.astype(jnp.float32))
+         * jax.nn.silu(z.astype(jnp.float32)))
+    return _linear(layer["out_proj"], g.astype(z.dtype), cfg.act_quant)
+
+
+def _mamba1_prefill(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
+                    positions: jnp.ndarray, valid: jnp.ndarray,
+                    last: jnp.ndarray):
+    """A Mamba-1 mixer over whole sequences from a zero state, rows or one
+    packed stream, as ``_mamba2_prefill`` (its arguments and its rules at a
+    sequence's start and over padding).  Returns (out [B, S, hidden], the
+    state after each ``last`` [R, N, C] float32, the un-convolved ``u`` rows
+    ending there [R, conv_kernel - 1, C])."""
+    C = cfg.mamba_inner
+    with jax.named_scope("mixer"), jax.named_scope("mamba1"):
+        uz = _linear(layer["in_proj"], h, cfg.act_quant)
+        raw, z = uz[..., :C], uz[..., C:]
+        u = jax.nn.silu(_causal_conv(layer, raw, positions)).astype(h.dtype)
+        dt, Bm, Cm = _mamba1_ssm_in(layer, cfg, u)
+        scan = (u, jnp.where(valid[..., None], dt, 0.0),
+                -jnp.exp(layer["A_log"]), Bm, Cm, positions == 0, last)
+        if scan_form(cfg, last.shape[0]) == "kernel":
+            y, states = ssm.mamba1_scan_kernel(*scan)
+        else:
+            y, states = ssm.mamba1_scan(*scan)
+        out = _mamba1_out(layer, cfg, y, u, z)
+        tail = _conv_tail(raw, positions, last, cfg.conv_kernel)
+        # As in _mamba2_prefill: the tail is the program's output alone.
+        out, tail = jax.lax.optimization_barrier((out, tail))
+    return out, states, tail
+
+
+def _mamba1_decode(layer: Params, cfg: ModelConfig, h: jnp.ndarray,
+                   active: jnp.ndarray, ssm_pool: jnp.ndarray,
+                   conv: jnp.ndarray, lanes: Optional[jnp.ndarray], update):
+    """One token a row through a Mamba-1 mixer, against the state pool, as
+    ``_mamba2_decode`` (an idle row leaves its lane as it was).  ``update``:
+    ops/ssm.py:mamba1_decode_update or its XLA form.  Returns (out [B, 1,
+    hidden], the layer's ``ssm`` and ``conv`` pools, updated)."""
+    B = h.shape[0]
+    C = cfg.mamba_inner
+    with jax.named_scope("mixer"), jax.named_scope("mamba1"):
+        uz = _linear(layer["in_proj"], h[:, 0], cfg.act_quant)
+        raw, z = uz[..., :C], uz[..., C:]
+        tail = conv if lanes is None else conv[lanes]
+        seen = jnp.concatenate([tail, raw[:, None].astype(tail.dtype)], axis=1)
+        convd = layer["conv"]["bias"] + jnp.sum(
+            layer["conv"]["kernel"] * seen.astype(jnp.float32), axis=1)
+        u = jax.nn.silu(convd).astype(h.dtype)
+        dt, Bm, Cm = _mamba1_ssm_in(layer, cfg, u)
+        dt = jnp.where(active[:, None], dt, 0.0)
+        y, ssm_pool = update(
+            ssm_pool, jnp.arange(B, dtype=jnp.int32) if lanes is None else lanes,
+            dt, dt * u.astype(jnp.float32), -jnp.exp(layer["A_log"]), Bm, Cm)
+        out = _mamba1_out(layer, cfg, y.astype(u.dtype), u, z)[:, None]
+        tail = jnp.where(active[:, None, None], seen[:, 1:], tail)
+        conv = tail if lanes is None else conv.at[lanes].set(tail, mode="drop")
+    return out, ssm_pool, conv
 
 
 def _mlp_act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
@@ -1653,6 +1795,11 @@ def layer_block(
     h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps,
                  cfg.rmsnorm_unit_offset)
     spec = cfg.layer_spec(layer_idx)
+    if spec.mixer == "mamba1":
+        out, _, _ = _mamba1_prefill(
+            layer, cfg, h, positions, jnp.ones((B, S), bool),
+            jnp.arange(B, dtype=jnp.int32) * S + S - 1)
+        return _residual_tail(layer, cfg, x, out, collect_aux)
     if spec.mixer in ("mamba2", "none"):
         # One sub-block under one norm and one residual.
         if spec.mixer == "none":
@@ -1943,14 +2090,19 @@ def _prefill_impl(
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
         spec = cfg.layer_spec(li)
         cos, sin = _rope_of(cfg, ropes, li)
-        if spec.mixer == "mamba2":
-            out, state, tail = _mamba2_prefill(layer, cfg, h, positions,
-                                               valid, last)
+        if spec.mixer in ("mamba2", "mamba1"):
+            out, state, tail = (_mamba2_prefill if spec.mixer == "mamba2"
+                                else _mamba1_prefill)(layer, cfg, h,
+                                                      positions, valid, last)
             n = len(new_ssm)
             new_ssm.append(pages.ssm[n].at[lanes].set(state, mode="drop"))
             new_conv.append(pages.conv[n].at[lanes].set(
                 tail.astype(pages.conv[n].dtype), mode="drop"))
-            x = x + out
+            if spec.mlp == "none":
+                x = x + out
+            else:       # jamba: the layer's MLP follows its mixer
+                x, _ = _residual_tail(layer, cfg, x, out, valid=valid,
+                                      moe_stats=moe_stats)
             continue
         if spec.mixer == "none":
             with jax.named_scope("mlp"):
@@ -2298,7 +2450,7 @@ def decode_step(
     moe_stats: Optional[list] = None,
     hidden: Optional[list] = None,
     lanes: Optional[jnp.ndarray] = None,
-    ssm_update=ssm_decode_update_xla,
+    ssm_update=None,
     sel_stats: Optional[list] = None,
     index_scores=index_scores_decode,
     selection: Optional[list] = None,
@@ -2318,7 +2470,8 @@ def decode_step(
         (``_sel_counts``; the keys selected are the sum of the keep mask
         the attention kernel is handed).
       ssm_update: the recurrent layers' one-step state update
-        (ops/ssm.py:ssm_decode_update, or its XLA form).
+        (ops/ssm.py:select_ssm_update for the description's mixer: the
+        kernel, or its XLA form; None = the XLA form).
       index_scores: the indexer's scores against a lane's index-key pages
         (ops/attention.py:select_index_scores_impl: the Pallas kernel, or
         its XLA form).
@@ -2332,6 +2485,8 @@ def decode_step(
     B = tokens.shape[0]
     positions = context_lens[:, None]  # [B, 1]
     active = (context_lens > 0)[:, None]
+    if ssm_update is None:
+        ssm_update = ssm.select_ssm_update("xla", cfg.state_mixer)
     ropes = _rope_tables(cfg, positions)
     if not cfg.use_rope:
         # The fused kernels rotate inside, by the tables they are handed:
@@ -2373,14 +2528,19 @@ def decode_step(
         h = rms_norm(x, layer["input_norm"], cfg.rms_norm_eps, uo)
         spec = cfg.layer_spec(li)
         cos, sin = _rope_of(cfg, ropes, li)
-        if spec.mixer == "mamba2":
+        if spec.mixer in ("mamba2", "mamba1"):
             n = len(new_ssm)
-            out, ssm, conv = _mamba2_decode(
+            out, ssm_pool, conv = (
+                _mamba2_decode if spec.mixer == "mamba2" else _mamba1_decode)(
                 layer, cfg, h, active[:, 0], pages.ssm[n], pages.conv[n],
                 lanes, ssm_update)
-            new_ssm.append(ssm)
+            new_ssm.append(ssm_pool)
             new_conv.append(conv)
-            x = x + out
+            if spec.mlp == "none":
+                x = x + out
+            else:       # jamba: the layer's MLP follows its mixer
+                x, _ = _residual_tail(layer, cfg, x, out, valid=active,
+                                      moe_stats=moe_stats)
             continue
         if spec.mixer == "none":
             with jax.named_scope("mlp"):

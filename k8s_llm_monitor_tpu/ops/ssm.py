@@ -1,4 +1,5 @@
-"""Mamba-2 state-space mixing: the recurrence in its two serving forms.
+"""State-space mixing (Mamba-2 and Mamba-1): each recurrence in its two
+serving forms.
 
 The recurrence, per head h (group g = h // (H / G)), state ``S_h`` [P, N]:
 
@@ -28,6 +29,15 @@ that lands as a row, and only ``B`` and ``C`` — one pair a *group*, shared by
 its 16 heads — have to be turned into columns.  In ``[H, P, N]`` each head
 needs ``dt * x`` as a column and yields ``y`` as one: a lane reduction and a
 lane broadcast for each of the state's 1,024 registers a lane-layer.
+
+**Mamba-1** (``mamba1_*``, jamba's mixer) decays each channel c and state n
+by its own ``exp(dt[c] A[n, c])``, so no chunked matrix form exists: both
+forms run the recurrence token by token.  A lane's state is ``[N, C]``
+float32 — the N = 16 states on sublanes, the channels on lanes — so a
+token's step size and input are rows (broadcast down the sublanes), its
+``y`` is a sum down them, and only ``B`` and ``C`` (one N-vector a token)
+have to be columns: the admission kernel turns a chunk's whole ``[N, Q]``
+tile of them into columns once, ahead of its channels.
 """
 
 from __future__ import annotations
@@ -505,10 +515,299 @@ def ssm_decode_update(pool, lanes, decay, dtx, Bm, Cm, *,
     return y.reshape(B, H, P), pool
 
 
-def select_ssm_update(platform: str | None = None):
-    """The decode-step state update for the backend: the kernel on a TPU,
-    its XLA form elsewhere (tests put the kernel through the interpreter
-    themselves)."""
-    if (platform or jax.default_backend()) == "tpu":
-        return ssm_decode_update
-    return ssm_decode_update_xla
+def select_ssm_update(platform: str | None = None, mixer: str = "mamba2"):
+    """The decode-step state update of a ``mixer`` (``"mamba2"`` |
+    ``"mamba1"``) for the backend: the kernel on a TPU, its XLA form
+    elsewhere (tests put the kernel through the interpreter themselves)."""
+    tpu = (platform or jax.default_backend()) == "tpu"
+    if mixer == "mamba1":
+        return mamba1_decode_update if tpu else mamba1_decode_update_xla
+    return ssm_decode_update if tpu else ssm_decode_update_xla
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1: a decay per channel and state
+# ---------------------------------------------------------------------------
+
+
+def mamba1_scan(u, dt, A, Bm, Cm, first, last):
+    """Mamba-1's selective scan over whole sequences from a zero state, token
+    by token (a ``lax.scan``): the oracle, and what runs off the TPU.
+
+        S[t][n, c] = exp(dt[t][c] A[n, c]) S[t-1][n, c] + dt[t][c] B[t][n] u[t][c]
+        y[t][c]    = sum_n S[t][n, c] C[t][n]      (the D * u term is the caller's)
+
+    Args:
+      u: [Bt, S, C] inputs, in the activation dtype (the arithmetic is
+        float32).
+      dt: [Bt, S, C] float32 step sizes, **0 at padding** (padding leaves
+        the state as the last real token left it).
+      A: [N, C] float32, negative (the pool's layout).
+      Bm, Cm: [Bt, S, N].
+      first: [Bt, S] bool — the token starts a sequence (its state starts
+        from zero).
+      last: [R] int32 flat indices into ``Bt * S`` of the wanted final
+        states.
+
+    Returns:
+      (y [Bt, S, C] in u's dtype, states [R, N, C] float32).
+    """
+    Bt, S, C = u.shape
+    N, R = A.shape[0], last.shape[0]
+    b_r, s_r = last // S, last % S
+    A = A.astype(F32)
+
+    def step(carry, xs):
+        state, states = carry
+        u_t, dt_t, b_t, c_t, first_t, t = xs
+        state = (jnp.where(first_t[:, None, None], 0.0, state)
+                 * jnp.exp(dt_t[:, None, :] * A)
+                 + b_t.astype(F32)[:, :, None]
+                 * (dt_t * u_t.astype(F32))[:, None, :])
+        y = jnp.sum(state * c_t.astype(F32)[:, :, None], axis=1)
+        states = jnp.where((s_r == t)[:, None, None], state[b_r], states)
+        return (state, states), y.astype(u.dtype)
+
+    steps = lambda a: jnp.moveaxis(a, 1, 0)  # noqa: E731
+    (_, states), y = jax.lax.scan(
+        step, (jnp.zeros((Bt, N, C), F32), jnp.zeros((R, N, C), F32)),
+        (steps(u), steps(dt.astype(F32)), steps(Bm), steps(Cm), steps(first),
+         jnp.arange(S)))
+    return steps(y), states
+
+
+# The admission kernel's tiles: tokens a grid step, channels a register
+# block (the state's [16, 512] float32 is eight registers), tokens whose
+# rows are loaded and stored together (one bfloat16 tile of sublanes).
+MAMBA1_CHUNK = 128
+MAMBA1_LANES = 512
+MAMBA1_GROUP = 16
+# The most bytes of wanted states the kernel keeps in VMEM (its output
+# block, resident over the call): 25 lanes at jamba's widths.
+MAMBA1_STATES_VMEM_BYTES = 8 << 20
+
+
+def mamba1_scan_form(channels: int, state: int, wanted: int,
+                     platform: str | None = None) -> str:
+    """``"kernel"`` or ``"xla"``: which form the Mamba-1 admission scan of a
+    call that wants ``wanted`` final states takes.  The kernel is for the
+    TPU, channels in whole lane tiles, a state of whole sublane tiles, and at
+    most ``MAMBA1_STATES_VMEM_BYTES`` of wanted states."""
+    if ((platform or jax.default_backend()) == "tpu"
+            and channels % 128 == 0 and state % 8 == 0
+            and wanted * state * channels * 4 <= MAMBA1_STATES_VMEM_BYTES):
+        return "kernel"
+    return "xla"
+
+
+def _mamba1_scan_kernel(code_ref, u_ref, dt_ref, bt_ref, ct_ref, a_ref,
+                        y_ref, st_ref, state, bcol, ccol, *, lanes: int,
+                        wanted: int):
+    """One chunk of one row, every channel (``mamba1_scan``'s arithmetic).
+    ``state`` [N, C] float32 carries the row's state across its chunks;
+    ``code_ref`` holds a token's ``first`` in bit 0 and, above it, the
+    wanted state taken after it (``wanted`` = none)."""
+    b, c = pl.program_id(0), pl.program_id(1)
+    N, Q = bt_ref.shape[1:]
+    C = u_ref.shape[-1]
+    G = MAMBA1_GROUP
+    base = (b * pl.num_programs(1) + c) * Q
+
+    @pl.when((b == 0) & (c == 0))
+    def _():
+        st_ref[...] = jnp.zeros(st_ref.shape, F32)
+
+    @pl.when(c == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, F32)
+
+    # Every token's B and C as columns [N, 128], from the chunk's [N, Q]
+    # tiles at once, ahead of the channels that all use them.
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, Q), 1)
+    b_tile, c_tile = bt_ref[0].astype(F32), ct_ref[0].astype(F32)
+
+    def columns(t, _):
+        pick = at == t
+        for tile, col in ((b_tile, bcol), (c_tile, ccol)):
+            col[t] = jnp.broadcast_to(
+                jnp.sum(jnp.where(pick, tile, 0.0), axis=1, keepdims=True),
+                (N, 128))
+        return 0
+
+    jax.lax.fori_loop(0, Q, columns, 0)
+    wide = lambda col: jnp.concatenate([col] * (lanes // 128), axis=1)  # noqa: E731
+    sub = jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
+
+    # Rolled loops over the channels' blocks and the chunk's groups of
+    # tokens: the kernel's text is lowered once a Mamba-1 layer and rung.
+    def block(j, _):
+        here = pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
+        a = a_ref[:, here]                                   # [N, lanes]
+
+        def group(g, s):
+            t0 = pl.multiple_of(g * G, G)
+            dts = dt_ref[0, pl.ds(t0, G), here]               # [G, lanes]
+            dtu = dts * u_ref[0, pl.ds(t0, G), here].astype(F32)
+            ys = jnp.zeros((G, lanes), F32)
+            for i in range(G):
+                code = code_ref[base + t0 + i]
+                s = (jnp.where((code & 1) == 1, 0.0, s)
+                     * jnp.exp(a * dts[i:i + 1])
+                     + wide(bcol[t0 + i]) * dtu[i:i + 1])
+                ys = jnp.where(sub == i, jnp.sum(
+                    s * wide(ccol[t0 + i]), axis=0, keepdims=True), ys)
+                r = code >> 1
+
+                @pl.when(r < wanted)
+                def _(s=s, r=r):
+                    st_ref[r, :, here] = s
+
+            y_ref[0, pl.ds(t0, G), here] = ys.astype(y_ref.dtype)
+            return s
+
+        state[:, here] = jax.lax.fori_loop(0, Q // G, group, state[:, here])
+        return 0
+
+    jax.lax.fori_loop(0, C // lanes, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def mamba1_scan_kernel(u, dt, A, Bm, Cm, first, last, *,
+                       chunk: int = MAMBA1_CHUNK, interpret: bool = False):
+    """``mamba1_scan`` as one Pallas kernel (``mamba1_scan`` in a trace;
+    ``mamba1_scan_form`` says where it runs): the same arguments, results
+    and roundings.  A grid step is one chunk of ``chunk`` tokens of one row
+    over every channel; the state and the chunk's columns of ``B`` and ``C``
+    live in VMEM alone, the state in registers while a block of channels
+    runs down the chunk's tokens."""
+    Bt, S, C = u.shape
+    N, R = A.shape[0], last.shape[0]
+    Q = chunk
+    lanes = next(w for w in (MAMBA1_LANES, 256, 128) if C % w == 0)
+    nC = -(-S // Q)
+    St = nC * Q
+    if St != S:
+        widen = lambda a: jnp.pad(  # noqa: E731
+            a, [(0, 0), (0, St - S)] + [(0, 0)] * (a.ndim - 2))
+        u, dt, Bm, Cm, first = (widen(a) for a in (u, dt, Bm, Cm, first))
+    # Where the wanted states are taken: the first of the rows that want a
+    # token's state is written (an idle row's index may repeat a real one).
+    at = jnp.clip(last, 0, Bt * S - 1)
+    at = (at // S) * St + at % S
+    slot = jnp.full((Bt * St,), R, jnp.int32).at[at].min(
+        jnp.arange(R, dtype=jnp.int32))
+    code = slot * 2 + first.reshape(-1).astype(jnp.int32)
+    row = pl.BlockSpec((1, Q, C), lambda b, c, *_: (b, c, 0))
+    col = pl.BlockSpec((1, N, Q), lambda b, c, *_: (b, 0, c))
+    isz = jnp.dtype(u.dtype).itemsize
+    y, states = pl.pallas_call(
+        functools.partial(_mamba1_scan_kernel, lanes=lanes, wanted=R),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Bt, nC),
+            in_specs=[row, row, col, col,
+                      pl.BlockSpec((N, C), lambda b, c, *_: (0, 0))],
+            out_specs=[row, pl.BlockSpec((R, N, C),
+                                         lambda b, c, *_: (0, 0, 0))],
+            scratch_shapes=[pltpu.VMEM((N, C), F32),
+                            pltpu.VMEM((Q, N, 128), F32),
+                            pltpu.VMEM((Q, N, 128), F32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((Bt, St, C), u.dtype),
+                   jax.ShapeDtypeStruct((R, N, C), F32)],
+        compiler_params=pltpu.CompilerParams(
+            # The wanted states are one block over the whole grid.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # u, y and dt blocks, B and C, A, the wanted states, each
+            # double-buffered; the state and the columns; slack.
+            vmem_limit_bytes=(2 * Q * C * (2 * isz + 4) + 4 * N * Q * 4
+                              + 2 * (R + 1) * N * C * 4 + N * C * 4
+                              + 2 * Q * N * 128 * 4 + (16 << 20))),
+        interpret=interpret,
+        name="mamba1_scan",
+    )(code, u, dt.astype(F32), jnp.swapaxes(Bm, 1, 2).astype(F32),
+      jnp.swapaxes(Cm, 1, 2).astype(F32), A.astype(F32))
+    return y[:, :S], states
+
+
+def mamba1_decode_update_xla(pool, lanes, dt, dtu, A, Bm, Cm):
+    """``mamba1_decode_update`` in XLA operations (gathers the lanes' states
+    and scatters them back): the oracle, and the path off the TPU."""
+    s = (pool[lanes] * jnp.exp(dt[:, None, :] * A)
+         + Bm.astype(F32)[:, :, None] * dtu[:, None, :])
+    y = jnp.sum(s * Cm.astype(F32)[:, :, None], axis=1)
+    return y, pool.at[lanes].set(s, mode="drop")
+
+
+def _mamba1_decode_kernel(lanes_ref, dt_ref, dtu_ref, b_ref, c_ref, a_ref,
+                          s_ref, y_ref, o_ref, *, lanes: int):
+    """One lane's state ``[N, C]``: ``s <- s * exp(dt A) + B (x) dtu``,
+    ``y = sum_n s * C``, a block of channels at a time.  ``b_ref`` /
+    ``c_ref``: the lane's ``B`` and ``C`` as columns ``[N, 128]``."""
+    del lanes_ref                                   # the index maps' alone
+    C = s_ref.shape[-1]
+    wide = lambda col: jnp.concatenate([col] * (lanes // 128), axis=1)  # noqa: E731
+    bcol, ccol = wide(b_ref[0]), wide(c_ref[0])
+
+    def block(j, _):
+        here = pl.ds(pl.multiple_of(j * lanes, lanes), lanes)
+        s = (s_ref[0, :, here] * jnp.exp(a_ref[:, here] * dt_ref[0, :, here])
+             + bcol * dtu_ref[0, :, here])
+        o_ref[0, :, here] = s
+        y_ref[0, :, here] = jnp.sum(s * ccol, axis=0, keepdims=True)
+        return 0
+
+    jax.lax.fori_loop(0, C // lanes, block, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mamba1_decode_update(pool, lanes, dt, dtu, A, Bm, Cm, *,
+                         interpret: bool = False):
+    """One decode step of Mamba-1's recurrence for ``B`` lanes, on the pool
+    in place.
+
+    Args:
+      pool: [L, N, C] float32 — the layer's state pool; donated by the
+        caller's program, aliased to the second result.
+      lanes: [B] int32 — the pool lane of each batch row (distinct).
+      dt: [B, C] float32 step sizes; **0 for an idle row**.
+      dtu: [B, C] float32 ``dt * u``; **0 for an idle row** (with ``dt`` 0
+        the row's state is written back as it was read).
+      A: [N, C] float32.
+      Bm, Cm: [B, N].
+
+    Returns:
+      (y [B, C] float32 without the ``D * u`` term, the pool).
+    """
+    L, N, C = pool.shape
+    B = dt.shape[0]
+    lanes_c = next(w for w in (MAMBA1_LANES, 256, 128) if C % w == 0)
+    columns = lambda m: jnp.broadcast_to(  # noqa: E731
+        m.astype(F32)[:, :, None], (B, N, 128))
+    row_spec = pl.BlockSpec((1, 1, C), lambda b, lanes: (b, 0, 0))
+    col_spec = pl.BlockSpec((1, N, 128), lambda b, lanes: (b, 0, 0))
+    pool_spec = pl.BlockSpec((1, N, C), lambda b, lanes: (lanes[b], 0, 0))
+    lane_bytes = N * C * 4
+    y, pool = pl.pallas_call(
+        functools.partial(_mamba1_decode_kernel, lanes=lanes_c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[row_spec, row_spec, col_spec, col_spec,
+                      pl.BlockSpec((N, C), lambda b, lanes: (0, 0)),
+                      pool_spec],
+            out_specs=[row_spec, pool_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, C), F32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # The pool (input 6, after the scalar-prefetch operand) is result 1.
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # A lane in and out and A, each double-buffered; slack.
+            vmem_limit_bytes=6 * lane_bytes + (8 << 20)),
+        interpret=interpret,
+        name="mamba1_decode_update",
+    )(lanes.astype(jnp.int32), dt.astype(F32)[:, None], dtu.astype(F32)[:, None],
+      columns(Bm), columns(Cm), A.astype(F32), pool)
+    return y[:, 0], pool

@@ -483,8 +483,9 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     "index_tokens", "sel_tokens", "window_tokens",
                     "attn_select_form",
                     # Admission and chunk calls of a description with
-                    # Mamba-2 layers: "kernel" | "xla", the form of their
-                    # chunked scan (ops/ssm.py:scan_form).
+                    # recurrent layers: "kernel" | "xla", the form of their
+                    # admission scan (ops/ssm.py:scan_form for Mamba-2's
+                    # chunked scan, mamba1_scan_form for Mamba-1's).
                     "ssm_scan_form"),
     "xla.compile": ("seconds", "program"),
 }
@@ -949,10 +950,12 @@ class InferenceEngine:
                                            mode=ec.decode_path,
                                            kv_quant=self.kv_quant)
         self._attn_impl = attn_impl
-        # The recurrent layers' one-step state update: the Pallas kernel on
-        # the pool in place on a TPU, its XLA form elsewhere.
+        # The recurrent layers' one-step state update, of their mixer's
+        # kind: the Pallas kernel on the pool in place on a TPU, its XLA
+        # form elsewhere.
         from k8s_llm_monitor_tpu.ops.ssm import select_ssm_update
-        self._ssm_update = select_ssm_update()
+        self._ssm_update = select_ssm_update(
+            mixer=cfg.state_mixer or "mamba2")
         # The indexer's decode-step scores over the index-key pages, picked
         # as the latent attention beside them is.
         from k8s_llm_monitor_tpu.ops.attention import select_index_scores_impl
@@ -1302,9 +1305,10 @@ class InferenceEngine:
         # with an indexer by the form of their selected attention.
         self.sel_totals = dict.fromkeys(SEL_COUNTS, 0)
         self.attn_select_calls = {"mask": 0}
-        # Admission and chunk calls of a description with Mamba-2 layers by
-        # the form of their chunked scan (models/llama.py:scan_form on the
-        # call's rows).
+        # Admission and chunk calls of a description with recurrent layers
+        # by the form of their admission scan (models/llama.py:scan_form on
+        # the call's rows: Mamba-2's chunked scan or Mamba-1's selective
+        # scan, kernel or XLA).
         self.ssm_scan_calls = {"kernel": 0, "xla": 0}
         self._scan_form_of: dict[int, str] = {}
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
@@ -1347,7 +1351,9 @@ class InferenceEngine:
         those with it)."""
         parts = []
         if cfg.recurrent:
-            parts.append("recurrent state (a per-lane state pool)")
+            mixer = {"mamba1": "Mamba-1", "mamba2": "Mamba-2"}[cfg.state_mixer]
+            parts.append(f"recurrent state (a per-lane state pool of "
+                         f"{mixer} layers)")
         if cfg.latent:
             parts.append("a latent (compressed-KV) pool")
         if cfg.layers_with("window"):

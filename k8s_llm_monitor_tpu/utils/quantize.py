@@ -123,6 +123,14 @@ def quantize_params(params: Params) -> Params:
             for name in ("kv_norm", "kv_b", "q_norm", "idx_k_norm"):
                 if name in layer:
                     ql[name] = layer[name]
+        elif "x_proj" in layer:
+            # Mamba-1 mixer: its four projections quantize like any; the
+            # convolution, the recurrence's vectors and the norms stay wide.
+            for name, p in layer.items():
+                if name in _MAMBA1_LINEARS:
+                    ql[name] = quantize_linear(p)
+                elif name in _MAMBA1_WIDE:
+                    ql[name] = p
         else:
             for name in ("q", "k", "v", "o"):
                 ql[name] = quantize_linear(layer[name])
@@ -153,6 +161,12 @@ def quantize_params(params: Params) -> Params:
 # A latent mixer's projections that take int8 kernels.
 _LATENT_LINEARS = ("q", "q_a", "q_b", "kv_a", "o", "attn_gate", "idx_q",
                    "idx_k", "idx_w")
+
+# A Mamba-1 mixer's projections, and what it keeps wide (float32: the
+# convolution and the recurrence's per-channel vectors; the norms).
+_MAMBA1_LINEARS = ("in_proj", "x_proj", "dt_proj", "out_proj")
+_MAMBA1_WIDE = ("conv", "dt_bias", "A_log", "D", "dt_norm", "B_norm",
+                "C_norm")
 
 # What a one-sub-block layer (``ModelConfig.layer_pattern``) keeps wide: the
 # norms, the router, and a Mamba-2 mixer's convolution and per-head vectors
@@ -244,7 +258,12 @@ def init_params_quantized(rng: jax.Array, cfg: ModelConfig) -> Params:
             continue
         layer: Params = {"input_norm": jnp.ones((H,), dtype),
                          "post_norm": jnp.ones((H,), dtype)}
-        if spec.mixer == "latent" and cfg.latent_geometry(i).q_lora_rank:
+        if spec.mixer == "mamba1":
+            from k8s_llm_monitor_tpu.models.llama import init_mamba1_mixer
+
+            layer.update(init_mamba1_mixer(
+                jax.random.fold_in(keys[2 + i], 3), cfg, qdense))
+        elif spec.mixer == "latent" and cfg.latent_geometry(i).q_lora_rank:
             from k8s_llm_monitor_tpu.models.llama import init_latent_mixer
 
             layer.update(init_latent_mixer(

@@ -526,6 +526,69 @@ def test_attention_kernels_compile_at_sixteen_queries_a_kv_head(one_chip,
                  pool, pool, S((2, 224), I32), S((2,), I32), S((2,), I32))
 
 
+# -- the jamba cell's kernels ------------------------------------------------
+
+JCFG = PRESETS["jamba2-3b"]
+
+
+@pytest.mark.parametrize("tokens,wanted", [(4096, 8), (8192, 8)],
+                         ids=["t4096", "t8192"])
+def test_mamba1_scan_kernel_compiles_at_the_served_cell(one_chip, tokens,
+                                                        wanted):
+    """The Mamba-1 admission scan as the chip's program takes it
+    (ops/ssm.py:mamba1_scan_form says "kernel" at the served widths on a
+    TPU): a packed stream of 5,120 channels, the state of 16, at T = 4,096
+    and at the cell's largest rung (8,192, 8 prompts), bfloat16 inputs; the
+    kernel's custom call is in the program under its own name."""
+    from k8s_llm_monitor_tpu.ops import ssm
+
+    S = one_chip
+    C, N = JCFG.mamba_inner, JCFG.ssm_state_size
+    assert ssm.mamba1_scan_form(C, N, wanted, platform="tpu") == "kernel"
+    compiled = jax.jit(ssm.mamba1_scan_kernel).lower(
+        S((1, tokens, C), BF16), S((1, tokens, C), F32), S((N, C), F32),
+        S((1, tokens, N), F32), S((1, tokens, N), F32),
+        S((1, tokens), jnp.bool_), S((wanted,), I32)).compile()
+    assert re.search(r"%mamba1_scan[.\d]* = .*custom-call", compiled.as_text())
+
+
+def test_mamba1_decode_update_compiles_at_the_served_cell(one_chip):
+    """The Mamba-1 state update on the served pool, in place: 256 lanes of
+    16 x 5,120 float32; no temporary the size of the pool."""
+    from k8s_llm_monitor_tpu.ops import ssm
+
+    S = one_chip
+    C, N = JCFG.mamba_inner, JCFG.ssm_state_size
+    compiled = jax.jit(ssm.mamba1_decode_update, donate_argnums=(0,)).lower(
+        S((256, N, C), F32), S((256,), I32), S((256, C), F32),
+        S((256, C), F32), S((N, C), F32), S((256, N), F32),
+        S((256, N), F32)).compile()
+    assert "mamba1_decode_update" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("kernel", ["fused_decode", "flash_prefill_packed"])
+def test_attention_kernels_compile_at_twenty_queries_a_kv_head(one_chip,
+                                                               kernel):
+    """20 q / 1 kv head x 128 (no multiple of 8 query heads a kv head): the
+    fused decode kernel on the served pool of 65,537 blocks, 256 lanes and
+    256-block tables, and the flash kernel over a packed stream of 8,192
+    tokens."""
+    S = one_chip
+    nH, nKV, Dh = JCFG.num_heads, JCFG.num_kv_heads, JCFG.head_dim_
+    assert ops._pallas_geometry_ok(JCFG, 1)
+    pool = S((65_537, 16, nKV * Dh), BF16)
+    if kernel == "fused_decode":
+        _compile(pa.paged_decode_attention_fused,
+                 S((256, 1, nH, Dh), BF16), S((256, 1, nKV, Dh), BF16),
+                 S((256, 1, nKV, Dh), BF16), S((256, 1, Dh), F32),
+                 S((256, 1, Dh), F32), pool, pool, S((256, 256), I32),
+                 S((256,), I32))
+    else:
+        _compile(pa.flash_prefill_attention_packed, S((8192, nH, Dh), BF16),
+                 pool, pool, S((8, 256), I32), S((8,), I32), S((8,), I32))
+
+
 # -- PR 32: the decode step's expert products ---------------------------------
 
 
