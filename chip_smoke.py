@@ -242,6 +242,75 @@ def kernel_checks(cfg, seed: int, num_blocks: int, bs: int, max_blocks: int,
                list(positions[:8]), [5, 5, 3, 5, 1, 5, 5, 2], True)
 
 
+def fused_run_checks(cfg, seed: int, lanes: int, max_blocks: int,
+                     num_blocks: int, mean_tokens: int,
+                     reps: int = 20) -> None:
+    """The fused decode kernel at ``cfg``'s heads on ``lanes`` lanes of about
+    ``mean_tokens`` cached tokens, on tables the allocator lays out in runs
+    of 8 blocks (a group is ONE copy an array) and on the same pool with
+    every block id moved at random (a copy a page): both outputs bit-equal,
+    and each form's time a lane (in place, ``reps`` calls)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from k8s_llm_monitor_tpu.ops import pallas_attention as pa
+    from k8s_llm_monitor_tpu.ops.rope import rope_angles
+    from k8s_llm_monitor_tpu.serving.kv_cache import BlockAllocator
+
+    H, KVH, D, bs = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_, 16
+    interpret = jax.default_backend() != "tpu"
+    rng = np.random.default_rng(seed)
+    cap = max_blocks * bs - 1
+    pos = np.clip(rng.integers(1, 2 * mean_tokens, size=lanes), 1, cap)
+    alloc = BlockAllocator(num_blocks, bs, table_blocks=max_blocks)
+    table = np.zeros((lanes, max_blocks), np.int32)
+    for b, p in enumerate(pos):
+        row = alloc.alloc(int(p) + 1, at=0)
+        table[b, :len(row)] = row
+    move = np.concatenate([[0], 1 + rng.permutation(num_blocks - 1)])
+    tables = {"runs": table, "pages": move[table].astype(np.int32)}
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    bf16 = jnp.bfloat16
+
+    def normal(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(bf16)
+
+    q, kn, vn = (normal(keys[0], lanes, 1, H, D),
+                 normal(keys[1], lanes, 1, KVH, D),
+                 normal(keys[2], lanes, 1, KVH, D))
+    cos, sin = rope_angles(jnp.asarray(pos)[:, None], D, 10_000.0)
+    inverse = jnp.asarray(np.argsort(move).astype(np.int32))
+    kernel = jax.jit(functools.partial(pa.paged_decode_attention_fused,
+                                       interpret=interpret),
+                     donate_argnums=(5, 6))
+    out, times = {}, {}
+    for form, tbl in tables.items():
+        kp = normal(keys[3], num_blocks, bs, KVH * D)
+        vp = normal(keys[4], num_blocks, bs, KVH * D)
+        if form == "pages":
+            kp, vp = jnp.take(kp, inverse, axis=0), jnp.take(vp, inverse, axis=0)
+        groups, runs = pa.fetch_groups(tbl, pos, bs)
+        args = (jnp.asarray(tbl), jnp.asarray(pos, jnp.int32))
+        got, kp, vp = kernel(q, kn, vn, cos, sin, kp, vp, *args)
+        out[form] = np.asarray(got)
+        jax.block_until_ready((kp, vp))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            _, kp, vp = kernel(q, kn, vn, cos, sin, kp, vp, *args)
+        jax.block_until_ready((kp, vp))
+        times[form] = (time.perf_counter() - t0) / reps
+        say(f"  fused decode, {form}: {H} q / {KVH} kv x {D}, {lanes} lanes "
+            f"of {pos.mean():.0f} cached tokens, {runs} of {groups} groups "
+            f"one copy: {times[form] * 1e3:.4f} ms a call, "
+            f"{times[form] / lanes * 1e6:.3f} us a lane")
+        del kp, vp
+    same = np.array_equal(out["runs"], out["pages"])
+    say(f"  fused decode: runs and pages {'bit-equal' if same else 'DIFFER'}; "
+        f"pages / runs = {times['pages'] / times['runs']:.3f}")
+    assert same, "fused decode: a run's copy changed the output"
+
+
 def state_kernel_checks(cfg, seed: int, lanes: int,
                         tokens: int = 3072) -> None:
     """The recurrent-state kernels at ``cfg``'s widths (a description with
@@ -853,6 +922,9 @@ def one_chip(seed: int, model: str = MODEL, max_tokens: int = 9,
             f"{tcfg.max_batch} lanes, atol=rtol={ATOL}")
         kernel_checks(PRESETS[model], seed, tcfg.kv_blocks, 16, 64,
                       tcfg.max_batch)
+        # The fused decode kernel's run copies against a copy a page, at
+        # the Qwen2 cell's call (64 lanes, 96-block tables, 6,144 blocks).
+        fused_run_checks(PRESETS[model], seed, 64, 96, 6144, 530)
         # The served nemotron_h description's own kernel, and the attention
         # kernels at its head shape (16 query heads a kv head).
         hybrid = PRESETS["nemotron3-super-120b-a12b-22l"]
@@ -870,6 +942,7 @@ def one_chip(seed: int, model: str = MODEL, max_tokens: int = 9,
         # head).
         jamba = PRESETS["jamba2-3b"]
         mamba1_kernel_checks(jamba, seed, 256)
+        fused_run_checks(jamba, seed, 256, 256, 65_537, 750)
         kernel_checks(jamba, seed, tcfg.kv_blocks, 16, 64, tcfg.max_batch)
         say(f"phase kernels: ok in {time.monotonic() - t0:.1f} s "
             "(information)")

@@ -419,6 +419,14 @@ def _loop_metrics(w: _Writer, engine) -> None:
                  "kernel = ssm_chunk_scan, a chunk's decay tiles and the "
                  "carried state in VMEM; xla = einsums over blocks of chunks",
                  [(f'{{form="{k}"}}', n) for k, n in sorted(scan.items())])
+    groups = getattr(engine, "kv_page_groups", None)
+    if groups is not None and not engine.cfg.latent:
+        w.metric("engine_kv_page_groups_total", "counter",
+                 "Fetch groups of 8 pages the fused decode kernel streamed "
+                 "over decode calls' cached pages, by form: run = the "
+                 "group's block ids are consecutive, one copy an array; "
+                 "pages = a copy a page",
+                 [(f'{{form="{k}"}}', n) for k, n in sorted(groups.items())])
     if getattr(engine, "_sel_counted", False):
         # A description with an indexer or window layers: its calls bring
         # these back with their result (models/llama.py:_sel_counts).

@@ -29,8 +29,10 @@ The kernels:
     DMAs are ONE pipeline across a program's lanes: every lane's append tile
     is read at program start, window 0 of lane t+1 is in flight while lane
     t multiplies its last window, windows are ``_FUSED_WINDOW`` = 32 pages
-    fetched in groups of 8 with one wait a group, and groups past a lane's
-    pages are skipped.  The hazard argument is in the kernel's docstring.
+    fetched in groups of 8 with one wait a group — a group of consecutive
+    block ids, which serving/kv_cache.py lays a lane's pages out in, as one
+    copy an array — and groups past a lane's pages are skipped.  The hazard
+    argument is in the kernel's docstring.
     On a v5e at the served Qwen2-7B cell's shape (64 lanes of ~590 cached
     tokens, 28 q / 4 kv heads x 128, bf16 pool) it takes 2.4-2.5 us a
     lane, 52-54% of the time 819 GB/s needs for the live pages; the
@@ -59,6 +61,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -83,7 +86,61 @@ _WINDOW = 8
 # whole: 16 pages 2.78, 32 2.70; in groups of 8: 16 pages 2.67, 32 2.40, 48
 # 2.73 (32 in groups of 4: 3.02); the parent's pipeline at 8 pages 4.07.
 _FUSED_WINDOW = 32
-_FUSED_GROUP = 8
+FUSED_GROUP = 8
+# A copy costs the kernel its descriptor on the scalar core whatever its
+# size, so a group whose table entries are consecutive block ids is fetched as
+# one copy an array; serving/kv_cache.py lays a lane's pages out in such runs
+# of FUSED_GROUP blocks.  On a v5e at 20 q / 1 kv heads x 128 (4 KB pages),
+# 256 lanes of ~730 cached tokens, that took the kernel from ~2.8 to ~1.6 us
+# a lane inside the decode program; at 28 q / 4 kv x 128 (16 KB pages) it
+# moved ~6%: the products set the pace there.
+
+
+def _fetch_group(NB: int) -> int:
+    """Pages of one fetch group of the fused decode kernel for a table of
+    ``NB`` entries: FUSED_GROUP, or a whole window where that does not
+    divide it."""
+    W = min(_FUSED_WINDOW, NB)
+    return FUSED_GROUP if W % FUSED_GROUP == 0 else W
+
+
+def group_is_run(entry, G: int):
+    """Whether a fetch group's ``G`` table entries, ``entry(0)`` ..
+    ``entry(G - 1)``, are consecutive block ids: the fused decode kernel
+    then fetches the group as ONE copy an array.  The one predicate of the
+    kernel (on scalars of its prefetched table) and of the host's count
+    (``fetch_groups``, on every group of a batch of tables at once)."""
+    first = entry(0)
+    run = entry(1) == first + 1
+    for i in range(2, G):
+        run = run & (entry(i) == first + i)
+    return run
+
+
+def fetch_groups(table, cached, block_size: int) -> tuple[int, int]:
+    """(groups, run groups) the fused decode kernel fetches for block
+    tables ``table`` [B, NB] at ``cached`` tokens a lane (``[B]``, or
+    ``[steps, B]`` for a call of several steps; 0 = an inactive lane): a
+    lane streams ``ceil(ceil(cached / bs) / G)`` groups, and those whose
+    entries are consecutive are one copy an array (``group_is_run``).  Past
+    the table the kernel repeats its last entry, and so does this."""
+    table = np.asarray(table)
+    B, NB = table.shape
+    G = _fetch_group(NB)
+    if G == 1:
+        runs = np.ones((B, NB), bool)
+    else:
+        n_groups = -(-NB // G)
+        padded = np.concatenate(
+            [table, np.repeat(table[:, -1:], n_groups * G - NB, axis=1)],
+            axis=1).reshape(B, n_groups, G)
+        runs = group_is_run(lambda i: padded[:, :, i], G)
+    runs_upto = np.concatenate(
+        [np.zeros((B, 1), np.int64), np.cumsum(runs, axis=1)], axis=1)
+    live = -(-np.asarray(cached, np.int64) // block_size)
+    live = -(-live // G)
+    got = np.take_along_axis(runs_upto, np.atleast_2d(live).T, axis=1)
+    return int(live.sum()), int(got.sum())
 
 
 def _paged_attn_kernel(
@@ -1262,8 +1319,9 @@ def _fused_decode_kernel(
         none) window 0 of lane t+1 is copied into the free slot; ``slot0``
         carries the slot parity across lanes;
       * a window is ``_FUSED_WINDOW`` pages deep, fetched in groups of
-        ``_FUSED_GROUP`` with one wait a group and array; groups past the
-        lane's pages are neither fetched nor waited for.
+        ``FUSED_GROUP`` with one wait a group and array — a group whose
+        block ids are consecutive (``group_is_run``) as one copy an array;
+        groups past the lane's pages are neither fetched nor waited for.
 
     Why nothing races.  A lane appends to its tail block, which it alone
     owns, and prefix blocks that lanes share are never appended to: the
@@ -1291,10 +1349,10 @@ def _fused_decode_kernel(
     R = _append_tile_rows(bs, k_hbm.dtype)
 
     def scoped(k_buf, v_buf, k_tiles, v_tiles, sem, append_sem):
-        G = _FUSED_GROUP if W % _FUSED_GROUP == 0 else W   # pages a group
+        G = _fetch_group(NB)                             # pages a group
 
-        def group_rows(gi):
-            return pl.ds(pl.multiple_of(gi * (G * bs), G * bs), G * bs)
+        def group_pages(gi):
+            return pl.ds(pl.multiple_of(gi * G, G), G)
 
         def live_groups(w, n_blk):
             # Groups of window ``w`` that hold pages of a lane with
@@ -1305,32 +1363,55 @@ def _fused_decode_kernel(
         def start_window(slot, b, w, n_blk):
             # The page copies of window ``w`` of lane ``b``, both arrays, a
             # group of G a turn of a rolled loop (its trip count is the
-            # window's live groups; the body is the parent's 8-page burst,
-            # so the kernel's text, which is lowered 28 x 8 times a decode
-            # program, does not grow with the window).  A group past the
-            # lane's pages is not fetched; its V rows are zeroed instead,
-            # because a probability of exactly 0 times whatever an earlier
-            # window left there must stay 0 (K's stale rows give scores
-            # that the position mask replaces).  A slot's K copies signal
-            # one semaphore and its V copies another.
+            # window's live groups; the body is one group's copies, so the
+            # kernel's text, which is lowered 28 x 8 times a decode
+            # program, does not grow with the window).  A group whose G
+            # table entries are consecutive block ids is ONE copy an array
+            # (the allocator lays a lane's pages out in such runs), any
+            # other G copies of a page: the same rows land in the slot
+            # either way.  A group past the lane's pages is not fetched;
+            # its V rows are zeroed instead, because a probability of
+            # exactly 0 times whatever an earlier window left there must
+            # stay 0 (K's stale rows give scores that the position mask
+            # replaces).  A slot's K copies signal one semaphore and its V
+            # copies another.
             live = live_groups(w, n_blk)
 
             def fetch(gi, _):
-                j0, row0 = w * W + gi * G, gi * (G * bs)
-                for i in range(G):
+                j0, page0 = w * W + gi * G, gi * G
+
+                def entry(i):
                     j = j0 + i
-                    blk = tables_ref[b, j if NB % W == 0 else
-                                     jax.lax.min(j, NB - 1)]
-                    rows = pl.ds(pl.multiple_of(row0 + i * bs, bs), bs)
-                    pltpu.make_async_copy(k_hbm.at[blk], k_buf.at[slot, rows],
+                    return tables_ref[b, j if NB % W == 0 else
+                                      jax.lax.min(j, NB - 1)]
+
+                def copy(src_k, src_v, pages):
+                    pltpu.make_async_copy(src_k, k_buf.at[slot, pages],
                                           sem.at[slot, 0]).start()
-                    pltpu.make_async_copy(v_hbm.at[blk], v_buf.at[slot, rows],
+                    pltpu.make_async_copy(src_v, v_buf.at[slot, pages],
                                           sem.at[slot, 1]).start()
+
+                def by_page():
+                    for i in range(G):
+                        blk = entry(i)
+                        copy(k_hbm.at[blk], v_hbm.at[blk], page0 + i)
+
+                if G == 1:
+                    by_page()
+                    return 0
+                run = group_is_run(entry, G)
+
+                @pl.when(run)
+                def _one_copy():
+                    blks = pl.ds(entry(0), G)
+                    copy(k_hbm.at[blks], v_hbm.at[blks], group_pages(gi))
+
+                pl.when(jnp.logical_not(run))(by_page)
                 return 0
 
             def clear(gi, _):
-                v_buf[slot, group_rows(gi), :] = jnp.zeros((G * bs, F),
-                                                           v_buf.dtype)
+                v_buf[slot, group_pages(gi)] = jnp.zeros((G, bs, F),
+                                                         v_buf.dtype)
                 return 0
 
             jax.lax.fori_loop(0, live, fetch, 0)
@@ -1338,13 +1419,13 @@ def _fused_decode_kernel(
 
         def wait_window(slot, w, n_blk):
             # A DMA semaphore counts what has arrived: one wait sized as a
-            # group's rows takes its G page copies' signals (a wait a copy
-            # was 2 W scalar-core stalls a window, serial with the window's
-            # products).  The same trip count as the start: every started
-            # copy is waited for, and nothing else.
+            # group's rows takes its signals, from one copy or from G (a
+            # wait a copy was 2 W scalar-core stalls a window, serial with
+            # the window's products).  The same trip count as the start:
+            # every started copy is waited for, and nothing else.
             def arrived(gi, _):
                 for i, buf in enumerate((k_buf, v_buf)):
-                    view = buf.at[slot, group_rows(gi)]
+                    view = buf.at[slot, group_pages(gi)]
                     pltpu.make_async_copy(view, view, sem.at[slot, i]).wait()
                 return 0
 
@@ -1430,8 +1511,10 @@ def _fused_decode_kernel(
                 # The row being appended (p_idx == pos) is masked, so the
                 # in-flight append DMA can never race a row we consume.
                 valid = p_idx < pos
-                kblk = k_buf[slot].astype(jnp.float32)
-                vblk = v_buf[slot].astype(jnp.float32)
+                # [W, bs, F] -> [W * bs, F]: whole sublane tiles where bs
+                # is a multiple of the tile's rows (16 in bf16), no move.
+                kblk = k_buf[slot].reshape(W * bs, F).astype(jnp.float32)
+                vblk = v_buf[slot].reshape(W * bs, F).astype(jnp.float32)
                 s = jax.lax.dot_general(
                     qf, kblk, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
@@ -1472,8 +1555,8 @@ def _fused_decode_kernel(
 
     pl.run_scoped(
         scoped,
-        k_buf=pltpu.VMEM((2, W * bs, F), k_hbm.dtype),
-        v_buf=pltpu.VMEM((2, W * bs, F), v_hbm.dtype),
+        k_buf=pltpu.VMEM((2, W, bs, F), k_hbm.dtype),
+        v_buf=pltpu.VMEM((2, W, bs, F), v_hbm.dtype),
         k_tiles=pltpu.VMEM((TB, R, F), k_hbm.dtype),
         v_tiles=pltpu.VMEM((TB, R, F), v_hbm.dtype),
         sem=pltpu.SemaphoreType.DMA((2, 2)),

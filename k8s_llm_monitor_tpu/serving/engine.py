@@ -76,6 +76,7 @@ from k8s_llm_monitor_tpu.resilience.tenancy import (
     DEFAULT_TENANT,
     normalize_tenant,
 )
+from k8s_llm_monitor_tpu.ops.pallas_attention import fetch_groups
 from k8s_llm_monitor_tpu.ops.sampling import (
     fsm_advance,
     fsm_mask_logits,
@@ -486,7 +487,12 @@ SPAN_CATALOG: dict[str, tuple[str, ...]] = {
                     # recurrent layers: "kernel" | "xla", the form of their
                     # admission scan (ops/ssm.py:scan_form for Mamba-2's
                     # chunked scan, mamba1_scan_form for Mamba-1's).
-                    "ssm_scan_form"),
+                    "ssm_scan_form",
+                    # Decode calls of a model with paged keys and values:
+                    # the fused kernel's fetch groups over the lanes' cached
+                    # pages, and those fetched as one copy an array
+                    # (ops/pallas_attention.py:fetch_groups).
+                    "kv_groups", "kv_run_groups"),
     "xla.compile": ("seconds", "program"),
 }
 
@@ -913,7 +919,8 @@ class InferenceEngine:
             )
         self.params = params
         self.pages = pages
-        self.allocator = BlockAllocator(ec.num_blocks, ec.block_size)
+        self.allocator = BlockAllocator(ec.num_blocks, ec.block_size,
+                                        table_blocks=ec.max_blocks_per_seq)
         # No lookup for a description with a recurrent layer: a cached
         # block holds a prefix's keys and values, and nothing holds the
         # recurrent state at its end, so a hit could not be continued.
@@ -1311,6 +1318,12 @@ class InferenceEngine:
         # scan, kernel or XLA).
         self.ssm_scan_calls = {"kernel": 0, "xla": 0}
         self._scan_form_of: dict[int, str] = {}
+        # Fused decode calls' fetch groups of 8 pages over the lanes' cached
+        # pages (a model with paged keys and values), by whether the group's
+        # block ids are consecutive, so the kernel fetches it as one copy
+        # ("run") or page by page ("pages"): ops/pallas_attention.py:
+        # fetch_groups on the tables each call is dispatched with.
+        self.kv_page_groups = {"run": 0, "pages": 0}
         # Request-lifecycle histograms (observability/metrics.py): per-SLO
         # class, with exemplar trace ids, observed on the step thread only.
         # The exporter renders these as real Prometheus histograms.
@@ -1691,7 +1704,8 @@ class InferenceEngine:
             if not self._ensure_free(L + n_decode + 1 - start):
                 raise OutOfBlocks(
                     f"no room for {L + n_decode + 1 - start} tokens")
-            blocks = shared + self.allocator.alloc(L + n_decode + 1 - start)
+            blocks = shared + self.allocator.alloc(L + n_decode + 1 - start,
+                                                   at=len(shared))
         except BaseException:
             self.allocator.free(shared)
             raise
@@ -1919,6 +1933,10 @@ class InferenceEngine:
                     self.cfg, counts["rows"])
             self.ssm_scan_calls[form] += 1
             counts["ssm_scan_form"] = form
+        if "kv_groups" in counts:
+            self.kv_page_groups["run"] += counts["kv_run_groups"]
+            self.kv_page_groups["pages"] += (counts["kv_groups"]
+                                             - counts["kv_run_groups"])
         attrs = {"kind": kind, "program": program,
                  "call_id": self._next_call_id,
                  "device_empty": int(device_empty), **counts}
@@ -2827,7 +2845,8 @@ class InferenceEngine:
             if req.orig_prompt_len < 0:
                 req.orig_prompt_len = L
             try:
-                blocks = shared + self.allocator.alloc(L + 1 - shared_toks)
+                blocks = shared + self.allocator.alloc(L + 1 - shared_toks,
+                                                       at=len(shared))
             except OutOfBlocks:
                 # can_alloc said yes but alloc still failed (injected
                 # exhaustion, or a racing sharer): push back, end the scan.
@@ -3760,6 +3779,13 @@ class InferenceEngine:
         self._record_dispatch_ok()
         if self._faults.should_fire("decode_stuck"):
             payload = _StuckPayload(payload)
+        groups = {}
+        if kind == "decode" and not self.cfg.latent:
+            # What the fused kernel fetches at each step of the call: lane
+            # i at ctx[i] + s cached tokens while s < its steps.
+            s = np.arange(K)[:, None]
+            groups["kv_groups"], groups["kv_run_groups"] = fetch_groups(
+                table, np.where(s < steps_arr, ctx + s, 0), ec.block_size)
         self._inflight.append(_Inflight(
             kind=kind, call_id=self._next_call_id, arr=payload, lanes=meta,
             t0=t_dispatch,
@@ -3768,7 +3794,7 @@ class InferenceEngine:
                 sampler_filter=(None if all_greedy and not constrained
                                 else any_filtered),
                 steps=K, lanes=len(lanes), slots=B,
-                ctx_tokens=int(ctx.sum())),
+                ctx_tokens=int(ctx.sum()), **groups),
             moe_counts=self._take_moe_counts()))
         self._next_call_id += 1
         return True

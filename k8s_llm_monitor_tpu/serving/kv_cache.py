@@ -66,6 +66,7 @@ import hashlib
 
 import numpy as np
 
+from k8s_llm_monitor_tpu.ops.pallas_attention import FUSED_GROUP
 from k8s_llm_monitor_tpu.resilience.faults import get_injector
 from k8s_llm_monitor_tpu.resilience.tenancy import DEFAULT_TENANT, tenant_seed
 
@@ -102,62 +103,158 @@ def page_slice_bytes(num_kv_heads: int, head_dim: int, block_size: int,
             + 2 * block_size * heads * scale_bytes)
 
 
+# Blocks of a run: the fused decode kernel's fetch group.
+RUN_BLOCKS = FUSED_GROUP
+
+
 class OutOfBlocks(Exception):
     pass
 
 
 class BlockAllocator:
-    """Free-list allocator with per-block reference counts.
+    """Free-list allocator with per-block reference counts, laid out in runs.
 
     ``alloc``/``extend`` hand out blocks at refcount 1; ``incref`` adds
     sharers; ``free`` decrements and returns a block to the free list only
     when its count reaches zero.
+
+    Runs.  Block ids ``[R k, R k + R)`` for k >= 1 (``R = RUN_BLOCKS``) form
+    a *run*; block 0 is the null block and blocks 1 .. R - 1 are loose.  The
+    fused decode kernel fetches a group of R table entries as ONE copy when
+    their ids are consecutive (ops/pallas_attention.py:group_is_run), so a
+    lane's own part of its table is laid out a whole run per whole group of
+    R table positions: ``alloc(..., at=)`` and ``extend`` round the lane's
+    part up to the end of its last group (capped at ``table_blocks``), and
+    the blocks past its tokens are reserved for its growth — ``extend``
+    takes nothing until the lane crosses into the next group.  A whole
+    group takes a whole free run whenever there is one.  Every other block
+    (a group that holds shared prefix blocks, a table narrower than a
+    group, a pool with no whole run left) is handed out singly, from the
+    free blocks of runs that are not whole (partly pinned, say, by a prefix
+    entry that outlived its lane) and the loose ones, a whole run split only
+    when those are gone.  Rounding up only happens where it buys runs: when
+    the pool has too few whole runs (or blocks) for the rounded part,
+    exactly the blocks the tokens need are taken, so whether a request fits
+    never depends on runs (``can_alloc`` counts exactly that).  Reserved
+    blocks count as used.
     """
 
-    def __init__(self, num_blocks: int, block_size: int):
+    def __init__(self, num_blocks: int, block_size: int,
+                 table_blocks: int | None = None):
         if num_blocks < 2:
             raise ValueError("need at least 2 blocks (block 0 is reserved)")
         self.num_blocks = num_blocks
         self.block_size = block_size
-        self._free: list[int] = list(range(num_blocks - 1, 0, -1))  # pop -> 1,2,...
+        # Width of a lane's block table: rounding never reserves past it.
+        self.table_blocks = table_blocks
+        R = RUN_BLOCKS
+        self._runs_total = num_blocks // R     # run 0 holds the null block
+        # Whole free runs by index (pop -> 1, 2, ...), free blocks outside
+        # them (an ordered set: popitem -> the last one freed), and each
+        # run's free blocks, so a run that fills up again is whole again.
+        self._runs: list[int] = list(range(self._runs_total - 1, 0, -1))
+        self._run_free = [R] * self._runs_total
+        self._singles: dict[int, None] = dict.fromkeys(
+            [b for b in range(num_blocks - 1, 0, -1)
+             if not self._in_run(b)])
         self._refs: dict[int, int] = {}
+
+    def _in_run(self, block: int) -> bool:
+        return 0 < block // RUN_BLOCKS < self._runs_total
 
     @property
     def free_blocks(self) -> int:
-        return len(self._free)
+        return RUN_BLOCKS * len(self._runs) + len(self._singles)
 
     def blocks_for(self, num_tokens: int) -> int:
         return (num_tokens + self.block_size - 1) // self.block_size
 
     def can_alloc(self, num_tokens: int) -> bool:
-        return self.blocks_for(num_tokens) <= len(self._free)
+        """Whether ``alloc`` for ``num_tokens`` succeeds: it takes more than
+        the tokens need only when the more fits."""
+        return self.blocks_for(num_tokens) <= self.free_blocks
 
-    def alloc(self, num_tokens: int) -> list[int]:
-        n = self.blocks_for(num_tokens)
-        if get_injector().should_fire("alloc_exhaustion"):
-            raise OutOfBlocks(
-                f"injected exhaustion: need {n} blocks (fault point)")
-        if n > len(self._free):
-            raise OutOfBlocks(f"need {n} blocks, {len(self._free)} free")
-        out = [self._free.pop() for _ in range(n)]
+    def _span_end(self, start: int, need: int) -> int:
+        """End of a lane's part that covers table positions ``[start, start
+        + need)``: rounded up to the end of its last group of RUN_BLOCKS
+        (unless it ends inside the group ``start`` lies in, which holds
+        shared blocks), capped at the table's width — when a whole free run
+        is there for each whole group from the first boundary on, and the
+        rest fits too.  Otherwise exact: a reserved block that buys no run
+        would only cost capacity."""
+        R, end = RUN_BLOCKS, start + need
+        boundary = -(-start // R) * R
+        if end > boundary:
+            rounded = -(-end // R) * R
+            if self.table_blocks is not None:
+                rounded = max(end, min(rounded, self.table_blocks))
+            if ((rounded - boundary) // R <= len(self._runs)
+                    and rounded - start <= self.free_blocks):
+                end = rounded
+        return end
+
+    def _take_run(self) -> list[int]:
+        k = self._runs.pop()
+        self._run_free[k] = 0
+        return list(range(k * RUN_BLOCKS, (k + 1) * RUN_BLOCKS))
+
+    def _take_single(self) -> int:
+        if not self._singles:
+            # Split the last whole run: its head now, the rest in order.
+            first, *rest = self._take_run()
+            self._run_free[first // RUN_BLOCKS] = len(rest)
+            self._singles.update(dict.fromkeys(reversed(rest)))
+            return first
+        b, _ = self._singles.popitem()
+        if self._in_run(b):
+            self._run_free[b // RUN_BLOCKS] -= 1
+        return b
+
+    def _take(self, start: int, end: int) -> list[int]:
+        """Blocks for table positions ``[start, end)``: a whole run for each
+        whole group while one is free, single blocks for the rest."""
+        R, out, p = RUN_BLOCKS, [], start
+        while p < end:
+            q = min(end, (p // R + 1) * R)
+            if q - p == R and self._runs:
+                out += self._take_run()
+            else:
+                out += [self._take_single() for _ in range(q - p)]
+            p = q
         for b in out:
             self._refs[b] = 1
         return out
 
+    def alloc(self, num_tokens: int, at: int | None = None) -> list[int]:
+        """Blocks for ``num_tokens`` tokens.  ``at``: the table position the
+        first block takes in a lane's table (the shared prefix's blocks
+        before it), for a lane's own part, which is rounded up to whole
+        groups (class docstring).  Without it exactly the blocks the tokens
+        need (a span the prefix cache owns, written in one go)."""
+        n = self.blocks_for(num_tokens)
+        if get_injector().should_fire("alloc_exhaustion"):
+            raise OutOfBlocks(
+                f"injected exhaustion: need {n} blocks (fault point)")
+        if n > self.free_blocks:
+            raise OutOfBlocks(f"need {n} blocks, {self.free_blocks} free")
+        start = 0 if at is None else at
+        end = start + n if at is None else self._span_end(start, n)
+        return self._take(start, end)
+
     def extend(self, blocks: list[int], new_len: int) -> None:
-        """Grow ``blocks`` in place to cover ``new_len`` tokens."""
+        """Grow a lane's ``blocks`` in place to cover ``new_len`` tokens,
+        rounded up as ``alloc(..., at=len(blocks))`` rounds."""
         need = self.blocks_for(new_len) - len(blocks)
         if need <= 0:
             return
         if get_injector().should_fire("alloc_exhaustion"):
             raise OutOfBlocks(
                 f"injected exhaustion: need {need} more blocks (fault point)")
-        if need > len(self._free):
-            raise OutOfBlocks(f"need {need} more blocks, {len(self._free)} free")
-        for _ in range(need):
-            b = self._free.pop()
-            self._refs[b] = 1
-            blocks.append(b)
+        if need > self.free_blocks:
+            raise OutOfBlocks(
+                f"need {need} more blocks, {self.free_blocks} free")
+        start = len(blocks)
+        blocks += self._take(start, self._span_end(start, need))
 
     def incref(self, blocks: list[int]) -> None:
         for b in blocks:
@@ -175,8 +272,22 @@ class BlockAllocator:
             self._refs[b] -= 1
             if self._refs[b] == 0:
                 del self._refs[b]
-                self._free.append(b)
+                self._release(b)
         blocks.clear()
+
+    def _release(self, b: int) -> None:
+        if not self._in_run(b):
+            self._singles[b] = None
+            return
+        k = b // RUN_BLOCKS
+        self._run_free[k] += 1
+        if self._run_free[k] < RUN_BLOCKS:
+            self._singles[b] = None
+            return
+        # The run is whole again: its other blocks leave the singles.
+        for other in range(k * RUN_BLOCKS, (k + 1) * RUN_BLOCKS):
+            self._singles.pop(other, None)
+        self._runs.append(k)
 
 
 @dataclasses.dataclass

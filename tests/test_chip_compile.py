@@ -126,6 +126,26 @@ def test_fused_decode_compiles_at_the_served_cell(one_chip):
              S((lanes, nb), I32), S((lanes,), I32))
 
 
+def test_fused_decode_compiles_at_the_jamba_cell(one_chip):
+    """The call ``jamba2-3b.reasoning-loops`` makes (20 q / 1 kv x 128, so
+    F = 128: a page is 4 KB and a run of 8 one 32 KB copy; 256 lanes,
+    256-block tables, a bf16 pool of 65,537 blocks), beside the Qwen2 cell's
+    above: the page arrays update in place, with no temporary the size of
+    the pool."""
+    S = one_chip
+    nH, nKV, Dh = JCFG.num_heads, JCFG.num_kv_heads, JCFG.head_dim_
+    lanes, nb, nblk = 256, 256, 65_537
+    pool = S((nblk, 16, nKV * Dh), BF16)
+    compiled = jax.jit(pa.paged_decode_attention_fused,
+                       donate_argnums=(5, 6)).lower(
+        S((lanes, 1, nH, Dh), BF16), S((lanes, 1, nKV, Dh), BF16),
+        S((lanes, 1, nKV, Dh), BF16), S((lanes, 1, Dh), F32),
+        S((lanes, 1, Dh), F32), pool, pool, S((lanes, nb), I32),
+        S((lanes,), I32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 @pytest.mark.parametrize("bs,dtype", [(32, BF16), (16, F32)],
                          ids=["bf16-bs32", "f32-bs16"])
 def test_fused_decode_partial_tile_append_compiles(one_chip, bs, dtype):

@@ -377,11 +377,13 @@ def test_alloc_exhaustion_preempts_then_recovers(params):
     eng = _mk_engine(params)
     baseline = eng.allocator.free_blocks
     # Skip the two admission allocs; fire on the first extend and on its
-    # post-release retry so the engine must preempt a victim.
+    # post-release retry so the engine must preempt a victim.  A lane holds
+    # whole runs of 8 blocks (64 tokens here), so its first extend that
+    # takes blocks comes as it crosses into the second run.
     get_injector().arm("alloc_exhaustion", rate=1.0, times=2, after=2)
 
     prompts = [[3, 4, 5, 6, 7, 8], [9, 10, 11, 12, 13, 14]]
-    results = eng.generate(prompts, SamplingParams(max_tokens=12))
+    results = eng.generate(prompts, SamplingParams(max_tokens=64))
 
     assert get_injector().fired("alloc_exhaustion") == 2
     assert eng.preemptions >= 1
